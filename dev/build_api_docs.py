@@ -8,8 +8,8 @@ functions.  `dev/run-tests.sh`-adjacent usage:
 
     python dev/build_api_docs.py        # rewrites docs/API.md
 
-Run under the CPU backend guard (jax.config) so a wedged TPU claim
-cannot hang the docs build.
+Runs on the CPU backend (jax.config): the docs build needs no
+accelerator and must not hold one.
 """
 
 import importlib

@@ -20,6 +20,7 @@ Public API (mirrors the reference's __init__.py exports):
 __version__ = "0.5.0"
 
 import spark_sklearn_tpu.models  # noqa: F401 — registers Tier-A families
+from spark_sklearn_tpu.models.base import NotCompiledError
 from spark_sklearn_tpu.search.grid import GridSearchCV, RandomizedSearchCV
 from spark_sklearn_tpu.search.halving import (
     HalvingGridSearchCV,
@@ -49,6 +50,7 @@ __all__ = [
     "HalvingGridSearchCV",
     "HalvingRandomSearchCV",
     "AdmissionError",
+    "NotCompiledError",
     "SearchCancelledError",
     "SearchExecutor",
     "SearchFuture",

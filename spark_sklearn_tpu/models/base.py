@@ -31,6 +31,17 @@ import numpy as np
 _FAMILIES_BY_CLASSNAME: Dict[str, Any] = {}
 
 
+class NotCompiledError(ValueError):
+    """A family's (or the engine's) own refusal: this estimator
+    configuration, scorer or data mode has no compiled program — "...
+    is not compiled; use backend='host'".  It is raised host-side,
+    from static values, and is the ONLY exception `backend=None` trades
+    for a run on the host tier; under `backend="tpu"` it propagates.
+    Anything else that leaves the compiled path — a jax trace, lowering
+    or compile refusal, a runtime or device error — is a failure and
+    propagates under every backend setting."""
+
+
 def register_family(family, *qualified_names: str):
     """Register a family under fully-qualified estimator class names
     (e.g. "sklearn.linear_model._logistic.LogisticRegression")."""
@@ -216,9 +227,9 @@ def class_weight_multiplier(mask, y_enc, meta, class_weight):
     y1h = jax.nn.one_hot(y_enc, k, dtype=mask.dtype)         # (n, k)
     if isinstance(class_weight, str):
         if class_weight != "balanced":
-            raise ValueError(
-                f"class_weight={class_weight!r} is not compiled; use the "
-                "host backend")
+            raise NotCompiledError(
+                f"class_weight={class_weight!r} is not compiled; use "
+                "backend='host'")
         ind = (mask > 0).astype(mask.dtype)                  # (..., n)
         cnt = ind @ y1h                                      # (..., k)
         n_eff = jnp.sum(ind, axis=-1, keepdims=True)         # (..., 1)
@@ -236,9 +247,9 @@ def class_weight_multiplier(mask, y_enc, meta, class_weight):
             cw[hits[0]] = weight
         arr = jnp.asarray(cw, mask.dtype)
         return jnp.broadcast_to(arr[y_enc], mask.shape)
-    raise ValueError(
-        f"class_weight={class_weight!r} is not compiled; use the host "
-        "backend")
+    raise NotCompiledError(
+        f"class_weight={class_weight!r} is not compiled; use "
+        "backend='host'")
 
 
 def apply_class_weight(mask, y_enc, meta, class_weight):

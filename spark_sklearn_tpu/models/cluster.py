@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_sklearn_tpu.models.base import Family, register_family
+from spark_sklearn_tpu.models.base import (
+    Family, NotCompiledError, register_family)
 
 
 def _sq_dists(X, C):
@@ -83,7 +84,7 @@ class KMeansFamily(Family):
         base_key = jax.random.PRNGKey(0 if seed is None else int(seed))
         init = static.get("init", "k-means++")
         if not isinstance(init, str) or init not in ("k-means++", "random"):
-            raise ValueError(
+            raise NotCompiledError(
                 f"init={init!r} is not compiled; use backend='host'")
         n_init = static.get("n_init", "auto")
         if n_init == "auto":

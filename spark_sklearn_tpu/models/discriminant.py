@@ -30,7 +30,8 @@ import numpy as np
 
 import warnings
 
-from spark_sklearn_tpu.models.base import Family, register_family
+from spark_sklearn_tpu.models.base import (
+    Family, NotCompiledError, register_family)
 from spark_sklearn_tpu.models.naive_bayes import (_class_sums,
                                                   _prep_classifier_data)
 
@@ -42,23 +43,23 @@ class LinearDiscriminantFamily(Family):
     is_classifier = True
     dynamic_params = {"shrinkage": np.float32}
     accepts_sample_weight = False
-    #: sklearn's LDA preserves the user's X dtype to the proba output
-    #: (grid.py's log_loss clip resolves the eps per family)
-    proba_dtype_rule = "input"
+    #: sklearn 1.9's LDA(solver="lsqr") returns float64 probas even for
+    #: a float32 X (grid.py's log_loss clip resolves the eps per family)
+    proba_dtype_rule = "float64"
 
     @classmethod
     def check_static(cls, static):
         solver = static.get("solver", "svd")
         if solver != "lsqr":
-            raise ValueError(
+            raise NotCompiledError(
                 f"solver={solver!r} is not compiled (lsqr only); use "
                 "backend='host'")
         if static.get("shrinkage") == "auto":
-            raise ValueError(
+            raise NotCompiledError(
                 "shrinkage='auto' (Ledoit-Wolf) is not compiled; use "
                 "backend='host'")
         if static.get("covariance_estimator") is not None:
-            raise ValueError(
+            raise NotCompiledError(
                 "covariance_estimator is not compiled; use "
                 "backend='host'")
 

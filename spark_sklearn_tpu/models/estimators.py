@@ -32,6 +32,11 @@ class _TpuEstimatorBase(BaseEstimator):
     one jitted family fit with all-ones weights -> fitted attrs."""
 
     _family = None
+    #: sklearn 1.9's BaseEstimator._validate_params (which the search's
+    #: host-side prevalidation calls on every candidate) reads this
+    #: declaration; the native estimators declare no constraints — the
+    #: compiled solvers accept any finite value
+    _parameter_constraints: dict = {}
 
     def _fit_family(self, X, y, sample_weight=None):
         import jax

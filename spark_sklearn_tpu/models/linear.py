@@ -9,7 +9,9 @@ axis, MXU-friendly dense matmuls.
 
 Numeric conventions follow sklearn so the vendored oracle tests pass:
   - LogisticRegression: minimise sum-logloss + 0.5/C * ||coef||^2 (intercept
-    unpenalised), lbfgs, tol on max|grad|.
+    unpenalised), lbfgs.  sklearn 1.9 hands scipy the MEAN loss, so
+    its `tol` bounds max|grad| of the objective divided by the sum of the
+    sample weights; here that is max|grad| <= tol * sum(weights) (`_sum_tol`).
   - Ridge: weighted normal equations with unpenalised intercept.
   - LinearRegression: lstsq on weighted-centred data.
   - ElasticNet/Lasso: FISTA on 1/(2n) LSQ + alpha*(l1_ratio*L1 + (1-l1_ratio)
@@ -24,18 +26,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_sklearn_tpu.models.base import Family, encode_labels, register_family
+from spark_sklearn_tpu.models.base import (
+    Family, NotCompiledError, encode_labels, register_family)
 from spark_sklearn_tpu.ops.solvers import lbfgs
+
+
+def _sum_tol(tol, train_w):
+    """sklearn's lbfgs `tol` on this module's sum-loss objective: the
+    gradient of sklearn's mean-loss objective is ours divided by the
+    summed sample weights (class weights folded in), so its
+    `gtol=tol` stop is max|grad| <= tol * sum(weights) here.  Without
+    the factor the solver runs ~n_samples times tighter than sklearn
+    and lands on a different (more overfit) model wherever the
+    regularisation is weak."""
+    return tol * jnp.sum(train_w, axis=-1)
 
 
 def _is_bcoo(X) -> bool:
     """True when X is a device BCOO operand (the sparse Tier-A path).
     jnp.matmul/einsum reject BCOO, so the matmul sites below switch to
     the equivalent `@`-operator forms when this holds."""
-    try:
-        from jax.experimental import sparse as jsparse
-    except ImportError:       # pragma: no cover - jax always ships it
-        return False
+    from jax.experimental import sparse as jsparse
     return isinstance(X, jsparse.BCOO)
 
 
@@ -115,12 +126,13 @@ class LogisticRegressionFamily(Family):
                 return jax.tree_util.tree_map(lambda a: a[0], model)
             penalty = "l2"   # elasticnet with l1_ratio == 0
         if penalty not in ("l2", None, "none"):
-            raise ValueError(
-                f"penalty={penalty!r} is not compiled; use the host backend")
+            raise NotCompiledError(
+                f"penalty={penalty!r} is not compiled; use backend='host'")
         from spark_sklearn_tpu.models.base import apply_class_weight
         train_w = apply_class_weight(
             train_w, data["y"], meta, static.get("class_weight"))
         l2 = (0.5 / C) if penalty == "l2" else 0.0
+        tol = _sum_tol(jnp.asarray(tol, X.dtype), train_w)
 
         if k == 2:
             yb = data["y"].astype(X.dtype)
@@ -189,8 +201,8 @@ class LogisticRegressionFamily(Family):
         if penalty == "elasticnet" and not l1_ratio:
             penalty = "l2"   # pure-l2 config: quasi-Newton is ~10x cheaper
         if penalty not in ("l2", "elasticnet", None, "none"):
-            raise ValueError(
-                f"penalty={penalty!r} is not compiled; use the host backend")
+            raise NotCompiledError(
+                f"penalty={penalty!r} is not compiled; use backend='host'")
         from spark_sklearn_tpu.models.base import apply_class_weight
         train_w = apply_class_weight(
             train_w, data["y"], meta, static.get("class_weight"))
@@ -214,7 +226,8 @@ class LogisticRegressionFamily(Family):
                 if sparse_X:
                     Z = Xm @ x[:, :d].T
                 else:
-                    Z = jnp.matmul(Xm, x[:, :d].astype(mm_dtype).T,
+                    Z = jnp.einsum("nd,bd->nb", Xm,
+                                   x[:, :d].astype(mm_dtype),
                                    preferred_element_type=X.dtype)
                 return Z + x[None, :, d] if fit_intercept else Z
 
@@ -229,7 +242,7 @@ class LogisticRegressionFamily(Family):
                 if sparse_X:
                     gW = G.T @ Xm
                 else:
-                    gW = jnp.matmul(G.astype(mm_dtype).T, Xm,
+                    gW = jnp.einsum("nb,nd->bd", G.astype(mm_dtype), Xm,
                                     preferred_element_type=X.dtype)
                 gb = jnp.sum(G, axis=0) if fit_intercept else \
                     jnp.zeros((B,), X.dtype)
@@ -251,7 +264,7 @@ class LogisticRegressionFamily(Family):
                 res = glm_lbfgs_batched(
                     Ax, data_loss, data_grad, AT, reg_loss, reg_grad,
                     jnp.zeros((B, d + 1), X.dtype), max_iter=max_iter,
-                    tol=tol)
+                    tol=_sum_tol(tol, train_w))
                 n_exec = res.n_iter
             W = res.x[:, :d]
             b = res.x[:, d]
@@ -311,7 +324,8 @@ class LogisticRegressionFamily(Family):
         else:
             res = glm_lbfgs_batched(
                 Ax, data_loss, data_grad, AT, reg_loss, reg_grad,
-                jnp.zeros((B, kd + k), X.dtype), max_iter=max_iter, tol=tol)
+                jnp.zeros((B, kd + k), X.dtype), max_iter=max_iter,
+                tol=_sum_tol(tol, train_w))
             n_exec = res.n_iter
         W = res.x[:, :kd].reshape(B, k, d)
         b = res.x[:, kd:]
@@ -441,8 +455,8 @@ def _centered_problem(static, X, y, train_w):
     """Shared OLS/Ridge preamble: positive= guard + optional weighted
     centering.  Returns (Xc, yc, xm, ym)."""
     if static.get("positive", False):
-        raise ValueError(
-            "positive=True is not compiled; use the host backend")
+        raise NotCompiledError(
+            "positive=True is not compiled; use backend='host'")
     if bool(static.get("fit_intercept", True)):
         return _weighted_center(X, y, train_w)
     d = X.shape[1]
@@ -487,8 +501,8 @@ class RidgeFamily(Family):
     @classmethod
     def stream_fit_partial(cls, static, data, fit_w, meta):
         if static.get("positive", False):
-            raise ValueError(
-                "positive=True is not compiled; use the host backend")
+            raise NotCompiledError(
+                "positive=True is not compiled; use backend='host'")
         X, y = data["X"], data["y"]
 
         def one_fold(w):
@@ -502,8 +516,8 @@ class RidgeFamily(Family):
     @classmethod
     def stream_fit_finalize(cls, dynamic, static, stats, meta):
         if static.get("positive", False):
-            raise ValueError(
-                "positive=True is not compiled; use the host backend")
+            raise NotCompiledError(
+                "positive=True is not compiled; use backend='host'")
         G, s, c = stats["G"], stats["s"], stats["c"]
         dt = G.dtype
         d = s.shape[0]

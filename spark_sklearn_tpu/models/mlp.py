@@ -28,7 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_sklearn_tpu.models.base import Family, encode_labels, register_family
+from spark_sklearn_tpu.models.base import (
+    Family, NotCompiledError, encode_labels, register_family)
 
 EPS = 1e-8
 
@@ -67,11 +68,13 @@ def _forward(params, X, act):
 def _check_supported(static):
     solver = static.get("solver", "adam")
     if solver not in ("adam", "sgd"):
-        raise ValueError(f"solver={solver!r} is not compiled")
+        raise NotCompiledError(
+            f"solver={solver!r} is not compiled; use backend='host'")
     if static.get("learning_rate", "constant") not in (
             "constant", "invscaling", "adaptive"):
-        raise ValueError(
-            f"learning_rate={static.get('learning_rate')!r} is not compiled")
+        raise NotCompiledError(
+            f"learning_rate={static.get('learning_rate')!r} is not "
+            "compiled; use backend='host'")
 
 
 class MLPClassifierFamily(Family):

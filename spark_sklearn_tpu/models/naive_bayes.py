@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_sklearn_tpu.models.base import Family, encode_labels, register_family
+from spark_sklearn_tpu.models.base import (
+    Family, NotCompiledError, encode_labels, register_family)
 
 _EPS = 1e-10
 
@@ -103,6 +104,7 @@ class GaussianNBFamily(Family):
     # so the f32 engine mode IS the parity mode — an x64 override was
     # tried and made neg_log_loss diverge (f64 probas clip at 2.2e-16
     # where sklearn's f32 probas clip at 1.19e-7)
+    proba_dtype_rule = "input"
 
     @classmethod
     def observe_candidates(cls, candidates, base_params, meta):
@@ -526,7 +528,7 @@ class CategoricalNBFamily(MultinomialNBFamily):
         super().observe_candidates(candidates, base_params, meta)
         mc = base_params.get("min_categories")
         if any(c.get("min_categories", mc) is not mc for c in candidates):
-            raise ValueError(
+            raise NotCompiledError(
                 "min_categories changes the compiled shapes; grid it "
                 "with backend='host'")
         if mc is not None and "n_categories" in meta:

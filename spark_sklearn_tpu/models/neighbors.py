@@ -40,7 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from spark_sklearn_tpu.models.base import Family, encode_labels, register_family
+from spark_sklearn_tpu.models.base import (
+    Family, NotCompiledError, encode_labels, register_family)
 
 _EPS_DIST = 1e-12
 
@@ -50,15 +51,16 @@ def _check_metric(static):
     p = static.get("p", 2)
     if metric not in ("minkowski", "euclidean") or \
             (metric == "minkowski" and p not in (2, 2.0)):
-        raise ValueError(
+        raise NotCompiledError(
             f"metric={metric!r}/p={p!r} is not compiled (brute euclidean "
             "only); use backend='host'")
     weights = static.get("weights", "uniform")
     if weights not in ("uniform", "distance") and not callable(weights):
-        raise ValueError(f"weights={weights!r} is not compiled")
+        raise NotCompiledError(
+            f"weights={weights!r} is not compiled; use backend='host'")
     if callable(weights):
-        raise ValueError("callable weights are not compiled; use "
-                         "backend='host'")
+        raise NotCompiledError("callable weights are not compiled; use "
+                               "backend='host'")
 
 
 def _sq_dists(X):

@@ -20,6 +20,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from spark_sklearn_tpu.models.base import NotCompiledError
+
 EPS = 1e-12
 
 
@@ -164,8 +166,9 @@ class PCAStep:
         nc = static.get("n_components")
         if nc is None or isinstance(nc, bool) or \
                 not isinstance(nc, (int, np.integer)):
-            raise ValueError(
-                "PCA needs an integer n_components on the compiled path")
+            raise NotCompiledError(
+                "PCA without an integer n_components is not compiled; "
+                "use backend='host'")
         if nc < 0:
             raise ValueError(f"n_components={nc} must be >= 0")
         if n_features is not None and nc > n_features:
@@ -173,7 +176,8 @@ class PCAStep:
                 f"n_components={nc} must be <= n_features={n_features}")
         if static.get("svd_solver", "auto") not in ("auto", "full",
                                                     "covariance_eigh"):
-            raise ValueError("only full-SVD PCA is compiled")
+            raise NotCompiledError(
+                "only full-SVD PCA is compiled; use backend='host'")
 
     @staticmethod
     def fit(static, X, w):

@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_sklearn_tpu.models.base import Family, encode_labels, register_family
+from spark_sklearn_tpu.models.base import (
+    Family, NotCompiledError, encode_labels, register_family)
 
 
 def _pairs(k: int) -> np.ndarray:
@@ -543,7 +544,8 @@ class SVCFamily(Family):
         B = train_w.shape[0]
         kind = static.get("kernel", "rbf")
         if kind == "precomputed":
-            raise ValueError("precomputed kernels: use backend='host'")
+            raise NotCompiledError(
+                "precomputed kernels are not compiled; use backend='host'")
         degree = float(static.get("degree", 3))
         coef0 = float(static.get("coef0", 0.0))
         max_iter = int(static.get("max_iter", -1))

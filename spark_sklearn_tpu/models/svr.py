@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_sklearn_tpu.models.base import Family, register_family
+from spark_sklearn_tpu.models.base import (
+    Family, NotCompiledError, register_family)
 from spark_sklearn_tpu.models.svm import (
     _box_fista,
     _kernel,
@@ -219,7 +220,8 @@ class SVRFamily(Family):
         B = train_w.shape[0]
         kind = static.get("kernel", "rbf")
         if kind == "precomputed":
-            raise ValueError("precomputed kernels: use backend='host'")
+            raise NotCompiledError(
+                "precomputed kernels are not compiled; use backend='host'")
         degree = float(static.get("degree", 3))
         coef0 = float(static.get("coef0", 0.0))
         max_iter = int(static.get("max_iter", -1))
@@ -305,14 +307,15 @@ class SVRFamily(Family):
 
 def _check_linear_svc_static(static):
     if static.get("penalty", "l2") != "l2":
-        raise ValueError("penalty='l1' is not compiled; use backend='host'")
+        raise NotCompiledError(
+            "penalty='l1' is not compiled; use backend='host'")
     if static.get("loss", "squared_hinge") not in (
             "squared_hinge", "hinge"):
-        raise ValueError(
+        raise NotCompiledError(
             f"loss={static.get('loss')!r} is not compiled; use "
             "backend='host'")
     if static.get("multi_class", "ovr") != "ovr":
-        raise ValueError(
+        raise NotCompiledError(
             "multi_class='crammer_singer' is not compiled; use "
             "backend='host'")
 
@@ -562,7 +565,8 @@ class LinearSVRFamily(Family):
         loss = static.get("loss", "epsilon_insensitive")
         if loss not in ("epsilon_insensitive",
                         "squared_epsilon_insensitive"):
-            raise ValueError(f"loss={loss!r} is not compiled")
+            raise NotCompiledError(
+                f"loss={loss!r} is not compiled; use backend='host'")
         X, y = data["X"], data["y"]
         n, d = X.shape
         B = train_w.shape[0]

@@ -77,7 +77,8 @@ def lbfgs(
     """Minimise `fun(x) -> scalar` from flat `x0`.
 
     Matches the role scipy's lbfgs plays for sklearn's LogisticRegression
-    (sum-loss objective, gradient-infinity-norm stopping at `tol`).
+    (sum-loss objective, gradient-infinity-norm stopping at `tol`; the
+    caller converts sklearn's mean-loss `tol` — models/linear._sum_tol).
     """
     m = history
     d = x0.shape[0]

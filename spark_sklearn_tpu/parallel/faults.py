@@ -122,6 +122,12 @@ _TRANSIENT_MARKERS = ("UNAVAILABLE", "ABORTED", "CANCELLED",
                       "DEADLINE_EXCEEDED", "Socket closed",
                       "connection reset", "transient")
 
+#: checked BEFORE the transient markers: a backend that cannot come up
+#: (jax: "Unable to initialize backend 'tpu': UNAVAILABLE: ..." — the
+#: chip belongs to another process) will not come up on a retry either,
+#: so it fails at once instead of sleeping through the back-off budget
+_FATAL_MARKERS = ("Unable to initialize backend", "TPU is already in use")
+
 #: user-extensible classifiers, consulted first: fn(exc) -> class | None
 _CUSTOM_CLASSIFIERS: List[Callable[[BaseException], Optional[str]]] = []
 
@@ -155,12 +161,7 @@ class LaunchTimeoutError(TimeoutError):
     ``mode="heartbeat"`` means a scanned launch's in-flight beats
     (``obs/heartbeat.py``) went silent for ``heartbeat_timeout_s`` —
     the error then names the last scan step that beat, so a postmortem
-    knows WHERE inside the multi-minute launch the device died.  Never
-    silently re-run on the host (a hung device would only hang the
-    host re-run's next compiled search)."""
-
-    #: consumed by grid._dispatch: no compiled->host fallback
-    _sst_no_fallback = True
+    knows WHERE inside the multi-minute launch the device died."""
 
     def __init__(self, key: str, group: int, timeout_s: float,
                  injected: bool = False, mode: str = "wall",
@@ -191,10 +192,6 @@ class SearchDeadlineError(RuntimeError):
     ``partial_results="raise"``.  Under ``"best_effort"`` the deadline
     sheds the remaining candidates to ``error_score`` instead of
     raising this."""
-
-    #: consumed by grid._dispatch: an expired budget on the compiled
-    #: path must not buy a full host re-run of the same search
-    _sst_no_fallback = True
 
     def __init__(self, deadline_s: float, elapsed_s: float,
                  n_remaining: int = 0):
@@ -238,6 +235,8 @@ def classify_error(exc: BaseException) -> str:
     if isinstance(exc, MemoryError):
         return OOM
     msg = f"{type(exc).__name__}: {exc}"
+    if any(m in msg for m in _FATAL_MARKERS):
+        return FATAL
     if any(m in msg for m in _OOM_MARKERS):
         return OOM
     if any(m in msg for m in _TRANSIENT_MARKERS):

@@ -58,17 +58,22 @@ class TpuConfig:
     # inside compiled fits instead of masking it into error_score — the
     # checkify-style sanitizer for our purely-functional programs.
     debug_nans: bool = False
-    # bf16 data matmuls with fp32 accumulation (solver state stays fp32):
-    # the MXU's native precision — typically ~2x on v5e for the GLM hot
-    # path at a small, oracle-tested score tolerance cost.
+    # bf16 data matmuls with fp32 accumulation (solver state stays fp32),
+    # at a small, oracle-tested score tolerance cost.  Not a measured
+    # win: the only chip record (docs/BENCH_TPU_2026-07-29.json, digits,
+    # d=64, before PRs 1-20) has this arm 8.5% SLOWER than f32 — f32
+    # matmuls already take the TPU's default single-pass bf16 precision.
     bf16_matmul: bool = False
     # persistent XLA compilation cache: compiled search programs survive
     # process restarts (jax_compilation_cache_dir), so repeated searches
-    # over the same shapes skip the cold compile entirely.
+    # over the same shapes skip the cold compile entirely.  The directory
+    # is decided in ONE place (parallel/pipeline.py
+    # resolve_compile_cache_dir): a set JAX_COMPILATION_CACHE_DIR wins
+    # over both fields below; with neither, the cache lives at one fixed
+    # git-ignored path inside the checkout.
     compile_cache_dir: Optional[str] = None
     # preferred spelling of compile_cache_dir (kept above for
-    # back-compat); when both are set this one wins.  See
-    # parallel/pipeline.py enable_persistent_cache.
+    # back-compat); when both are set this one wins.
     compilation_cache_dir: Optional[str] = None
     # jax only persists programs whose XLA compile took at least this
     # long (jax_persistent_cache_min_compile_time_secs); 0.0 caches

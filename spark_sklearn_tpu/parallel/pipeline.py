@@ -39,6 +39,7 @@ its absence on a host-bound box — is observable in `search_report`.
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
 import time
 from collections import deque
@@ -71,6 +72,7 @@ __all__ = [
     "enable_persistent_cache",
     "persistent_cache_counts",
     "precompile",
+    "resolve_compile_cache_dir",
 ]
 
 
@@ -91,11 +93,9 @@ def _install_cache_listener() -> None:
     with _LISTENER_LOCK:
         if _LISTENER_INSTALLED:
             return
-        try:
-            from jax._src import monitoring
-        except ImportError:      # jax moved the module: counts stay zero
-            _LISTENER_INSTALLED = True
-            return
+        # no ImportError guard: if jax moves this module the hit/miss
+        # counters must fail loudly, not read a silent zero
+        from jax._src import monitoring
 
         def _on_event(event: str, **kwargs) -> None:
             # jax may fire this from whichever thread compiles (the
@@ -118,34 +118,77 @@ def persistent_cache_counts() -> Dict[str, int]:
     return dict(_CACHE_EVENTS)
 
 
-def enable_persistent_cache(cache_dir: Optional[str],
-                            min_compile_time_s: float = 0.5) -> bool:
-    """Point jax's persistent compilation cache at `cache_dir`.
+#: where the persistent cache lives when neither the environment nor
+#: the TpuConfig names a directory: ONE fixed, git-ignored path inside
+#: the checkout.  The path is part of jax's cache key, so a directory
+#: that moves between runs (a temp name, a pid, a timestamp) never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def resolve_compile_cache_dir(config=None) -> str:
+    """The one place the persistent compilation cache directory is
+    decided.  ``JAX_COMPILATION_CACHE_DIR`` wins whenever it is set —
+    whoever runs the program places the cache, whatever the TpuConfig
+    says; then ``TpuConfig.compilation_cache_dir`` /
+    ``compile_cache_dir``; then :data:`DEFAULT_COMPILE_CACHE_DIR`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    named = config.resolved_cache_dir() if config is not None else None
+    return named or DEFAULT_COMPILE_CACHE_DIR
+
+
+#: the directory this process bound (the first enable_persistent_cache
+#: call decides) and the different directories asked for since
+_BOUND_CACHE_DIR: Optional[str] = None
+_REFUSED_CACHE_DIRS: set = set()
+
+
+def enable_persistent_cache(config=None) -> str:
+    """Bind jax's persistent compilation cache for this PROCESS and
+    return the bound directory.
 
     Amortizes the cold python->jaxpr->HLO->binary walk across processes
-    (bench cold runs, gate re-runs, checkpoint-resume restarts): the
-    first process pays the XLA compile, every later process with the
-    same program shapes reloads the serialized executable.
+    (repeated runs, checkpoint-resume restarts): the first process pays
+    the XLA compile, every later process with the same program shapes
+    reloads the serialized executable.
 
-    Only-if-different semantics: a search that did not ask for a cache
-    never clobbers a user's own `jax_compilation_cache_dir` setting.
-    Returns True when a cache directory is active after the call.
-    """
-    if not cache_dir:
-        # a cache the USER configured directly still deserves hit/miss
-        # accounting in search_report
-        if jax.config.jax_compilation_cache_dir:
-            _install_cache_listener()
-            return True
-        return False
+    The first call decides.  It writes the resolved directory
+    (:func:`resolve_compile_cache_dir`) and the config's min-compile
+    threshold to the live jax config, once; where neither the
+    environment nor the TpuConfig places the cache, a directory the
+    user already set in code (``jax.config.update``) is kept over the
+    default.  jax itself binds its cache at the process's first compile
+    and re-binding is not safe while another thread compiles (one
+    session serves several tenants, each with a compile-ahead thread),
+    so a later call that names another directory changes nothing and
+    says so in the log.  Call this before the process's first compile
+    (a `TpuSession`, or the first search, does) — a process that
+    compiled with no directory set keeps no cache."""
+    global _BOUND_CACHE_DIR
     _install_cache_listener()
-    if jax.config.jax_compilation_cache_dir != cache_dir:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # the threshold rides along only when WE (re)configure the dir —
-        # an unchanged cache never clobbers out-of-band tuning
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_time_s))
-    return True
+    wanted = resolve_compile_cache_dir(config)
+    with _LISTENER_LOCK:
+        if _BOUND_CACHE_DIR is None:
+            preset = jax.config.jax_compilation_cache_dir
+            if wanted == DEFAULT_COMPILE_CACHE_DIR and preset:
+                wanted = preset
+            if preset != wanted:
+                jax.config.update("jax_compilation_cache_dir", wanted)
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs",
+                float(getattr(config, "persistent_cache_min_compile_s",
+                              0.5)))
+            _BOUND_CACHE_DIR = wanted
+        elif wanted not in (_BOUND_CACHE_DIR, DEFAULT_COMPILE_CACHE_DIR) \
+                and wanted not in _REFUSED_CACHE_DIRS:
+            _REFUSED_CACHE_DIRS.add(wanted)
+            _slog.warning(
+                "compile cache is bound to %r for this process; the "
+                "request for %r is ignored", _BOUND_CACHE_DIR, wanted)
+        return _BOUND_CACHE_DIR
 
 
 def precompile(jit_fn, *args):

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 
 from sklearn.base import BaseEstimator, MetaEstimatorMixin, clone, is_classifier
+from sklearn.callback import CallbackSupportMixin
+from sklearn.callback._callback_support import callback_management_context
 from sklearn.model_selection import ParameterGrid, ParameterSampler, check_cv
 from sklearn.utils import Bunch
 from sklearn.utils.metadata_routing import (
@@ -53,7 +55,7 @@ from sklearn.utils.metadata_routing import (
 from sklearn.utils.metaestimators import available_if
 from sklearn.utils.validation import _check_method_params, check_is_fitted
 
-from spark_sklearn_tpu.models.base import resolve_family
+from spark_sklearn_tpu.models.base import NotCompiledError, resolve_family
 from spark_sklearn_tpu.parallel import mesh as mesh_lib
 from spark_sklearn_tpu.parallel import ownership as _ownership
 from spark_sklearn_tpu.parallel.mesh import TpuConfig, build_mesh
@@ -315,34 +317,6 @@ def _search_estimator_has(attr):
         return True
 
     return check
-
-
-try:
-    from sklearn.callback import CallbackSupportMixin
-    from sklearn.callback._callback_support import (
-        callback_management_context)
-except ImportError:
-    # installed sklearn predates (or dropped) the callback module — run
-    # with inert stand-ins so the search works identically minus hooks
-    class _NullCallbackContext:
-        def subcontext(self, *args, **kwargs):
-            return self
-
-        def call_on_fit_task_begin(self, **kwargs):
-            return self
-
-        def call_on_fit_task_end(self, **kwargs):
-            return None
-
-        def propagate_callback_context(self, estimator):
-            return _nullcontext()
-
-    class CallbackSupportMixin:  # type: ignore[no-redef]
-        def _init_callback_context(self, max_subtasks=None):
-            return _NullCallbackContext()
-
-    def callback_management_context(estimator):
-        return _nullcontext()
 
 
 class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
@@ -629,14 +603,14 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             unsupported_compiled = unsupported_compiled | {"sample_weight"}
         if use_compiled and unsupported_compiled:
             if self.backend == "tpu":
-                raise ValueError(
+                raise NotCompiledError(
                     f"fit/score params {sorted(unsupported_compiled)} are "
                     "not supported on the compiled path; use backend='host'")
             use_compiled = False
         if use_compiled:
             try:
                 resolve_scoring(self.scoring, family)
-            except (KeyError, TypeError):
+            except NotCompiledError:
                 if self.backend == "tpu":
                     raise
                 use_compiled = False
@@ -707,28 +681,21 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         family, X_c, y_c, cands, splits_c,
                         fit_weight=fw_c, score_weight=sw_c,
                         eval_ctxs=eval_ctxs)
-                except (KeyboardInterrupt, SystemExit):
-                    # an interactive abort / interpreter shutdown must
-                    # never be traded for a silent host re-run of the
-                    # whole grid (narrowed guard; Exception below never
-                    # caught these, but the contract is now explicit and
-                    # pinned by test)
-                    raise
-                except Exception as exc:  # unsupported static combo etc.
-                    if self.backend == "tpu" or \
-                            getattr(exc, "_sst_no_fallback", False):
-                        # _sst_no_fallback: error_score='raise' with
-                        # invalid candidate params (or a watchdog
-                        # LaunchTimeoutError — a hung device would only
-                        # wedge the host re-run's next compiled search)
-                        # — sklearn raises this exact exception; a host
-                        # re-run would only repeat the failure after
-                        # redundant work
+                except NotCompiledError as exc:
+                    # The ONE exception traded for the host tier: a
+                    # family's (or the engine's) own refusal, raised
+                    # host-side from static values.  Everything else — a
+                    # jax trace / lowering / compile refusal, a runtime
+                    # or device error, an exhausted supervisor, a
+                    # watchdog timeout — propagates exactly as under
+                    # backend="tpu": a silent sklearn re-run would hide
+                    # that the accelerator never produced the scores.
+                    if self.backend == "tpu":
                         raise
                     state["use_compiled"] = False  # fall back ONCE
                     # recorded into the host report's faults block so
-                    # the fallback cause stays observable after the
-                    # compiled registry is replaced
+                    # the refusal stays observable after the compiled
+                    # registry is replaced
                     state["fallback_exc"] = exc
                     warnings.warn(
                         f"compiled search path failed ({exc!r}); falling "
@@ -1006,7 +973,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             # support, which drops zero-weight rows sklearn would count
             if any(v == "balanced" for c in candidates for k, v in c.items()
                    if k == "class_weight" or k.endswith("__class_weight")):
-                raise ValueError(
+                raise NotCompiledError(
                     "class_weight='balanced' with zero-valued sample "
                     "weights is not compiled; use backend='host'")
         out = self._fit_compiled_dispatch(
@@ -1082,11 +1049,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         hasattr(sub, "get_params"):
                     sub._validate_params()
 
-        try:
-            from sklearn.utils._param_validation import (
-                validate_parameter_constraints)
-        except ImportError:            # future sklearn moved it: slow path
-            validate_parameter_constraints = None
+        from sklearn.utils._param_validation import (
+            validate_parameter_constraints)
 
         base = clone(self.estimator)
         base_exc = None
@@ -1123,8 +1087,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             # base_exc disables the fast path entirely: a candidate may
             # OVERRIDE the base's invalid value with a valid one, which
             # only the real clone+set_params+validate can decide
-            fast = validate_parameter_constraints is not None \
-                and base_exc is None and not rewires(params)
+            fast = base_exc is None and not rewires(params)
             if fast and any(k not in deep for k in params):
                 fast = False           # key may be unknown: let set_params
                                        # produce its own (aborting) error
@@ -1154,8 +1117,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
 
         from spark_sklearn_tpu.parallel.pipeline import (
             enable_persistent_cache)
-        enable_persistent_cache(config.resolved_cache_dir(),
-                                config.persistent_cache_min_compile_s)
+        enable_persistent_cache(config)
         # persistent AOT program store: sessionless fits activate it
         # here (a TpuSession already did at construction) — programs
         # resolve from serialized artifacts instead of re-tracing, and
@@ -1228,7 +1190,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
         sparse_op = None
         if data_mode == "sparse" and _scipy_sparse.issparse(X):
             if not getattr(family, "supports_sparse", False):
-                raise ValueError(
+                raise NotCompiledError(
                     f"data_mode='sparse' requires a family with BCOO "
                     f"fit/predict programs; {family.name} has none.  "
                     "Use data_mode='device' (densified upload) or "
@@ -1281,7 +1243,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     meta.get("n_classes", 2) > 2:
                 # sklearn's semantics for these on multiclass (averaging
                 # options, undefined-metric warnings) live on the host path
-                raise ValueError(
+                raise NotCompiledError(
                     f"scoring={self.scoring!r} on multiclass targets is "
                     "not compiled; use backend='host'")
         n_samples = X.shape[0]
@@ -1343,10 +1305,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 self._prevalidate_candidates(candidates)
         if preval_exc is not None and isinstance(self.error_score, str) \
                 and self.error_score == "raise":
-            # marker consumed by _dispatch: re-raise instead of the usual
-            # fall-back-to-host (sklearn raises this exact exception with
-            # no fallback warning and no duplicate host work)
-            preval_exc._sst_no_fallback = True
+            # sklearn raises this exact exception
             raise preval_exc
 
         launch_index = None
@@ -1890,19 +1849,12 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             if fit_failed.all():
                 # sklearn's _warn_or_raise_about_fit_failures raises when
                 # EVERY fit failed, even with a numeric error_score (the
-                # host tier inherits this from sklearn directly).  Only
-                # host-reproducible failures (invalid params caught by
-                # prevalidation) suppress the host fallback: an all-NaN
-                # outcome from the float32 device solvers might still
-                # succeed under sklearn's float64 host fits
-                all_failed = ValueError(
+                # host tier inherits this from sklearn directly)
+                raise ValueError(
                     f"\nAll the {n_cand * n_folds} fits failed.\n"
                     "It is very likely that your model is misconfigured.\n"
                     "You can try to debug the error by setting "
                     "error_score='raise'.")
-                if preval_failed.all():
-                    all_failed._sst_no_fallback = True
-                raise all_failed
             from sklearn.exceptions import FitFailedWarning
             warnings.warn(
                 f"\n{n_bad} fits failed out of a total of "

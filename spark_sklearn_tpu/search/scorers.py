@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_sklearn_tpu.models.base import NotCompiledError
+
 EPS = 1e-12
 
 #: view name -> per-task builder (the generic path; families may batch
@@ -304,10 +306,7 @@ def compiled_name_for_scorer(obj):
     """Map a sklearn make_scorer object with default kwargs to the
     equivalent compiled scorer name, or None when it has no compiled
     twin (custom kwargs, custom callables, pos_label overrides...)."""
-    try:
-        from sklearn.metrics._scorer import _Scorer
-    except ImportError:                                # pragma: no cover
-        return None
+    from sklearn.metrics._scorer import _Scorer
     if not isinstance(obj, _Scorer):
         return None
     if getattr(obj, "_kwargs", None):
@@ -329,7 +328,7 @@ def resolve_scoring(scoring, family):
         return {"score": SCORERS[name]}, "score"
     if isinstance(scoring, str):
         if scoring not in SCORERS:
-            raise KeyError(
+            raise NotCompiledError(
                 f"scoring={scoring!r} has no compiled implementation; "
                 f"available: {sorted(SCORERS)} (or use backend='host')")
         return {"score": SCORERS[scoring]}, "score"
@@ -343,7 +342,7 @@ def resolve_scoring(scoring, family):
         out = {}
         for s in scoring:
             if not isinstance(s, str) or s not in SCORERS:
-                raise KeyError(
+                raise NotCompiledError(
                     f"scoring entry {s!r} not compiled (list scoring takes "
                     "unique metric-name strings); use backend='host'")
             out[s] = SCORERS[s]
@@ -354,10 +353,10 @@ def resolve_scoring(scoring, family):
             if not isinstance(s, str):
                 s = compiled_name_for_scorer(s)
             if s is None or s not in SCORERS:
-                raise KeyError(
+                raise NotCompiledError(
                     f"multimetric entry {name}={scoring[name]!r} not "
                     "compiled; use backend='host'")
             out[name] = SCORERS[s]
         return out, None
-    raise TypeError(f"Unsupported scoring spec for the compiled path: "
-                    f"{scoring!r}; use backend='host'")
+    raise NotCompiledError(
+        f"scoring spec {scoring!r} is not compiled; use backend='host'")

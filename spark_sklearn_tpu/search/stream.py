@@ -49,6 +49,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from spark_sklearn_tpu.models.base import NotCompiledError
 from spark_sklearn_tpu.obs.log import get_logger
 from spark_sklearn_tpu.obs.trace import get_tracer
 
@@ -105,16 +106,16 @@ def resolve_shard_bytes(config) -> int:
 
 
 def check_stream_supported(family, scoring, config) -> None:
-    """Fail fast (clear ValueError, never a silent densified fallback)
+    """Fail fast (clear refusal, never a silent densified fallback)
     when this search cannot run the streaming-fold tier."""
     if not getattr(family, "supports_stream", False):
-        raise ValueError(
+        raise NotCompiledError(
             f"data_mode='stream' requires a family implementing the "
             f"streaming-fold protocol (stream_fit_partial/"
             f"stream_fit_finalize); {family.name} does not.  Use "
             "data_mode='device' or backend='host'.")
     if scoring is not None:
-        raise ValueError(
+        raise NotCompiledError(
             "data_mode='stream' scores through the family's default "
             f"scorer only (accuracy / r2); scoring={scoring!r} is not "
             "streamable.  Use data_mode='device' or backend='host'.")

@@ -140,9 +140,6 @@ class SearchCancelledError(RuntimeError):
     checkpoint journal, so an identically-configured search resumes
     them."""
 
-    #: consumed by grid._dispatch: a cancelled compiled search must
-    #: never be silently re-run on the host tier
-    _sst_no_fallback = True
     #: consumed by faults.LaunchSupervisor: cancellation is an
     #: instruction, not a fault — no retry, no recovery, no journal
     _sst_cancelled = True

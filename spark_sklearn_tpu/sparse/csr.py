@@ -183,28 +183,27 @@ _BCOO_EXPORT_REGISTERED = False
 def register_bcoo_export() -> bool:
     """Teach ``jax.export`` to serialize BCOO-carrying pytrees so the
     ProgramStore can persist sparse Tier-A programs (AOT prewarm).
-    Idempotent; returns False when the running jax cannot register
-    (old jax, or another module already claimed the name) — callers
-    then simply skip the store for sparse programs."""
+    Idempotent (a name another engine in this process already
+    registered counts as success); returns True."""
     global _BCOO_EXPORT_REGISTERED
     if _BCOO_EXPORT_REGISTERED:
         return True
+    import json
+
+    from jax import export as jexport
+    from jax.experimental import sparse as jsparse
+
+    def _ser(aux):
+        d = dict(aux)
+        d["shape"] = [int(s) for s in d["shape"]]
+        return json.dumps(d, sort_keys=True).encode()
+
+    def _de(b):
+        d = json.loads(b.decode())
+        d["shape"] = tuple(d["shape"])
+        return d
+
     try:
-        import json
-
-        from jax import export as jexport
-        from jax.experimental import sparse as jsparse
-
-        def _ser(aux):
-            d = dict(aux)
-            d["shape"] = [int(s) for s in d["shape"]]
-            return json.dumps(d, sort_keys=True).encode()
-
-        def _de(b):
-            d = json.loads(b.decode())
-            d["shape"] = tuple(d["shape"])
-            return d
-
         jexport.register_pytree_node_serialization(
             jsparse.BCOO,
             serialized_name="jax.experimental.sparse.BCOO",
@@ -213,9 +212,6 @@ def register_bcoo_export() -> bool:
     except ValueError:
         # already registered (e.g. a second engine in-process): that is
         # success for our purposes
-        _BCOO_EXPORT_REGISTERED = True
-        return True
-    except (ImportError, AttributeError, TypeError):
-        return False
+        pass
     _BCOO_EXPORT_REGISTERED = True
     return True

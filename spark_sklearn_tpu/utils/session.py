@@ -24,8 +24,10 @@ logger = get_logger(__name__)
 class TpuSession:
     """Holds the mesh + config a process uses for searches and fleets.
 
-    A session with `TpuConfig(compilation_cache_dir=...)` points jax's
-    persistent compilation cache there at construction, so every search
+    A session points jax's persistent compilation cache at the resolved
+    directory (`parallel.pipeline.resolve_compile_cache_dir`:
+    `JAX_COMPILATION_CACHE_DIR`, else `TpuConfig.compilation_cache_dir`,
+    else the fixed in-checkout default) at construction, so every search
     in the process — and every LATER process sharing the directory —
     amortizes the python->HLO->binary walk (the session-level analog of
     a Spark cluster reusing its deployed jars)."""
@@ -44,9 +46,7 @@ class TpuSession:
                 max_events=getattr(self.config, "trace_buffer_size", None))
         with get_tracer().span("session.init", appName=appName):
             self.mesh = build_mesh(self.config)
-            enable_persistent_cache(
-                self.config.resolved_cache_dir(),
-                self.config.persistent_cache_min_compile_s)
+            self.compile_cache_dir = enable_persistent_cache(self.config)
             # size the device data plane (parallel/dataplane.py) now:
             # every search this session runs shares the same resident
             # X/y/mask uploads — the session-lifetime sc.broadcast
@@ -116,7 +116,7 @@ class TpuSession:
         # legacy print contract)
         logger.info("TpuSession %r: mesh=%s, cache_dir=%r", appName,
                     dict(self.mesh.shape),
-                    self.config.resolved_cache_dir(),
+                    self.compile_cache_dir,
                     appName=appName, n_devices=self.mesh.size)
         logger.info(
             "data plane: %s (geometry_mode=%s)",

@@ -132,6 +132,15 @@ class TestTaxonomy:
         assert classify_error(RuntimeError("ABORTED: retry")) == TRANSIENT
         assert classify_error(TypeError("bad arg")) == FATAL
         assert classify_error(ValueError("nope")) == FATAL
+        # a backend that cannot come up (another process holds the
+        # chip) fails at once — UNAVAILABLE in its text must not buy a
+        # back-off retry
+        assert classify_error(RuntimeError(
+            "Unable to initialize backend 'tpu': UNAVAILABLE: TPU "
+            "initialization failed")) == FATAL
+        assert classify_error(RuntimeError(
+            "ABORTED: The TPU is already in use by process with pid "
+            "1234")) == FATAL
 
     def test_injected_and_timeout(self):
         assert classify_error(InjectedFault("transient", "x")) == TRANSIENT
@@ -140,8 +149,6 @@ class TestTaxonomy:
         assert classify_error(err) == HUNG
         assert isinstance(err, TimeoutError)
         assert "0:0:8" in str(err) and "compile group 0" in str(err)
-        # no silent host re-run for a hung device
-        assert err._sst_no_fallback
 
     def test_custom_classifier_extension(self):
         class WeirdBackendError(Exception):
@@ -280,17 +287,80 @@ class TestInjectionRecovery:
         with pytest.raises(InjectedFault):
             _fit(X, y, config=cfg)
 
-    def test_fatal_falls_back_to_host_and_records_cause(self):
-        """backend=None keeps today's compiled->host fallback for fatal
-        errors; the host report's faults block names the cause."""
+    def test_device_failure_propagates_without_host_rerun(self):
+        """backend=None is no softer than backend="tpu" for a compiler,
+        runtime or device failure: it propagates, it is never traded
+        for a silent sklearn re-run of the whole grid."""
         X, y = _data()
         cfg = sst.TpuConfig(fault_plan="fatal@1")
         with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            gs = _fit(X, y, config=cfg, backend=None)
+            warnings.simplefilter("error")     # no fallback warning
+            with pytest.raises(InjectedFault):
+                _fit(X, y, config=cfg, backend=None)
+
+    @pytest.mark.parametrize("exc_type", [NotImplementedError, TypeError,
+                                          ValueError])
+    def test_lowering_refusal_propagates(self, monkeypatch, exc_type):
+        """The host fallback is an allow-list of ONE type
+        (NotCompiledError).  What jax raises when a program cannot be
+        lowered for the platform — before anything has launched — is a
+        NotImplementedError / TypeError / ValueError, and it must not
+        be traded for a host run under the default backend."""
+        from jax._src.interpreters import mlir
+        from sklearn.linear_model import LogisticRegression
+
+        def refuse(*a, **kw):
+            raise exc_type("MLIR translation rule for primitive 'foo' "
+                           "not found for platform tpu")
+
+        monkeypatch.setattr(mlir, "lower_jaxpr_to_module", refuse)
+        X, y = _data()
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")     # no fallback warning
+            with pytest.raises(exc_type, match="not found for platform"):
+                # max_iter no other test uses: a program that must lower
+                sst.GridSearchCV(
+                    LogisticRegression(max_iter=7), {"C": [0.3, 3.0]},
+                    cv=2, refit=False).fit(X, y)
+
+    def test_error_after_device_work_propagates(self, monkeypatch):
+        """A failure of the compiled tier after its launches completed
+        propagates under backend=None like any other non-refusal."""
+        from spark_sklearn_tpu.search import grid as grid_mod
+
+        X, y = _data()
+        real = grid_mod.BaseSearchTPU._run_groups
+
+        def run_then_fail(self, **kw):
+            real(self, **kw)
+            raise ValueError("late engine failure")
+
+        monkeypatch.setattr(grid_mod.BaseSearchTPU, "_run_groups",
+                            run_then_fail)
+        with pytest.raises(ValueError, match="late engine failure"):
+            _fit(X, y, backend=None)
+
+    def test_family_refusal_still_falls_back_and_records_cause(self):
+        """A family's own refusal (NotCompiledError) is the one thing
+        backend=None answers with the host tier; the host report's
+        faults block names the cause.  backend="tpu" raises it."""
+        from sklearn.discriminant_analysis import (
+            LinearDiscriminantAnalysis)
+        rng = np.random.RandomState(0)
+        X = rng.randn(90, 4).astype(np.float32)
+        y = np.repeat(np.arange(3), 30)
+        with pytest.warns(UserWarning, match="falling back"):
+            gs = sst.GridSearchCV(
+                LinearDiscriminantAnalysis(solver="svd"),
+                {"tol": [1e-4, 1e-3]}, cv=2, refit=False).fit(X, y)
         assert gs.search_report["backend"] == "host"
-        assert "InjectedFault" in \
+        assert "not compiled" in \
             gs.search_report["faults"]["fallback_exception"]
+        with pytest.raises(sst.NotCompiledError, match="not compiled"):
+            sst.GridSearchCV(
+                LinearDiscriminantAnalysis(solver="svd"),
+                {"tol": [1e-4, 1e-3]}, cv=2, refit=False,
+                backend="tpu").fit(X, y)
 
     def test_clean_run_reports_zeroed_faults(self, baseline):
         f = baseline.search_report["faults"]

@@ -207,6 +207,7 @@ gs = sst.GridSearchCV(LogisticRegression(max_iter=3), {"C": [0.5, 2.0]},
 gs.fit(X, y)
 pl = dict(gs.search_report["pipeline"])
 pl.pop("launches", None)
+pl["jax_cache_dir"] = jax.config.jax_compilation_cache_dir
 print(json.dumps(pl))
 """
 
@@ -217,16 +218,90 @@ class TestPersistentCache:
         must record persistent-cache hits — the cross-process compile
         amortization the pipeline's cold path is built on."""
         outs = []
+        # the TpuConfig places the cache only where the environment does
+        # not (conftest sets the variable for the suite's own process)
+        env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
         for _ in range(2):
             proc = subprocess.run(
                 [sys.executable, "-c", _CACHE_PROC, str(tmp_path)],
                 capture_output=True, text=True, timeout=300,
-                env={**os.environ, "JAX_PLATFORMS": "cpu"})
+                env=env)
             assert proc.returncode == 0, proc.stderr[-2000:]
             outs.append(json.loads(proc.stdout.strip().splitlines()[-1]))
         assert outs[1]["persistent_cache_hits"] > 0, outs
         # and the first process genuinely compiled (wrote the cache)
         assert outs[0]["persistent_cache_misses"] > 0, outs
+        assert outs[0]["jax_cache_dir"] == str(tmp_path)
+
+    def test_environment_places_the_cache(self, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR wins over a TpuConfig that names
+        another directory: after the fit jax's cache points at the
+        environment's path, and nothing lands in the config's."""
+        env_dir, cfg_dir = tmp_path / "env", tmp_path / "cfg"
+        proc = subprocess.run(
+            [sys.executable, "-c", _CACHE_PROC, str(cfg_dir)],
+            capture_output=True, text=True, timeout=300,
+            env={**os.environ, "JAX_PLATFORMS": "cpu",
+                 "JAX_COMPILATION_CACHE_DIR": str(env_dir)})
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        out = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert out["jax_cache_dir"] == str(env_dir)
+        assert out["persistent_cache_misses"] > 0, out
+        assert os.listdir(env_dir) and not cfg_dir.exists()
+
+    def test_first_call_binds_the_process(self, tmp_path):
+        """The cache directory is bound once per process: a later
+        request for another directory changes nothing (jax's binding is
+        never reset under a tenant that may be compiling), and a
+        directory the user set in code is kept over the default."""
+        script = (
+            "import json, sys, jax\n"
+            "jax.config.update('jax_platforms', 'cpu')\n"
+            "import spark_sklearn_tpu as sst\n"
+            "from spark_sklearn_tpu.parallel.pipeline import ("
+            "enable_persistent_cache)\n"
+            "a, b = sys.argv[1:3]\n"
+            "if sys.argv[3] == 'preset':\n"
+            "    jax.config.update('jax_compilation_cache_dir', a)\n"
+            "    got = [enable_persistent_cache(sst.TpuConfig())]\n"
+            "else:\n"
+            "    got = [enable_persistent_cache(sst.TpuConfig("
+            "compilation_cache_dir=d)) for d in (a, b)]\n"
+            "got.append(jax.config.jax_compilation_cache_dir)\n"
+            "print(json.dumps(got))\n")
+        env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        a, b = str(tmp_path / "a"), str(tmp_path / "b")
+        for mode, n in (("named", 3), ("preset", 2)):
+            proc = subprocess.run(
+                [sys.executable, "-c", script, a, b, mode],
+                capture_output=True, text=True, timeout=300, env=env)
+            assert proc.returncode == 0, proc.stderr[-2000:]
+            got = json.loads(proc.stdout.strip().splitlines()[-1])
+            assert got == [a] * n, (mode, got)
+
+    def test_resolver_order_and_fixed_default(self, monkeypatch):
+        from spark_sklearn_tpu.parallel import pipeline as pl
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        # neither named: ONE fixed path inside the checkout — no temp
+        # name, pid or time in it, identical on every call
+        default = pl.resolve_compile_cache_dir(sst.TpuConfig())
+        assert default == os.path.join(repo, ".jax_cache")
+        assert default == pl.resolve_compile_cache_dir() \
+            == pl.DEFAULT_COMPILE_CACHE_DIR
+        # the config names one (preferred spelling wins over the alias)
+        assert pl.resolve_compile_cache_dir(
+            sst.TpuConfig(compile_cache_dir="/a")) == "/a"
+        assert pl.resolve_compile_cache_dir(sst.TpuConfig(
+            compile_cache_dir="/a", compilation_cache_dir="/b")) == "/b"
+        # the environment wins over everything
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+        assert pl.resolve_compile_cache_dir(sst.TpuConfig(
+            compile_cache_dir="/a", compilation_cache_dir="/b")) == "/x"
+        assert pl.resolve_compile_cache_dir() == "/x"
 
 
 class TestChunkPipelineUnit:

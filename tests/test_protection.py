@@ -124,7 +124,6 @@ class TestDeadline:
             logreg_search(cfg).fit(X, y)
         assert ei.value.deadline_s == 1e-9
         assert ei.value.n_remaining > 0
-        assert getattr(ei.value, "_sst_no_fallback") is True
 
     def test_best_effort_sheds_to_error_score(self):
         cfg = sst.TpuConfig(search_deadline_s=1e-9,

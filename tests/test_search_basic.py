@@ -460,3 +460,41 @@ class TestL1Logistic:
         nz_l1 = int(np.sum(np.abs(np.asarray(l1["coef"][0])) > 1e-6))
         nz_l2 = int(np.sum(np.abs(np.asarray(l2["coef"][0])) > 1e-6))
         assert nz_l1 < nz_l2
+
+    def test_lbfgs_stops_by_sklearns_rule(self, digits):
+        """sklearn hands scipy the MEAN loss, so its `tol` bounds the
+        gradient of the sum-loss objective divided by the summed sample
+        weights.  Held without that factor the solver runs ~n_samples
+        times tighter than sklearn, burns max_iter, and lands on a more
+        overfit model wherever regularisation is weak (C = 1000 on
+        digits: 1e-2 off sklearn's mean_test_score)."""
+        import warnings
+
+        import jax.numpy as jnp
+        from spark_sklearn_tpu.models.linear import LogisticRegressionFamily
+        X, y = digits
+        tr = np.arange(len(y)) % 5 != 0
+        data, meta = LogisticRegressionFamily.prepare_data(X, y)
+        dd = {k: jnp.asarray(v) for k, v in data.items()}
+        C, tol, max_iter = 1000.0, 1e-4, 1000
+        m = LogisticRegressionFamily.fit_task_batched(
+            {"C": jnp.asarray([C], jnp.float32)},
+            {"max_iter": max_iter, "tol": tol}, dd,
+            jnp.asarray(tr[None, :], jnp.float32), meta)
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            sk = SkLogReg(C=C, tol=tol, max_iter=max_iter).fit(X[tr], y[tr])
+        n_sk, n_ours = int(sk.n_iter_[0]), int(m["n_iter"][0])
+        assert n_sk < max_iter and n_ours < max_iter
+        assert 0.5 * n_sk <= n_ours <= 2 * n_sk, (n_ours, n_sk)
+        # stopped inside sklearn's ball, not n_samples times deeper
+        W = np.asarray(m["coef"][0], np.float64)
+        b = np.asarray(m["intercept"][0], np.float64)
+        Z = X[tr] @ W.T + b
+        P = np.exp(Z - Z.max(1, keepdims=True))
+        P /= P.sum(1, keepdims=True)
+        R = P - np.eye(10)[y[tr]]
+        n = int(tr.sum())
+        g = max(np.abs(R.T @ X[tr] / n + W / (C * n)).max(),
+                np.abs(R.mean(0)).max())
+        assert tol / 10 < g <= 1.5 * tol, g

@@ -563,9 +563,8 @@ class TestCancellation:
         np.testing.assert_array_equal(scores(resumed), scores(ref))
         assert resumed.search_report["n_chunks_resumed"] > 0
 
-    def test_cancelled_error_is_no_fallback_no_retry(self):
+    def test_cancelled_error_is_no_retry(self):
         exc = SearchCancelledError("x")
-        assert getattr(exc, "_sst_no_fallback") is True
         assert getattr(exc, "_sst_cancelled") is True
         from spark_sklearn_tpu.parallel.faults import LaunchSupervisor
         sup = LaunchSupervisor(sst.TpuConfig(retry_backoff_s=0.0))

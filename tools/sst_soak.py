@@ -46,6 +46,11 @@ the recovered ``cv_results_`` is bit-exact (``np.array_equal``)
 against the uncrashed baseline, the crash-marker flight bundle
 landed, and the journal owes nothing afterwards.
 
+The crash drill's victim child is CPU-pinned by design (the harness
+process and its child cannot both hold one chip — a chip belongs to one
+process at a time): the drill checks journal recovery, never the
+accelerator, and nothing it prints is a chip result.
+
 Exits nonzero when any assertion fails; ``--json`` emits the full
 per-search ledger for CI artifacts.
 """
