@@ -15,12 +15,22 @@ Design constraints (ISSUE 2 tentpole):
   - **bounded**: events land in a ``deque(maxlen=...)`` ring buffer
     (default 65536); a pathological span storm evicts the oldest spans
     instead of growing without bound;
-  - **overhead budget**: tracing OFF costs one attribute read per
-    instrumentation site (the shared no-op span is returned before any
-    allocation) and must be bit-exact with uninstrumented behavior;
-    tracing ON is budgeted at **<2% of search wall** — spans are
-    per-launch/per-phase (tens per search), never per-sample.  Both
-    sides are enforced by ``tests/test_obs.py``.
+  - **overhead budget**: tracing OFF costs one attribute read and one
+    ``TraceAnnotation.is_enabled()`` check per instrumentation site
+    (the shared no-op span is returned before any allocation) and must
+    be bit-exact with uninstrumented behavior; tracing ON is budgeted
+    at **<2% of search wall** — spans are per-launch/per-phase (tens
+    per search), never per-sample.  Both sides are enforced by
+    ``tests/test_obs.py``;
+  - **one clock with the device**: while a ``jax.profiler`` trace is
+    being recorded (a user's ``TpuConfig(profile_dir=...)``, a
+    benchmark's ``start_trace``), every span the vocabulary marks
+    ``mirror`` is also written into that trace as the host event
+    ``sst.<name>`` (a ``jax.profiler.TraceAnnotation``), whether or not
+    this tracer is recording.  The profiler puts it on the axis of the
+    device operations, so an idle gap of the device can be named by the
+    span that covers it with no clock arithmetic.  The span's
+    attributes and the search's number ride along as the event's stats.
 
 Enablement: ``TpuConfig(trace=...)`` per search (``True`` records;
 a string records AND exports a Chrome trace there after ``fit``), or
@@ -31,15 +41,21 @@ record, any other value is treated as an export path).
 from __future__ import annotations
 
 import contextlib
+import itertools
 import os
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
+from spark_sklearn_tpu.obs.spans import MIRRORED_SPANS
+
 __all__ = [
     "Tracer",
     "current_correlation",
+    "current_search",
     "get_tracer",
     "search_tracing",
     "set_correlation",
@@ -61,16 +77,30 @@ DEFAULT_BUFFER_SIZE = 65536
 _CORR = threading.local()
 
 
-def set_correlation(attrs: Optional[Dict[str, Any]]) -> None:
+#: per-process search numbers: ``search_tracing`` draws one per fit
+_SEARCH_NUMBERS = itertools.count(1)
+
+
+def set_correlation(attrs: Optional[Dict[str, Any]],
+                    search: Optional[int] = None) -> None:
     """Bind (or clear, with None) the calling thread's correlation
-    attributes.  Explicit span attributes win over correlation keys on
-    collision."""
+    attributes, and the number of the search the thread works for.
+    Explicit span attributes win over correlation keys on collision.
+    The search number goes on mirrored profiler annotations only, so
+    an untenanted fit's in-memory events stay byte-identical."""
     _CORR.attrs = dict(attrs) if attrs else None
+    _CORR.search = search
 
 
 def current_correlation() -> Optional[Dict[str, Any]]:
     """The calling thread's correlation attrs, or None."""
     return getattr(_CORR, "attrs", None)
+
+
+def current_search() -> Optional[int]:
+    """The number of the search the calling thread works for (what
+    worker threads hand to :func:`set_correlation`), or None."""
+    return getattr(_CORR, "search", None)
 
 
 def _stamp(attrs: Dict[str, Any]) -> Dict[str, Any]:
@@ -109,26 +139,54 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _Mirror(TraceAnnotation):
+    """A span written into the live ``jax.profiler`` trace."""
+
+    __slots__ = ()
+
+    def set(self, **attrs):
+        self.set_metadata(**attrs)
+        return self
+
+
+def _mirror(name: str, attrs: Dict[str, Any]) -> Optional[_Mirror]:
+    """The profiler annotation of a mirrored span while a profiler
+    session is live, else None."""
+    if name not in MIRRORED_SPANS or not TraceAnnotation.is_enabled():
+        return None
+    search = current_search()
+    if search is not None:
+        attrs = {"search": search, **attrs}
+    return _Mirror("sst." + name, **attrs)
+
+
 class _Span:
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
         self._t0 = 0.0
+        self._ann = _mirror(name, attrs)
 
     def set(self, **attrs):
         """Attach attributes after the span opened (e.g. results)."""
         self._attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set(**attrs)
         return self
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         th = threading.current_thread()
         # deque.append is atomic under the GIL: no lock on the hot path
         self._tracer._events.append(
@@ -173,7 +231,7 @@ class Tracer:
     def span(self, name: str, **attrs):
         """Context manager timing a block on the current thread."""
         if not self._enabled:
-            return _NULL_SPAN
+            return _mirror(name, attrs) or _NULL_SPAN
         return _Span(self, name, attrs)
 
     def instant(self, name: str, **attrs) -> None:
@@ -265,9 +323,12 @@ def search_tracing(config=None):
     if we_enabled:
         tracer.clear()
         tracer.enable(max_events=getattr(config, "trace_buffer_size", None))
+    outer_search = current_search()
+    _CORR.search = next(_SEARCH_NUMBERS)
     try:
         yield tracer
     finally:
+        _CORR.search = outer_search
         if path and (tracer.enabled or we_enabled):
             from spark_sklearn_tpu.obs.export import export_chrome_trace
             try:

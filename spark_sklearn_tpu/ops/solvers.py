@@ -199,15 +199,25 @@ def glm_lbfgs_batched(
     eps = jnp.finfo(dtype).eps
     tol = jnp.broadcast_to(jnp.asarray(tol, dtype), (B,))
 
+    # the phases carry jax.named_scope names (obs/spans.py, kind
+    # "scope"): debug metadata only — the compiled instructions are the
+    # same with and without them (tests/test_scopes_tpu_compile.py) —
+    # by which a profiler trace says which phase a device op belongs to
     def full_grad(x, Z):
-        return AT(data_grad(Z)) + reg_grad(x)
+        with jax.named_scope("glm_lbfgs.gradient"):
+            dZ = data_grad(Z)
+        with jax.named_scope("glm_lbfgs.backward"):
+            g_data = AT(dZ)
+        with jax.named_scope("glm_lbfgs.gradient"):
+            return g_data + reg_grad(x)
 
     def full_f(x, Z):
         return data_loss(Z) + reg_loss(x)
 
-    Z0 = Ax(x0)
-    f0 = full_f(x0, Z0)
-    g0 = full_grad(x0, Z0)
+    with jax.named_scope("glm_lbfgs.init"):
+        Z0 = Ax(x0)
+        f0 = full_f(x0, Z0)
+        g0 = full_grad(x0, Z0)
 
     state = dict(
         x=x0, Z=Z0, f=f0, g=g0,
@@ -229,54 +239,55 @@ def glm_lbfgs_batched(
 
     def body(st):
         x, Z, f, g, it = st["x"], st["Z"], st["f"], st["g"], st["it"]
-        n_hist = jnp.minimum(it, m)
+        with jax.named_scope("glm_lbfgs.direction"):
+            n_hist = jnp.minimum(it, m)
 
-        def bwd(i, carry):
-            q, alpha = carry
-            idx = jnp.mod(it - 1 - i, m)
-            s_i = lax.dynamic_index_in_dim(st["s_mem"], idx, 0, False)
-            y_i = lax.dynamic_index_in_dim(st["y_mem"], idx, 0, False)
-            rho_i = lax.dynamic_index_in_dim(st["rho"], idx, 0, False)
-            a = jnp.where(i < n_hist,
-                          rho_i * jnp.sum(s_i * q, axis=1), 0.0)
-            q = q - a[:, None] * y_i
-            return q, alpha.at[i].set(a)
+            def bwd(i, carry):
+                q, alpha = carry
+                idx = jnp.mod(it - 1 - i, m)
+                s_i = lax.dynamic_index_in_dim(st["s_mem"], idx, 0, False)
+                y_i = lax.dynamic_index_in_dim(st["y_mem"], idx, 0, False)
+                rho_i = lax.dynamic_index_in_dim(st["rho"], idx, 0, False)
+                a = jnp.where(i < n_hist,
+                              rho_i * jnp.sum(s_i * q, axis=1), 0.0)
+                q = q - a[:, None] * y_i
+                return q, alpha.at[i].set(a)
 
-        q, alpha_rec = lax.fori_loop(
-            0, m, bwd, (g, jnp.zeros((m, B), dtype)))
-        r = st["gamma"][:, None] * q
+            q, alpha_rec = lax.fori_loop(
+                0, m, bwd, (g, jnp.zeros((m, B), dtype)))
+            r = st["gamma"][:, None] * q
 
-        def fwd(i, r):
-            j = m - 1 - i
-            idx = jnp.mod(it - 1 - j, m)
-            s_i = lax.dynamic_index_in_dim(st["s_mem"], idx, 0, False)
-            y_i = lax.dynamic_index_in_dim(st["y_mem"], idx, 0, False)
-            rho_i = lax.dynamic_index_in_dim(st["rho"], idx, 0, False)
-            b = rho_i * jnp.sum(y_i * r, axis=1)
-            corr = (alpha_rec[j] - b)[:, None] * s_i
-            return r + jnp.where(j < n_hist, 1.0, 0.0) * corr
+            def fwd(i, r):
+                j = m - 1 - i
+                idx = jnp.mod(it - 1 - j, m)
+                s_i = lax.dynamic_index_in_dim(st["s_mem"], idx, 0, False)
+                y_i = lax.dynamic_index_in_dim(st["y_mem"], idx, 0, False)
+                rho_i = lax.dynamic_index_in_dim(st["rho"], idx, 0, False)
+                b = rho_i * jnp.sum(y_i * r, axis=1)
+                corr = (alpha_rec[j] - b)[:, None] * s_i
+                return r + jnp.where(j < n_hist, 1.0, 0.0) * corr
 
-        r = lax.fori_loop(0, m, fwd, r)
-        p = -r
+            r = lax.fori_loop(0, m, fwd, r)
+            p = -r
 
-        dginit = jnp.sum(g * p, axis=1)
-        bad = dginit >= 0
-        p = jnp.where(bad[:, None], -g, p)
-        dginit = jnp.where(bad, -jnp.sum(g * g, axis=1), dginit)
-        # a lane whose direction went non-finite (overflowed gradient or
-        # history) is frozen this iteration: p=0 keeps x/Z exact under
-        # x + alpha*p, where alpha*non-finite would be NaN and poison the
-        # state (the pre-step-masking code preserved the last finite
-        # iterate with where()-guards; this keeps that guarantee)
-        lane_bad = jnp.logical_not(jnp.logical_and(
-            jnp.all(jnp.isfinite(p), axis=1), jnp.isfinite(dginit)))
-        p = jnp.where(lane_bad[:, None], 0.0, p)
-        dginit = jnp.where(lane_bad, 0.0, dginit)
+            dginit = jnp.sum(g * p, axis=1)
+            bad = dginit >= 0
+            p = jnp.where(bad[:, None], -g, p)
+            dginit = jnp.where(bad, -jnp.sum(g * g, axis=1), dginit)
+            # a lane whose direction went non-finite (overflowed gradient or
+            # history) is frozen this iteration: p=0 keeps x/Z exact under
+            # x + alpha*p, where alpha*non-finite would be NaN and poison the
+            # state (the pre-step-masking code preserved the last finite
+            # iterate with where()-guards; this keeps that guarantee)
+            lane_bad = jnp.logical_not(jnp.logical_and(
+                jnp.all(jnp.isfinite(p), axis=1), jnp.isfinite(dginit)))
+            p = jnp.where(lane_bad[:, None], 0.0, p)
+            dginit = jnp.where(lane_bad, 0.0, dginit)
 
-        a0 = jnp.where(
-            it == 0,
-            jnp.minimum(jnp.ones((B,), dtype), 1.0 / (gnorm(g) + eps)),
-            jnp.ones((B,), dtype))
+            a0 = jnp.where(
+                it == 0,
+                jnp.minimum(jnp.ones((B,), dtype), 1.0 / (gnorm(g) + eps)),
+                jnp.ones((B,), dtype))
 
         # --- matmul-free, single-pass backtracking line search ------------
         # Z moves linearly along p, so a trial is elementwise on
@@ -288,68 +299,72 @@ def glm_lbfgs_batched(
         # candidate steps in one fused pass: vmap over the trial axis
         # turns the halvings into register-level compute over a single
         # read of (Z, Zp), then each lane picks its largest passing step.
-        Zp = Ax(p)                                   # the ONE forward matmul
+        with jax.named_scope("glm_lbfgs.forward"):
+            Zp = Ax(p)                           # the ONE forward matmul
 
-        def eval_trial(a):
-            Zt = Z + _bcast(a, Z) * Zp
-            return data_loss(Zt) + reg_loss(x + a[:, None] * p)
+        with jax.named_scope("glm_lbfgs.linesearch"):
+            def eval_trial(a):
+                Zt = Z + _bcast(a, Z) * Zp
+                return data_loss(Zt) + reg_loss(x + a[:, None] * p)
 
-        halvings = 0.5 ** jnp.arange(ls_trials, dtype=dtype)
-        alphas = a0[None, :] * halvings[:, None]            # (T, B)
-        losses = jax.vmap(eval_trial)(alphas)               # (T, B)
-        armijo = losses <= f[None, :] + c1 * alphas * dginit[None, :]
-        # first (largest-step) passing trial per lane; no trial passed ->
-        # take the last (smallest) step rather than stall
-        first_ok = jnp.argmax(armijo, axis=0)               # (B,)
-        found = jnp.any(armijo, axis=0)
-        pick = jnp.where(found, first_ok, ls_trials - 1)
-        alpha = jnp.take_along_axis(alphas, pick[None, :], axis=0)[0]
-        f_pick = jnp.take_along_axis(losses, pick[None, :], axis=0)[0]
+            halvings = 0.5 ** jnp.arange(ls_trials, dtype=dtype)
+            alphas = a0[None, :] * halvings[:, None]            # (T, B)
+            losses = jax.vmap(eval_trial)(alphas)               # (T, B)
+            armijo = losses <= f[None, :] + c1 * alphas * dginit[None, :]
+            # first (largest-step) passing trial per lane; no trial passed ->
+            # take the last (smallest) step rather than stall
+            first_ok = jnp.argmax(armijo, axis=0)               # (B,)
+            found = jnp.any(armijo, axis=0)
+            pick = jnp.where(found, first_ok, ls_trials - 1)
+            alpha = jnp.take_along_axis(alphas, pick[None, :], axis=0)[0]
+            f_pick = jnp.take_along_axis(losses, pick[None, :], axis=0)[0]
 
         # mask the STEP, not the state: dead lanes (done, or a non-finite
         # trial loss) take alpha=0, so x_new == x and Z_new == Z exactly
         # and g_new recomputes to the same value — no Z-sized select
         # passes (profiled at ~4ms/iteration of pure bandwidth)
-        live = jnp.logical_and(jnp.isfinite(f_pick),
-                               jnp.logical_not(st["done"]))
-        alpha = jnp.where(live, alpha, 0.0)
-        x_new = x + alpha[:, None] * p
-        Z_new = Z + _bcast(alpha, Z) * Zp
-        # the picked trial's loss IS full_f(x_new, Z_new): reuse, no pass
-        f_new = jnp.where(live, f_pick, f)
+        with jax.named_scope("glm_lbfgs.step"):
+            live = jnp.logical_and(jnp.isfinite(f_pick),
+                                   jnp.logical_not(st["done"]))
+            alpha = jnp.where(live, alpha, 0.0)
+            x_new = x + alpha[:, None] * p
+            Z_new = Z + _bcast(alpha, Z) * Zp
+            # the picked trial's loss IS full_f(x_new, Z_new): reuse, no pass
+            f_new = jnp.where(live, f_pick, f)
         g_new = full_grad(x_new, Z_new)              # the ONE backward matmul
 
-        s = x_new - x
-        yv = g_new - g
-        sy = jnp.sum(s * yv, axis=1)
-        update = jnp.logical_and(sy > 1e-10, live)
-        slot = jnp.mod(it, m)
-        s_mem = lax.dynamic_update_index_in_dim(
-            st["s_mem"], jnp.where(update[:, None], s, 0.0), slot, 0)
-        y_mem = lax.dynamic_update_index_in_dim(
-            st["y_mem"], jnp.where(update[:, None], yv, 0.0), slot, 0)
-        rho = lax.dynamic_update_index_in_dim(
-            st["rho"],
-            jnp.where(update, 1.0 / jnp.where(sy > 1e-10, sy, 1.0), 0.0),
-            slot, 0)
-        gamma = jnp.where(update,
-                          sy / (jnp.sum(yv * yv, axis=1) + eps),
-                          st["gamma"])
-        # float32 stall detector: the sum-loss gradient has a rounding
-        # floor that often sits ABOVE tol (n terms x eps32), so the tol
-        # exit alone can be unreachable and every lane burns max_iter.
-        # A lane whose relative objective improvement stays below ~eps32
-        # for 3 consecutive iterations has hit that floor — its iterate
-        # is pinned by rounding, and the remaining lockstep iterations
-        # are pure waste.  (Safe for the strongly-convex GLM objectives
-        # this solver serves: genuine progress never hides behind
-        # consecutive sub-eps steps.)
-        rel_impr = (f - f_new) / jnp.maximum(jnp.abs(f), eps)
-        stall = jnp.where(jnp.logical_and(live, rel_impr <= eps),
-                          st["stall"] + 1, 0)
-        done = jnp.logical_or(
-            st["done"],
-            jnp.logical_or(gnorm(g_new) <= tol, stall >= 3))
+        with jax.named_scope("glm_lbfgs.history"):
+            s = x_new - x
+            yv = g_new - g
+            sy = jnp.sum(s * yv, axis=1)
+            update = jnp.logical_and(sy > 1e-10, live)
+            slot = jnp.mod(it, m)
+            s_mem = lax.dynamic_update_index_in_dim(
+                st["s_mem"], jnp.where(update[:, None], s, 0.0), slot, 0)
+            y_mem = lax.dynamic_update_index_in_dim(
+                st["y_mem"], jnp.where(update[:, None], yv, 0.0), slot, 0)
+            rho = lax.dynamic_update_index_in_dim(
+                st["rho"],
+                jnp.where(update, 1.0 / jnp.where(sy > 1e-10, sy, 1.0), 0.0),
+                slot, 0)
+            gamma = jnp.where(update,
+                              sy / (jnp.sum(yv * yv, axis=1) + eps),
+                              st["gamma"])
+            # float32 stall detector: the sum-loss gradient has a rounding
+            # floor that often sits ABOVE tol (n terms x eps32), so the tol
+            # exit alone can be unreachable and every lane burns max_iter.
+            # A lane whose relative objective improvement stays below ~eps32
+            # for 3 consecutive iterations has hit that floor — its iterate
+            # is pinned by rounding, and the remaining lockstep iterations
+            # are pure waste.  (Safe for the strongly-convex GLM objectives
+            # this solver serves: genuine progress never hides behind
+            # consecutive sub-eps steps.)
+            rel_impr = (f - f_new) / jnp.maximum(jnp.abs(f), eps)
+            stall = jnp.where(jnp.logical_and(live, rel_impr <= eps),
+                              st["stall"] + 1, 0)
+            done = jnp.logical_or(
+                st["done"],
+                jnp.logical_or(gnorm(g_new) <= tol, stall >= 3))
         return dict(x=x_new, Z=Z_new, f=f_new, g=g_new, s_mem=s_mem,
                     y_mem=y_mem, rho=rho, gamma=gamma, it=it + 1,
                     done=done, stall=stall)

@@ -137,18 +137,21 @@ def fingerprint(arr: np.ndarray) -> str:
     allocate its dense form (pinned by test_dataplane.py)."""
     parts = _csr_parts(arr)
     h = hashlib.blake2b(digest_size=16)
-    if parts is not None:
-        data, indices, indptr, shape = parts
-        h.update(repr(("csr", shape, data.dtype.str,
-                       indices.dtype.str)).encode())
-        for a in (data, indices, indptr):
-            a = np.ascontiguousarray(a)
-            h.update(a.data if a.flags["C_CONTIGUOUS"] else a.tobytes())
+    with get_tracer().span("dataplane.fingerprint",
+                           bytes=int(getattr(arr, "nbytes", 0))):
+        if parts is not None:
+            data, indices, indptr, shape = parts
+            h.update(repr(("csr", shape, data.dtype.str,
+                           indices.dtype.str)).encode())
+            for a in (data, indices, indptr):
+                a = np.ascontiguousarray(a)
+                h.update(a.data if a.flags["C_CONTIGUOUS"]
+                         else a.tobytes())
+            return h.hexdigest()
+        a = np.ascontiguousarray(arr)
+        h.update(repr((a.shape, a.dtype.str)).encode())
+        h.update(a.data if a.flags["C_CONTIGUOUS"] else a.tobytes())
         return h.hexdigest()
-    a = np.ascontiguousarray(arr)
-    h.update(repr((a.shape, a.dtype.str)).encode())
-    h.update(a.data if a.flags["C_CONTIGUOUS"] else a.tobytes())
-    return h.hexdigest()
 
 
 def _sharding_key(sharding) -> Any:

@@ -253,14 +253,12 @@ class MemoryLedger:
         with self._lock:
             if not force and self._measured is False:
                 return self._devices
-        t0 = time.perf_counter()
-        stats = _obs_memory.device_memory_stats()
-        measured = any(r["measured"] for r in stats)
-        in_use = max((r["bytes_in_use"] for r in stats), default=0)
-        get_tracer().record_span(
-            "memory.sample", t0, time.perf_counter(),
-            bytes_in_use=int(in_use), measured=bool(measured),
-            n_devices=len(stats))
+        with get_tracer().span("memory.sample") as sp:
+            stats = _obs_memory.device_memory_stats()
+            measured = any(r["measured"] for r in stats)
+            in_use = max((r["bytes_in_use"] for r in stats), default=0)
+            sp.set(bytes_in_use=int(in_use), measured=bool(measured),
+                   n_devices=len(stats))
         with self._lock:
             self._measured = measured
             self._devices = stats

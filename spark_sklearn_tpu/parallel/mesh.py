@@ -52,7 +52,10 @@ class TpuConfig:
     # search skips them.
     checkpoint_dir: Optional[str] = None
     # profiling (SURVEY §5.1): wrap the sweep in a jax.profiler trace whose
-    # artifacts land here (open with tensorboard / perfetto).
+    # artifacts land here (open with tensorboard / perfetto).  The trace
+    # holds the program's own names beside jax's: device ops carry the
+    # named scopes of obs/spans.py (glm_lbfgs.*, sst.fit, sst.score) and
+    # the mirrored host spans appear as `sst.<span>` events.
     profile_dir: Optional[str] = None
     # NaN debugging (SURVEY §5.2): raise at the first non-finite value
     # inside compiled fits instead of masking it into error_score — the

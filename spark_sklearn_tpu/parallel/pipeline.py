@@ -53,6 +53,7 @@ from spark_sklearn_tpu.obs import telemetry as _telemetry
 from spark_sklearn_tpu.obs.log import get_logger
 from spark_sklearn_tpu.obs.trace import (
     current_correlation,
+    current_search,
     get_tracer,
     set_correlation,
 )
@@ -156,8 +157,10 @@ def enable_persistent_cache(config=None) -> str:
     reloads the serialized executable.
 
     The first call decides.  It writes the resolved directory
-    (:func:`resolve_compile_cache_dir`) and the config's min-compile
-    threshold to the live jax config, once; where neither the
+    (:func:`resolve_compile_cache_dir`), the config's min-compile
+    threshold and the two options that put the programs' debug metadata
+    (named scopes, one source frame an op) into the cache key to the
+    live jax config, once; where neither the
     environment nor the TpuConfig places the cache, a directory the
     user already set in code (``jax.config.update``) is kept over the
     default.  jax itself binds its cache at the process's first compile
@@ -181,6 +184,23 @@ def enable_persistent_cache(config=None) -> str:
                 "jax_persistent_cache_min_compile_time_secs",
                 float(getattr(config, "persistent_cache_min_compile_s",
                               0.5)))
+            # the programs' jax.named_scope phases (obs/spans.py, kind
+            # "scope") are debug metadata, which jax strips from the
+            # cache key by default: an executable compiled before a
+            # scope existed, or was renamed, would be served under the
+            # same key with the old names in it, and a profiler trace
+            # would show them.  With the metadata in the key such an
+            # entry misses and is compiled again.  The metadata also
+            # holds each op's python traceback, and which thread first
+            # traced a shared inner function (the compile-ahead thread
+            # or the dispatching one) decides the outer frames: keys
+            # would differ from run to run.  One frame — the line that
+            # made the op, the trace's `source` — is the same whoever
+            # called, so the key follows the package's own source and
+            # not the caller's script or a race.
+            jax.config.update(
+                "jax_compilation_cache_include_metadata_in_key", True)
+            jax.config.update("jax_traceback_in_locations_limit", 1)
             _BOUND_CACHE_DIR = wanted
         elif wanted not in (_BOUND_CACHE_DIR, DEFAULT_COMPILE_CACHE_DIR) \
                 and wanted not in _REFUSED_CACHE_DIRS:
@@ -420,8 +440,10 @@ class ChunkPipeline:
         self._tracer = get_tracer()
         # the constructing thread's tenant/handle correlation, applied
         # to the stage/gather/compile worker threads so every span and
-        # log line they emit attributes to the owning search
+        # log line they emit attributes to the owning search; the
+        # search's number rides along for the mirrored profiler spans
         self._corr = current_correlation()
+        self._search = current_search()
         # per compile group: [first dispatch t, last finalize t] — the
         # compile-group boundary spans of the exported trace
         self._group_bounds: Dict[int, List[float]] = {}
@@ -440,7 +462,7 @@ class ChunkPipeline:
                 max_workers=1, thread_name_prefix="sst-compile")
 
         def job():
-            set_correlation(self._corr)
+            set_correlation(self._corr, self._search)
             with self._tracer.span("compile", label=label):
                 exe = precompile(jit_fn, *args)
             self._n_precompiled += 1
@@ -669,7 +691,7 @@ class ChunkPipeline:
         exhausted = False
 
         def staged_call(item):
-            set_correlation(self._corr)
+            set_correlation(self._corr, self._search)
             t0 = time.perf_counter()
             # bytes accounted via the (single) stage thread's delta of
             # the process-wide data-plane counter — supervisor re-stages
@@ -694,7 +716,7 @@ class ChunkPipeline:
                 staged.append((nxt, fut))
 
         def gather_job(item, out, t_dispatch0, t_dispatched, tm):
-            set_correlation(self._corr)
+            set_correlation(self._corr, self._search)
             with tr.span("compute.wait", key=item.key):
                 out = self._wait_item(item, out)
             t_ready = time.perf_counter()

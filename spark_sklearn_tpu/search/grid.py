@@ -29,6 +29,7 @@ Execution: two tiers (SURVEY §7.0).
 
 from __future__ import annotations
 
+import functools
 import numbers
 import threading
 import time
@@ -540,80 +541,83 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 list(self.scoring.keys())
                 if isinstance(self.scoring, dict) else list(self.scoring))
 
-        cv = check_cv(self.cv, y, classifier=is_classifier(estimator))
-        from spark_sklearn_tpu.sparse.csr import CSRMatrix
-        if isinstance(X, CSRMatrix):
-            X = X.to_scipy()  # splitters/refit understand scipy CSR
-        else:
-            import scipy.sparse as _sp
-            if _sp.issparse(X) and X.format not in ("csr", "csc"):
-                X = X.tocsr()  # COO/DOK are not sliceable by fold indices
-        X_arr = X if hasattr(X, "shape") else np.asarray(X)
+        with get_tracer().span("fit.prepare"):
+            cv = check_cv(self.cv, y, classifier=is_classifier(estimator))
+            from spark_sklearn_tpu.sparse.csr import CSRMatrix
+            if isinstance(X, CSRMatrix):
+                X = X.to_scipy()  # splitters/refit understand scipy CSR
+            else:
+                import scipy.sparse as _sp
+                if _sp.issparse(X) and X.format not in ("csr", "csc"):
+                    X = X.tocsr()  # COO/DOK are not sliceable by fold indices
+            X_arr = X if hasattr(X, "shape") else np.asarray(X)
 
-        params = _check_method_params(X, params=params)
-        routed_params = self._get_routed_params_for_fit(params)
+            params = _check_method_params(X, params=params)
+            routed_params = self._get_routed_params_for_fit(params)
 
-        sw_meta = params.get("sample_weight")
-        metadata_callbacks = ({"sample_weight": sw_meta}
-                              if sw_meta is not None else None)
-        root_callback_ctx = self._init_callback_context(
-            max_subtasks=1 + (self.refit is not False)
-        ).call_on_fit_task_begin(
-            estimator=self, X=X, y=y, metadata=metadata_callbacks)
+            sw_meta = params.get("sample_weight")
+            metadata_callbacks = ({"sample_weight": sw_meta}
+                                  if sw_meta is not None else None)
+            root_callback_ctx = self._init_callback_context(
+                max_subtasks=1 + (self.refit is not False)
+            ).call_on_fit_task_begin(
+                estimator=self, X=X, y=y, metadata=metadata_callbacks)
 
-        splits = list(cv.split(X_arr, y, **routed_params.splitter.split))
-        self.n_splits_ = len(splits)
-        if hasattr(cv, "get_n_splits"):
-            expected_n_splits = cv.get_n_splits(
-                X_arr, y, **routed_params.splitter.split)
-            if expected_n_splits != self.n_splits_:
-                raise ValueError(
-                    "cv.split and cv.get_n_splits return "
-                    f"inconsistent results. Expected {expected_n_splits} "
-                    f"splits, got {self.n_splits_}")
+            splits = list(cv.split(X_arr, y, **routed_params.splitter.split))
+            self.n_splits_ = len(splits)
+            if hasattr(cv, "get_n_splits"):
+                expected_n_splits = cv.get_n_splits(
+                    X_arr, y, **routed_params.splitter.split)
+                if expected_n_splits != self.n_splits_:
+                    raise ValueError(
+                        "cv.split and cv.get_n_splits return "
+                        f"inconsistent results. Expected {expected_n_splits} "
+                        f"splits, got {self.n_splits_}")
 
-        family = None if self.backend == "host" else resolve_family(estimator)
-        use_compiled = family is not None
-        # groups is fine on the compiled path: the splits above already
-        # encode it and only fold masks reach the device.  sample_weight is
-        # too: it is one multiply into the fold masks.  Any OTHER fit/score
-        # param is an arbitrary kwarg that cannot enter a traced fit.
-        est_fit_params = dict(routed_params.estimator.fit)
-        score_params = dict(routed_params.scorer.score)
-        fit_weight = est_fit_params.get("sample_weight")
-        score_weight = score_params.get("sample_weight")
-        unsupported_compiled = (
-            {k for k, v in est_fit_params.items()
-             if k != "sample_weight" and v is not None}
-            | {k for k, v in score_params.items()
-               if k != "sample_weight" and v is not None})
-        if use_compiled and fit_weight is not None and \
-                getattr(estimator, "class_weight", None) == "balanced" \
-                and np.any(np.asarray(fit_weight) == 0):
-            # sklearn's balanced counts are unweighted bincounts over ALL
-            # train-fold rows; the compiled tier derives them from the
-            # weighted mask's support, which drops zero-weight rows ->
-            # reproduce sklearn on the host instead
-            unsupported_compiled = unsupported_compiled | {"sample_weight"}
-        if use_compiled and fit_weight is not None and not getattr(
-                family, "accepts_sample_weight", True):
-            # e.g. Pipelines: sklearn raises on a bare sample_weight (step
-            # routing wants "step__sample_weight") — the host path
-            # reproduces that contract
-            unsupported_compiled = unsupported_compiled | {"sample_weight"}
-        if use_compiled and unsupported_compiled:
-            if self.backend == "tpu":
-                raise NotCompiledError(
-                    f"fit/score params {sorted(unsupported_compiled)} are "
-                    "not supported on the compiled path; use backend='host'")
-            use_compiled = False
-        if use_compiled:
-            try:
-                resolve_scoring(self.scoring, family)
-            except NotCompiledError:
+            family = (None if self.backend == "host"
+                      else resolve_family(estimator))
+            use_compiled = family is not None
+            # groups is fine on the compiled path: the splits above already
+            # encode it and only fold masks reach the device.  sample_weight is
+            # too: it is one multiply into the fold masks.  Any OTHER fit/score
+            # param is an arbitrary kwarg that cannot enter a traced fit.
+            est_fit_params = dict(routed_params.estimator.fit)
+            score_params = dict(routed_params.scorer.score)
+            fit_weight = est_fit_params.get("sample_weight")
+            score_weight = score_params.get("sample_weight")
+            unsupported_compiled = (
+                {k for k, v in est_fit_params.items()
+                 if k != "sample_weight" and v is not None}
+                | {k for k, v in score_params.items()
+                   if k != "sample_weight" and v is not None})
+            if use_compiled and fit_weight is not None and \
+                    getattr(estimator, "class_weight", None) == "balanced" \
+                    and np.any(np.asarray(fit_weight) == 0):
+                # sklearn's balanced counts are unweighted bincounts over ALL
+                # train-fold rows; the compiled tier derives them from the
+                # weighted mask's support, which drops zero-weight rows ->
+                # reproduce sklearn on the host instead
+                unsupported_compiled = unsupported_compiled | {"sample_weight"}
+            if use_compiled and fit_weight is not None and not getattr(
+                    family, "accepts_sample_weight", True):
+                # e.g. Pipelines: sklearn raises on a bare sample_weight (step
+                # routing wants "step__sample_weight") — the host path
+                # reproduces that contract
+                unsupported_compiled = unsupported_compiled | {"sample_weight"}
+            if use_compiled and unsupported_compiled:
                 if self.backend == "tpu":
-                    raise
+                    raise NotCompiledError(
+                        f"fit/score params {sorted(unsupported_compiled)} are "
+                        "not supported on the compiled path; use "
+                        "backend='host'")
                 use_compiled = False
+            if use_compiled:
+                try:
+                    resolve_scoring(self.scoring, family)
+                except NotCompiledError:
+                    if self.backend == "tpu":
+                        raise
+                    use_compiled = False
 
         # sklearn's extension point (_search.py evaluate_candidates):
         # _run_search may call evaluate_candidates several times; batches
@@ -781,14 +785,16 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             if more_results:
                 for k, v in more_results.items():
                     acc["more"].setdefault(k, []).extend(v)
-            acc["results"] = self._format_results(
-                acc["params"],
-                {s: np.concatenate(v) for s, v in acc["test"].items()},
-                ({s: np.concatenate(v) for s, v in acc["train"].items()}
-                 if self.return_train_score else None),
-                np.concatenate(acc["fit_t"]),
-                np.concatenate(acc["score_t"]), acc["names"],
-                more_results=acc["more"])
+            with get_tracer().span("fit.results",
+                                   n_candidates=len(acc["params"])):
+                acc["results"] = self._format_results(
+                    acc["params"],
+                    {s: np.concatenate(v) for s, v in acc["test"].items()},
+                    ({s: np.concatenate(v) for s, v in acc["train"].items()}
+                     if self.return_train_score else None),
+                    np.concatenate(acc["fit_t"]),
+                    np.concatenate(acc["score_t"]), acc["names"],
+                    more_results=acc["more"])
             return acc["results"]
 
         from inspect import signature as _signature
@@ -1113,183 +1119,188 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
     def _fit_compiled_impl(self, family, X, y, candidates, splits, config,
                            fit_weight=None, score_weight=None,
                            dtype_override=None):
-        from sklearn.metrics import check_scoring
+        with get_tracer().span("fit.prepare"):
+            from sklearn.metrics import check_scoring
 
-        from spark_sklearn_tpu.parallel.pipeline import (
-            enable_persistent_cache)
-        enable_persistent_cache(config)
-        # persistent AOT program store: sessionless fits activate it
-        # here (a TpuSession already did at construction) — programs
-        # resolve from serialized artifacts instead of re-tracing, and
-        # the search publishes what it compiles for the next process
-        from spark_sklearn_tpu.parallel import programstore as _programstore
-        pstore = _programstore.activate_store(config)
-        ps_before = _programstore.snapshot_counters(pstore)
-        # successive-halving rung owner (search/halving.py, attached
-        # through the launch-ownership protocol): when set, this
-        # evaluate_candidates call is ONE RUNG of a multi-rung search —
-        # the report registry, pipeline and counter baselines are
-        # shared across rungs so the final search_report covers the
-        # whole search, not the last rung
-        rung = _ownership.current_owner(self, kind="rung")
-        if rung is not None:
-            if rung.ps_before is None:
-                rung.ps_before = ps_before
-            ps_before = rung.ps_before
-        dtype = dtype_override or config.dtype or np.float32
-        scorers, _ = resolve_scoring(self.scoring, family)
-        scorer_names = list(scorers)
+            from spark_sklearn_tpu.parallel.pipeline import (
+                enable_persistent_cache)
+            enable_persistent_cache(config)
+            # persistent AOT program store: sessionless fits activate it
+            # here (a TpuSession already did at construction) — programs
+            # resolve from serialized artifacts instead of re-tracing, and
+            # the search publishes what it compiles for the next process
+            from spark_sklearn_tpu.parallel import (
+                programstore as _programstore)
+            pstore = _programstore.activate_store(config)
+            ps_before = _programstore.snapshot_counters(pstore)
+            # successive-halving rung owner (search/halving.py, attached
+            # through the launch-ownership protocol): when set, this
+            # evaluate_candidates call is ONE RUNG of a multi-rung search —
+            # the report registry, pipeline and counter baselines are
+            # shared across rungs so the final search_report covers the
+            # whole search, not the last rung
+            rung = _ownership.current_owner(self, kind="rung")
+            if rung is not None:
+                if rung.ps_before is None:
+                    rung.ps_before = ps_before
+                ps_before = rung.ps_before
+            dtype = dtype_override or config.dtype or np.float32
+            scorers, _ = resolve_scoring(self.scoring, family)
+            scorer_names = list(scorers)
 
-        # sklearn's log_loss clips probas at THEIR dtype's machine eps
-        # (_classification.py log_loss), and the sklearn twin's proba
-        # dtype is a per-family fact: on this sklearn nearly every
-        # classifier (libsvm, forests, KNN, LogReg, the NB family)
-        # produces f64 probas regardless of X dtype; only MLP and LDA
-        # preserve the user's X dtype (proba_dtype_rule="input") — the
-        # compiled scorer must clip where the oracle clips, not where
-        # the engine's compute dtype lands (see scorers.py
-        # _neg_log_loss)
-        proba_rule = getattr(family, "proba_dtype_rule", "float64")
-        # the dtype that matters is the one sklearn's own validation
-        # would hand the estimator: float32 stays float32, EVERYTHING
-        # else (float64, ints, lists, frames — check_array's numeric
-        # rule) becomes float64.  Resolve it after coercion: sparse
-        # matrices and ndarrays expose .dtype directly; other inputs
-        # (lists, DataFrames) go through np.asarray like sklearn's
-        # check_array would
-        x_dt = getattr(X, "dtype", None)
-        if not isinstance(x_dt, np.dtype):
-            # dtype-less inputs resolve WITHOUT copying the dataset:
-            # DataFrames promote their column dtypes; lists/tuples
-            # resolve from their first row (a float32-ndarray row list
-            # stays float32 under np.asarray, everything else becomes
-            # float64 under check_array's numeric rule)
-            col_dtypes = getattr(X, "dtypes", None)
-            if col_dtypes is not None and len(col_dtypes):
-                x_dt = np.result_type(*col_dtypes)
-            elif isinstance(X, (list, tuple)) and len(X) \
-                    and isinstance(X[0], np.ndarray):
-                x_dt = X[0].dtype
-            elif isinstance(X, (list, tuple)):
-                x_dt = np.dtype(np.float64)
+            # sklearn's log_loss clips probas at THEIR dtype's machine eps
+            # (_classification.py log_loss), and the sklearn twin's proba
+            # dtype is a per-family fact: on this sklearn nearly every
+            # classifier (libsvm, forests, KNN, LogReg, the NB family)
+            # produces f64 probas regardless of X dtype; only MLP and LDA
+            # preserve the user's X dtype (proba_dtype_rule="input") — the
+            # compiled scorer must clip where the oracle clips, not where
+            # the engine's compute dtype lands (see scorers.py
+            # _neg_log_loss)
+            proba_rule = getattr(family, "proba_dtype_rule", "float64")
+            # the dtype that matters is the one sklearn's own validation
+            # would hand the estimator: float32 stays float32, EVERYTHING
+            # else (float64, ints, lists, frames — check_array's numeric
+            # rule) becomes float64.  Resolve it after coercion: sparse
+            # matrices and ndarrays expose .dtype directly; other inputs
+            # (lists, DataFrames) go through np.asarray like sklearn's
+            # check_array would
+            x_dt = getattr(X, "dtype", None)
+            if not isinstance(x_dt, np.dtype):
+                # dtype-less inputs resolve WITHOUT copying the dataset:
+                # DataFrames promote their column dtypes; lists/tuples
+                # resolve from their first row (a float32-ndarray row list
+                # stays float32 under np.asarray, everything else becomes
+                # float64 under check_array's numeric rule)
+                col_dtypes = getattr(X, "dtypes", None)
+                if col_dtypes is not None and len(col_dtypes):
+                    x_dt = np.result_type(*col_dtypes)
+                elif isinstance(X, (list, tuple)) and len(X) \
+                        and isinstance(X[0], np.ndarray):
+                    x_dt = X[0].dtype
+                elif isinstance(X, (list, tuple)):
+                    x_dt = np.dtype(np.float64)
+                else:
+                    x_dt = np.asarray(X).dtype
+            oracle_proba_dt = np.float64 if (
+                proba_rule == "float64" or x_dt != np.float32) else np.float32
+            # the pre-densified X (what sklearn estimators would see): the
+            # supervisor's per-candidate host fallback fits on THIS, so a
+            # bisection that bottoms out reproduces sklearn exactly
+            X_host = X
+            # data tier (search/stream.py): "device" is the legacy resident
+            # path, "stream" folds sample shards through the pipeline,
+            # "sparse" keeps a scipy CSR as a device BCOO end to end
+            import scipy.sparse as _scipy_sparse
+
+            from spark_sklearn_tpu.search import stream as _stream
+            data_mode = _stream.resolve_data_mode(config)
+            sparse_op = None
+            if data_mode == "sparse" and _scipy_sparse.issparse(X):
+                if not getattr(family, "supports_sparse", False):
+                    raise NotCompiledError(
+                        f"data_mode='sparse' requires a family with BCOO "
+                        f"fit/predict programs; {family.name} has none.  "
+                        "Use data_mode='device' (densified upload) or "
+                        "backend='host'.")
+                if config.n_data_shards > 1:
+                    raise ValueError(
+                        "data_mode='sparse' does not compose with "
+                        "n_data_shards>1 (BCOO operands replicate only)")
+                from spark_sklearn_tpu.sparse.csr import register_bcoo_export
+                register_bcoo_export()
+                X = X.tocsr()
+                data, meta = family.prepare_data_sparse(X, y, dtype=dtype)
+                sparse_op = data["X"]
             else:
-                x_dt = np.asarray(X).dtype
-        oracle_proba_dt = np.float64 if (
-            proba_rule == "float64" or x_dt != np.float32) else np.float32
-        # the pre-densified X (what sklearn estimators would see): the
-        # supervisor's per-candidate host fallback fits on THIS, so a
-        # bisection that bottoms out reproduces sklearn exactly
-        X_host = X
-        # data tier (search/stream.py): "device" is the legacy resident
-        # path, "stream" folds sample shards through the pipeline,
-        # "sparse" keeps a scipy CSR as a device BCOO end to end
-        import scipy.sparse as _scipy_sparse
+                if data_mode == "stream":
+                    _stream.check_stream_supported(family, self.scoring,
+                                                   config)
+                X = self._densify(X, dtype)
+                data, meta = family.prepare_data(X, y, dtype=dtype)
+            meta["logloss_clip_eps"] = float(np.finfo(oracle_proba_dt).eps)
+            if self.scoring is not None:
+                if "y" not in data:
+                    raise ValueError(
+                        f"scoring={self.scoring!r} needs labels, but none "
+                        f"reached the device ({family.name} is unsupervised: "
+                        "y was absent or not numerically encodable; only its "
+                        "default scorer applies)")
+                from spark_sklearn_tpu.search.scorers import (
+                    compiled_name_for_scorer)
 
-        from spark_sklearn_tpu.search import stream as _stream
-        data_mode = _stream.resolve_data_mode(config)
-        sparse_op = None
-        if data_mode == "sparse" and _scipy_sparse.issparse(X):
-            if not getattr(family, "supports_sparse", False):
-                raise NotCompiledError(
-                    f"data_mode='sparse' requires a family with BCOO "
-                    f"fit/predict programs; {family.name} has none.  "
-                    "Use data_mode='device' (densified upload) or "
-                    "backend='host'.")
-            if config.n_data_shards > 1:
-                raise ValueError(
-                    "data_mode='sparse' does not compose with "
-                    "n_data_shards>1 (BCOO operands replicate only)")
-            from spark_sklearn_tpu.sparse.csr import register_bcoo_export
-            register_bcoo_export()
-            X = X.tocsr()
-            data, meta = family.prepare_data_sparse(X, y, dtype=dtype)
-            sparse_op = data["X"]
-        else:
-            if data_mode == "stream":
-                _stream.check_stream_supported(family, self.scoring,
-                                               config)
-            X = self._densify(X, dtype)
-            data, meta = family.prepare_data(X, y, dtype=dtype)
-        meta["logloss_clip_eps"] = float(np.finfo(oracle_proba_dt).eps)
-        if self.scoring is not None:
-            if "y" not in data:
-                raise ValueError(
-                    f"scoring={self.scoring!r} needs labels, but none "
-                    f"reached the device ({family.name} is unsupervised: "
-                    "y was absent or not numerically encodable; only its "
-                    "default scorer applies)")
+                def _canon(s):
+                    return s if isinstance(s, str) \
+                        else compiled_name_for_scorer(s)
+                if isinstance(self.scoring, str):
+                    wanted = [self.scoring]
+                elif isinstance(self.scoring, dict):
+                    # dict values name the metrics; keys are display labels
+                    wanted = [_canon(s) for s in self.scoring.values()]
+                elif isinstance(self.scoring, (list, tuple, set)):
+                    wanted = [_canon(s) for s in self.scoring]
+                else:
+                    wanted = [_canon(self.scoring)]
+                wanted = [s for s in wanted if s is not None]
+                if any(s in CLASSIFICATION_SCORERS for s in wanted) and \
+                        "n_classes" not in meta:
+                    raise ValueError(
+                        f"scoring={self.scoring!r} requires a classifier "
+                        f"family; {family.name} has no class structure")
+                if any(s in BINARY_ONLY_SCORERS for s in wanted) and \
+                        meta.get("n_classes", 2) > 2:
+                    # sklearn's semantics for these on multiclass (averaging
+                    # options, undefined-metric warnings) live on the host path
+                    raise NotCompiledError(
+                        f"scoring={self.scoring!r} on multiclass targets is "
+                        "not compiled; use backend='host'")
+            n_samples = X.shape[0]
+            train_masks, test_masks = fold_masks(
+                splits, n_samples, dtype=dtype)
+            # families whose validity depends on fold geometry (e.g. KNN's
+            # n_neighbors <= smallest train fold) check this in
+            # observe_candidates, so both backends raise on the same grids
+            meta["min_fold_train_count"] = int(
+                np.sum(train_masks > 0, axis=1).min())
+            n_folds = len(splits)
+            n_cand = len(candidates)
+            return_train = self.return_train_score
+
+            # sample_weight enters the compiled tier as mask multiplies: the
+            # estimator's weights scale the FIT masks, the scorer's weights
+            # scale the SCORING masks (sklearn routes the two independently —
+            # a scorer that rejects sample_weight scores unweighted even when
+            # the fit was weighted)
+            fit_masks = train_masks
+            if fit_weight is not None:
+                fw = np.asarray(fit_weight, dtype=dtype)
+                if fw.shape != (n_samples,):
+                    raise ValueError(
+                        f"sample_weight has shape {fw.shape}, expected "
+                        f"({n_samples},)")
+                fit_masks = train_masks * fw[None, :]
+            if score_weight is not None:
+                sw = np.asarray(score_weight, dtype=dtype)
+                if sw.shape != (n_samples,):
+                    raise ValueError(
+                        f"scorer sample_weight has shape {sw.shape}, expected "
+                        f"({n_samples},)")
+                test_sc_masks = test_masks * sw[None, :]
+                train_sc_masks = train_masks * sw[None, :]
+            else:
+                test_sc_masks = test_masks
+                train_sc_masks = train_masks
+            # scorers whose sklearn twin rejects sample_weight score unweighted
+            # even in a weighted search (_MultimetricScorer forwards per
+            # scorer)
             from spark_sklearn_tpu.search.scorers import (
-                compiled_name_for_scorer)
+                SAMPLE_WEIGHT_BLIND_FNS)
+            sw_blind = frozenset(
+                name for name, fn in scorers.items()
+                if fn in SAMPLE_WEIGHT_BLIND_FNS)
+            need_unweighted = score_weight is not None and bool(sw_blind)
 
-            def _canon(s):
-                return s if isinstance(s, str) \
-                    else compiled_name_for_scorer(s)
-            if isinstance(self.scoring, str):
-                wanted = [self.scoring]
-            elif isinstance(self.scoring, dict):
-                # dict values name the metrics; keys are display labels
-                wanted = [_canon(s) for s in self.scoring.values()]
-            elif isinstance(self.scoring, (list, tuple, set)):
-                wanted = [_canon(s) for s in self.scoring]
-            else:
-                wanted = [_canon(self.scoring)]
-            wanted = [s for s in wanted if s is not None]
-            if any(s in CLASSIFICATION_SCORERS for s in wanted) and \
-                    "n_classes" not in meta:
-                raise ValueError(
-                    f"scoring={self.scoring!r} requires a classifier "
-                    f"family; {family.name} has no class structure")
-            if any(s in BINARY_ONLY_SCORERS for s in wanted) and \
-                    meta.get("n_classes", 2) > 2:
-                # sklearn's semantics for these on multiclass (averaging
-                # options, undefined-metric warnings) live on the host path
-                raise NotCompiledError(
-                    f"scoring={self.scoring!r} on multiclass targets is "
-                    "not compiled; use backend='host'")
-        n_samples = X.shape[0]
-        train_masks, test_masks = fold_masks(splits, n_samples, dtype=dtype)
-        # families whose validity depends on fold geometry (e.g. KNN's
-        # n_neighbors <= smallest train fold) check this in
-        # observe_candidates, so both backends raise on the same grids
-        meta["min_fold_train_count"] = int(
-            np.sum(train_masks > 0, axis=1).min())
-        n_folds = len(splits)
-        n_cand = len(candidates)
-        return_train = self.return_train_score
-
-        # sample_weight enters the compiled tier as mask multiplies: the
-        # estimator's weights scale the FIT masks, the scorer's weights
-        # scale the SCORING masks (sklearn routes the two independently —
-        # a scorer that rejects sample_weight scores unweighted even when
-        # the fit was weighted)
-        fit_masks = train_masks
-        if fit_weight is not None:
-            fw = np.asarray(fit_weight, dtype=dtype)
-            if fw.shape != (n_samples,):
-                raise ValueError(
-                    f"sample_weight has shape {fw.shape}, expected "
-                    f"({n_samples},)")
-            fit_masks = train_masks * fw[None, :]
-        if score_weight is not None:
-            sw = np.asarray(score_weight, dtype=dtype)
-            if sw.shape != (n_samples,):
-                raise ValueError(
-                    f"scorer sample_weight has shape {sw.shape}, expected "
-                    f"({n_samples},)")
-            test_sc_masks = test_masks * sw[None, :]
-            train_sc_masks = train_masks * sw[None, :]
-        else:
-            test_sc_masks = test_masks
-            train_sc_masks = train_masks
-        # scorers whose sklearn twin rejects sample_weight score unweighted
-        # even in a weighted search (_MultimetricScorer forwards per-scorer)
-        from spark_sklearn_tpu.search.scorers import SAMPLE_WEIGHT_BLIND_FNS
-        sw_blind = frozenset(
-            name for name, fn in scorers.items()
-            if fn in SAMPLE_WEIGHT_BLIND_FNS)
-        need_unweighted = score_weight is not None and bool(sw_blind)
-
-        base_params = family.extract_params(self.estimator)
+            base_params = family.extract_params(self.estimator)
         # sklearn raises InvalidParameterError inside fit() for
         # out-of-range hyperparameters (LinearSVC C=0, negative alpha...);
         # the compiled solvers accept any finite value, so reproduce the
@@ -1308,87 +1319,89 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             # sklearn raises this exact exception
             raise preval_exc
 
-        launch_index = None
-        launch_candidates = candidates
-        if preval_failed.any():
-            launch_index = np.flatnonzero(~preval_failed)
-            launch_candidates = [candidates[i] for i in launch_index]
-        if hasattr(family, "observe_candidates"):
-            # e.g. tree families need the grid-wide max n_estimators to fix
-            # the compiled program's static tree count (valid candidates
-            # only — an invalid static value would crash the observation)
-            family.observe_candidates(launch_candidates, base_params, meta)
-        dyn_names = list(family.dynamic_params)
-        groups = build_compile_groups(
-            launch_candidates, dyn_names, family.dynamic_params)
-        if launch_index is not None:
-            for g in groups:
-                g.candidate_indices = launch_index[
-                    np.asarray(g.candidate_indices)]
+        with get_tracer().span("fit.plan",
+                               n_candidates=len(candidates)):
+            launch_index = None
+            launch_candidates = candidates
+            if preval_failed.any():
+                launch_index = np.flatnonzero(~preval_failed)
+                launch_candidates = [candidates[i] for i in launch_index]
+            if hasattr(family, "observe_candidates"):
+                # e.g. tree families need the grid-wide max n_estimators to fix
+                # the compiled program's static tree count (valid candidates
+                # only — an invalid static value would crash the observation)
+                family.observe_candidates(launch_candidates, base_params, meta)
+            dyn_names = list(family.dynamic_params)
+            groups = build_compile_groups(
+                launch_candidates, dyn_names, family.dynamic_params)
+            if launch_index is not None:
+                for g in groups:
+                    g.candidate_indices = launch_index[
+                        np.asarray(g.candidate_indices)]
 
-        mesh = build_mesh(config)
-        n_task_shards = mesh.shape[mesh_lib.TASK_AXIS]
-        logger.info(
-            "compiled search: family=%s, %d candidates x %d folds, "
-            "%d compile group(s), mesh=%s", family.name, n_cand, n_folds,
-            len(groups), dict(mesh.shape))
-        repl = mesh_lib.replicated_sharding(mesh)
-        task_shard = mesh_lib.task_sharding(mesh)
+            mesh = build_mesh(config)
+            n_task_shards = mesh.shape[mesh_lib.TASK_AXIS]
+            logger.info(
+                "compiled search: family=%s, %d candidates x %d folds, "
+                "%d compile group(s), mesh=%s", family.name, n_cand, n_folds,
+                len(groups), dict(mesh.shape))
+            repl = mesh_lib.replicated_sharding(mesh)
+            task_shard = mesh_lib.task_sharding(mesh)
 
-        # device data plane: a fingerprint-keyed, sharding-aware LRU of
-        # device arrays shared by every search in the process — X/y and
-        # the fold masks upload ONCE per content+placement and are
-        # reused across chunks, compile groups, calibration and
-        # subsequent searches (the persistent sc.broadcast).  Disabled
-        # (dataplane_bytes=0) restores per-search device_put.
-        from spark_sklearn_tpu.parallel import dataplane as _dataplane
-        plane = _dataplane.plane_for(config)
-        dp_before = _dataplane.snapshot_counters(plane)
-        if rung is not None:
-            if rung.dp_before is None:
-                rung.dp_before = dp_before
-            dp_before = rung.dp_before
-        # device-memory ledger (parallel/memledger.py): model each
-        # launch's footprint from its abstract shapes, reconcile
-        # against jax memory_stats at launch boundaries, cap planned
-        # widths to the HBM budget and render search_report["memory"].
-        # Disabled (memory_ledger=False) the report and cv_results_
-        # stay byte-identical to the pre-ledger engine.
-        from spark_sklearn_tpu.obs import memory as _obs_memory
-        from spark_sklearn_tpu.parallel import memledger as _memledger
-        ledger = _memledger.ledger_for(config)
-        mem_before = _memledger.snapshot_counters(ledger)
-        if ledger is not None and (rung is None or rung.itr == 0):
-            mem_stats = ledger.sample(force=True)
-            self._memory_ctx = {
-                "groups": [],
-                "resident_bytes": 0,
-                "budget_bytes": _obs_memory.resolve_hbm_budget(
-                    config, mem_stats),
-                "device_limit_bytes": _obs_memory.
-                detect_device_memory_bytes(mem_stats),
-                "measured_baseline_bytes": max(
-                    (r["bytes_in_use"] for r in mem_stats), default=0),
-            }
-        if rung is not None:
-            if rung.mem_before is None:
-                rung.mem_before = mem_before
-            mem_before = rung.mem_before
-        # in-flight heartbeats (obs/heartbeat.py): allocate ONE hub
-        # scope per fit (halving rungs share it) so the report block
-        # aggregates exactly this search's segments — cid_ns is empty
-        # for plain fits and cannot key the hub.  Off is an exact
-        # no-op: no ctx, no block, no beacon traced.
-        from spark_sklearn_tpu.obs import heartbeat as _heartbeat
-        _hb_enabled = _heartbeat.resolve_heartbeat(config)
-        if _hb_enabled and (rung is None or rung.itr == 0):
-            self._hb_ctx = {"scope": _heartbeat.get_hub().new_scope()}
-        hb_ctx = getattr(self, "_hb_ctx", None) if _hb_enabled else None
-        # a search submitted through a session's SearchExecutor charges
-        # its broadcast residents to its tenant's data-plane quota
-        from spark_sklearn_tpu import serve as _serve
-        _binding = _serve.current_binding()
-        _tenant = _binding.tenant if _binding is not None else None
+            # device data plane: a fingerprint-keyed, sharding-aware LRU of
+            # device arrays shared by every search in the process — X/y and
+            # the fold masks upload ONCE per content+placement and are
+            # reused across chunks, compile groups, calibration and
+            # subsequent searches (the persistent sc.broadcast).  Disabled
+            # (dataplane_bytes=0) restores per-search device_put.
+            from spark_sklearn_tpu.parallel import dataplane as _dataplane
+            plane = _dataplane.plane_for(config)
+            dp_before = _dataplane.snapshot_counters(plane)
+            if rung is not None:
+                if rung.dp_before is None:
+                    rung.dp_before = dp_before
+                dp_before = rung.dp_before
+            # device-memory ledger (parallel/memledger.py): model each
+            # launch's footprint from its abstract shapes, reconcile
+            # against jax memory_stats at launch boundaries, cap planned
+            # widths to the HBM budget and render search_report["memory"].
+            # Disabled (memory_ledger=False) the report and cv_results_
+            # stay byte-identical to the pre-ledger engine.
+            from spark_sklearn_tpu.obs import memory as _obs_memory
+            from spark_sklearn_tpu.parallel import memledger as _memledger
+            ledger = _memledger.ledger_for(config)
+            mem_before = _memledger.snapshot_counters(ledger)
+            if ledger is not None and (rung is None or rung.itr == 0):
+                mem_stats = ledger.sample(force=True)
+                self._memory_ctx = {
+                    "groups": [],
+                    "resident_bytes": 0,
+                    "budget_bytes": _obs_memory.resolve_hbm_budget(
+                        config, mem_stats),
+                    "device_limit_bytes": _obs_memory.
+                    detect_device_memory_bytes(mem_stats),
+                    "measured_baseline_bytes": max(
+                        (r["bytes_in_use"] for r in mem_stats), default=0),
+                }
+            if rung is not None:
+                if rung.mem_before is None:
+                    rung.mem_before = mem_before
+                mem_before = rung.mem_before
+            # in-flight heartbeats (obs/heartbeat.py): allocate ONE hub
+            # scope per fit (halving rungs share it) so the report block
+            # aggregates exactly this search's segments — cid_ns is empty
+            # for plain fits and cannot key the hub.  Off is an exact
+            # no-op: no ctx, no block, no beacon traced.
+            from spark_sklearn_tpu.obs import heartbeat as _heartbeat
+            _hb_enabled = _heartbeat.resolve_heartbeat(config)
+            if _hb_enabled and (rung is None or rung.itr == 0):
+                self._hb_ctx = {"scope": _heartbeat.get_hub().new_scope()}
+            hb_ctx = getattr(self, "_hb_ctx", None) if _hb_enabled else None
+            # a search submitted through a session's SearchExecutor charges
+            # its broadcast residents to its tenant's data-plane quota
+            from spark_sklearn_tpu import serve as _serve
+            _binding = _serve.current_binding()
+            _tenant = _binding.tenant if _binding is not None else None
 
         def _bput(v, sharding, label):
             from spark_sklearn_tpu.sparse.csr import SparseOperand
@@ -1406,77 +1419,76 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                  tenant=_tenant)
             return _dataplane.upload(v, sharding, label=label)
 
-        _t_upload0 = time.perf_counter()
-        if data_mode == "stream":
-            # streaming tier: X/y and the masks stay host-side — each
-            # sample shard crosses host->device on the pipeline's stage
-            # thread inside run_stream, overlapped with the previous
-            # shard's compute
-            data_dev = {}
-            fit_dev = test_dev = train_sc_dev = None
-            test_unw_dev = train_unw_dev = None
-        elif config.n_data_shards > 1:
-            # large-X mode: shard samples over the "data" mesh axis instead
-            # of replicating (the TPU-native answer to X not fitting one
-            # chip's HBM) — sample-axis reductions inside the families
-            # become XLA collectives over ICI automatically.  Sample counts
-            # are padded to the shard count with zero-weight rows.
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            nd = config.n_data_shards
-            n_pad = mesh_lib.pad_to_multiple(n_samples, nd)
-            if n_pad != n_samples:
-                pad = n_pad - n_samples
-                data = {k: np.concatenate(
-                    [v, np.zeros((pad,) + v.shape[1:], v.dtype)])
-                    for k, v in data.items()}
+        with get_tracer().span(
+                "device_put.broadcast", n_samples=n_samples,
+                n_data_shards=config.n_data_shards):
+            if data_mode == "stream":
+                # streaming tier: X/y and the masks stay host-side — each
+                # sample shard crosses host->device on the pipeline's stage
+                # thread inside run_stream, overlapped with the previous
+                # shard's compute
+                data_dev = {}
+                fit_dev = test_dev = train_sc_dev = None
+                test_unw_dev = train_unw_dev = None
+            elif config.n_data_shards > 1:
+                # large-X mode: shard samples over the "data" mesh axis instead
+                # of replicating (the TPU-native answer to X not fitting one
+                # chip's HBM) — sample-axis reductions inside the families
+                # become XLA collectives over ICI automatically.  Sample counts
+                # are padded to the shard count with zero-weight rows.
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                nd = config.n_data_shards
+                n_pad = mesh_lib.pad_to_multiple(n_samples, nd)
+                if n_pad != n_samples:
+                    pad = n_pad - n_samples
+                    data = {k: np.concatenate(
+                        [v, np.zeros((pad,) + v.shape[1:], v.dtype)])
+                        for k, v in data.items()}
 
-                def _padm(m, pad=pad):
-                    return np.concatenate(
-                        [m, np.zeros((n_folds, pad), m.dtype)], axis=1)
-                train_sc_aliases_fit = train_sc_masks is fit_masks
-                fit_masks = _padm(fit_masks)
-                test_sc_masks = _padm(test_sc_masks)
-                train_sc_masks = (fit_masks if train_sc_aliases_fit
-                                  else _padm(train_sc_masks))
-                if need_unweighted:
-                    test_masks = _padm(test_masks)
-                    train_masks = _padm(train_masks)
-            sample_shard = NamedSharding(mesh, P(mesh_lib.DATA_AXIS))
-            mask_shard = NamedSharding(mesh, P(None, mesh_lib.DATA_AXIS))
-            data_dev = {k: _bput(v, sample_shard, f"data.{k}")
-                        for k, v in data.items()}
-            put_masks = mask_shard
-        else:
-            data_dev = {k: _bput(v, repl, f"data.{k}")
-                        for k, v in data.items()}
-            put_masks = repl
-        # one device buffer per DISTINCT mask array: in the unweighted case
-        # fit/train-scoring masks are the same object, so they share one
-        # upload and one HBM allocation (the plane's content keys make
-        # the dedup hold even across separately-built equal arrays).
-        # A halving rung's subsampled masks carry a RUNG-SCOPED label
-        # ("mask.r1.fit"): the next rung's barrier then demotes exactly
-        # the previous rung's buffers — plane keys are shared by
-        # content, so a bare "mask." sweep could un-charge a sibling
-        # search's live masks under the same tenant
-        mask_ns = (f"mask.{rung.ns}." if rung is not None
-                   and rung.resource == "n_samples" else "mask.")
-        if data_mode != "stream":
-            fit_dev = _bput(fit_masks, put_masks, mask_ns + "fit")
-            test_dev = _bput(test_sc_masks, put_masks, mask_ns + "test")
-            train_sc_dev = (fit_dev if train_sc_masks is fit_masks
-                            else _bput(train_sc_masks, put_masks,
-                                       mask_ns + "train"))
-            if need_unweighted:
-                test_unw_dev = _bput(test_masks, put_masks,
-                                     mask_ns + "test_unw")
-                train_unw_dev = _bput(train_masks, put_masks,
-                                      mask_ns + "train_unw")
+                    def _padm(m, pad=pad):
+                        return np.concatenate(
+                            [m, np.zeros((n_folds, pad), m.dtype)], axis=1)
+                    train_sc_aliases_fit = train_sc_masks is fit_masks
+                    fit_masks = _padm(fit_masks)
+                    test_sc_masks = _padm(test_sc_masks)
+                    train_sc_masks = (fit_masks if train_sc_aliases_fit
+                                      else _padm(train_sc_masks))
+                    if need_unweighted:
+                        test_masks = _padm(test_masks)
+                        train_masks = _padm(train_masks)
+                sample_shard = NamedSharding(mesh, P(mesh_lib.DATA_AXIS))
+                mask_shard = NamedSharding(mesh, P(None, mesh_lib.DATA_AXIS))
+                data_dev = {k: _bput(v, sample_shard, f"data.{k}")
+                            for k, v in data.items()}
+                put_masks = mask_shard
             else:
-                test_unw_dev, train_unw_dev = test_dev, train_sc_dev
-        get_tracer().record_span(
-            "device_put.broadcast", _t_upload0, time.perf_counter(),
-            n_samples=n_samples, n_data_shards=config.n_data_shards)
+                data_dev = {k: _bput(v, repl, f"data.{k}")
+                            for k, v in data.items()}
+                put_masks = repl
+            # one device buffer per DISTINCT mask array: in the unweighted case
+            # fit/train-scoring masks are the same object, so they share one
+            # upload and one HBM allocation (the plane's content keys make
+            # the dedup hold even across separately-built equal arrays).
+            # A halving rung's subsampled masks carry a RUNG-SCOPED label
+            # ("mask.r1.fit"): the next rung's barrier then demotes exactly
+            # the previous rung's buffers — plane keys are shared by
+            # content, so a bare "mask." sweep could un-charge a sibling
+            # search's live masks under the same tenant
+            mask_ns = (f"mask.{rung.ns}." if rung is not None
+                       and rung.resource == "n_samples" else "mask.")
+            if data_mode != "stream":
+                fit_dev = _bput(fit_masks, put_masks, mask_ns + "fit")
+                test_dev = _bput(test_sc_masks, put_masks, mask_ns + "test")
+                train_sc_dev = (fit_dev if train_sc_masks is fit_masks
+                                else _bput(train_sc_masks, put_masks,
+                                           mask_ns + "train"))
+                if need_unweighted:
+                    test_unw_dev = _bput(test_masks, put_masks,
+                                         mask_ns + "test_unw")
+                    train_unw_dev = _bput(train_masks, put_masks,
+                                          mask_ns + "train_unw")
+                else:
+                    test_unw_dev, train_unw_dev = test_dev, train_sc_dev
 
         test_scores = {s: np.empty((n_cand, n_folds)) for s in scorer_names}
         train_scores = ({s: np.empty((n_cand, n_folds))
@@ -2122,337 +2134,338 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
         # ------------------------------------------------------------------
         # group plans: chunk geometry + (lazily built) programs
         # ------------------------------------------------------------------
-        plans = []
-        for gi, group in enumerate(groups):
-            static = {**base_params, **group.static_params}
-            nc = group.n_candidates
+        with get_tracer().span("fit.plan", n_groups=len(groups)):
+            plans = []
+            for gi, group in enumerate(groups):
+                static = {**base_params, **group.static_params}
+                nc = group.n_candidates
 
-            # convergence-sorted chunking: a lockstep launch executes the
-            # MAX iteration count over its lanes, so one wide launch pays
-            # the slowest candidate's iterations for every lane.  When
-            # the family knows a difficulty proxy (e.g. GLM: larger C =
-            # weaker regularisation = slower convergence), sort the
-            # group's candidates by it and split into several narrower
-            # launches — all chunks of a group share ONE compiled program
-            # (uniform width), so this costs dispatches, not compiles,
-            # and easy launches early-exit at their own iteration count.
-            # cv_results_ order is unaffected (cells are written through
-            # candidate_indices).
-            sorted_chunks = False
-            proxy_hook = getattr(family, "convergence_proxy", None)
-            if proxy_hook is not None and config.sort_candidates:
-                proxy = proxy_hook(group.dynamic_params, static)
-                if proxy is not None:
-                    proxy = np.asarray(proxy)
-                    if len(proxy) >= getattr(
-                            family, "min_sort_candidates", 32) \
-                            and np.unique(proxy).size > 1:
-                        order = np.argsort(proxy, kind="stable")
-                        group.candidate_indices = np.asarray(
-                            group.candidate_indices)[order]
-                        group.dynamic_params = {
-                            k: np.asarray(v)[order]
-                            for k, v in group.dynamic_params.items()}
-                        sorted_chunks = True
+                # convergence-sorted chunking: a lockstep launch executes the
+                # MAX iteration count over its lanes, so one wide launch pays
+                # the slowest candidate's iterations for every lane.  When
+                # the family knows a difficulty proxy (e.g. GLM: larger C =
+                # weaker regularisation = slower convergence), sort the
+                # group's candidates by it and split into several narrower
+                # launches — all chunks of a group share ONE compiled program
+                # (uniform width), so this costs dispatches, not compiles,
+                # and easy launches early-exit at their own iteration count.
+                # cv_results_ order is unaffected (cells are written through
+                # candidate_indices).
+                sorted_chunks = False
+                proxy_hook = getattr(family, "convergence_proxy", None)
+                if proxy_hook is not None and config.sort_candidates:
+                    proxy = proxy_hook(group.dynamic_params, static)
+                    if proxy is not None:
+                        proxy = np.asarray(proxy)
+                        if len(proxy) >= getattr(
+                                family, "min_sort_candidates", 32) \
+                                and np.unique(proxy).size > 1:
+                            order = np.argsort(proxy, kind="stable")
+                            group.candidate_indices = np.asarray(
+                                group.candidate_indices)[order]
+                            group.dynamic_params = {
+                                k: np.asarray(v)[order]
+                                for k, v in group.dynamic_params.items()}
+                            sorted_chunks = True
 
-            sorted_cap = None
-            if sorted_chunks:
-                # ~8 difficulty-graded launches per group (bounded below
-                # by the task-shard multiple so sharding stays uniform)
-                sorted_cap = min(
-                    mesh_lib.pad_to_multiple(nc, n_task_shards),
-                    max_cand_per_batch,
-                    max(n_task_shards,
-                        mesh_lib.pad_to_multiple(
-                            -(-nc // _SORTED_LAUNCHES), n_task_shards)))
-            plans.append({
-                "gi": gi, "group": group, "static": static, "nc": nc,
-                "sorted": sorted_chunks, "sorted_cap": sorted_cap})
+                sorted_cap = None
+                if sorted_chunks:
+                    # ~8 difficulty-graded launches per group (bounded below
+                    # by the task-shard multiple so sharding stays uniform)
+                    sorted_cap = min(
+                        mesh_lib.pad_to_multiple(nc, n_task_shards),
+                        max_cand_per_batch,
+                        max(n_task_shards,
+                            mesh_lib.pad_to_multiple(
+                                -(-nc // _SORTED_LAUNCHES), n_task_shards)))
+                plans.append({
+                    "gi": gi, "group": group, "static": static, "nc": nc,
+                    "sorted": sorted_chunks, "sorted_cap": sorted_cap})
 
-        # per-group prefix digests (stage-1 grouping): groups map
-        # many-to-one onto digests — groups differing only in
-        # final-step statics share the digest, and therefore the
-        # cached transformed matrix
-        px_digests = [None] * len(plans)
-        if px_stage:
-            px_digests = _prefix.group_prefix_digests(
-                groups, base_params, family)
-            if all(d is None for d in px_digests):
-                px_stage = False
-                px_state["fallbacks"].append("undigestable-prefix")
-        for plan, dg in zip(plans, px_digests):
-            plan["prefix"] = dg if px_stage else None
+            # per-group prefix digests (stage-1 grouping): groups map
+            # many-to-one onto digests — groups differing only in
+            # final-step statics share the digest, and therefore the
+            # cached transformed matrix
+            px_digests = [None] * len(plans)
+            if px_stage:
+                px_digests = _prefix.group_prefix_digests(
+                    groups, base_params, family)
+                if all(d is None for d in px_digests):
+                    px_stage = False
+                    px_state["fallbacks"].append("undigestable-prefix")
+            for plan, dg in zip(plans, px_digests):
+                plan["prefix"] = dg if px_stage else None
 
-        # ------------------------------------------------------------------
-        # waste-aware launch geometry (parallel/taskgrid.plan_geometry):
-        # per-group chunk widths from power-of-two bucketing over the
-        # measured cost model, minimizing launch overhead + padding
-        # waste.  The chosen plan is pinned into the checkpoint journal
-        # so a resumed search replays the EXACT same chunk ids; a
-        # structurally different journalled geometry is a hard error,
-        # never a silent mix of chunk ids.
-        # ------------------------------------------------------------------
-        from spark_sklearn_tpu.parallel.taskgrid import (
-            GeometryMismatchError, GeometryPlan, freeze,
-            geometry_cost_model, plan_geometry)
-        import dataclasses as _dc
-        # ledger-informed width ceiling: resident broadcast bytes (one
-        # count per distinct device buffer) plus each group's modeled
-        # per-candidate slope bound the widest chunk the HBM budget
-        # holds — a chunk the model says cannot fit is never planned,
-        # so OOM bisection becomes the fallback, not the discovery
-        # mechanism.  No budget (CPU default, or hbm_budget_bytes=0)
-        # means no caps: planning is bit-identical to the pre-ledger
-        # engine.
-        mem_caps = None
-        resident_est = 0
-        mem_kw = None
-        if ledger is not None:
-            seen_bufs = set()
-            for dev_arr in list(data_dev.values()) + [
-                    fit_dev, test_dev, train_sc_dev, test_unw_dev,
-                    train_unw_dev]:
-                if id(dev_arr) in seen_bufs:
-                    continue
-                seen_bufs.add(id(dev_arr))
-                # leaf-wise so a BCOO data operand prices its
-                # values+indices components (nnz-proportional; the
-                # wrapper itself has no nbytes) — dense arrays are
-                # their own single leaf, so this is the same number
-                # the old getattr spelling produced
-                for leaf in jax.tree_util.tree_leaves(dev_arr):
-                    resident_est += int(getattr(leaf, "nbytes", 0))
-            mem_kw = dict(
-                task_batched=task_batched,
-                n_samples=int(fit_masks.shape[1]),
-                mask_itemsize=int(fit_masks.dtype.itemsize),
-                n_scorers=len(scorers), return_train=return_train,
-                dtype_itemsize=int(np.dtype(dtype).itemsize))
-            budget = int(mem_ctx.get("budget_bytes", 0)) \
-                if mem_ctx is not None else 0
-            if budget:
-                mem_caps = [
-                    _memledger.width_cap(
-                        budget, resident_est,
-                        _memledger.model_group_footprint(
-                            p["group"].dynamic_params, 1, n_folds,
-                            **mem_kw)["per_candidate_bytes"],
-                        n_task_shards, max_cand_per_batch,
-                        ledger.safety_margin)
+            # --------------------------------------------------------------
+            # waste-aware launch geometry (parallel/taskgrid.plan_geometry):
+            # per-group chunk widths from power-of-two bucketing over the
+            # measured cost model, minimizing launch overhead + padding
+            # waste.  The chosen plan is pinned into the checkpoint journal
+            # so a resumed search replays the EXACT same chunk ids; a
+            # structurally different journalled geometry is a hard error,
+            # never a silent mix of chunk ids.
+            # --------------------------------------------------------------
+            from spark_sklearn_tpu.parallel.taskgrid import (
+                GeometryMismatchError, GeometryPlan, freeze,
+                geometry_cost_model, plan_geometry)
+            import dataclasses as _dc
+            # ledger-informed width ceiling: resident broadcast bytes (one
+            # count per distinct device buffer) plus each group's modeled
+            # per-candidate slope bound the widest chunk the HBM budget
+            # holds — a chunk the model says cannot fit is never planned,
+            # so OOM bisection becomes the fallback, not the discovery
+            # mechanism.  No budget (CPU default, or hbm_budget_bytes=0)
+            # means no caps: planning is bit-identical to the pre-ledger
+            # engine.
+            mem_caps = None
+            resident_est = 0
+            mem_kw = None
+            if ledger is not None:
+                seen_bufs = set()
+                for dev_arr in list(data_dev.values()) + [
+                        fit_dev, test_dev, train_sc_dev, test_unw_dev,
+                        train_unw_dev]:
+                    if id(dev_arr) in seen_bufs:
+                        continue
+                    seen_bufs.add(id(dev_arr))
+                    # leaf-wise so a BCOO data operand prices its
+                    # values+indices components (nnz-proportional; the
+                    # wrapper itself has no nbytes) — dense arrays are
+                    # their own single leaf, so this is the same number
+                    # the old getattr spelling produced
+                    for leaf in jax.tree_util.tree_leaves(dev_arr):
+                        resident_est += int(getattr(leaf, "nbytes", 0))
+                mem_kw = dict(
+                    task_batched=task_batched,
+                    n_samples=int(fit_masks.shape[1]),
+                    mask_itemsize=int(fit_masks.dtype.itemsize),
+                    n_scorers=len(scorers), return_train=return_train,
+                    dtype_itemsize=int(np.dtype(dtype).itemsize))
+                budget = int(mem_ctx.get("budget_bytes", 0)) \
+                    if mem_ctx is not None else 0
+                if budget:
+                    mem_caps = [
+                        _memledger.width_cap(
+                            budget, resident_est,
+                            _memledger.model_group_footprint(
+                                p["group"].dynamic_params, 1, n_folds,
+                                **mem_kw)["per_candidate_bytes"],
+                            n_task_shards, max_cand_per_batch,
+                            ledger.safety_margin)
+                        for p in plans]
+            geo_kwargs = dict(
+                sizes=[p["nc"] for p in plans],
+                sorted_caps=[p["sorted_cap"] for p in plans],
+                n_folds=n_folds, n_task_shards=n_task_shards,
+                max_width=max_cand_per_batch,
+                mode=getattr(config, "geometry_mode", "auto"),
+                cost_model=geometry_cost_model(),
+                overhead_override=getattr(config, "geometry_overhead_s", None),
+                lane_cost_override=getattr(config, "geometry_lane_cost_s",
+                                           None),
+                width_caps=mem_caps,
+                # fleet-wide padding: under cross-search fusion a padded
+                # lane is fillable by a same-program peer, so it prices at
+                # half the solo waste; 0.0 keeps pre-fusion plans
+                # byte-identical
+                fusion_lane_discount=0.5 if fusion_on else 0.0,
+                # chunk widths are loop-mode-invariant (chunk ids must stay
+                # byte-identical across modes so journals and the per-chunk
+                # OOM fallback interoperate); the key field keeps the two
+                # modes' plans distinct cache residents all the same
+                chunk_loop=chunk_loop,
+                # per-group shared-prefix digests join the PlanKey: a
+                # prefix-staged plan (suffix programs over cached (F, n,
+                # d') matrices) must never alias an atomic plan with the
+                # same sizes in the plan cache or plans.json
+                prefix=[p["prefix"] for p in plans])
+            #: per-group structure identity ACROSS rungs: the static params
+            #: minus the budgeted resource (survivor groups at rung k+1
+            #: carry the same key as the rung-0 group they came from, even
+            #: when the resource itself is static for the family)
+            rung_keys = None
+            if rung is not None:
+                rung_keys = [
+                    freeze({k: v for k, v in p["static"].items()
+                            if k != rung.resource})
                     for p in plans]
-        geo_kwargs = dict(
-            sizes=[p["nc"] for p in plans],
-            sorted_caps=[p["sorted_cap"] for p in plans],
-            n_folds=n_folds, n_task_shards=n_task_shards,
-            max_width=max_cand_per_batch,
-            mode=getattr(config, "geometry_mode", "auto"),
-            cost_model=geometry_cost_model(),
-            overhead_override=getattr(config, "geometry_overhead_s", None),
-            lane_cost_override=getattr(config, "geometry_lane_cost_s",
-                                       None),
-            width_caps=mem_caps,
-            # fleet-wide padding: under cross-search fusion a padded
-            # lane is fillable by a same-program peer, so it prices at
-            # half the solo waste; 0.0 keeps pre-fusion plans
-            # byte-identical
-            fusion_lane_discount=0.5 if fusion_on else 0.0,
-            # chunk widths are loop-mode-invariant (chunk ids must stay
-            # byte-identical across modes so journals and the per-chunk
-            # OOM fallback interoperate); the key field keeps the two
-            # modes' plans distinct cache residents all the same
-            chunk_loop=chunk_loop,
-            # per-group shared-prefix digests join the PlanKey: a
-            # prefix-staged plan (suffix programs over cached (F, n,
-            # d') matrices) must never alias an atomic plan with the
-            # same sizes in the plan cache or plans.json
-            prefix=[p["prefix"] for p in plans])
-        #: per-group structure identity ACROSS rungs: the static params
-        #: minus the budgeted resource (survivor groups at rung k+1
-        #: carry the same key as the rung-0 group they came from, even
-        #: when the resource itself is static for the family)
-        rung_keys = None
-        if rung is not None:
-            rung_keys = [
-                freeze({k: v for k, v in p["static"].items()
-                        if k != rung.resource})
-                for p in plans]
-        if rung is None or rung.itr == 0:
-            # the first rung (and every exhaustive search) prices the
-            # full grid exactly as before, plan-cache included
-            geo = plan_geometry(reuse=True, **geo_kwargs)
-        else:
-            # mid-search re-plan: the survivors' geometry is a
-            # search-local decision fed by the PREVIOUS rungs' measured
-            # timelines (the cost model observed each rung's pipeline
-            # on the way out), so it bypasses the cross-search plan
-            # cache.  With lane reclamation on, widths shrink to the
-            # surviving sizes — width-affine to already-compiled
-            # widths, priced by the model's measured compile wall;
-            # off, survivors stay pinned to rung-0 widths and ride
-            # along as padding (the A/B baseline).  Widths are pure
-            # geometry: cv_results_ is identical either way.
-            with get_tracer().span("geometry.replan", iter=rung.itr,
-                                   replan=bool(rung.replan)):
-                if rung.replan:
-                    geo = plan_geometry(
-                        reuse=False, min_width=rung.min_rung_width,
-                        preferred=[rung.last_widths.get(k)
-                                   for k in rung_keys],
-                        **geo_kwargs)
-                    geo = _dc.replace(geo, source="halving-replan")
-                else:
-                    geo = plan_geometry(reuse=False, **geo_kwargs)
-                    pinned = []
-                    for gg, k in zip(geo.groups, rung_keys):
-                        base_w = rung.base_widths.get(k)
-                        if base_w is not None \
-                                and base_w % n_task_shards == 0 \
-                                and base_w <= max_cand_per_batch:
-                            gg = _dc.replace(
-                                gg, width=int(base_w),
-                                n_chunks=-(-gg.n_candidates
-                                           // int(base_w)))
-                        pinned.append(gg)
-                    geo = _dc.replace(geo, groups=pinned,
-                                      source="halving-pinned")
-        if ckpt is not None:
-            journalled = ckpt.get_meta("geometry_plan")
-            if journalled is not None:
-                jplan = GeometryPlan.from_dict(journalled)
-                if jplan.signature() != geo.signature():
-                    raise GeometryMismatchError(
-                        "checkpoint was written under a different launch "
-                        "geometry (journalled per-group (n_candidates, "
-                        f"sorted) = {jplan.signature()}, current = "
-                        f"{geo.signature()}); resuming would mix chunk "
-                        "ids across geometries.  Delete "
-                        f"{ckpt.path!r} or restore the original "
-                        "sort_candidates/grid configuration.")
-                # the journalled widths must still be valid under the
-                # CURRENT mesh and HBM bound: every other width path
-                # guarantees shard-multiple widths within
-                # max_cand_per_batch, and replaying a stale plan would
-                # silently break that (e.g. resumed on a smaller mesh,
-                # or after lowering max_tasks_per_batch to dodge an OOM)
-                bad = [g.width for g in jplan.groups
-                       if g.width % n_task_shards != 0
-                       or g.width > max_cand_per_batch]
-                if bad:
-                    raise GeometryMismatchError(
-                        f"journalled chunk widths {bad} are invalid "
-                        f"under the current configuration (task shards="
-                        f"{n_task_shards}, max width per launch="
-                        f"{max_cand_per_batch}); the checkpoint was "
-                        "written on a different mesh or "
-                        "max_tasks_per_batch.  Delete "
-                        f"{ckpt.path!r} or restore the original "
-                        "configuration.")
-                # replay: widths come from the journal, so chunk ids —
-                # and therefore resume hits — match the original run
-                # even if the cost model has since drifted
-                import dataclasses as _dc
-                geo = _dc.replace(jplan, source="journal")
+            if rung is None or rung.itr == 0:
+                # the first rung (and every exhaustive search) prices the
+                # full grid exactly as before, plan-cache included
+                geo = plan_geometry(reuse=True, **geo_kwargs)
             else:
-                ckpt.put_meta("geometry_plan", geo.to_dict())
-            # the prefix grouping journals beside the geometry: chunk
-            # results written under a prefix-staged run carry suffix
-            # semantics (same numbers, but per-group programs keyed on
-            # the digest), and a resume whose digests drifted — grid
-            # edited, step params changed, prefix_reuse toggled off —
-            # must fail loudly like any other geometry drift, never
-            # mix.  Atomic searches journal NO prefix meta (their
-            # checkpoint artifacts stay byte-compatible with the
-            # pre-prefix format and the prefix_reuse=False escape
-            # hatch), so an atomic checkpoint may resume under shared
-            # staging: the durable chunks are bit-exact either way and
-            # the meta then records the shared grouping going forward
-            px_cur = [p["prefix"] for p in plans]
-            px_journalled = ckpt.get_meta("prefix_plan")
-            if px_journalled is not None:
-                if list(px_journalled) != list(px_cur):
-                    raise GeometryMismatchError(
-                        "checkpoint was written under a different "
-                        "shared-prefix grouping (journalled per-group "
-                        f"digests = {px_journalled}, current = "
-                        f"{px_cur}); resuming would mix prefix-staged "
-                        "and atomic chunk results.  Delete "
-                        f"{ckpt.path!r} or restore the original grid/"
-                        "prefix_reuse configuration.")
-            elif any(d is not None for d in px_cur):
-                ckpt.put_meta("prefix_plan", px_cur)
-        metrics.put("geometry", geo.report_block())
-        if rung is not None:
-            # rung bookkeeping: remember rung-0 widths (the pin/affinity
-            # anchors) and account the lanes this rung's re-plan
-            # reclaimed vs. running the SAME survivors at rung-0 widths
-            for gg, k in zip(geo.groups, rung_keys):
-                rung.base_widths.setdefault(k, int(gg.width))
-                rung.last_widths[k] = int(gg.width)
-            rung_rec = rung.current
-            if rung_rec is not None:
-                rung_rec["widths"] = [int(g.width) for g in geo.groups]
-                rung_rec["n_launches_planned"] = int(
-                    sum(g.n_chunks for g in geo.groups))
-                rung_rec["cost_observations"] = int(
-                    geo.cost_model.get("n_observations", 0))
-                if rung.itr > 0:
-                    base_lanes = act_lanes = 0
-                    for gg, k in zip(geo.groups, rung_keys):
-                        bw = rung.base_widths.get(k, gg.width)
-                        base_lanes += (-(-gg.n_candidates // bw)) \
-                            * bw * n_folds
-                        act_lanes += gg.n_chunks * gg.width * n_folds
-                    reclaimed = max(0, base_lanes - act_lanes)
-                    rung_rec["lanes_reclaimed"] = int(reclaimed)
-                    rung_rec["padding_saved_frac"] = round(
-                        reclaimed / base_lanes, 6) if base_lanes else 0.0
-                    rung.lanes_reclaimed_total += int(reclaimed)
+                # mid-search re-plan: the survivors' geometry is a
+                # search-local decision fed by the PREVIOUS rungs' measured
+                # timelines (the cost model observed each rung's pipeline
+                # on the way out), so it bypasses the cross-search plan
+                # cache.  With lane reclamation on, widths shrink to the
+                # surviving sizes — width-affine to already-compiled
+                # widths, priced by the model's measured compile wall;
+                # off, survivors stay pinned to rung-0 widths and ride
+                # along as padding (the A/B baseline).  Widths are pure
+                # geometry: cv_results_ is identical either way.
+                with get_tracer().span("geometry.replan", iter=rung.itr,
+                                       replan=bool(rung.replan)):
+                    if rung.replan:
+                        geo = plan_geometry(
+                            reuse=False, min_width=rung.min_rung_width,
+                            preferred=[rung.last_widths.get(k)
+                                       for k in rung_keys],
+                            **geo_kwargs)
+                        geo = _dc.replace(geo, source="halving-replan")
+                    else:
+                        geo = plan_geometry(reuse=False, **geo_kwargs)
+                        pinned = []
+                        for gg, k in zip(geo.groups, rung_keys):
+                            base_w = rung.base_widths.get(k)
+                            if base_w is not None \
+                                    and base_w % n_task_shards == 0 \
+                                    and base_w <= max_cand_per_batch:
+                                gg = _dc.replace(
+                                    gg, width=int(base_w),
+                                    n_chunks=-(-gg.n_candidates
+                                               // int(base_w)))
+                            pinned.append(gg)
+                        geo = _dc.replace(geo, groups=pinned,
+                                          source="halving-pinned")
+            if ckpt is not None:
+                journalled = ckpt.get_meta("geometry_plan")
+                if journalled is not None:
+                    jplan = GeometryPlan.from_dict(journalled)
+                    if jplan.signature() != geo.signature():
+                        raise GeometryMismatchError(
+                            "checkpoint was written under a different launch "
+                            "geometry (journalled per-group (n_candidates, "
+                            f"sorted) = {jplan.signature()}, current = "
+                            f"{geo.signature()}); resuming would mix chunk "
+                            "ids across geometries.  Delete "
+                            f"{ckpt.path!r} or restore the original "
+                            "sort_candidates/grid configuration.")
+                    # the journalled widths must still be valid under the
+                    # CURRENT mesh and HBM bound: every other width path
+                    # guarantees shard-multiple widths within
+                    # max_cand_per_batch, and replaying a stale plan would
+                    # silently break that (e.g. resumed on a smaller mesh,
+                    # or after lowering max_tasks_per_batch to dodge an OOM)
+                    bad = [g.width for g in jplan.groups
+                           if g.width % n_task_shards != 0
+                           or g.width > max_cand_per_batch]
+                    if bad:
+                        raise GeometryMismatchError(
+                            f"journalled chunk widths {bad} are invalid "
+                            f"under the current configuration (task shards="
+                            f"{n_task_shards}, max width per launch="
+                            f"{max_cand_per_batch}); the checkpoint was "
+                            "written on a different mesh or "
+                            "max_tasks_per_batch.  Delete "
+                            f"{ckpt.path!r} or restore the original "
+                            "configuration.")
+                    # replay: widths come from the journal, so chunk ids —
+                    # and therefore resume hits — match the original run
+                    # even if the cost model has since drifted
+                    import dataclasses as _dc
+                    geo = _dc.replace(jplan, source="journal")
+                else:
+                    ckpt.put_meta("geometry_plan", geo.to_dict())
+                # the prefix grouping journals beside the geometry: chunk
+                # results written under a prefix-staged run carry suffix
+                # semantics (same numbers, but per-group programs keyed on
+                # the digest), and a resume whose digests drifted — grid
+                # edited, step params changed, prefix_reuse toggled off —
+                # must fail loudly like any other geometry drift, never
+                # mix.  Atomic searches journal NO prefix meta (their
+                # checkpoint artifacts stay byte-compatible with the
+                # pre-prefix format and the prefix_reuse=False escape
+                # hatch), so an atomic checkpoint may resume under shared
+                # staging: the durable chunks are bit-exact either way and
+                # the meta then records the shared grouping going forward
+                px_cur = [p["prefix"] for p in plans]
+                px_journalled = ckpt.get_meta("prefix_plan")
+                if px_journalled is not None:
+                    if list(px_journalled) != list(px_cur):
+                        raise GeometryMismatchError(
+                            "checkpoint was written under a different "
+                            "shared-prefix grouping (journalled per-group "
+                            f"digests = {px_journalled}, current = "
+                            f"{px_cur}); resuming would mix prefix-staged "
+                            "and atomic chunk results.  Delete "
+                            f"{ckpt.path!r} or restore the original grid/"
+                            "prefix_reuse configuration.")
+                elif any(d is not None for d in px_cur):
+                    ckpt.put_meta("prefix_plan", px_cur)
+            metrics.put("geometry", geo.report_block())
+            if rung is not None:
+                # rung bookkeeping: remember rung-0 widths (the pin/affinity
+                # anchors) and account the lanes this rung's re-plan
+                # reclaimed vs. running the SAME survivors at rung-0 widths
+                for gg, k in zip(geo.groups, rung_keys):
+                    rung.base_widths.setdefault(k, int(gg.width))
+                    rung.last_widths[k] = int(gg.width)
+                rung_rec = rung.current
+                if rung_rec is not None:
+                    rung_rec["widths"] = [int(g.width) for g in geo.groups]
+                    rung_rec["n_launches_planned"] = int(
+                        sum(g.n_chunks for g in geo.groups))
+                    rung_rec["cost_observations"] = int(
+                        geo.cost_model.get("n_observations", 0))
+                    if rung.itr > 0:
+                        base_lanes = act_lanes = 0
+                        for gg, k in zip(geo.groups, rung_keys):
+                            bw = rung.base_widths.get(k, gg.width)
+                            base_lanes += (-(-gg.n_candidates // bw)) \
+                                * bw * n_folds
+                            act_lanes += gg.n_chunks * gg.width * n_folds
+                        reclaimed = max(0, base_lanes - act_lanes)
+                        rung_rec["lanes_reclaimed"] = int(reclaimed)
+                        rung_rec["padding_saved_frac"] = round(
+                            reclaimed / base_lanes, 6) if base_lanes else 0.0
+                        rung.lanes_reclaimed_total += int(reclaimed)
 
-        for plan, gg in zip(plans, geo.groups):
-            gi, nc = plan["gi"], plan["nc"]
-            sorted_chunks = plan["sorted"]
-            nc_batch = plan["nc_batch"] = int(gg.width)
-            # chunk resume state resolved up front: the calibration
-            # structure (which chunk calibrates, which chunks fuse) must
-            # be known before dispatch, not discovered mid-pipeline
-            chunks = []
-            for lo in range(0, nc, nc_batch):
-                hi = min(lo + nc_batch, nc)
-                # sorted chunks write cells through a PERMUTED index set:
-                # a checkpoint from an unsorted run must not resume into
-                # them (and vice versa), so the id carries the mode.
-                # Halving rungs prefix their namespace ("r2:...") so the
-                # journal, fault events and trace stay rung-addressable
-                # and supervisor bisection keys can never collide
-                # across rungs
-                chunk_id = cid_ns + f"{gi}:{lo}:{hi}" + \
-                    (":s" if sorted_chunks else "")
-                rec = ckpt.get(chunk_id) if ckpt is not None else None
-                if rec is not None and return_train and \
-                        rec.get("train") is None:
-                    rec = None  # written without train scores: recompute
-                chunks.append((lo, hi, chunk_id, rec))
-            plan["chunks"] = chunks
-            plan["n_live"] = sum(1 for c in chunks if c[3] is None)
-
-        if ledger is not None and mem_ctx is not None:
-            # register every (group, chosen width) footprint with the
-            # ledger — the per-group records search_report["memory"]
-            # renders, the memory.footprint trace instants
-            # trace_summary digests, and the modeled bytes OOM events
-            # report against the budget
-            mem_ctx["resident_bytes"] = resident_est
             for plan, gg in zip(plans, geo.groups):
-                fp = _memledger.model_group_footprint(
-                    plan["group"].dynamic_params, plan["nc_batch"],
-                    n_folds, **mem_kw)
-                rec = {"group": cid_ns + str(plan["gi"]),
-                       "width": int(plan["nc_batch"]),
-                       "capped": bool(getattr(gg, "capped", False)),
-                       "resident_bytes": int(resident_est), **fp}
-                plan["mem_chunk_bytes"] = int(fp["chunk_bytes"])
-                ledger.note_group(rec)
-                mem_ctx["groups"].append(rec)
+                gi, nc = plan["gi"], plan["nc"]
+                sorted_chunks = plan["sorted"]
+                nc_batch = plan["nc_batch"] = int(gg.width)
+                # chunk resume state resolved up front: the calibration
+                # structure (which chunk calibrates, which chunks fuse) must
+                # be known before dispatch, not discovered mid-pipeline
+                chunks = []
+                for lo in range(0, nc, nc_batch):
+                    hi = min(lo + nc_batch, nc)
+                    # sorted chunks write cells through a PERMUTED index set:
+                    # a checkpoint from an unsorted run must not resume into
+                    # them (and vice versa), so the id carries the mode.
+                    # Halving rungs prefix their namespace ("r2:...") so the
+                    # journal, fault events and trace stay rung-addressable
+                    # and supervisor bisection keys can never collide
+                    # across rungs
+                    chunk_id = cid_ns + f"{gi}:{lo}:{hi}" + \
+                        (":s" if sorted_chunks else "")
+                    rec = ckpt.get(chunk_id) if ckpt is not None else None
+                    if rec is not None and return_train and \
+                            rec.get("train") is None:
+                        rec = None  # written without train scores: recompute
+                    chunks.append((lo, hi, chunk_id, rec))
+                plan["chunks"] = chunks
+                plan["n_live"] = sum(1 for c in chunks if c[3] is None)
+
+            if ledger is not None and mem_ctx is not None:
+                # register every (group, chosen width) footprint with the
+                # ledger — the per-group records search_report["memory"]
+                # renders, the memory.footprint trace instants
+                # trace_summary digests, and the modeled bytes OOM events
+                # report against the budget
+                mem_ctx["resident_bytes"] = resident_est
+                for plan, gg in zip(plans, geo.groups):
+                    fp = _memledger.model_group_footprint(
+                        plan["group"].dynamic_params, plan["nc_batch"],
+                        n_folds, **mem_kw)
+                    rec = {"group": cid_ns + str(plan["gi"]),
+                           "width": int(plan["nc_batch"]),
+                           "capped": bool(getattr(gg, "capped", False)),
+                           "resident_bytes": int(resident_est), **fp}
+                    plan["mem_chunk_bytes"] = int(fp["chunk_bytes"])
+                    ledger.note_group(rec)
+                    mem_ctx["groups"].append(rec)
 
         def plan_data(plan):
             """The launch data dict: prefix-staged plans swap the raw
@@ -2604,11 +2617,12 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 def fit_batch_tb(dyn_t, data_d, w_t,
                                  static={**static, "__n_folds__": n_folds,
                                          "__bf16__": config.bf16_matmul}):
-                    model = family.fit_task_batched(
-                        dyn_t, static, data_d, w_t, meta)
-                    return jax.tree_util.tree_map(
-                        lambda l: l.reshape(
-                            (nc_batch, n_folds) + l.shape[1:]), model)
+                    with jax.named_scope("sst.fit"):
+                        model = family.fit_task_batched(
+                            dyn_t, static, data_d, w_t, meta)
+                        return jax.tree_util.tree_map(
+                            lambda l: l.reshape(
+                                (nc_batch, n_folds) + l.shape[1:]), model)
 
                 # the mesh joins the in-memory key exactly as
                 # mesh_desc joins the store key (declared-vs-actual
@@ -2647,7 +2661,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         return family.fit(dyn_scalars, static, data_d, w,
                                           meta)
                     return jax.vmap(one_fold)(train_m)
-                return jax.vmap(one_cand)(dyn_arrs)
+                with jax.named_scope("sst.fit"):
+                    return jax.vmap(one_cand)(dyn_arrs)
 
             def score_batch_wide(models, data_d, test_m, train_m, test_u,
                                  train_u, static=static):
@@ -2725,8 +2740,13 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         model_c, test_m, train_m, test_u, train_u)
                 return jax.vmap(one_cand)(models)
 
-            score_batch = score_batch_wide if all_cores \
+            score_core = score_batch_wide if all_cores \
                 else score_batch_nested
+
+            @functools.wraps(score_core)    # the program keeps its name
+            def score_batch(*args):
+                with jax.named_scope("sst.score"):
+                    return score_core(*args)
 
             fused_jit = None
             if fused_mode:
@@ -2735,27 +2755,28 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 def fused_batch(dyn_t, data_d, w_fit, test_m, train_m,
                                 test_u, train_u):
                     models = fit_core(dyn_t, data_d, w_fit)
-                    bad = _models_health(models)
-                    if bad is None:
-                        leaf = jax.tree_util.tree_leaves(models)[0]
-                        bad = jnp.zeros(leaf.shape[:2], bool)
-                    # executed-iteration counts for FLOP/MFU accounting
-                    # (-1 sentinel: family has no iterative solver).
-                    # max = lockstep meaning (a launch executes the max
-                    # over its lanes); sum = per-lane meaning (scan-
-                    # sequential families like SVC execute each lane's
-                    # own count) — consumers pick the one that matches
-                    # the family's execution model.
-                    iters = jnp.int32(-1)
-                    iters_sum = jnp.int32(-1)
-                    if isinstance(models, dict):
-                        it = models.get("n_iter_exec",
-                                        models.get("n_iter"))
-                        if it is not None:
-                            iters = jnp.max(it).astype(jnp.int32)
-                            iters_sum = jnp.sum(it).astype(jnp.int32)
-                    te, tr = score_batch_wide(models, data_d, test_m,
-                                              train_m, test_u, train_u)
+                    with jax.named_scope("sst.score"):
+                        bad = _models_health(models)
+                        if bad is None:
+                            leaf = jax.tree_util.tree_leaves(models)[0]
+                            bad = jnp.zeros(leaf.shape[:2], bool)
+                        # executed-iteration counts for FLOP/MFU accounting
+                        # (-1 sentinel: family has no iterative solver).
+                        # max = lockstep meaning (a launch executes the max
+                        # over its lanes); sum = per-lane meaning (scan-
+                        # sequential families like SVC execute each lane's
+                        # own count) — consumers pick the one that matches
+                        # the family's execution model.
+                        iters = jnp.int32(-1)
+                        iters_sum = jnp.int32(-1)
+                        if isinstance(models, dict):
+                            it = models.get("n_iter_exec",
+                                            models.get("n_iter"))
+                            if it is not None:
+                                iters = jnp.max(it).astype(jnp.int32)
+                                iters_sum = jnp.sum(it).astype(jnp.int32)
+                        te, tr = score_batch_wide(models, data_d, test_m,
+                                                  train_m, test_u, train_u)
                     return te, tr, bad, iters, iters_sum
 
                 fused_jit = _cached_program(
@@ -4106,85 +4127,86 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 "(%r): %d candidate(s) shed to error_score, the search "
                 "returns declared-partial results", exc, len(left))
         finally:
-            # the scheduler's per-search view (queue waits, interleave,
-            # measured tenant shares) — zeroed enabled=False shape for
-            # a standalone fit, so the report schema never changes
-            metrics.put("scheduler", _serve.report_block(binding))
-            # the compile thread traces under this search's jax config
-            # (e.g. temporarily-enabled x64): join it before returning.
-            # A halving rung only DRAINS it — no queued AOT job crosses
-            # the rung boundary's config restore, but the thread stays
-            # warm for the next rung (halving closes the shared
-            # pipeline when the last rung ends).
-            if rung is None:
-                pipe.close()
-            else:
-                pipe.drain()
-            pr = pipe.report()
-            cache1 = persistent_cache_counts()
-            pr["persistent_cache_hits"] = cache1["hits"] - cache0["hits"]
-            pr["persistent_cache_misses"] = \
-                cache1["misses"] - cache0["misses"]
-            # distinct traced-program constructions this search (program-
-            # cache misses; each is one python->jaxpr->HLO walk whether
-            # the compile then ran on the AOT thread or at jit dispatch)
-            total_builds = _program_build_count() - builds0
-            pr["n_compiles"] = total_builds
-            metrics.put("pipeline", pr)
-            metrics.put("chunkloop", chunkloop_block(
-                metrics.struct("chunkloop"), mode=chunk_loop,
-                enabled=scan_mode,
-                score_attribution="folded" if scan_mode
-                else "calibrated"))
-            metrics.put("prefix", _prefix.prefix_block(
-                metrics.struct("prefix"),
-                mode="shared" if px_on else "atomic",
-                enabled=bool(px_state.get("enabled"))))
-            # feed the measured per-launch overhead / per-lane cost back
-            # into the geometry planner's cost model: the NEXT search
-            # over a new structure prices its widths from real walls
-            # (plans already computed this process keep their widths via
-            # the plan cache, so drift never forces recompiles).  For a
-            # halving search this runs at EVERY rung boundary over that
-            # rung's timeline slice — rung k+1's re-plan prices its
-            # widths from rung k's measured overhead and lane cost, not
-            # from cross-search priors.
-            # n_builds normalizes the compile lane PER PROGRAM: a
-            # scanned group compiles once however many chunks it
-            # serves, and the old per-timeline-median heuristic would
-            # double-count that one compile into every launch's excess
-            launches = pr.get("launches") or []
-            if rung is not None:
-                new_launches = launches[rung.launches_seen:]
-                rung.launches_seen = len(launches)
-                nb = total_builds - int(
-                    getattr(rung, "builds_observed", 0))
-                rung.builds_observed = total_builds
-                geometry_cost_model().observe(new_launches, n_builds=nb)
-                rung_rec = rung.current
-                if rung_rec is not None:
-                    rung_rec["n_chunks_resumed"] = int(
-                        metrics.data.get("n_chunks_resumed", 0)) \
-                        - resumed0
-                    wall = float(pr.get("wall_s", 0.0))
-                    rung_rec["pipe_wall_s"] = round(
-                        max(0.0, wall - rung.prev_pipe_wall), 4)
-                    rung.prev_pipe_wall = wall
-                    # the rung's end boundary in the shared pipeline's
-                    # cumulative launch timeline — what the attribution
-                    # analyzer slices per-rung lanes from
-                    rung_rec["launches_end"] = len(launches)
-            else:
-                geometry_cost_model().observe(launches,
-                                              n_builds=total_builds)
-            # persist the plan cache + cost-model state next to the AOT
-            # artifacts: a fresh process then plans the SAME chunk
-            # widths — and resolves the same stored programs — without
-            # re-measuring (parallel/programstore.py plans.json)
-            if search_store is not None:
-                from spark_sklearn_tpu.parallel.taskgrid import (
-                    export_plan_state)
-                search_store.save_plan_state(export_plan_state())
+            with get_tracer().span("fit.report"):
+                # the scheduler's per-search view (queue waits, interleave,
+                # measured tenant shares) — zeroed enabled=False shape for
+                # a standalone fit, so the report schema never changes
+                metrics.put("scheduler", _serve.report_block(binding))
+                # the compile thread traces under this search's jax config
+                # (e.g. temporarily-enabled x64): join it before returning.
+                # A halving rung only DRAINS it — no queued AOT job crosses
+                # the rung boundary's config restore, but the thread stays
+                # warm for the next rung (halving closes the shared
+                # pipeline when the last rung ends).
+                if rung is None:
+                    pipe.close()
+                else:
+                    pipe.drain()
+                pr = pipe.report()
+                cache1 = persistent_cache_counts()
+                pr["persistent_cache_hits"] = cache1["hits"] - cache0["hits"]
+                pr["persistent_cache_misses"] = \
+                    cache1["misses"] - cache0["misses"]
+                # distinct traced-program constructions this search (program-
+                # cache misses; each is one python->jaxpr->HLO walk whether
+                # the compile then ran on the AOT thread or at jit dispatch)
+                total_builds = _program_build_count() - builds0
+                pr["n_compiles"] = total_builds
+                metrics.put("pipeline", pr)
+                metrics.put("chunkloop", chunkloop_block(
+                    metrics.struct("chunkloop"), mode=chunk_loop,
+                    enabled=scan_mode,
+                    score_attribution="folded" if scan_mode
+                    else "calibrated"))
+                metrics.put("prefix", _prefix.prefix_block(
+                    metrics.struct("prefix"),
+                    mode="shared" if px_on else "atomic",
+                    enabled=bool(px_state.get("enabled"))))
+                # feed the measured per-launch overhead / per-lane cost back
+                # into the geometry planner's cost model: the NEXT search
+                # over a new structure prices its widths from real walls
+                # (plans already computed this process keep their widths via
+                # the plan cache, so drift never forces recompiles).  For a
+                # halving search this runs at EVERY rung boundary over that
+                # rung's timeline slice — rung k+1's re-plan prices its
+                # widths from rung k's measured overhead and lane cost, not
+                # from cross-search priors.
+                # n_builds normalizes the compile lane PER PROGRAM: a
+                # scanned group compiles once however many chunks it
+                # serves, and the old per-timeline-median heuristic would
+                # double-count that one compile into every launch's excess
+                launches = pr.get("launches") or []
+                if rung is not None:
+                    new_launches = launches[rung.launches_seen:]
+                    rung.launches_seen = len(launches)
+                    nb = total_builds - int(
+                        getattr(rung, "builds_observed", 0))
+                    rung.builds_observed = total_builds
+                    geometry_cost_model().observe(new_launches, n_builds=nb)
+                    rung_rec = rung.current
+                    if rung_rec is not None:
+                        rung_rec["n_chunks_resumed"] = int(
+                            metrics.data.get("n_chunks_resumed", 0)) \
+                            - resumed0
+                        wall = float(pr.get("wall_s", 0.0))
+                        rung_rec["pipe_wall_s"] = round(
+                            max(0.0, wall - rung.prev_pipe_wall), 4)
+                        rung.prev_pipe_wall = wall
+                        # the rung's end boundary in the shared pipeline's
+                        # cumulative launch timeline — what the attribution
+                        # analyzer slices per-rung lanes from
+                        rung_rec["launches_end"] = len(launches)
+                else:
+                    geometry_cost_model().observe(launches,
+                                                  n_builds=total_builds)
+                # persist the plan cache + cost-model state next to the AOT
+                # artifacts: a fresh process then plans the SAME chunk
+                # widths — and resolves the same stored programs — without
+                # re-measuring (parallel/programstore.py plans.json)
+                if search_store is not None:
+                    from spark_sklearn_tpu.parallel.taskgrid import (
+                        export_plan_state)
+                    search_store.save_plan_state(export_plan_state())
 
     def _print_task_end_lines(self, candidates, idx, n_folds, scorer_names,
                               test_scores, train_scores, return_train,

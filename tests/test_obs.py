@@ -69,6 +69,10 @@ class TestTracer:
             tr.instant("b")
         tr.record_span("c", 0.0, 1.0)
         tr.record_async("d", 0.0, 1.0, track="t")
+        # a span the vocabulary mirrors into a profiler trace is the
+        # same no-op while no profiler session is live
+        with tr.span("stage", key="k") as sp:
+            sp.set(result=1)
         assert len(tr) == 0
 
     def test_nested_spans_record_with_thread(self):
@@ -388,7 +392,11 @@ class TestTracedUntracedParity:
             return gs
 
         run(False)                       # warm the program cache
-        a, b = run(False), run(True)
+        a = run(False)
+        # no profiler, TpuConfig.trace unset: nothing was recorded
+        assert len(clean_tracer) == 0
+        b = run(True)
+        assert len(clean_tracer) > 0
         # cv_results_ bit-exact (tracing must not touch the math)
         for k in a.cv_results_:
             if "time" in k or k == "params":

@@ -308,6 +308,28 @@ class TestSpanRules:
         assert any("'stag'" in s for s in syms)
         assert any("'lunch'" in s for s in syms)
 
+    @pytest.mark.parametrize("call, flagged", [
+        ("jax.named_scope('glm_lbfgs.forward')", False),
+        ("named_scope('sst.fit')", False),
+        ("jax.named_scope('glm_lbfgs.forwrd')", True),
+        ("jax.named_scope('stage')", True),      # a span is no scope
+        ("jax.named_scope(name)", True),         # not checkable
+    ])
+    def test_named_scope_vocabulary(self, tmp_path, call, flagged):
+        spans = write(tmp_path, "pkg/spans.py", _FIXTURE_SPANS + (
+            "def known_scope_names():\n"
+            "    return frozenset({'glm_lbfgs.forward', 'sst.fit'})\n"))
+        write(tmp_path, "pkg/a.py", (
+            "def f(jax, named_scope, name):\n"
+            f"    with {call}:\n"
+            "        pass\n"))
+        proj = make_project(tmp_path, spans_path=spans)
+        hits = rule_hits(lint(proj, ["span-unknown-name"]),
+                         "span-unknown-name")
+        assert len(hits) == (1 if flagged else 0), hits
+        if flagged:
+            assert hits[0]["line"] == 2
+
     def test_span_context_manager(self, tmp_path):
         spans = write(tmp_path, "pkg/spans.py", _FIXTURE_SPANS)
         write(tmp_path, "pkg/a.py", (

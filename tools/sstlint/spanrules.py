@@ -41,6 +41,15 @@ def _span_calls(mod: ModuleInfo):
         yield node
 
 
+def _scope_calls(mod: ModuleInfo):
+    """``jax.named_scope(...)`` / ``named_scope(...)`` calls."""
+    for node in ast.walk(mod.tree):
+        if isinstance(node, ast.Call) and (
+                astutil.call_name(node) or "").split(".")[-1] \
+                == "named_scope":
+            yield node
+
+
 def _span_name(node: ast.Call) -> Optional[str]:
     """The literal (or f-string constant prefix) name of a recorder
     call; None when the name is not statically known."""
@@ -70,11 +79,26 @@ def check_span_vocabulary(ctx: Context) -> Iterable[Finding]:
     """Every recorded span/instant/async name must be registered in
     the span vocabulary (``obs/spans.py``) — trace_summary groups and
     documents by those names, so an ad-hoc name silently falls out of
-    every digest."""
+    every digest.  Likewise every ``jax.named_scope`` name (kind
+    "scope"): the benchmark's trace readers sum device time by them."""
     spans = _load_spans(ctx)
     if spans is None:
         return
+    scope_names = getattr(spans, "known_scope_names", frozenset)()
     for mod in ctx.modules:
+        for node in _scope_calls(mod):
+            name = _span_name(node)
+            if name in scope_names or \
+                    mod.suppressed("span-unknown-name", node.lineno):
+                continue
+            yield Finding(
+                "span-unknown-name", mod.relpath, node.lineno,
+                f"named scope {name!r} is not registered (kind "
+                "\"scope\") in obs/spans.py SPAN_VOCABULARY"
+                if name is not None else
+                "named scope is not a literal — sstlint cannot check "
+                "it against the vocabulary",
+                symbol=name or f"<dynamic>@{mod.qualname(node)}")
         if mod.relpath.endswith("obs/trace.py"):
             continue               # the recorder itself
         for node in _span_calls(mod):
