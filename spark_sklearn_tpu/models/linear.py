@@ -20,15 +20,84 @@ Numeric conventions follow sklearn so the vendored oracle tests pass:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from spark_sklearn_tpu.models.base import (
     Family, NotCompiledError, encode_labels, register_family)
 from spark_sklearn_tpu.ops.solvers import lbfgs
+
+
+def _multinomial_loss(Z, wT, y1h):
+    """Weighted multinomial log-loss per lane: logits Z (n, B, k), row
+    weights wT (n, B), one-hot labels y1h (n, k) -> (B,)."""
+    lse = jax.scipy.special.logsumexp(Z, axis=2)              # (n, B)
+    fit_term = lse - jnp.einsum("nbk,nk->nb", Z, y1h)
+    return jnp.sum(wT * fit_term, axis=0)
+
+
+def _multinomial_trial_losses(Z, Zp, alphas, wT, y1h):
+    """`_multinomial_loss(Z + a*Zp, ...)` for every row a of alphas
+    (T, B) -> (T, B): the line search of `glm_lbfgs_batched`, written so
+    that XLA:TPU makes ONE pass over (Z, Zp) of it and writes nothing
+    but the (T, B) sums.
+
+    `jax.vmap` of the loss over the trial axis compiles there to five
+    fusions that hand each other f32[T, n, B] tensors through HBM, and
+    to two relayout copies of Z: the reduction over classes is a
+    `reduce` of its own and is not fused into the one over rows.  So:
+
+    - the class axis is unrolled over the k (n, B) class planes, sliced
+      BEFORE the step is applied (slicing Z + a*Zp materialises
+      f32[T, n, B, k]): both reductions over classes, the logsumexp and
+      the label's logit, become elementwise;
+    - the trial axis is unrolled too, not vmapped: under vmap every
+      plane is broadcast along the trial axis, and XLA:TPU does not fuse
+      a slice into a broadcast, it writes the twenty planes to HBM first
+      (a copy of Z and one of Zp an iteration);
+    - the T sums over rows are ONE variadic `lax.reduce`, which is one
+      fusion; T separate `jnp.sum`s compile to T passes over (Z, Zp).
+
+    Row by row it is the arithmetic of `_multinomial_loss`: the shift is
+    the trial's own maximum, the label's logit is subtracted before the
+    sum over rows (summed apart, sum(w*lse) - sum(w*z_y) cancels to a
+    loss of 1e-3 from terms of 1e5 on a fit that separates its
+    classes), a non-finite trial reads non-finite.  Only the order of
+    the sums is the compiler's.  Measured on a v5e: PERF.md, PR 27."""
+    k = Z.shape[2]
+    planes = [(Z[:, :, j], Zp[:, :, j], y1h[:, j, None]) for j in range(k)]
+
+    def weighted_rows(a):                                 # (B,) -> (n, B)
+        zt = [z + a[None, :] * zp for z, zp, _ in planes]
+        m = functools.reduce(jnp.maximum, zt)
+        lse = m + jnp.log(sum(jnp.exp(z - m) for z in zt))
+        z_label = sum(z * y for z, (_, _, y) in zip(zt, planes))
+        return wT * (lse - z_label)
+
+    rows = [weighted_rows(a) for a in alphas]             # T x (n, B)
+    sums = lax.reduce(
+        rows, [jnp.zeros((), Z.dtype)] * len(rows),
+        lambda xs, ys: [x + y for x, y in zip(xs, ys)], dimensions=(0,))
+    return jnp.stack(sums)
+
+
+def _batched_penalty(static):
+    """(penalty, l1_ratio) as `fit_task_batched` solves it: "elasticnet"
+    goes to FISTA, everything else to L-BFGS."""
+    penalty = static.get("penalty", "l2")
+    l1_ratio = static.get("l1_ratio", 0.0) or 0.0
+    if penalty == "deprecated":
+        penalty = "l2" if not l1_ratio else "elasticnet"
+    if penalty == "l1":
+        penalty, l1_ratio = "elasticnet", 1.0
+    if penalty == "elasticnet" and not l1_ratio:
+        penalty = "l2"   # pure-l2 config: quasi-Newton is ~10x cheaper
+    return penalty, l1_ratio
 
 
 def _sum_tol(tol, train_w):
@@ -171,6 +240,16 @@ class LogisticRegressionFamily(Family):
                     "converged": res.converged, "n_iter": res.n_iter}
 
     @classmethod
+    def linesearch_one_pass(cls, static, meta):
+        """True where `fit_task_batched` hands `glm_lbfgs_batched` the
+        one-pass evaluator of the line search's trial losses: the
+        multinomial L-BFGS fit (its loss has a class axis to unroll).
+        The engine reports it per launch
+        (``search_report["linesearch_one_pass_per_launch"]``)."""
+        return (meta["n_classes"] != 2
+                and _batched_penalty(static)[0] != "elasticnet")
+
+    @classmethod
     def fit_task_batched(cls, dynamic, static, data, train_w, meta):
         """All (candidate x fold) tasks as ONE wide-matmul program.
 
@@ -192,14 +271,7 @@ class LogisticRegressionFamily(Family):
             dynamic.get("tol", static.get("tol", 1e-4)), X.dtype), (B,))
         max_iter = int(static.get("max_iter", 100))
         fit_intercept = bool(static.get("fit_intercept", True))
-        penalty = static.get("penalty", "l2")
-        l1_ratio = static.get("l1_ratio", 0.0) or 0.0
-        if penalty == "deprecated":
-            penalty = "l2" if not l1_ratio else "elasticnet"
-        if penalty == "l1":
-            penalty, l1_ratio = "elasticnet", 1.0
-        if penalty == "elasticnet" and not l1_ratio:
-            penalty = "l2"   # pure-l2 config: quasi-Newton is ~10x cheaper
+        penalty, l1_ratio = _batched_penalty(static)
         if penalty not in ("l2", "elasticnet", None, "none"):
             raise NotCompiledError(
                 f"penalty={penalty!r} is not compiled; use backend='host'")
@@ -290,9 +362,10 @@ class LogisticRegressionFamily(Family):
             return Z + x[None, :, kd:] if fit_intercept else Z
 
         def data_loss(Z):
-            lse = jax.scipy.special.logsumexp(Z, axis=2)      # (n, B)
-            fit_term = lse - jnp.einsum("nbk,nk->nb", Z, y1h)
-            return jnp.sum(wT * fit_term, axis=0)
+            return _multinomial_loss(Z, wT, y1h)
+
+        def trial_data_loss(Z, Zp, alphas):
+            return _multinomial_trial_losses(Z, Zp, alphas, wT, y1h)
 
         def data_grad(Z):                                     # (n, B, k)
             P = jax.nn.softmax(Z, axis=2)
@@ -325,7 +398,8 @@ class LogisticRegressionFamily(Family):
             res = glm_lbfgs_batched(
                 Ax, data_loss, data_grad, AT, reg_loss, reg_grad,
                 jnp.zeros((B, kd + k), X.dtype), max_iter=max_iter,
-                tol=_sum_tol(tol, train_w))
+                tol=_sum_tol(tol, train_w),
+                trial_data_loss=trial_data_loss)
             n_exec = res.n_iter
         W = res.x[:, :kd].reshape(B, k, d)
         b = res.x[:, kd:]

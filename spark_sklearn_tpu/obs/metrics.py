@@ -110,6 +110,13 @@ SEARCH_REPORT_SCHEMA = (
         "Per-launch padded lane count (candidate x fold program "
         "instances actually computed, including padding)."),
     MetricDef(
+        "linesearch_one_pass_per_launch", "series",
+        "Per launch of an iterative solver: 1 where the line search of "
+        "glm_lbfgs_batched evaluated its trial steps through the family's "
+        "one-pass evaluator (multinomial LogisticRegression, class "
+        "planes), 0 where it was the generic vmap of the loss or the "
+        "launch ran another solver."),
+    MetricDef(
         "padding_waste", "histogram",
         "Per-launch fraction of computed lanes that were padding "
         "(chunk tail repeated to the group's uniform width) — the "

@@ -16,7 +16,7 @@ over candidates (a batched while_loop runs until every lane converges).
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -177,6 +177,9 @@ def glm_lbfgs_batched(
     history: int = 10,
     c1: float = 1e-4,
     ls_trials: int = 16,
+    trial_data_loss: Optional[Callable] = None,
+                           # (Z, Zp, alphas (T,B)) -> (T,B): data_loss of
+                           # Z + a*Zp for every row a of alphas, in one pass
 ) -> LBFGSResult:
     """L-BFGS for batched GLMs: objective f(x) = data_loss(A(x)) + reg(x)
     with A *linear* in x.
@@ -184,14 +187,25 @@ def glm_lbfgs_batched(
     The TPU-shaped trick: logits are linear in the parameters, so along a
     search direction p the logits move as Z(x + a*p) = Zx + a*Zp.  Carrying
     Zx in the solver state means one iteration costs exactly TWO wide
-    matmuls — Ax(p) forward and AT(dL/dZ) backward — and the whole
-    backtracking line search is ONE fused elementwise pass: all
-    `ls_trials` candidate steps evaluate together (vmap over the trial
-    axis reads Z/Zp once), and each lane keeps its largest
-    Armijo-passing step.  Measured on the 1000-candidate digits grid
-    this layout is ~12x over a generic batched L-BFGS (whose line search
-    re-evaluates full losses sequentially) and far over vmapping the
-    scalar solver.
+    matmuls — Ax(p) forward and AT(dL/dZ) backward — and the backtracking
+    line search needs no matmul: all `ls_trials` candidate steps are
+    evaluated every iteration, elementwise on (Z, Zp), and each lane keeps
+    its largest Armijo-passing step.
+
+    What that line search compiles to depends on `data_loss`.  By default
+    it is `jax.vmap` of `data_loss(Z + a*Zp)` over the trial axis.  XLA:TPU
+    makes one fusion of that where the loss reduces over rows alone or
+    sums over classes and rows at once (binary logistic, squared hinge,
+    squared epsilon-insensitive: compiled for a described v5e, PR 27).  A
+    loss that reduces over a class axis INSIDE the sum over rows
+    (logsumexp over k) compiled to five fusions that hand each other
+    f32[ls_trials, n, B] tensors through HBM plus two relayout copies of
+    Z: 65 % of the solver's device time on a v5e (PERF.md section 6,
+    PR 27).  A caller with such a loss hands `trial_data_loss`, which
+    produces the (ls_trials, B) data terms itself
+    (models/linear.py::_multinomial_trial_losses); the regulariser's
+    trial term and the Armijo pick stay here.  Same mathematics either
+    way; the order of summation, so the last bits, differ.
     """
     m = history
     B, D = x0.shape
@@ -289,16 +303,17 @@ def glm_lbfgs_batched(
                 jnp.minimum(jnp.ones((B,), dtype), 1.0 / (gnorm(g) + eps)),
                 jnp.ones((B,), dtype))
 
-        # --- matmul-free, single-pass backtracking line search ------------
+        # --- matmul-free backtracking line search -------------------------
         # Z moves linearly along p, so a trial is elementwise on
         # Zx + a*Zp.  A sequential halving loop with an all-lanes early
         # exit is a trap at large B: ONE stubborn lane forces EVERY lane
-        # through all trials, each a full Z-sized memory pass (profiled at
-        # ~14 passes/iteration on the 5000-lane digits grid — line search
-        # was most of the solver).  Instead evaluate ALL ls_trials
-        # candidate steps in one fused pass: vmap over the trial axis
-        # turns the halvings into register-level compute over a single
-        # read of (Z, Zp), then each lane picks its largest passing step.
+        # through all trials, each a full Z-sized memory pass.  Instead
+        # ALL ls_trials candidate steps are evaluated every iteration and
+        # each lane picks its largest passing step.  The generic form is
+        # a vmap of data_loss over the trial axis; whether that is one
+        # pass over (Z, Zp) is up to the compiler (see the docstring), so
+        # a caller that can say how its loss evaluates along a direction
+        # in one pass hands trial_data_loss.
         with jax.named_scope("glm_lbfgs.forward"):
             Zp = Ax(p)                           # the ONE forward matmul
 
@@ -309,7 +324,11 @@ def glm_lbfgs_batched(
 
             halvings = 0.5 ** jnp.arange(ls_trials, dtype=dtype)
             alphas = a0[None, :] * halvings[:, None]            # (T, B)
-            losses = jax.vmap(eval_trial)(alphas)               # (T, B)
+            if trial_data_loss is None:
+                losses = jax.vmap(eval_trial)(alphas)           # (T, B)
+            else:
+                losses = trial_data_loss(Z, Zp, alphas) + jax.vmap(
+                    lambda a: reg_loss(x + a[:, None] * p))(alphas)
             armijo = losses <= f[None, :] + c1 * alphas * dginit[None, :]
             # first (largest-step) passing trial per lane; no trial passed ->
             # take the last (smallest) step rather than stall

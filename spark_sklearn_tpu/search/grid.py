@@ -2178,9 +2178,15 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         max(n_task_shards,
                             mesh_lib.pad_to_multiple(
                                 -(-nc // _SORTED_LAUNCHES), n_task_shards)))
+                one_pass_hook = getattr(family, "linesearch_one_pass", None)
                 plans.append({
                     "gi": gi, "group": group, "static": static, "nc": nc,
-                    "sorted": sorted_chunks, "sorted_cap": sorted_cap})
+                    "sorted": sorted_chunks, "sorted_cap": sorted_cap,
+                    # the family's word on how this group's task-batched
+                    # fit evaluates its line search (record_iters)
+                    "ls_one_pass": int(
+                        task_batched and one_pass_hook is not None
+                        and one_pass_hook(static, meta))})
 
             # per-group prefix digests (stage-1 grouping): groups map
             # many-to-one onto digests — groups differing only in
@@ -3348,7 +3354,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 # of launching (same contract as the OOM host fallback)
                 cstate["host"] = (te, tr)
                 if im >= 0:
-                    record_iters(im, isum, lanes)
+                    record_iters(plan, im, isum, lanes)
                 return np.asarray(bad, bool), None
             return bisect
 
@@ -3430,11 +3436,13 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                "wide-fused" if fused_mode else
                                "wide" if all_cores else "nested")})
 
-        def record_iters(it_max, it_sum, lanes):
+        def record_iters(plan, it_max, it_sum, lanes):
             metrics.series("solver_iters_per_launch").append(int(it_max))
             metrics.series("solver_iters_sum_per_launch").append(
                 int(it_sum))
             metrics.series("lanes_per_launch").append(int(lanes))
+            metrics.series("linesearch_one_pass_per_launch").append(
+                plan["ls_one_pass"])
 
         def replay_chunk(idx, rec):
             """Write a journalled chunk's cells back — shared by the
@@ -3708,7 +3716,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         fit_failed[idx, :] |= np.asarray(
                             bad[:hi - lo], bool)
                         if im >= 0:
-                            record_iters(im, isum, lanes)
+                            record_iters(plan, im, isum, lanes)
                         write_cells(plan, idx, lo, hi, chunk_id, te,
                                     tr, t_fit, 0.0, count_launch=False)
                     metrics.counter("n_launches").inc()
@@ -3864,7 +3872,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                             fit_failed[idx, :] |= np.asarray(
                                 bad[:hi - lo], bool)
                             if im >= 0:
-                                record_iters(im, isum, lanes)
+                                record_iters(plan, im, isum, lanes)
                             write_cells(plan, idx, lo, hi, chunk_id,
                                         te, tr, t_fit, t_score)
 
@@ -3913,13 +3921,13 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                             if it_arr is not None else None)
                         return bad_h, it_h
 
-                    def fin_fit(host, tm, idx=idx, lo=lo, hi=hi,
-                                cstate=cstate, lanes=lanes):
+                    def fin_fit(host, tm, plan=plan, idx=idx, lo=lo,
+                                hi=hi, cstate=cstate, lanes=lanes):
                         bad_h, it_h = host
                         if bad_h is not None:
                             fit_failed[idx, :] |= bad_h[:hi - lo]
                         if it_h is not None:
-                            record_iters(np.max(it_h), np.sum(it_h),
+                            record_iters(plan, np.max(it_h), np.sum(it_h),
                                          lanes)
                         cstate["t_fit"] = tm.dispatch_s + tm.compute_s
 

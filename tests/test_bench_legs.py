@@ -140,6 +140,10 @@ class TestLegsToyShapes:
         assert d["memory"]["peak_modeled_bytes"] > 0
 
     def test_serve_contended(self):
+        # the plane is process-wide: whatever an earlier test file of this
+        # worker left resident under its own tenant would be reported too
+        from spark_sklearn_tpu.parallel.dataplane import get_dataplane
+        get_dataplane().clear()
         d = bench.leg_serve_contended(n_rows=96, n_candidates=16,
                                       folds=2, max_iter=5, levels=(2,))
         _assert_finite(d, ["solo_wall_s"])

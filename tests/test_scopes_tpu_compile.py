@@ -1,4 +1,4 @@
-"""The named scopes of ``glm_lbfgs_batched`` change no instruction.
+"""What ``glm_lbfgs_batched`` compiles to on a described TPU v5e.
 
 The solver's launch is compiled for a described (not attached) TPU v5e at
 the shape of the benchmark's ``logreg_mnist10k.grid1000`` cell — 10 000
@@ -8,6 +8,12 @@ null context.  With the debug metadata stripped — each instruction's
 ``metadata={...}`` and the module's tables of files, functions, locations
 and stack frames that it points into — the optimized HLO is the same text;
 and the scoped one names every phase in its ``op_name``s.
+
+The line search's compiled shape is pinned at both cells' launch shapes
+(``test_linesearch_compiled_shape``): with the multinomial family's
+one-pass evaluator no array of ``ls_trials * n * B`` elements is written
+and Z is not copied into another layout, and the three other callers of
+the solver hand it no evaluator.
 
 Nothing runs on a device here and nothing is timed.  The topology is
 described inside a fixture (never at import time: only one process may
@@ -56,8 +62,8 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _compiled_text(one_chip):
-    """Optimized HLO of one task-batched LogisticRegression fit launch."""
+def _compiled(one_chip, n=N, lanes=LANES):
+    """One task-batched LogisticRegression fit launch, compiled."""
     from spark_sklearn_tpu.models.linear import LogisticRegressionFamily
 
     def arg(shape, dtype=jnp.float32):
@@ -71,21 +77,21 @@ def _compiled_text(one_chip):
             dyn, static, data, w, meta)
 
     lowered = jax.jit(launch).lower(
-        {"C": arg((LANES,))},
-        {"X": arg((N, D)), "y": arg((N,), jnp.int32), "y1h": arg((N, K))},
-        arg((LANES, N)))
-    return lowered.compile().as_text()
+        {"C": arg((lanes,))},
+        {"X": arg((n, D)), "y": arg((n,), jnp.int32), "y1h": arg((n, K))},
+        arg((lanes, n)))
+    return lowered.compile()
 
 
 @pytest.fixture(scope="module")
 def hlo_pair(topo, no_compile_cache):
     from jax.sharding import SingleDeviceSharding
     one_chip = SingleDeviceSharding(topo.devices[0])
-    scoped = _compiled_text(one_chip)
+    scoped = _compiled(one_chip).as_text()
     real = jax.named_scope
     jax.named_scope = lambda name: contextlib.nullcontext()
     try:
-        bare = _compiled_text(one_chip)
+        bare = _compiled(one_chip).as_text()
     finally:
         jax.named_scope = real
     return scoped, bare
@@ -108,3 +114,134 @@ def test_scopes_change_no_instruction(hlo_pair):
 def test_compiled_op_names_carry_scope(hlo_pair, scope):
     scoped, _ = hlo_pair
     assert re.search(r'op_name="[^"]*/' + re.escape(scope) + r'[/"]', scoped)
+
+
+# --- the line search's compiled shape --------------------------------------
+
+LS_TRIALS = 16
+COMPUTATION = re.compile(r'^(?:ENTRY )?%([\w.\-]+) \(.*\) -> .*\{\s*$')
+ARRAY = re.compile(r'\b(?:f32|bf16|s32|u32|pred)\[([\d,]*)\]')
+
+
+def _instructions_outside_fusions(text):
+    """(computation, instruction line) for every instruction that is not
+    inside a fusion's body: what such a line produces is a buffer."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = COMPUTATION.match(line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            cur.append(line)
+    bodies = set(re.findall(r' fusion\(.*calls=%([\w.\-]+)', text))
+    return [(name, line) for name, lines in comps.items()
+            if name not in bodies for line in lines]
+
+
+def _result_elements(line):
+    """Element counts of the arrays an instruction line produces."""
+    rhs = line.split(" = ", 1)[1]
+    if rhs.startswith("("):                   # a tuple of results
+        depth = 0
+        for end, ch in enumerate(rhs):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        result = rhs[:end + 1]
+    else:
+        result = rhs.split(" ", 1)[0]
+    return [int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in ARRAY.findall(result)]
+
+
+def _handed_evaluators(monkeypatch, fit):
+    """What ``fit`` hands ``glm_lbfgs_batched`` as ``trial_data_loss``,
+    and the fit's result with that evaluator withheld."""
+    from spark_sklearn_tpu.ops import solvers
+    handed = []
+    real = solvers.glm_lbfgs_batched
+
+    def withheld(*args, trial_data_loss=None, **kw):
+        handed.append(trial_data_loss)
+        return real(*args, **kw)
+
+    monkeypatch.setattr(solvers, "glm_lbfgs_batched", withheld)
+    out = fit()
+    monkeypatch.setattr(solvers, "glm_lbfgs_batched", real)
+    return handed, out
+
+
+def _other_caller(name):
+    """(family, static, n_classes, y dtype) of a caller of the solver
+    whose loss has no logsumexp."""
+    from spark_sklearn_tpu.models.linear import LogisticRegressionFamily
+    from spark_sklearn_tpu.models.svr import LinearSVCFamily, LinearSVRFamily
+    return {
+        "binary_logreg": (LogisticRegressionFamily, {"max_iter": 100},
+                          2, jnp.int32),
+        "linear_svc": (LinearSVCFamily, {"max_iter": 1000}, K, jnp.int32),
+        "linear_svr": (LinearSVRFamily,
+                       {"max_iter": 1000,
+                        "loss": "squared_epsilon_insensitive"},
+                       0, jnp.float32),
+    }[name]
+
+
+@pytest.mark.parametrize("case", [
+    ("multinomial", 10_000, 625), ("multinomial", 70_000, 190),
+    ("binary_logreg",), ("linear_svc",), ("linear_svr",)],
+    ids=lambda c: "-".join(str(p) for p in c))
+def test_linesearch_compiled_shape(case, request, monkeypatch):
+    if case[0] != "multinomial":
+        # no logsumexp in the loss, no class axis to unroll: the solver is
+        # handed no evaluator and its line search is the parent's, to the
+        # instruction (the launch's optimized HLO for a described v5e was
+        # diffed against the parent commit's once: CHANGES.md, PR 27)
+        family, static, n_classes, y_dtype = _other_caller(case[0])
+        n, lanes = 64, 6
+        meta = {"n_classes": n_classes, "classes": np.arange(n_classes),
+                "n_features": D}
+        data = {"X": jax.ShapeDtypeStruct((n, D), jnp.float32),
+                "y": jax.ShapeDtypeStruct((n,), y_dtype),
+                "y1h": jax.ShapeDtypeStruct((n, max(n_classes, 1)),
+                                            jnp.float32)}
+        handed, _ = _handed_evaluators(monkeypatch, lambda: jax.eval_shape(
+            lambda dyn, data, w: family.fit_task_batched(
+                dyn, static, data, w, meta),
+            {"C": jax.ShapeDtypeStruct((lanes,), jnp.float32)}, data,
+            jax.ShapeDtypeStruct((lanes, n), jnp.float32)))
+        assert handed == [None]
+        hook = getattr(family, "linesearch_one_pass", None)
+        assert hook is None or not hook(static, meta)
+        return
+
+    from jax.sharding import SingleDeviceSharding
+    request.getfixturevalue("no_compile_cache")
+    one_chip = SingleDeviceSharding(request.getfixturevalue("topo").devices[0])
+    _, n, lanes = case
+    one_pass = _compiled(one_chip, n, lanes)
+    handed, generic = _handed_evaluators(
+        monkeypatch, lambda: _compiled(one_chip, n, lanes))
+    assert len(handed) == 1 and handed[0] is not None
+
+    def buffers(compiled):
+        """(lines that write an array of ls_trials*n*B elements or more,
+        copies of an f32[n,B,k] array inside the while loop's body)."""
+        outside = _instructions_outside_fusions(compiled.as_text())
+        trial_tensors = [line for _, line in outside
+                         if max(_result_elements(line), default=0)
+                         >= LS_TRIALS * n * lanes]
+        relayouts = [line for comp, line in outside
+                     if "region" in comp and re.search(r' copy(-start)?\(', line)
+                     and re.search(r'= \(?f32\[%d,%d,%d\]' % (n, lanes, K),
+                                   line)]
+        return trial_tensors, relayouts
+
+    # the generic path shows that the test can see both
+    trial_tensors, relayouts = buffers(generic)
+    assert len(trial_tensors) >= 3 and len(relayouts) == 2
+    assert buffers(one_pass) == ([], [])
+    assert (one_pass.memory_analysis().temp_size_in_bytes
+            < 0.6 * generic.memory_analysis().temp_size_in_bytes)
