@@ -92,7 +92,8 @@ class SVC(ClassifierMixin, BaseEstimator):
         bound = C * box if cw is None else C * box * cw[None, :]
         from spark_sklearn_tpu.models.svm import _tol_or_default
         A, b, _ = fista_dual_ascent(
-            K, yb, bound, _power_step(K, n, jnp.float32), max_iter,
+            K, yb, bound, _power_step(K, n, jnp.float32, centred=True),
+            max_iter,
             tol=_tol_or_default(self._static))
         return np.asarray(A * yb), np.asarray(b)      # signed alphas + b
 

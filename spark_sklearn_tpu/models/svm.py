@@ -13,16 +13,22 @@ gradient ascent** where every iteration is ONE kernel matmul for all
 This is the true libsvm dual, equality constraint included: each ascent
 step projects onto the box-and-hyperplane set via a vectorized bisection
 (`_project_box_hyperplane`) and the intercept comes from the KKT
-conditions (`_kkt_intercept`, libsvm's -rho).  The step size
-1/lambda_max(K) is safe for every masked subproblem because a principal
-submatrix of a PSD matrix cannot have a larger top eigenvalue, and the
-y-sign flip DKD is a similarity transform.
+conditions (`_kkt_intercept`, libsvm's -rho).  The step size is
+1/lambda_max of the CENTRED kernel (`_power_step(centred=True)`): safe
+for every masked subproblem because a principal submatrix of a PSD matrix
+cannot have a larger top eigenvalue, the y-sign flip DKD is a similarity
+transform, and the equality constraint keeps every iterate's y*a summing
+to zero, where K and the centred K are the same quadratic form.
 
 Multi-class follows sklearn: one-vs-one over all k(k-1)/2 pairs with
 majority voting (confidence-scaled tie-break like _ovr_decision_function).
 
 Deviation from libsvm (documented, tested at the accuracy level): a
-fixed iteration budget instead of SMO's working-set convergence.
+fixed iteration budget (300 where `max_iter` is -1) beside the `tol` exit
+on the prox-gradient residual.  In exact float32 (XLA:CPU) `tol` ends the
+solve; on a TPU the product's one bfloat16 pass leaves the residual a
+floor of 0.015-0.06, over the default `tol`, so there the budget ends it
+and `search_report["dual_iters_per_candidate"]` says so (PERF.md, PR 28).
 """
 
 from __future__ import annotations
@@ -57,18 +63,38 @@ def _kernel(X1, X2, kind, gamma, degree, coef0):
     return jnp.exp(-gamma * jnp.maximum(d2, 0.0))
 
 
-def _power_step(K, n, dtype):
+def _power_step(K, n, dtype, centred=False):
     """1/lambda_max(K) via power iteration — a safe ascent step for every
     masked/sign-flipped subproblem (principal submatrices of a PSD matrix
-    cannot have a larger top eigenvalue)."""
-    v = jnp.ones((n,), dtype) / jnp.sqrt(n)
+    cannot have a larger top eigenvalue).
+
+    `centred`: the top eigenvalue of K on the vectors that sum to zero
+    (of C K C, C = I - 11'/n) instead.  That is the curvature a dual with
+    the equality constraint sum_i y_i a_i = 0 can meet: v = y * a sums to
+    zero for every feasible a, masked to any subproblem's rows and under
+    any signs, so every iterate, every momentum point and every
+    difference of two of them lies there, and a'Qa = v'Kv = v'(CKC)v.
+    An RBF kernel's top eigenvector is nearly the constant vector, which
+    the constraint removes: on the benchmark's 20 000 MNIST-width rows
+    lambda_max falls from 11 787 to 223 at gamma 0.004 and from 432 to 57
+    at gamma 0.03, and with it the iterations a dual needs (PERF.md,
+    PR 28).  A 10 % margin there, since the spectrum under the constant
+    is flatter and the estimate comes from below."""
+    def apply(v):
+        if not centred:
+            return K @ v
+        w = K @ (v - jnp.mean(v))
+        return w - jnp.mean(w)
 
     def power(i, v):
-        v = K @ v
+        v = apply(v)
         return v / (jnp.linalg.norm(v) + 1e-12)
 
-    v = jax.lax.fori_loop(0, 20, power, v)
-    return 1.0 / (jnp.dot(v, K @ v) + 1e-6)
+    v0 = (jax.random.normal(jax.random.PRNGKey(0), (n,), dtype) if centred
+          else jnp.ones((n,), dtype) / jnp.sqrt(n))
+    v = jax.lax.fori_loop(0, 20, power, v0)
+    margin = 1.1 if centred else 1.0
+    return 1.0 / (margin * jnp.dot(v, apply(v)) + 1e-6)
 
 
 def _box_fista(grad_fn, project, x0, step, max_iter, tol=None):
@@ -91,16 +117,20 @@ def _box_fista(grad_fn, project, x0, step, max_iter, tol=None):
     lane has converged and returns (x, n_iter, converged)."""
     dtype = x0.dtype
 
-    if tol is None:
-        def body(i, carry):
-            x, z, t = carry
-            x_new = project(z - step * grad_fn(z))
+    def advance(x, z, t):
+        with jax.named_scope("sst.box_fista.gradient"):
+            g = grad_fn(z)
+        with jax.named_scope("sst.box_fista.project"):
+            x_new = project(z - step * g)
+        with jax.named_scope("sst.box_fista.momentum"):
             t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
             z_new = x_new + ((t - 1.0) / t_new) * (x_new - x)
-            return x_new, z_new, t_new
+        return x_new, z_new, t_new
 
+    if tol is None:
         x, _, _ = jax.lax.fori_loop(
-            0, max_iter, body, (x0, x0, jnp.asarray(1.0, dtype)))
+            0, max_iter, lambda i, carry: advance(*carry),
+            (x0, x0, jnp.asarray(1.0, dtype)))
         return x
 
     lane_axes = tuple(range(1, x0.ndim))
@@ -113,13 +143,12 @@ def _box_fista(grad_fn, project, x0, step, max_iter, tol=None):
 
     def body(carry):
         x, z, t, it, n_iter, done = carry
-        x_new = project(z - step * grad_fn(z))
-        t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
-        z_new = x_new + ((t - 1.0) / t_new) * (x_new - x)
-        resid = jnp.max(jnp.abs(x_new - z), axis=lane_axes) / step
-        done_new = jnp.logical_or(done, resid <= tol)
-        n_iter = jnp.where(jnp.logical_and(jnp.logical_not(done),
-                                           done_new), it + 1, n_iter)
+        x_new, z_new, t_new = advance(x, z, t)
+        with jax.named_scope("sst.box_fista.momentum"):
+            resid = jnp.max(jnp.abs(x_new - z), axis=lane_axes) / step
+            done_new = jnp.logical_or(done, resid <= tol)
+            n_iter = jnp.where(jnp.logical_and(jnp.logical_not(done),
+                                               done_new), it + 1, n_iter)
         return x_new, z_new, t_new, it + 1, n_iter, done_new
 
     x, _, _, it, n_iter, done = jax.lax.while_loop(
@@ -257,7 +286,8 @@ def nu_dual_ascent(K, yb, bound, nu, step, max_iter, tol=None):
     A, n_it = _run_dual(grad, project, project(jnp.zeros_like(bound)),
                         step, max_iter, tol, K.dtype)
 
-    V = (A * yb) @ K
+    with jax.named_scope("sst.svc.decision"):
+        V = (A * yb) @ K
     G = yb * V                         # gradient of 0.5 a'Qa
     inb = bound > 0
     at_lo = A <= bound * 1e-6
@@ -326,7 +356,9 @@ def fista_dual_ascent(K, yb, bound, step, max_iter, tol=None):
     A, n_it = _run_dual(
         grad, lambda Zt: _project_box_hyperplane(Zt, yb, bound),
         jnp.zeros_like(bound), step, max_iter, tol, K.dtype)
-    return A, _kkt_intercept(K, A, yb, bound), n_it
+    with jax.named_scope("sst.svc.intercept"):
+        b = _kkt_intercept(K, A, yb, bound)
+    return A, b, n_it
 
 
 def _platt_fit(f, t, w, n_iter=50):
@@ -481,7 +513,8 @@ class SVCFamily(Family):
         per-lane residual exit (libsvm's eps stopping rule)."""
         bound = p_c * base_bound
         A, b, n_it = fista_dual_ascent(K, yb, bound, step, max_iter, tol)
-        return (A * yb) @ K + b[:, None], n_it
+        with jax.named_scope("sst.svc.decision"):
+            return (A * yb) @ K + b[:, None], n_it
 
     # kernel matrices + per-task decision caches are the memory hot spot;
     # tell the search to keep task batches small
@@ -491,6 +524,42 @@ class SVCFamily(Family):
         p = max(1, k * (k - 1) // 2)
         budget = 1 << 30   # ~1 GiB of decision cache per launch
         return max(1, budget // max(1, n_samples * p * 4))
+
+    #: the fit's `n_iter` is per task (a candidate's folds share its
+    #: count): the engine keeps the counts, not only their max and sum
+    reports_task_iters = True
+
+    @staticmethod
+    def launch_workspace(n_samples: int, meta, n_folds: int,
+                         itemsize: int = 4):
+        """What a launch holds besides its arguments, for the memory
+        ledger (read off the launch compiled for a v5e at 20 000 rows:
+        5.03 GB at 16 candidates, 4.57 GB at 8, 4.22 GB at 4).  Whatever
+        the width, since candidates are scanned: ONE candidate's kernel
+        matrix, its copy in the other layout and the bfloat16 copy the
+        MXU reads, and eight (folds x pairs, n) arrays of the dual
+        (iterates, signs, bounds, gradient, the projection's
+        temporaries).  A candidate: its cached (folds, n, pairs) pair
+        decisions, about three times over (the scan's stacked output,
+        its transpose, the task-major copy)."""
+        k = meta["n_classes"]
+        m = n_folds * max(1, k * (k - 1) // 2)
+        n = int(n_samples)
+        return {"fixed_bytes": n * n * (2 * itemsize + 2)
+                + 8 * m * n * itemsize,
+                "per_candidate_bytes": 3 * m * n * itemsize}
+
+    @staticmethod
+    def launch_counters(meta, n_candidates: int, n_folds: int):
+        """Per-launch counts for `search_report`
+        (`gram_builds_per_launch`, `dual_subproblems_per_launch`): kernel
+        matrices built and dual subproblems advanced by a launch of
+        `n_candidates` (padding included: a padded candidate is
+        computed)."""
+        k = meta["n_classes"]
+        return {"gram_builds": n_candidates,
+                "dual_subproblems":
+                    n_candidates * n_folds * max(1, k * (k - 1) // 2)}
 
     @classmethod
     def extract_params(cls, estimator):
@@ -600,8 +669,10 @@ class SVCFamily(Family):
         def one_candidate(carry, inp):
             C_c, g_c, w_f = inp                               # w_f (F, n)
             if X_folds is None:
-                K = _kernel(X, X, kind, g_c, degree, coef0)   # (n, n)
-                step = _power_step(K, n, X.dtype)
+                with jax.named_scope("sst.svc.gram"):
+                    K = _kernel(X, X, kind, g_c, degree, coef0)   # (n, n)
+                with jax.named_scope("sst.svc.power_step"):
+                    step = _power_step(K, n, X.dtype, centred=True)
                 # subproblem box masks: (F, P, n) -> flatten (F*P, n)
                 base = ((w_f * cw_fold)[:, None, :]
                         * in_pair[None, :, :]).reshape(-1, n)
@@ -628,8 +699,10 @@ class SVCFamily(Family):
                                      * jnp.maximum(var, 1e-12))
                     else:
                         g_f = g_c
-                    Kf = _kernel(Xf, Xf, kind, g_f, degree, coef0)
-                    step = _power_step(Kf, n, Xf.dtype)
+                    with jax.named_scope("sst.svc.gram"):
+                        Kf = _kernel(Xf, Xf, kind, g_f, degree, coef0)
+                    with jax.named_scope("sst.svc.power_step"):
+                        step = _power_step(Kf, n, Xf.dtype, centred=True)
                     base = (w_row * cw_row)[None, :] * in_pair
                     return cls._pair_dec(
                         Kf, C_c, base, ybin, step, max_iter,

@@ -117,6 +117,25 @@ SEARCH_REPORT_SCHEMA = (
         "planes), 0 where it was the generic vmap of the loss or the "
         "launch ran another solver."),
     MetricDef(
+        "gram_builds_per_launch", "series",
+        "Per launch of a kernel-dual family (SVC, NuSVC): kernel "
+        "matrices the launch built, one per candidate it computed "
+        "(padding included).  Absent where a compiled Pipeline wraps "
+        "the estimator (a matrix per candidate and fold there)."),
+    MetricDef(
+        "dual_subproblems_per_launch", "series",
+        "Per launch of a kernel-dual family: box-constrained dual "
+        "subproblems advanced through _box_fista, candidates x folds x "
+        "one-vs-one pairs."),
+    MetricDef(
+        "dual_iters_per_candidate", "series",
+        "Kernel-dual families: executed _box_fista iterations of each "
+        "candidate, in cv_results_ order (the candidate's folds and "
+        "pairs advance together and stop together; a count equal to the "
+        "iteration cap means the cap ended the solve, not tol).  -1: the "
+        "candidate was restored from a checkpoint or fitted on the "
+        "host."),
+    MetricDef(
         "padding_waste", "histogram",
         "Per-launch fraction of computed lanes that were padding "
         "(chunk tail repeated to the group's uniform width) — the "

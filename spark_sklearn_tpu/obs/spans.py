@@ -345,6 +345,32 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
     SpanDef("glm_lbfgs.history", "scope", "ops.solvers",
             "s, y, the memory update, the stall detector and done.",
             layer="solvers"),
+    SpanDef("sst.box_fista.gradient", "scope", "models.svm",
+            "_box_fista: the caller's gradient at the momentum point, "
+            "for the kernel duals one (subproblems, n) @ (n, n) product.",
+            layer="solvers"),
+    SpanDef("sst.box_fista.project", "scope", "models.svm",
+            "_box_fista: the gradient step and the caller's projection "
+            "(for SVC the bisection onto box and hyperplane).",
+            layer="solvers"),
+    SpanDef("sst.box_fista.momentum", "scope", "models.svm",
+            "_box_fista: Nesterov's extrapolation, the per-lane "
+            "prox-gradient residual and done.",
+            layer="solvers"),
+    SpanDef("sst.svc.gram", "scope", "models.svm",
+            "SVCFamily: one candidate's (n, n) kernel matrix.",
+            layer="solvers"),
+    SpanDef("sst.svc.power_step", "scope", "models.svm",
+            "SVCFamily: the power iterations for 1/lambda_max, the "
+            "dual's step.",
+            layer="solvers"),
+    SpanDef("sst.svc.intercept", "scope", "models.svm",
+            "SVCFamily: each pair's intercept from the KKT conditions.",
+            layer="solvers"),
+    SpanDef("sst.svc.decision", "scope", "models.svm",
+            "SVCFamily: every pair's decision value on all rows, the "
+            "cache the scoring epilogue votes on.",
+            layer="solvers"),
     # async virtual tracks (name prefixes)
     SpanDef("launch", "async", "parallel.pipeline",
             "Whole-launch span (dispatch..finalize) per chunk, on the "

@@ -119,7 +119,9 @@ def model_group_footprint(dynamic_params: Dict[str, np.ndarray],
                           task_batched: bool, n_samples: int,
                           mask_itemsize: int = 4, n_scorers: int = 1,
                           return_train: bool = False,
-                          dtype_itemsize: int = 4) -> Dict[str, Any]:
+                          dtype_itemsize: int = 4,
+                          workspace: Optional[Dict[str, int]] = None
+                          ) -> Dict[str, Any]:
     """One compile group's modeled per-chunk device bytes at ``width``.
 
     Everything is linear in the width, derived from the same abstract
@@ -136,11 +138,19 @@ def model_group_footprint(dynamic_params: Dict[str, np.ndarray],
       - ``out_bytes`` — per-task score cells (+ train cells) and
         health flags the launch materializes.
 
+      - ``workspace_bytes`` — what the family says its launch holds
+        besides (``Family.launch_workspace``: ``fixed_bytes`` whatever
+        the width, ``per_candidate_bytes`` a candidate): for the kernel
+        duals one candidate's Gram matrix and its copies, the dual's
+        state and the cached pair decisions.  0 for a family that
+        prices none.
+
     Returns the breakdown plus ``per_candidate_bytes`` (the slope the
-    width ceiling divides by) and ``chunk_bytes`` (the total at
-    ``width``).  Model-pytree and XLA temp bytes are deliberately NOT
-    modeled here — they are backend/fusion-dependent; the ledger's
-    safety margin (trained by observed OOMs) and the precompile-time
+    width ceiling divides by), ``fixed_bytes`` (the family's workspace
+    that no width changes) and ``chunk_bytes`` (the total at ``width``).
+    Other model-pytree and XLA temp bytes are deliberately NOT modeled
+    here — they are backend/fusion-dependent; the ledger's safety
+    margin (trained by observed OOMs) and the precompile-time
     ``memory_analysis`` readings cover them.
     """
     width = int(width)
@@ -160,14 +170,23 @@ def model_group_footprint(dynamic_params: Dict[str, np.ndarray],
     out_per_cand = n_folds * (
         int(n_scorers) * (2 if return_train else 1) * _SCORE_CELL_BYTES
         + 1)  # + per-task health flag
-    per_cand = dyn_per_cand + mask_per_cand + out_per_cand
-    return {
+    workspace = workspace or {}
+    ws_fixed = int(workspace.get("fixed_bytes", 0))
+    ws_per_cand = int(workspace.get("per_candidate_bytes", 0))
+    per_cand = dyn_per_cand + mask_per_cand + out_per_cand + ws_per_cand
+    out = {
         "dyn_bytes": dyn_per_cand * width,
         "mask_bytes": mask_per_cand * width,
         "out_bytes": out_per_cand * width,
         "per_candidate_bytes": per_cand,
-        "chunk_bytes": per_cand * width,
+        "chunk_bytes": per_cand * width + ws_fixed,
     }
+    if workspace:
+        # only where a family prices one: other searches' records (and
+        # reports) keep their keys
+        out["workspace_bytes"] = ws_fixed + ws_per_cand * width
+        out["fixed_bytes"] = ws_fixed
+    return out
 
 
 def width_cap(budget_bytes: int, resident_bytes: int,

@@ -239,6 +239,17 @@ def _program_build_count() -> int:
         return _PROGRAM_BUILDS
 
 
+def _iters_sum_host(isum, n_tasks=None):
+    """A launch's summed executed iterations, on the host.  A family that
+    solves task after task (``reports_task_iters``) hands the per-task
+    counts in the sum's place: they stay a vector, cut to the launch's
+    first ``n_tasks`` (real) tasks where that is given."""
+    isum = np.asarray(isum)
+    if isum.ndim == 0:
+        return int(isum)
+    return isum if n_tasks is None else isum[:n_tasks]
+
+
 @jax.jit
 def _models_health(models):
     """(nc_batch, n_folds) True where any inexact model leaf went NaN —
@@ -2247,12 +2258,21 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     mask_itemsize=int(fit_masks.dtype.itemsize),
                     n_scorers=len(scorers), return_train=return_train,
                     dtype_itemsize=int(np.dtype(dtype).itemsize))
+                ws_hook = getattr(family, "launch_workspace", None)
+                ws_fixed = 0
+                if ws_hook is not None:
+                    # the family's own launch workspace (the kernel
+                    # duals' Gram matrix and decision cache)
+                    mem_kw["workspace"] = ws_hook(
+                        int(fit_masks.shape[1]), meta, n_folds,
+                        int(np.dtype(dtype).itemsize))
+                    ws_fixed = int(mem_kw["workspace"]["fixed_bytes"])
                 budget = int(mem_ctx.get("budget_bytes", 0)) \
                     if mem_ctx is not None else 0
                 if budget:
                     mem_caps = [
                         _memledger.width_cap(
-                            budget, resident_est,
+                            budget, resident_est + ws_fixed,
                             _memledger.model_group_footprint(
                                 p["group"].dynamic_params, 1, n_folds,
                                 **mem_kw)["per_candidate_bytes"],
@@ -2781,6 +2801,12 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                             if it is not None:
                                 iters = jnp.max(it).astype(jnp.int32)
                                 iters_sum = jnp.sum(it).astype(jnp.int32)
+                                if getattr(family, "reports_task_iters",
+                                           False):
+                                    # the per-task counts in the sum's
+                                    # place (_iters_sum_host)
+                                    iters_sum = it.astype(
+                                        jnp.int32).reshape(-1)
                         te, tr = score_batch_wide(models, data_d, test_m,
                                                   train_m, test_u, train_u)
                     return te, tr, bad, iters, iters_sum
@@ -3110,8 +3136,11 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             tr = {s: np.concatenate([a[1][s], b[1][s]]) for s in a[1]}
             bad = np.concatenate([a[2], b[2]])
             im = max(a[3], b[3])
-            isum = a[4] + b[4] if a[4] >= 0 and b[4] >= 0 \
-                else max(a[4], b[4])
+            if np.ndim(a[4]) and np.ndim(b[4]):
+                isum = np.concatenate([a[4], b[4]])
+            else:
+                sa, sb = int(np.sum(a[4])), int(np.sum(b[4]))
+                isum = sa + sb if sa >= 0 and sb >= 0 else max(sa, sb)
             return te, tr, bad, im, isum
 
         def exec_fused_range(plan, lo, hi, sup, chunk_id):
@@ -3170,7 +3199,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 tr = {s: np.asarray(mesh_lib.device_get_tree(v))[:n]
                       for s, v in tr_d.items()}
                 bad = np.asarray(mesh_lib.device_get_tree(bad_d))[:n]
-                return te, tr, bad, int(im_d), int(isum_d)
+                return (te, tr, bad, int(im_d),
+                        _iters_sum_host(isum_d, n * n_folds))
 
             try:
                 return sup.call(attempt, key=key, group=plan["gi"],
@@ -3319,6 +3349,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
 
             def slice_out(out, off, n):
                 te, tr, bad, im, isum = out
+                if np.ndim(isum):
+                    isum = isum[off * n_folds:(off + n) * n_folds]
                 return ({s: v[off:off + n] for s, v in te.items()},
                         {s: v[off:off + n] for s, v in tr.items()},
                         bad[off:off + n], im, isum)
@@ -3354,7 +3386,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 # of launching (same contract as the OOM host fallback)
                 cstate["host"] = (te, tr)
                 if im >= 0:
-                    record_iters(plan, im, isum, lanes)
+                    record_iters(plan, im, isum, lanes,
+                                 plan["group"].candidate_indices[lo:hi])
                 return np.asarray(bad, bool), None
             return bisect
 
@@ -3436,13 +3469,30 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                "wide-fused" if fused_mode else
                                "wide" if all_cores else "nested")})
 
-        def record_iters(plan, it_max, it_sum, lanes):
+        def record_iters(plan, it_max, it_sum, lanes, idx=None):
             metrics.series("solver_iters_per_launch").append(int(it_max))
             metrics.series("solver_iters_sum_per_launch").append(
-                int(it_sum))
+                int(np.sum(it_sum)))
             metrics.series("lanes_per_launch").append(int(lanes))
             metrics.series("linesearch_one_pass_per_launch").append(
                 plan["ls_one_pass"])
+            counters = getattr(family, "launch_counters", None)
+            if counters is not None:
+                counts = counters(meta, lanes // n_folds, n_folds)
+                metrics.series("gram_builds_per_launch").append(
+                    int(counts["gram_builds"]))
+                metrics.series("dual_subproblems_per_launch").append(
+                    int(counts["dual_subproblems"]))
+            if getattr(family, "reports_task_iters", False) \
+                    and np.ndim(it_sum) and idx is not None:
+                # per-task counts: tasks are candidate-major and a
+                # candidate's folds share its count
+                per_cand = metrics.series("dual_iters_per_candidate")
+                if not per_cand:
+                    per_cand.extend([-1] * len(candidates))
+                for ci, it in zip(
+                        idx, np.asarray(it_sum).reshape(-1)[::n_folds]):
+                    per_cand[int(ci)] = int(it)
 
         def replay_chunk(idx, rec):
             """Write a journalled chunk's cells back — shared by the
@@ -3668,7 +3718,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         chunks.append((
                             {s: v[i] for s, v in te_h.items()},
                             {s: v[i] for s, v in tr_h.items()},
-                            bad_h[i], int(im_h[i]), int(isum_h[i])))
+                            bad_h[i], int(im_h[i]),
+                            _iters_sum_host(isum_h[i])))
                     surv_h = (np.asarray(
                         mesh_lib.device_get_tree(surv))
                         if seg_topk else None)
@@ -3716,7 +3767,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         fit_failed[idx, :] |= np.asarray(
                             bad[:hi - lo], bool)
                         if im >= 0:
-                            record_iters(plan, im, isum, lanes)
+                            record_iters(plan, im, isum, lanes, idx)
                         write_cells(plan, idx, lo, hi, chunk_id, te,
                                     tr, t_fit, 0.0, count_launch=False)
                     metrics.counter("n_launches").inc()
@@ -3851,7 +3902,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                     mesh_lib.device_get_tree(tr),
                                     np.asarray(
                                         mesh_lib.device_get_tree(bad)),
-                                    int(it_max), int(it_sum))
+                                    int(it_max), _iters_sum_host(it_sum))
 
                         def finalize(host, tm, plan=plan, idx=idx, lo=lo,
                                      hi=hi, chunk_id=chunk_id,
@@ -3872,7 +3923,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                             fit_failed[idx, :] |= np.asarray(
                                 bad[:hi - lo], bool)
                             if im >= 0:
-                                record_iters(plan, im, isum, lanes)
+                                record_iters(plan, im, isum, lanes, idx)
                             write_cells(plan, idx, lo, hi, chunk_id,
                                         te, tr, t_fit, t_score)
 
@@ -3927,8 +3978,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         if bad_h is not None:
                             fit_failed[idx, :] |= bad_h[:hi - lo]
                         if it_h is not None:
-                            record_iters(plan, np.max(it_h), np.sum(it_h),
-                                         lanes)
+                            record_iters(plan, np.max(it_h), it_h, lanes,
+                                         idx)
                         cstate["t_fit"] = tm.dispatch_s + tm.compute_s
 
                     def host_fb_fit(idx=idx, cstate=cstate):

@@ -15,6 +15,7 @@ XLA:CPU has no device plane, so nothing here reads a device time.
 import ast
 import glob
 import os
+import re
 
 import jax
 import numpy as np
@@ -183,8 +184,7 @@ def test_every_name_has_a_layer():
 # device: named scopes in the launch program
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def lowered_launch():
+def _lowered_launches(search):
     """Lowered text, debug info on, of the programs a tiny search hands
     to the compile thread (the fused fit + score launch among them)."""
     from spark_sklearn_tpu.parallel import pipeline
@@ -200,6 +200,21 @@ def lowered_launch():
 
     pipeline.precompile = recording
     try:
+        search()
+    finally:
+        pipeline.precompile = original
+    assert texts, "the search precompiled no program"
+    return "\n".join(texts)
+
+
+#: the scopes of the kernel duals' launch; the others are the GLM launch's
+DUAL_SCOPES = [s for s in SCOPES
+               if s.startswith(("sst.svc.", "sst.box_fista."))]
+
+
+@pytest.fixture(scope="module")
+def lowered_launch():
+    def search():
         # 40 candidates: convergence-sorted into several chunks, so the
         # group's fused program is compiled ahead on the compile thread
         from sklearn.linear_model import LogisticRegression
@@ -208,15 +223,31 @@ def lowered_launch():
             LogisticRegression(max_iter=5),
             {"C": np.logspace(-2, 1, 40).tolist()}, cv=3, refit=False,
             backend="tpu").fit(X, y)
-    finally:
-        pipeline.precompile = original
-    assert texts, "the search precompiled no program"
-    return "\n".join(texts)
+    return _lowered_launches(search)
+
+
+@pytest.fixture(scope="module")
+def lowered_dual_launch():
+    def search():
+        # the narrowest chunks the mesh allows (a candidate a task shard)
+        # and more candidates than one holds, so that a fused program is
+        # compiled ahead
+        from sklearn.svm import SVC
+        X, y = _problem(n=90, d=5)
+        sst.GridSearchCV(
+            SVC(kernel="rbf", max_iter=5),
+            {"C": np.logspace(-1, 2, 20).tolist()},
+            cv=3, refit=False, backend="tpu",
+            config=sst.TpuConfig(max_tasks_per_batch=3)).fit(X, y)
+    return _lowered_launches(search)
 
 
 @pytest.mark.parametrize("scope", SCOPES)
-def test_lowered_launch_holds_scope(lowered_launch, scope):
-    assert f"/{scope}/" in lowered_launch
+def test_lowered_launch_holds_scope(request, scope):
+    text = request.getfixturevalue(
+        "lowered_dual_launch" if scope in DUAL_SCOPES else "lowered_launch")
+    # a scope opens a name stack inside a scanned body: no slash before it
+    assert re.search(r'["/]' + re.escape(scope) + "/", text)
 
 
 def test_scopes_in_the_package_are_the_vocabulary():
