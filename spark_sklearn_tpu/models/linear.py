@@ -405,9 +405,16 @@ class LogisticRegressionFamily(Family):
         b = res.x[:, kd:]
         if not fit_intercept:
             b = jnp.zeros_like(b)
-        return {"coef": W, "intercept": b,
-                "converged": res.converged, "n_iter": res.n_iter,
-                "n_iter_exec": n_exec}
+        model = {"coef": W, "intercept": b,
+                 "converged": res.converged, "n_iter": res.n_iter,
+                 "n_iter_exec": n_exec}
+        if res.ls_second_pass is not None:
+            # a staged line search's count for the launch, on every lane
+            # as n_iter is (the engine reports it:
+            # "linesearch_second_pass_per_launch")
+            model["ls_second_pass"] = jnp.broadcast_to(
+                res.ls_second_pass, (B,))
+        return model
 
     @classmethod
     def decision(cls, model, static, X, meta):

@@ -117,6 +117,14 @@ SEARCH_REPORT_SCHEMA = (
         "planes), 0 where it was the generic vmap of the loss or the "
         "launch ran another solver."),
     MetricDef(
+        "linesearch_second_pass_per_launch", "series",
+        "Per launch of an iterative solver: iterations of "
+        "glm_lbfgs_batched in which its staged line search evaluated the "
+        "trial steps after the first four too, because some lane that was "
+        "not done passed none of those; at most the launch's "
+        "solver_iters_per_launch.  0 where the line search is not staged "
+        "(linesearch_one_pass_per_launch reads 0)."),
+    MetricDef(
         "gram_builds_per_launch", "series",
         "Per launch of a kernel-dual family (SVC, NuSVC): kernel "
         "matrices the launch built, one per candidate it computed "

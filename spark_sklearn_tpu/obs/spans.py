@@ -327,10 +327,11 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
             "Zp = Ax(p), the one forward matmul of an iteration.",
             layer="solvers"),
     SpanDef("glm_lbfgs.linesearch", "scope", "ops.solvers",
-            "All ls_trials trial losses of an iteration (the caller's "
-            "one-pass evaluator over (Z, Zp) where it hands one, else "
-            "a vmap of the loss over the trial axis), Armijo, each "
-            "lane's pick.",
+            "The trial losses of an iteration (the caller's one-pass "
+            "evaluator over (Z, Zp) where it hands one: the first four "
+            "steps, and the other ls_trials - 4 under a conditional; "
+            "else a vmap of the loss over the trial axis), Armijo, "
+            "each lane's pick.",
             layer="solvers"),
     SpanDef("glm_lbfgs.step", "scope", "ops.solvers",
             "Step masking, x_new, Z_new, f_new.",

@@ -29,6 +29,17 @@ class LBFGSResult(NamedTuple):
     grad_norm: jnp.ndarray
     n_iter: jnp.ndarray
     converged: jnp.ndarray
+    #: iterations in which `glm_lbfgs_batched` ran the second stage of its
+    #: line search, a scalar; None (no leaf, so no output of any program)
+    #: from every other solver and from its generic, unstaged search
+    ls_second_pass: Optional[jnp.ndarray] = None
+
+
+#: trial steps that the staged line search of `glm_lbfgs_batched` evaluates
+#: before it asks whether any live lane needs the other `ls_trials - 4`.
+#: On the benchmark's grids 99.4 % of lane-iterations pick among the first
+#: four and the knee of "every live lane did" is at 3-4 (PERF.md, PR 29).
+_LS_FIRST_STAGE = 4
 
 
 def _two_loop(g, s_mem, y_mem, rho, gamma, total, n_valid, m):
@@ -163,6 +174,15 @@ def lbfgs(
         converged=gnorm(st["g"]) <= tol)
 
 
+def _armijo_pick(armijo):
+    """Each lane's pick among its trial steps, (T, B) bool -> (B,): the
+    first (largest-step) passing trial; where no trial passed, the last
+    (smallest) step rather than stall."""
+    first_ok = jnp.argmax(armijo, axis=0)
+    found = jnp.any(armijo, axis=0)
+    return jnp.where(found, first_ok, armijo.shape[0] - 1)
+
+
 def glm_lbfgs_batched(
     Ax: Callable,          # x (B,D) -> Z (n, B) or (n, B, k)  ONE matmul
                            # (lane axis MUST be position 1 — see _bcast)
@@ -206,6 +226,17 @@ def glm_lbfgs_batched(
     (models/linear.py::_multinomial_trial_losses); the regulariser's
     trial term and the Armijo pick stay here.  Same mathematics either
     way; the order of summation, so the last bits, differ.
+
+    Such a line search is bound by arithmetic (ls_trials * k `exp` a row
+    and lane), most of it never used: a lane takes the FIRST step that
+    passes, 99 % of the time one of the first four.  So under
+    `trial_data_loss` the evaluation is staged: the first
+    `_LS_FIRST_STAGE` steps always, the others (`lax.cond`) only in an
+    iteration where some lane that is not done passed none of them.  The
+    steps offered, the Armijo rule and the pick are those of the single
+    pass; `LBFGSResult.ls_second_pass` counts the iterations that ran
+    the second stage.  The generic search is bound by its read of
+    (Z, Zp) and stays a single pass.
     """
     m = history
     B, D = x0.shape
@@ -243,6 +274,11 @@ def glm_lbfgs_batched(
         done=jnp.zeros((B,), bool),
         stall=jnp.zeros((B,), jnp.int32),
     )
+    # the staged line search (below) counts its second passes; the generic
+    # search carries the state it always carried
+    staged = trial_data_loss is not None and ls_trials > _LS_FIRST_STAGE
+    if staged:
+        state["ls_second_pass"] = jnp.asarray(0, jnp.int32)
 
     def gnorm(g):
         return jnp.max(jnp.abs(g), axis=1)
@@ -322,19 +358,36 @@ def glm_lbfgs_batched(
                 Zt = Z + _bcast(a, Z) * Zp
                 return data_loss(Zt) + reg_loss(x + a[:, None] * p)
 
+            def trial_losses(a):                 # (t, B) steps -> losses
+                return trial_data_loss(Z, Zp, a) + jax.vmap(
+                    lambda a: reg_loss(x + a[:, None] * p))(a)
+
+            def passes(losses, a):
+                return losses <= f[None, :] + c1 * a * dginit[None, :]
+
             halvings = 0.5 ** jnp.arange(ls_trials, dtype=dtype)
             alphas = a0[None, :] * halvings[:, None]            # (T, B)
             if trial_data_loss is None:
                 losses = jax.vmap(eval_trial)(alphas)           # (T, B)
+            elif not staged:
+                # no more trials than the first stage holds: the single
+                # pass, which is also what the tests hold the staged
+                # search against (tests/test_linesearch_staged.py)
+                losses = trial_losses(alphas)
             else:
-                losses = trial_data_loss(Z, Zp, alphas) + jax.vmap(
-                    lambda a: reg_loss(x + a[:, None] * p))(alphas)
-            armijo = losses <= f[None, :] + c1 * alphas * dginit[None, :]
-            # first (largest-step) passing trial per lane; no trial passed ->
-            # take the last (smallest) step rather than stall
-            first_ok = jnp.argmax(armijo, axis=0)               # (B,)
-            found = jnp.any(armijo, axis=0)
-            pick = jnp.where(found, first_ok, ls_trials - 1)
+                first, rest = (alphas[:_LS_FIRST_STAGE],
+                               alphas[_LS_FIRST_STAGE:])
+                losses = trial_losses(first)
+                # a lane with a non-finite loss passes nothing, so it
+                # asks for the rest; a done lane's pick is never used
+                need_rest = jnp.any(jnp.logical_not(jnp.logical_or(
+                    st["done"], jnp.any(passes(losses, first), axis=0))))
+                losses = jnp.concatenate([losses, lax.cond(
+                    need_rest, lambda: trial_losses(rest),
+                    lambda: jnp.full(rest.shape, jnp.inf, dtype))])
+                ls_second_pass = st["ls_second_pass"] + need_rest.astype(
+                    jnp.int32)
+            pick = _armijo_pick(passes(losses, alphas))         # (B,)
             alpha = jnp.take_along_axis(alphas, pick[None, :], axis=0)[0]
             f_pick = jnp.take_along_axis(losses, pick[None, :], axis=0)[0]
 
@@ -384,15 +437,19 @@ def glm_lbfgs_batched(
             done = jnp.logical_or(
                 st["done"],
                 jnp.logical_or(gnorm(g_new) <= tol, stall >= 3))
-        return dict(x=x_new, Z=Z_new, f=f_new, g=g_new, s_mem=s_mem,
-                    y_mem=y_mem, rho=rho, gamma=gamma, it=it + 1,
-                    done=done, stall=stall)
+        new = dict(x=x_new, Z=Z_new, f=f_new, g=g_new, s_mem=s_mem,
+                   y_mem=y_mem, rho=rho, gamma=gamma, it=it + 1,
+                   done=done, stall=stall)
+        if staged:
+            new["ls_second_pass"] = ls_second_pass
+        return new
 
     st = lax.while_loop(cond, body, state)
     gn = jnp.max(jnp.abs(st["g"]), axis=1)
     return LBFGSResult(
         x=st["x"], fun=st["f"], grad_norm=gn,
-        n_iter=jnp.broadcast_to(st["it"], (B,)), converged=gn <= tol)
+        n_iter=jnp.broadcast_to(st["it"], (B,)), converged=gn <= tol,
+        ls_second_pass=st.get("ls_second_pass"))
 
 
 def glm_fista_batched(

@@ -250,6 +250,15 @@ def _iters_sum_host(isum, n_tasks=None):
     return isum if n_tasks is None else isum[:n_tasks]
 
 
+def _iters_max_host(im):
+    """A launch's lockstep iteration count, on the host (-1: no iterative
+    solver).  A launch whose solver stages its line search
+    (``"ls_second_pass"`` among the model's leaves) hands
+    ``[iterations, second passes]`` in the scalar's place."""
+    im = np.asarray(im)
+    return int(im) if im.ndim == 0 else im
+
+
 @jax.jit
 def _models_health(models):
     """(nc_batch, n_folds) True where any inexact model leaf went NaN —
@@ -1788,6 +1797,12 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
         finally:
             if profiler_cm is not None:
                 profiler_cm.__exit__(None, None, None)
+            # let go of the broadcast: _run_groups' closures hold this
+            # dict and one another, so without this a search's X stays on
+            # the device until Python's cycle collector happens to run —
+            # one more copy per search of an X the plane's budget cannot
+            # keep (PERF.md, PR 29: 0.23 GB a search in cell 1)
+            data_dev.clear()
             # this search's broadcast-cache traffic (hits = arrays
             # reused with zero transfer; bytes_uploaded = cacheable
             # bytes actually shipped; bytes_staged = per-chunk dyn
@@ -2807,6 +2822,12 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                     # place (_iters_sum_host)
                                     iters_sum = it.astype(
                                         jnp.int32).reshape(-1)
+                                if "ls_second_pass" in models:
+                                    # beside the iterations, in their
+                                    # slot (_iters_max_host)
+                                    iters = jnp.stack([iters, jnp.max(
+                                        models["ls_second_pass"]
+                                    ).astype(jnp.int32)])
                         te, tr = score_batch_wide(models, data_d, test_m,
                                                   train_m, test_u, train_u)
                     return te, tr, bad, iters, iters_sum
@@ -3135,7 +3156,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             te = {s: np.concatenate([a[0][s], b[0][s]]) for s in a[0]}
             tr = {s: np.concatenate([a[1][s], b[1][s]]) for s in a[1]}
             bad = np.concatenate([a[2], b[2]])
-            im = max(a[3], b[3])
+            im = _iters_max_host(np.maximum(a[3], b[3]))
             if np.ndim(a[4]) and np.ndim(b[4]):
                 isum = np.concatenate([a[4], b[4]])
             else:
@@ -3199,7 +3220,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 tr = {s: np.asarray(mesh_lib.device_get_tree(v))[:n]
                       for s, v in tr_d.items()}
                 bad = np.asarray(mesh_lib.device_get_tree(bad_d))[:n]
-                return (te, tr, bad, int(im_d),
+                return (te, tr, bad, _iters_max_host(im_d),
                         _iters_sum_host(isum_d, n * n_folds))
 
             try:
@@ -3385,10 +3406,9 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 # the score item consumes the recovered cells instead
                 # of launching (same contract as the OOM host fallback)
                 cstate["host"] = (te, tr)
-                if im >= 0:
-                    record_iters(plan, im, isum, lanes,
-                                 plan["group"].candidate_indices[lo:hi])
-                return np.asarray(bad, bool), None
+                record_iters(plan, im, isum, lanes,
+                             plan["group"].candidate_indices[lo:hi])
+                return np.asarray(bad, bool), None, None
             return bisect
 
         def make_bisect_score(plan, lo, hi, chunk_id):
@@ -3470,7 +3490,16 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                "wide" if all_cores else "nested")})
 
         def record_iters(plan, it_max, it_sum, lanes, idx=None):
+            # it_max: the launch's iterations, or [iterations, second
+            # passes of a staged line search] (_iters_max_host)
+            counts = np.ravel(it_max)
+            it_max = counts[0]
+            ls_second = counts[1] if counts.size > 1 else 0
+            if it_max < 0:
+                return          # no iterative solver ran this launch
             metrics.series("solver_iters_per_launch").append(int(it_max))
+            metrics.series("linesearch_second_pass_per_launch").append(
+                int(ls_second))
             metrics.series("solver_iters_sum_per_launch").append(
                 int(np.sum(it_sum)))
             metrics.series("lanes_per_launch").append(int(lanes))
@@ -3718,7 +3747,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         chunks.append((
                             {s: v[i] for s, v in te_h.items()},
                             {s: v[i] for s, v in tr_h.items()},
-                            bad_h[i], int(im_h[i]),
+                            bad_h[i], _iters_max_host(im_h[i]),
                             _iters_sum_host(isum_h[i])))
                     surv_h = (np.asarray(
                         mesh_lib.device_get_tree(surv))
@@ -3766,8 +3795,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         t_fit = wall * n_real / max(1, total_real)
                         fit_failed[idx, :] |= np.asarray(
                             bad[:hi - lo], bool)
-                        if im >= 0:
-                            record_iters(plan, im, isum, lanes, idx)
+                        record_iters(plan, im, isum, lanes, idx)
                         write_cells(plan, idx, lo, hi, chunk_id, te,
                                     tr, t_fit, 0.0, count_launch=False)
                     metrics.counter("n_launches").inc()
@@ -3902,7 +3930,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                     mesh_lib.device_get_tree(tr),
                                     np.asarray(
                                         mesh_lib.device_get_tree(bad)),
-                                    int(it_max), _iters_sum_host(it_sum))
+                                    _iters_max_host(it_max),
+                                    _iters_sum_host(it_sum))
 
                         def finalize(host, tm, plan=plan, idx=idx, lo=lo,
                                      hi=hi, chunk_id=chunk_id,
@@ -3922,8 +3951,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                             t_fit = wall - t_score
                             fit_failed[idx, :] |= np.asarray(
                                 bad[:hi - lo], bool)
-                            if im >= 0:
-                                record_iters(plan, im, isum, lanes, idx)
+                            record_iters(plan, im, isum, lanes, idx)
                             write_cells(plan, idx, lo, hi, chunk_id,
                                         te, tr, t_fit, t_score)
 
@@ -3961,25 +3989,32 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                             # axis but runs a larger internal budget)
                             it_arr = models.get("n_iter_exec",
                                                 models.get("n_iter"))
-                        return models, bad, it_arr
+                        return (models, bad, it_arr,
+                                models.get("ls_second_pass")
+                                if it_arr is not None else None)
 
                     def gather_fit(out):
-                        _, bad, it_arr = out
+                        _, bad, it_arr, ls2_arr = out
                         bad_h = (np.asarray(mesh_lib.device_get_tree(bad))
                                  if bad is not None else None)
                         it_h = (np.asarray(
                             mesh_lib.device_get_tree(it_arr))
                             if it_arr is not None else None)
-                        return bad_h, it_h
+                        ls2_h = (np.asarray(
+                            mesh_lib.device_get_tree(ls2_arr))
+                            if ls2_arr is not None else None)
+                        return bad_h, it_h, ls2_h
 
                     def fin_fit(host, tm, plan=plan, idx=idx, lo=lo,
                                 hi=hi, cstate=cstate, lanes=lanes):
-                        bad_h, it_h = host
+                        bad_h, it_h, ls2_h = host
                         if bad_h is not None:
                             fit_failed[idx, :] |= bad_h[:hi - lo]
                         if it_h is not None:
-                            record_iters(plan, np.max(it_h), it_h, lanes,
-                                         idx)
+                            im = np.max(it_h)
+                            if ls2_h is not None:
+                                im = [im, np.max(ls2_h)]
+                            record_iters(plan, im, it_h, lanes, idx)
                         cstate["t_fit"] = tm.dispatch_s + tm.compute_s
 
                     def host_fb_fit(idx=idx, cstate=cstate):
@@ -3989,7 +4024,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         # launching
                         te, tr = host_eval(idx)
                         cstate["host"] = (te, tr)
-                        return (None, None)
+                        return (None, None, None)
 
                     yield LaunchItem(
                         key=chunk_id + ":fit", kind="fit", group=gi,

@@ -13,7 +13,11 @@ The line search's compiled shape is pinned at both cells' launch shapes
 (``test_linesearch_compiled_shape``): with the multinomial family's
 one-pass evaluator no array of ``ls_trials * n * B`` elements is written
 and Z is not copied into another layout, and the three other callers of
-the solver hand it no evaluator.
+the solver hand it no evaluator.  Its staged evaluation (the first four
+trial steps, the other twelve under a conditional) is one ``conditional``
+in the solver loop's body that writes no (t, n, B) rows; the three other
+callers lower to the text they lowered to before the staging
+(``test_generic_callers_lower_to_the_parents_text``).
 
 The kernel-dual launch (``SVCFamily.fit_task_batched``) is compiled at the
 shape of the ``svc_rbf_mnist20k.c4_gamma4`` cell — 20 000 rows x 16
@@ -196,6 +200,64 @@ def _other_caller(name):
     }[name]
 
 
+def _lowered_fit(name, n=64, lanes=6):
+    """StableHLO text (no locations in it) of one small task-batched fit
+    of a caller of the solver, as ``jax.jit(...).lower`` gives it."""
+    from spark_sklearn_tpu.models.linear import LogisticRegressionFamily
+    if name == "multinomial":
+        family, static, n_classes, y_dtype = (
+            LogisticRegressionFamily, {"max_iter": 100}, K, jnp.int32)
+    else:
+        family, static, n_classes, y_dtype = _other_caller(name)
+    meta = {"n_classes": n_classes, "classes": np.arange(n_classes),
+            "n_features": D}
+    data = {"X": jax.ShapeDtypeStruct((n, D), jnp.float32),
+            "y": jax.ShapeDtypeStruct((n,), y_dtype),
+            "y1h": jax.ShapeDtypeStruct((n, max(n_classes, 1)),
+                                        jnp.float32)}
+    return jax.jit(lambda dyn, data, w: family.fit_task_batched(
+        dyn, static, data, w, meta)).lower(
+        {"C": jax.ShapeDtypeStruct((lanes,), jnp.float32)}, data,
+        jax.ShapeDtypeStruct((lanes, n), jnp.float32)).as_text()
+
+
+#: sha256 of ``_lowered_fit(name)`` at the commit before the line search was
+#: staged (PR 28, 5401493), on the one supported installation.  A PR that
+#: means to change one of these launches replaces its digest.
+PARENT_LOWERED_SHA256 = {
+    "binary_logreg":
+        "c4ec3f648df3c91220d06b440b29cf453eef0be86102ba90614108e6cc716259",
+    "linear_svc":
+        "4d54bb6c48b6b48979eef07d1cdf6cf4375481bcbb100cbd23d479be87e29f03",
+    "linear_svr":
+        "9a71cecd6e585f440f5fd16389e27af8e91c685a287dc12efcfe6c011dfc68b3",
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_LOWERED_SHA256))
+def test_generic_callers_lower_to_the_parents_text(name, monkeypatch):
+    """The staged line search is for a caller that hands an evaluator.
+    Binary LogisticRegression, LinearSVC and LinearSVR hand none: their
+    launch has no conditional, is the same text whatever the staging
+    constant, and is the text of the commit before the staging."""
+    import hashlib
+    from spark_sklearn_tpu.ops import solvers
+    text = _lowered_fit(name)
+    assert "stablehlo.while" in text
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    monkeypatch.setattr(solvers, "_LS_FIRST_STAGE", 7)
+    assert _lowered_fit(name) == text
+    assert (hashlib.sha256(text.encode()).hexdigest()
+            == PARENT_LOWERED_SHA256[name])
+
+
+def test_multinomial_launch_lowers_to_one_conditional():
+    """Both stages, the conditional and the pick under the one scope the
+    benchmark reads; the counter in the loop's state."""
+    text = _lowered_fit("multinomial")
+    assert text.count("stablehlo.case") + text.count("stablehlo.if") == 1
+
+
 @pytest.mark.parametrize("case", [
     ("multinomial", 10_000, 625), ("multinomial", 70_000, 190),
     ("binary_logreg",), ("linear_svc",), ("linear_svr",)],
@@ -250,6 +312,20 @@ def test_linesearch_compiled_shape(case, request, monkeypatch):
     trial_tensors, relayouts = buffers(generic)
     assert len(trial_tensors) >= 3 and len(relayouts) == 2
     assert buffers(one_pass) == ([], [])
+    # the staged search: ONE conditional, in the solver loop's own body,
+    # and neither stage writes its trials' (t, n, B) rows out
+    text = one_pass.as_text()
+    outside = _instructions_outside_fusions(text)
+    conditionals = [comp for comp, line in outside
+                    if " conditional(" in line]
+    loop_bodies = re.findall(r' while\(.*body=%([\w.\-]+)', text)
+    assert len(conditionals) == 1 and conditionals[0] in loop_bodies
+    assert " conditional(" not in generic.as_text()
+    stage_rows = re.compile(
+        r'f32\[(?:4|12|16),%d,%d\]|f32\[%d,%d,(?:4|12|16)\]'
+        % (n, lanes, n, lanes))
+    assert not [line for _, line in outside
+                if stage_rows.search(line.split(" = ", 1)[1].split("(")[0])]
     assert (one_pass.memory_analysis().temp_size_in_bytes
             < 0.6 * generic.memory_analysis().temp_size_in_bytes)
 
