@@ -29,6 +29,7 @@ jax.config.update("jax_platforms", "cpu")
 MODULES = [
     "spark_sklearn_tpu",
     "spark_sklearn_tpu.search.grid",
+    "spark_sklearn_tpu.search.launch",
     "spark_sklearn_tpu.search.scorers",
     "spark_sklearn_tpu.convert.converter",
     "spark_sklearn_tpu.keyed.keyed",

@@ -193,6 +193,42 @@ class Family:
         """Margins/logits for log-loss & AUC scorers; optional."""
         raise NotImplementedError
 
+    # --- what a launch reports about its solver --------------------------
+    # Two hooks, one traced and one on the host, keyed by the ``stat``
+    # names of ``obs.metrics.LAUNCH_STATS``: that table declares how each
+    # combines across a bisected chunk and the ``search_report`` series
+    # it feeds, and the engine (search/launch.py) carries the values
+    # there unread.  A new solver counter is an entry here, a row there.
+    @classmethod
+    def launch_stats(cls, models, static, meta) -> Dict[str, Any]:
+        """Traced, once inside a launch's program, on the fitted models
+        of all its (candidate, fold) tasks: named int32 arrays, a scalar
+        each or (for a "per_candidate" stat) one entry a task in
+        candidate-major order.  Default: nothing where the model has no
+        ``n_iter_exec`` / ``n_iter`` leaf (no iterative solver), else the
+        launch's lockstep maximum and the sum over its lanes."""
+        import jax.numpy as jnp
+
+        it = None
+        if isinstance(models, dict):
+            # prefer the solver's true executed count over a
+            # sklearn-facing rescale (FISTA reports n_iter on the
+            # caller's max_iter axis but runs a larger internal budget)
+            it = models.get("n_iter_exec", models.get("n_iter"))
+        if it is None:
+            return {}
+        return {"solver_iters": jnp.max(it).astype(jnp.int32),
+                "solver_iters_sum": jnp.sum(it).astype(jnp.int32)}
+
+    @classmethod
+    def launch_facts(cls, static, meta, n_candidates: int,
+                     n_folds: int) -> Dict[str, int]:
+        """On the host: what is known of a launch of ``n_candidates``
+        (padding included) without running it, under the "fact" stats'
+        names.  Recorded only for a launch that reports solver stats.
+        Default: nothing."""
+        return {}
+
     # --- interop ---------------------------------------------------------
     @classmethod
     def sklearn_attrs(cls, model, static, meta) -> Dict[str, Any]:

@@ -243,11 +243,25 @@ class LogisticRegressionFamily(Family):
     def linesearch_one_pass(cls, static, meta):
         """True where `fit_task_batched` hands `glm_lbfgs_batched` the
         one-pass evaluator of the line search's trial losses: the
-        multinomial L-BFGS fit (its loss has a class axis to unroll).
-        The engine reports it per launch
-        (``search_report["linesearch_one_pass_per_launch"]``)."""
+        multinomial L-BFGS fit (its loss has a class axis to unroll)."""
         return (meta["n_classes"] != 2
                 and _batched_penalty(static)[0] != "elasticnet")
+
+    @classmethod
+    def launch_facts(cls, static, meta, n_candidates, n_folds):
+        return {"linesearch_one_pass":
+                int(cls.linesearch_one_pass(static, meta))}
+
+    @classmethod
+    def launch_stats(cls, models, static, meta):
+        """The default's iterations, and a staged line search's count of
+        iterations that ran its second stage (on every lane, as n_iter
+        is)."""
+        stats = super().launch_stats(models, static, meta)
+        if "ls_second_pass" in models:
+            stats["linesearch_second_pass"] = jnp.max(
+                models["ls_second_pass"]).astype(jnp.int32)
+        return stats
 
     @classmethod
     def fit_task_batched(cls, dynamic, static, data, train_w, meta):
@@ -410,8 +424,7 @@ class LogisticRegressionFamily(Family):
                  "n_iter_exec": n_exec}
         if res.ls_second_pass is not None:
             # a staged line search's count for the launch, on every lane
-            # as n_iter is (the engine reports it:
-            # "linesearch_second_pass_per_launch")
+            # as n_iter is (launch_stats reports it)
             model["ls_second_pass"] = jnp.broadcast_to(
                 res.ls_second_pass, (B,))
         return model

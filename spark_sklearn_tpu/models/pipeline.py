@@ -17,7 +17,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from spark_sklearn_tpu.models import preprocessing as prep
-from spark_sklearn_tpu.models.base import resolve_family
+from spark_sklearn_tpu.models.base import Family, resolve_family
 from spark_sklearn_tpu.utils.checkpoint import fingerprint
 
 
@@ -29,6 +29,12 @@ class PipelineFamily:
     #: routing requires "step__sample_weight"); weighted searches take the
     #: host path so that contract is reproduced, not silently reinvented
     accepts_sample_weight = False
+
+    #: what a launch reports (the protocol's two hooks, at their
+    #: defaults): the default reads the model's own top-level leaves and
+    #: this family's model nests the final step's, so nothing
+    launch_stats = Family.launch_stats
+    launch_facts = Family.launch_facts
 
     def __init__(self, steps: List[Tuple[str, Any]], final_name: str,
                  final_family):
@@ -250,6 +256,11 @@ class BinnedInvariantPipelineFamily:
     answer to BASELINE-config-#4/#5-shaped pipelines."""
 
     accepts_sample_weight = False    # same Pipeline.fit contract as above
+
+    #: the protocol's defaults: the model IS the final step's, so its
+    #: iteration leaves are reported as a bare tree family's are
+    launch_stats = Family.launch_stats
+    launch_facts = Family.launch_facts
 
     def __init__(self, final_name: str, final_family):
         self.final_name = final_name

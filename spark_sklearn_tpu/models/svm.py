@@ -525,10 +525,6 @@ class SVCFamily(Family):
         budget = 1 << 30   # ~1 GiB of decision cache per launch
         return max(1, budget // max(1, n_samples * p * 4))
 
-    #: the fit's `n_iter` is per task (a candidate's folds share its
-    #: count): the engine keeps the counts, not only their max and sum
-    reports_task_iters = True
-
     @staticmethod
     def launch_workspace(n_samples: int, meta, n_folds: int,
                          itemsize: int = 4):
@@ -549,17 +545,24 @@ class SVCFamily(Family):
                 + 8 * m * n * itemsize,
                 "per_candidate_bytes": 3 * m * n * itemsize}
 
-    @staticmethod
-    def launch_counters(meta, n_candidates: int, n_folds: int):
-        """Per-launch counts for `search_report`
-        (`gram_builds_per_launch`, `dual_subproblems_per_launch`): kernel
-        matrices built and dual subproblems advanced by a launch of
-        `n_candidates` (padding included: a padded candidate is
-        computed)."""
+    @classmethod
+    def launch_facts(cls, static, meta, n_candidates, n_folds):
+        """Kernel matrices built and dual subproblems advanced by a
+        launch of `n_candidates` (padding included: a padded candidate
+        is computed)."""
         k = meta["n_classes"]
         return {"gram_builds": n_candidates,
                 "dual_subproblems":
                     n_candidates * n_folds * max(1, k * (k - 1) // 2)}
+
+    @classmethod
+    def launch_stats(cls, models, static, meta):
+        """The default's maximum and sum, and the counts themselves: the
+        fit's `n_iter` is per task (a candidate's folds share its
+        count)."""
+        stats = super().launch_stats(models, static, meta)
+        stats["dual_iters"] = models["n_iter"].astype(jnp.int32).reshape(-1)
+        return stats
 
     @classmethod
     def extract_params(cls, estimator):

@@ -27,6 +27,7 @@ __all__ = [
     "MetricDef",
     "MetricsRegistry",
     "SEARCH_REPORT_SCHEMA",
+    "LAUNCH_STATS",
     "PIPELINE_BLOCK_SCHEMA",
     "FAULTS_BLOCK_SCHEMA",
     "DATAPLANE_BLOCK_SCHEMA",
@@ -57,6 +58,16 @@ class MetricDef:
     description: str
     #: which backends emit it ("tpu", "host", "tpu,host")
     backends: str = "tpu"
+    #: a series a launch feeds: the key a family's ``launch_stats``
+    #: (traced) or ``launch_facts`` (host) hook reports the value under
+    stat: Optional[str] = None
+    #: how a bisected chunk's launches combine it and where it is
+    #: written: "max" / "sum" / "fact" (known without running) one entry
+    #: a launch, "per_candidate" (a per-task vector) at the candidates'
+    #: cv_results_ positions
+    combine: Optional[str] = None
+    #: the entry of a launch that reports solver stats and not this one
+    fill: Optional[int] = None
 
 
 #: the pinned schema of ``BaseSearchTPU.search_report``
@@ -100,11 +111,13 @@ SEARCH_REPORT_SCHEMA = (
     MetricDef(
         "solver_iters_per_launch", "series",
         "Per-launch max executed solver iterations over the launch's "
-        "lanes (lockstep semantics; -1 launches are omitted)."),
+        "lanes (lockstep semantics; -1 launches are omitted).",
+        stat="solver_iters", combine="max"),
     MetricDef(
         "solver_iters_sum_per_launch", "series",
         "Per-launch sum of executed solver iterations over lanes "
-        "(per-lane semantics for scan-sequential families)."),
+        "(per-lane semantics for scan-sequential families).",
+        stat="solver_iters_sum", combine="sum"),
     MetricDef(
         "lanes_per_launch", "series",
         "Per-launch padded lane count (candidate x fold program "
@@ -115,7 +128,8 @@ SEARCH_REPORT_SCHEMA = (
         "glm_lbfgs_batched evaluated its trial steps through the family's "
         "one-pass evaluator (multinomial LogisticRegression, class "
         "planes), 0 where it was the generic vmap of the loss or the "
-        "launch ran another solver."),
+        "launch ran another solver.",
+        stat="linesearch_one_pass", combine="fact", fill=0),
     MetricDef(
         "linesearch_second_pass_per_launch", "series",
         "Per launch of an iterative solver: iterations of "
@@ -123,18 +137,21 @@ SEARCH_REPORT_SCHEMA = (
         "trial steps after the first four too, because some lane that was "
         "not done passed none of those; at most the launch's "
         "solver_iters_per_launch.  0 where the line search is not staged "
-        "(linesearch_one_pass_per_launch reads 0)."),
+        "(linesearch_one_pass_per_launch reads 0).",
+        stat="linesearch_second_pass", combine="max", fill=0),
     MetricDef(
         "gram_builds_per_launch", "series",
         "Per launch of a kernel-dual family (SVC, NuSVC): kernel "
         "matrices the launch built, one per candidate it computed "
         "(padding included).  Absent where a compiled Pipeline wraps "
-        "the estimator (a matrix per candidate and fold there)."),
+        "the estimator (a matrix per candidate and fold there).",
+        stat="gram_builds", combine="fact"),
     MetricDef(
         "dual_subproblems_per_launch", "series",
         "Per launch of a kernel-dual family: box-constrained dual "
         "subproblems advanced through _box_fista, candidates x folds x "
-        "one-vs-one pairs."),
+        "one-vs-one pairs.",
+        stat="dual_subproblems", combine="fact"),
     MetricDef(
         "dual_iters_per_candidate", "series",
         "Kernel-dual families: executed _box_fista iterations of each "
@@ -142,7 +159,8 @@ SEARCH_REPORT_SCHEMA = (
         "pairs advance together and stop together; a count equal to the "
         "iteration cap means the cap ended the solve, not tol).  -1: the "
         "candidate was restored from a checkpoint or fitted on the "
-        "host."),
+        "host.",
+        stat="dual_iters", combine="per_candidate"),
     MetricDef(
         "padding_waste", "histogram",
         "Per-launch fraction of computed lanes that were padding "
@@ -272,6 +290,12 @@ SEARCH_REPORT_SCHEMA = (
         "Host tier: joblib worker count the fan-out used.",
         backends="host"),
 )
+
+#: what a launch reports about its solver, by the key a family reports
+#: it under: the rows above that name a ``stat``.  The one place a solver
+#: counter is declared; ``search/launch.py`` carries, combines and writes
+#: the values by these rows and the engine reads none of them by name.
+LAUNCH_STATS = {d.stat: d for d in SEARCH_REPORT_SCHEMA if d.stat}
 
 #: sub-keys of ``search_report["pipeline"]`` (written by
 #: ``parallel.pipeline.ChunkPipeline.report`` plus the engine's cache /
@@ -1321,6 +1345,17 @@ def schema_markdown() -> str:
         out.append(
             f"| `{d.name}` | {d.kind} | {d.backends} | "
             f"{d.description} |\n")
+    out.append(
+        "\n### What a launch reports\n\nThe series above that a launch "
+        "feeds, by the key a family reports the value under "
+        "(`Family.launch_stats`, traced, and `Family.launch_facts`, on "
+        "the host; `models/base.py`).  `combine` is how the values of a "
+        "bisected chunk's launches become one; `fill` is the entry of a "
+        "launch that reports solver stats and not this one.\n"
+        "\n| stat | combine | fill | series |\n|---|---|---|---|\n")
+    for d in LAUNCH_STATS.values():
+        out.append(f"| `{d.stat}` | {d.combine} | "
+                   f"{'' if d.fill is None else d.fill} | `{d.name}` |\n")
     out.append("\n### `search_report[\"pipeline\"]` block\n")
     out.append("\n| key | kind | description |\n|---|---|---|\n")
     for d in PIPELINE_BLOCK_SCHEMA:

@@ -93,12 +93,13 @@ __all__ = [
     "snapshot_counters",
 ]
 
-#: on-disk format version: bump when the artifact layout changes —
-#: old stores become clean misses, never parse errors.
-STORE_FORMAT = 1
+#: on-disk format version: bump when the artifact layout changes (2:
+#: the launch programs' output tree is a LaunchResult) — old stores
+#: become clean misses, never parse errors.
+STORE_FORMAT = 2
 
 #: artifact file magic (format version baked in).
-_MAGIC = b"SSTPROG1"
+_MAGIC = b"SSTPROG2"
 
 #: default store byte budget (512 MiB): a few hundred bench-scale
 #: programs; oldest artifacts evict beyond it.

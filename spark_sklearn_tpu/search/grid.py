@@ -239,26 +239,6 @@ def _program_build_count() -> int:
         return _PROGRAM_BUILDS
 
 
-def _iters_sum_host(isum, n_tasks=None):
-    """A launch's summed executed iterations, on the host.  A family that
-    solves task after task (``reports_task_iters``) hands the per-task
-    counts in the sum's place: they stay a vector, cut to the launch's
-    first ``n_tasks`` (real) tasks where that is given."""
-    isum = np.asarray(isum)
-    if isum.ndim == 0:
-        return int(isum)
-    return isum if n_tasks is None else isum[:n_tasks]
-
-
-def _iters_max_host(im):
-    """A launch's lockstep iteration count, on the host (-1: no iterative
-    solver).  A launch whose solver stages its line search
-    (``"ls_second_pass"`` among the model's leaves) hands
-    ``[iterations, second passes]`` in the scalar's place."""
-    im = np.asarray(im)
-    return int(im) if im.ndim == 0 else im
-
-
 @jax.jit
 def _models_health(models):
     """(nc_batch, n_folds) True where any inexact model leaf went NaN —
@@ -1935,6 +1915,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
         from spark_sklearn_tpu.parallel.pipeline import (
             ChunkPipeline, FuseSpec, LaunchItem, persistent_cache_counts)
         from spark_sklearn_tpu.parallel.taskgrid import pad_chunk
+        from spark_sklearn_tpu.search.launch import (
+            LaunchResult, record_stats)
 
         #: successive-halving rung owner (search/halving.py, via the
         #: launch-ownership protocol): this call is one rung of a
@@ -1994,6 +1976,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
         # fit, and must not trip the all-fits-failed raise)
         errval = (np.nan if isinstance(self.error_score, str)
                   else self.error_score)
+        best_effort = str(getattr(config, "partial_results", "raise")
+                          or "raise") == "best_effort"
         # multi-controller runs force depth 0 below; resolved here so
         # the staging ring can size itself to the in-flight window
         depth = config.pipeline_depth if jax.process_count() == 1 else 0
@@ -2204,15 +2188,9 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         max(n_task_shards,
                             mesh_lib.pad_to_multiple(
                                 -(-nc // _SORTED_LAUNCHES), n_task_shards)))
-                one_pass_hook = getattr(family, "linesearch_one_pass", None)
                 plans.append({
                     "gi": gi, "group": group, "static": static, "nc": nc,
-                    "sorted": sorted_chunks, "sorted_cap": sorted_cap,
-                    # the family's word on how this group's task-batched
-                    # fit evaluates its line search (record_iters)
-                    "ls_one_pass": int(
-                        task_batched and one_pass_hook is not None
-                        and one_pass_hook(static, meta))})
+                    "sorted": sorted_chunks, "sorted_cap": sorted_cap})
 
             # per-group prefix digests (stage-1 grouping): groups map
             # many-to-one onto digests — groups differing only in
@@ -2665,25 +2643,6 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                             lambda l: l.reshape(
                                 (nc_batch, n_folds) + l.shape[1:]), model)
 
-                # the mesh joins the in-memory key exactly as
-                # mesh_desc joins the store key (declared-vs-actual
-                # drift from the pre-store key path: every other
-                # program key already carries it, and a same-shape
-                # search on a re-built mesh must not reuse a program
-                # whose store proxy was keyed to the old one)
-                fit_jit = _cached_program(
-                    ("fit_tb", family, static, meta, nc_batch, n_folds,
-                     bool(config.bf16_matmul), donate, mesh),
-                    lambda: jax.jit(fit_batch_tb, **donate_kw),
-                    store_parts=None if donate else (
-                        "fit_tb", family.name, static, meta, nc_batch,
-                        n_folds, bool(config.bf16_matmul), mesh_desc),
-                    store=search_store,
-                    check_fields={
-                        "bf16_matmul": bool(config.bf16_matmul),
-                        "donate_chunk_buffers": donate,
-                        "mesh": mesh_desc})
-
             def fit_batch(dyn_arrs, data_d, train_m, static=static):
                 def one_cand(dyn_scalars):
                     if px:
@@ -2704,6 +2663,16 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     return jax.vmap(one_fold)(train_m)
                 with jax.named_scope("sst.fit"):
                     return jax.vmap(one_cand)(dyn_arrs)
+
+            def with_stats(fit_fn):
+                # the unfused fit launch's program: the models and, from
+                # the hook the fused body calls, the launch's stats
+                @functools.wraps(fit_fn)    # the program keeps its name
+                def fit_program(*args):
+                    models = fit_fn(*args)
+                    return models, family.launch_stats(models, static,
+                                                       meta)
+                return fit_program
 
             def score_batch_wide(models, data_d, test_m, train_m, test_u,
                                  train_u, static=static):
@@ -2801,36 +2770,12 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         if bad is None:
                             leaf = jax.tree_util.tree_leaves(models)[0]
                             bad = jnp.zeros(leaf.shape[:2], bool)
-                        # executed-iteration counts for FLOP/MFU accounting
-                        # (-1 sentinel: family has no iterative solver).
-                        # max = lockstep meaning (a launch executes the max
-                        # over its lanes); sum = per-lane meaning (scan-
-                        # sequential families like SVC execute each lane's
-                        # own count) — consumers pick the one that matches
-                        # the family's execution model.
-                        iters = jnp.int32(-1)
-                        iters_sum = jnp.int32(-1)
-                        if isinstance(models, dict):
-                            it = models.get("n_iter_exec",
-                                            models.get("n_iter"))
-                            if it is not None:
-                                iters = jnp.max(it).astype(jnp.int32)
-                                iters_sum = jnp.sum(it).astype(jnp.int32)
-                                if getattr(family, "reports_task_iters",
-                                           False):
-                                    # the per-task counts in the sum's
-                                    # place (_iters_sum_host)
-                                    iters_sum = it.astype(
-                                        jnp.int32).reshape(-1)
-                                if "ls_second_pass" in models:
-                                    # beside the iterations, in their
-                                    # slot (_iters_max_host)
-                                    iters = jnp.stack([iters, jnp.max(
-                                        models["ls_second_pass"]
-                                    ).astype(jnp.int32)])
+                        # what the family reports of the launch: named
+                        # int32 arrays the engine carries without reading
+                        stats = family.launch_stats(models, static, meta)
                         te, tr = score_batch_wide(models, data_d, test_m,
                                                   train_m, test_u, train_u)
-                    return te, tr, bad, iters, iters_sum
+                    return LaunchResult(te, tr, bad, stats)
 
                 fused_jit = _cached_program(
                     ("fused", family, static, meta, nc_batch, n_folds,
@@ -2854,10 +2799,30 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             # never be a silent 0.0 — VERDICT r4 next #4).  jax.jit is
             # lazy, so a program a search never calls is never traced or
             # compiled.
-            if not task_batched:
+            if task_batched:
+                # the mesh joins the in-memory key exactly as
+                # mesh_desc joins the store key (declared-vs-actual
+                # drift from the pre-store key path: every other
+                # program key already carries it, and a same-shape
+                # search on a re-built mesh must not reuse a program
+                # whose store proxy was keyed to the old one)
+                fit_jit = _cached_program(
+                    ("fit_tb", family, static, meta, nc_batch, n_folds,
+                     bool(config.bf16_matmul), donate, mesh),
+                    lambda: jax.jit(with_stats(fit_batch_tb), **donate_kw),
+                    store_parts=None if donate else (
+                        "fit_tb", family.name, static, meta, nc_batch,
+                        n_folds, bool(config.bf16_matmul), mesh_desc),
+                    store=search_store,
+                    check_fields={
+                        "bf16_matmul": bool(config.bf16_matmul),
+                        "donate_chunk_buffers": donate,
+                        "mesh": mesh_desc})
+            else:
                 fit_jit = _cached_program(
                     ("fit", family, static, meta, mesh, donate, px),
-                    lambda: jax.jit(fit_batch, out_shardings=task_shard,
+                    lambda: jax.jit(with_stats(fit_batch),
+                                    out_shardings=(task_shard, None),
                                     **donate_kw),
                     store_parts=None if donate else (
                         "fit", family.name, static, meta, mesh_desc,
@@ -2937,7 +2902,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         dyn_c, idx_c, step_i = xs
                     else:
                         dyn_c, idx_c = xs
-                    te, tr, bad, im, isum = fused_body(
+                    res = fused_body(
                         dyn_c, data_d, w_fit, test_m, train_m,
                         test_u, train_u)
                     if hb:
@@ -2953,10 +2918,10 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         # BEFORE the mean, so the device ranking sees
                         # the same scores sklearn's _top_k would
                         sc = jnp.where(
-                            bad, jnp.float32(errval),
-                            te[score0].astype(jnp.float32))
+                            res.bad, jnp.float32(errval),
+                            res.test[score0].astype(jnp.float32))
                         carry = carry.at[idx_c].set(sc)
-                    return carry, (te, tr, bad, im, isum)
+                    return carry, res
 
                 xs = (dyn_st, idx_st)
                 if hb:
@@ -2998,39 +2963,88 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             cache[ck] = scan_jit
             return scan_jit
 
-        def group_masks(plan):
-            """The group's fit-mask device buffer.  Task-batched families
-            consume the fold masks tiled to the launch width — under the
-            data plane the tile is a cached ON-DEVICE broadcast of the
-            already-resident base masks (uploaded at most once per
-            search, reused across groups sharing a width, OOM relaunches
-            and subsequent searches); the legacy path host-tiles lazily
-            on the stage thread, once per group."""
+        def group_masks(plan, width=None):
+            """The fit-mask device buffer of a launch of the group.
+            Task-batched families consume the fold masks tiled to the
+            launch width — under the data plane a cached ON-DEVICE
+            broadcast of the resident base masks (reused across groups
+            sharing a width, OOM relaunches and later searches); the
+            legacy path host-tiles.  At the group's own width (`width`
+            None) the buffer is memoized per plan: re-hashing the mask
+            array every chunk would put serial host work back on the
+            stage thread."""
             if not task_batched:
                 return fit_dev
+            if width is None and plan.get("w_task_dev") is not None:
+                return plan["w_task_dev"]
+            lanes_w = width or plan["nc_batch"]
             if plane is not None:
-                # memoized per plan: stage() asks once per chunk, and
-                # re-hashing the full mask array every launch would put
-                # serial host work back on the stage thread
-                w = plan.get("w_task_dev")
-                if w is None:
-                    w = plan["w_task_dev"] = plane.tiled(
-                        fit_masks, fit_dev, plan["nc_batch"],
-                        tb_mask_shard, label=tiled_label,
-                        fp=fit_masks_fp(), tenant=sched_tenant)
-                return w
-            w = plan.get("w_task_dev")
-            if w is None:
+                w = plane.tiled(
+                    fit_masks, fit_dev, lanes_w, tb_mask_shard,
+                    label=tiled_label, fp=fit_masks_fp(),
+                    tenant=sched_tenant)
+            else:
                 w = _dataplane.upload(
-                    np.tile(fit_masks, (plan["nc_batch"], 1)),
+                    np.tile(fit_masks, (lanes_w, 1)),
                     tb_mask_shard, label=tiled_label)
+            if width is None:
                 plan["w_task_dev"] = w
             return w
+
+        def stage_operands(plan, arrays, lo, hi, width, label, ring=None):
+            """What a launch of `width` candidates (None: the group's
+            own width) takes: rows [lo, hi) of every dynamic parameter in
+            `arrays` padded to the width and uploaded under `label`, the
+            `_pad` operand of an all-static group, and the fit masks at
+            that width.  Shared by the per-chunk stage, the bisection's
+            relaunch and the cross-search fused launch."""
+            mask_width, width = width, width or plan["nc_batch"]
+            repeat = n_folds if task_batched else 1
+            dyn = {}
+            for k, arr in arrays.items():
+                # donate mode: pad into a reused host buffer (the slot
+                # blocks on its previous consumer before reuse)
+                slot = None if ring is None else ring.slot(
+                    (plan["gi"], k), (width * repeat,) + arr.shape[1:],
+                    arr.dtype)
+                dyn[k] = _dataplane.upload(
+                    pad_chunk(arr, lo, hi, width, repeat,
+                              out=None if slot is None else slot.array),
+                    task_shard, label=label)
+                if slot is not None:
+                    slot.commit(dyn[k])
+            if not dyn and not task_batched:
+                # all-static group: vmap still needs a batched operand
+                # to define the candidate axis (families ignore unknown
+                # keys).  The plane caches the zeros — except under
+                # donation, where the launch would invalidate them
+                dyn["_pad"] = (
+                    plane.zeros(width, dtype, task_shard,
+                                tenant=sched_tenant)
+                    if plane is not None and not donate else
+                    _dataplane.upload(np.zeros(width, dtype=dtype),
+                                      task_shard, label="dyn.pad"))
+            return dyn, group_masks(plan, mask_width)
+
+        #: the scoring masks every score, fused and scan program takes
+        score_ops = (test_dev, train_sc_dev, test_unw_dev, train_unw_dev)
 
         #: guards the per-plan staged-chunk bookkeeping: stage normally
         #: runs on the single stage thread, but supervisor retries
         #: re-stage on whichever thread is recovering
         stage_lock = named_lock("grid.stage_lock")
+
+        def note_staged(plan, chunk_ids):
+            """Once the group's last live chunk has staged, drop the
+            plan's tiled-mask reference (each payload keeps its own) so
+            one group's masks never outlive its launches.  A set of ids
+            under a lock: a transient retry re-stages on the recovering
+            thread and must not count twice."""
+            with stage_lock:
+                done = plan.setdefault("staged_ids", set())
+                done.update(chunk_ids)
+                if len(done) >= plan["n_live"]:
+                    plan.pop("w_task_dev", None)
 
         cache0 = persistent_cache_counts()
         builds0 = _program_build_count()
@@ -3094,8 +3108,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     w_spec = fit_dev
                 plan["aot_future"] = pipe.submit_precompile(
                     progs["fused"], dyn_spec, plan_data(plan), w_spec,
-                    test_dev, train_sc_dev, test_unw_dev, train_unw_dev,
-                    label=f"fused group {plan['gi']}")
+                    *score_ops, label=f"fused group {plan['gi']}")
             # sstlint: disable=launch-except-taxonomy — AOT compile-ahead
             # is an optimization only: any failure here means the jit
             # path compiles at first dispatch, exactly as it always did
@@ -3148,28 +3161,21 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             idx = plan["group"].candidate_indices[lo:hi]
             sup.record_host_fallback(f"{chunk_id}[{lo}:{hi}]",
                                      plan["gi"], len(idx) * n_folds)
-            te, tr = host_eval(idx)
-            bad = np.zeros((hi - lo, n_folds), bool)
-            return te, tr, bad, -1, -1
+            return LaunchResult.host_fill(*host_eval(idx), hi - lo, n_folds)
 
-        def merge_fused(a, b):
-            te = {s: np.concatenate([a[0][s], b[0][s]]) for s in a[0]}
-            tr = {s: np.concatenate([a[1][s], b[1][s]]) for s in a[1]}
-            bad = np.concatenate([a[2], b[2]])
-            im = _iters_max_host(np.maximum(a[3], b[3]))
-            if np.ndim(a[4]) and np.ndim(b[4]):
-                isum = np.concatenate([a[4], b[4]])
-            else:
-                sa, sb = int(np.sum(a[4])), int(np.sum(b[4]))
-                isum = sa + sb if sa >= 0 and sb >= 0 else max(sa, sb)
-            return te, tr, bad, im, isum
+        def exec_fused_halves(plan, lo, hi, sup, chunk_id):
+            from spark_sklearn_tpu.parallel.taskgrid import split_range
+            lo_, mid, hi_ = split_range(lo, hi)
+            return LaunchResult.merge(
+                exec_fused_range(plan, lo_, mid, sup, chunk_id),
+                exec_fused_range(plan, mid, hi_, sup, chunk_id))
 
         def exec_fused_range(plan, lo, hi, sup, chunk_id):
             """Relaunch candidates [lo, hi) as one fused program at the
             narrowest padded width (lanes re-padded via
             taskgrid.pad_chunk), recursing on further OOMs down to
-            single candidates and finally the host path.  Returns
-            host-side (te, tr, bad, iters, iters_sum) with exactly
+            single candidates and finally the host path.  Returns a
+            host-side LaunchResult with exactly
             hi - lo real rows — per-lane results are bit-identical to
             the full-width launch (vmap lanes are independent), so a
             successful recovery keeps cv_results_ exact."""
@@ -3180,48 +3186,12 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             key = f"{chunk_id}[{lo}:{hi}]"
 
             def attempt():
-                progs = build_programs(plan, width=width)
-                dyn = {}
-                for k, arr in group.dynamic_params.items():
-                    dyn[k] = _dataplane.upload(
-                        pad_chunk(arr, lo, hi, width,
-                                  n_folds if task_batched else 1),
-                        task_shard, label="dyn.recover")
-                if not dyn and not task_batched:
-                    dyn["_pad"] = (
-                        plane.zeros(width, dtype, task_shard,
-                                    tenant=sched_tenant)
-                        if plane is not None and not donate else
-                        _dataplane.upload(np.zeros(width, dtype=dtype),
-                                          task_shard, label="dyn.pad"))
-                if task_batched:
-                    # the bisected width's tiled masks come from the
-                    # same plane cache — a recovery revisiting a width
-                    # re-tiles on device at most once, never per
-                    # relaunch (the old per-relaunch host np.tile)
-                    w = (plane.tiled(fit_masks, fit_dev, width,
-                                     tb_mask_shard,
-                                     label=tiled_label,
-                                     fp=fit_masks_fp(),
-                                     tenant=sched_tenant)
-                         if plane is not None else
-                         _dataplane.upload(
-                             np.tile(fit_masks, (width, 1)),
-                             tb_mask_shard, label=tiled_label))
-                else:
-                    w = fit_dev
-                out = progs["fused"](dyn, plan_data(plan), w, test_dev,
-                                     train_sc_dev, test_unw_dev,
-                                     train_unw_dev)
+                dyn, w = stage_operands(plan, group.dynamic_params, lo, hi,
+                                        width, "dyn.recover")
+                out = build_programs(plan, width=width)["fused"](
+                    dyn, plan_data(plan), w, *score_ops)
                 out = sup.wait_ready(out, key=key, group=plan["gi"])
-                te_d, tr_d, bad_d, im_d, isum_d = out
-                te = {s: np.asarray(mesh_lib.device_get_tree(v))[:n]
-                      for s, v in te_d.items()}
-                tr = {s: np.asarray(mesh_lib.device_get_tree(v))[:n]
-                      for s, v in tr_d.items()}
-                bad = np.asarray(mesh_lib.device_get_tree(bad_d))[:n]
-                return (te, tr, bad, _iters_max_host(im_d),
-                        _iters_sum_host(isum_d, n * n_folds))
+                return out.to_host(n, n_folds)
 
             try:
                 return sup.call(attempt, key=key, group=plan["gi"],
@@ -3232,12 +3202,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         return host_fused_range(plan, lo, hi, sup,
                                                 chunk_id)
                     sup.record_bisection(key, plan["gi"])
-                    from spark_sklearn_tpu.parallel.taskgrid import (
-                        split_range)
-                    lo_, mid, hi_ = split_range(lo, hi)
-                    return merge_fused(
-                        exec_fused_range(plan, lo_, mid, sup, chunk_id),
-                        exec_fused_range(plan, mid, hi_, sup, chunk_id))
+                    return exec_fused_halves(plan, lo, hi, sup, chunk_id)
                 # poison-candidate quarantine (best_effort only — the
                 # supervisor arms quarantine_k solely under
                 # partial_results='best_effort'): FATAL ranges split
@@ -3251,12 +3216,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 if n > 1:
                     sup.record_bisection(key, plan["gi"],
                                          fault_class=_faults.FATAL)
-                    from spark_sklearn_tpu.parallel.taskgrid import (
-                        split_range)
-                    lo_, mid, hi_ = split_range(lo, hi)
-                    return merge_fused(
-                        exec_fused_range(plan, lo_, mid, sup, chunk_id),
-                        exec_fused_range(plan, mid, hi_, sup, chunk_id))
+                    return exec_fused_halves(plan, lo, hi, sup, chunk_id)
                 n_faults = sup.note_fatal(key)
                 if n_faults < sup.quarantine_k:
                     return exec_fused_range(plan, lo, hi, sup, chunk_id)
@@ -3270,23 +3230,17 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                             plan["group"].candidate_indices[lo:hi]],
                         "error": f"{type(exc).__name__}: {exc}"[:300],
                         "n_faults": int(n_faults)})
-                te = {s: np.full((n, n_folds), errval)
-                      for s in scorer_names}
-                tr = ({s: np.full((n, n_folds), errval)
-                       for s in scorer_names} if return_train else {})
-                bad = np.zeros((n, n_folds), bool)
-                return te, tr, bad, -1, -1
+                fill = {s: np.full((n, n_folds), errval)
+                        for s in scorer_names}
+                return LaunchResult.host_fill(
+                    fill, fill if return_train else {}, n, n_folds)
 
         def make_bisect_fused(plan, lo, hi, chunk_id):
             def bisect(sup):
                 if hi - lo <= 1:
                     return host_fused_range(plan, lo, hi, sup, chunk_id)
                 sup.record_bisection(chunk_id, plan["gi"])
-                from spark_sklearn_tpu.parallel.taskgrid import split_range
-                lo_, mid, hi_ = split_range(lo, hi)
-                return merge_fused(
-                    exec_fused_range(plan, lo_, mid, sup, chunk_id),
-                    exec_fused_range(plan, mid, hi_, sup, chunk_id))
+                return exec_fused_halves(plan, lo, hi, sup, chunk_id)
             return bisect
 
         # ------------------------------------------------------------------
@@ -3319,8 +3273,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 # plans pass their own derived per-fold matrices here)
                 tuple(id(leaf) for leaf in
                       jax.tree_util.tree_leaves(plan_data(plan))),
-                id(fit_dev), id(test_dev), id(train_sc_dev),
-                id(test_unw_dev), id(train_unw_dev))
+                id(fit_dev), *map(id, score_ops))
             _keycheck.note(
                 "fuse_spec", fkey,
                 fields={"bf16_matmul": bool(config.bf16_matmul)},
@@ -3335,46 +3288,14 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 width = max(n_task_shards,
                             mesh_lib.pad_to_multiple(total,
                                                      n_task_shards))
-                repeat = n_folds if task_batched else 1
-                progs = build_programs(plan, width=width)
                 member_rows = [s.rows() for s in specs]
-                dyn = {}
-                for k in sorted(member_rows[0]):
-                    cat = np.concatenate(
+                dyn, w = stage_operands(
+                    plan, {k: np.concatenate(
                         [np.asarray(r[k]) for r in member_rows])
-                    dyn[k] = _dataplane.upload(
-                        pad_chunk(cat, 0, total, width, repeat),
-                        task_shard, label="dyn.fuse")
-                if not dyn and not task_batched:
-                    dyn["_pad"] = (
-                        plane.zeros(width, dtype, task_shard,
-                                    tenant=sched_tenant)
-                        if plane is not None else
-                        _dataplane.upload(
-                            np.zeros(width, dtype=dtype),
-                            task_shard, label="dyn.pad"))
-                if task_batched:
-                    w = (plane.tiled(fit_masks, fit_dev, width,
-                                     tb_mask_shard, label=tiled_label,
-                                     fp=fit_masks_fp(),
-                                     tenant=sched_tenant)
-                         if plane is not None else
-                         _dataplane.upload(
-                             np.tile(fit_masks, (width, 1)),
-                             tb_mask_shard, label=tiled_label))
-                else:
-                    w = fit_dev
-                return progs["fused"](dyn, plan_data(plan), w, test_dev,
-                                      train_sc_dev, test_unw_dev,
-                                      train_unw_dev)
-
-            def slice_out(out, off, n):
-                te, tr, bad, im, isum = out
-                if np.ndim(isum):
-                    isum = isum[off * n_folds:(off + n) * n_folds]
-                return ({s: v[off:off + n] for s, v in te.items()},
-                        {s: v[off:off + n] for s, v in tr.items()},
-                        bad[off:off + n], im, isum)
+                        for k in sorted(member_rows[0])},
+                    0, total, width, "dyn.fuse")
+                return build_programs(plan, width=width)["fused"](
+                    dyn, plan_data(plan), w, *score_ops)
 
             # the fused width may legitimately exceed one chunk's solo
             # batch bound (that is the point of fusion); the honest
@@ -3385,7 +3306,9 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             return FuseSpec(key=fkey, n=hi - lo,
                             shard=int(n_task_shards),
                             max_width=int(cap) if cap else 0,
-                            rows=rows, run=run, slice_out=slice_out)
+                            rows=rows, run=run,
+                            slice_out=lambda out, off, n: out.slice(
+                                off, n, n_folds))
 
         # quarantine armed: the first-chunk fit/score items also carry
         # an isolate hook (below), so a poison candidate in ANY chunk
@@ -3393,30 +3316,26 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
         # whole-search degradation path.  Off (the default), those
         # items keep exactly their pre-protection shape.
         quarantine_armed = (
-            pctx is not None
-            and str(getattr(config, "partial_results", "raise")
-                    or "raise") == "best_effort"
+            pctx is not None and best_effort
             and int(getattr(config, "quarantine_fatal_k", 3) or 0) > 0)
 
-        def make_bisect_fit(plan, lo, hi, chunk_id, cstate, lanes):
+        def make_bisect_fit(plan, lo, hi, chunk_id, cstate):
             inner = make_bisect_fused(plan, lo, hi, chunk_id)
 
             def bisect(sup):
-                te, tr, bad, im, isum = inner(sup)
+                res = inner(sup)
                 # the score item consumes the recovered cells instead
                 # of launching (same contract as the OOM host fallback)
-                cstate["host"] = (te, tr)
-                record_iters(plan, im, isum, lanes,
-                             plan["group"].candidate_indices[lo:hi])
-                return np.asarray(bad, bool), None, None
+                cstate["host"] = (res.test, res.train)
+                return res
             return bisect
 
         def make_bisect_score(plan, lo, hi, chunk_id):
             inner = make_bisect_fused(plan, lo, hi, chunk_id)
 
             def bisect(sup):
-                te, tr, bad, im, isum = inner(sup)
-                return te, tr
+                res = inner(sup)
+                return res.test, res.train
             return bisect
 
         def write_cells(plan, idx, lo, hi, chunk_id, te, tr, t_fit,
@@ -3489,39 +3408,18 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                "wide-fused" if fused_mode else
                                "wide" if all_cores else "nested")})
 
-        def record_iters(plan, it_max, it_sum, lanes, idx=None):
-            # it_max: the launch's iterations, or [iterations, second
-            # passes of a staged line search] (_iters_max_host)
-            counts = np.ravel(it_max)
-            it_max = counts[0]
-            ls_second = counts[1] if counts.size > 1 else 0
-            if it_max < 0:
-                return          # no iterative solver ran this launch
-            metrics.series("solver_iters_per_launch").append(int(it_max))
-            metrics.series("linesearch_second_pass_per_launch").append(
-                int(ls_second))
-            metrics.series("solver_iters_sum_per_launch").append(
-                int(np.sum(it_sum)))
+        def record_launch(plan, res, lanes, idx):
+            """One launch's solver stats into the report: what the
+            family's traced hook reported and what its host hook knows of
+            a launch of this width.  Nothing for a launch without stats
+            (no iterative solver, or every cell from the host)."""
+            if not res.stats:
+                return
             metrics.series("lanes_per_launch").append(int(lanes))
-            metrics.series("linesearch_one_pass_per_launch").append(
-                plan["ls_one_pass"])
-            counters = getattr(family, "launch_counters", None)
-            if counters is not None:
-                counts = counters(meta, lanes // n_folds, n_folds)
-                metrics.series("gram_builds_per_launch").append(
-                    int(counts["gram_builds"]))
-                metrics.series("dual_subproblems_per_launch").append(
-                    int(counts["dual_subproblems"]))
-            if getattr(family, "reports_task_iters", False) \
-                    and np.ndim(it_sum) and idx is not None:
-                # per-task counts: tasks are candidate-major and a
-                # candidate's folds share its count
-                per_cand = metrics.series("dual_iters_per_candidate")
-                if not per_cand:
-                    per_cand.extend([-1] * len(candidates))
-                for ci, it in zip(
-                        idx, np.asarray(it_sum).reshape(-1)[::n_folds]):
-                    per_cand[int(ci)] = int(it)
+            facts = family.launch_facts(plan["static"], meta,
+                                        lanes // n_folds, n_folds)
+            record_stats(metrics, {**facts, **res.stats}, idx,
+                         len(candidates), n_folds)
 
         def replay_chunk(idx, rec):
             """Write a journalled chunk's cells back — shared by the
@@ -3548,8 +3446,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     or time.perf_counter() < pctx["t_deadline"]:
                 return False
             elapsed = time.perf_counter() - pctx["t_start"]
-            if str(getattr(config, "partial_results", "raise")
-                   or "raise") != "best_effort":
+            if not best_effort:
                 raise _faults.SearchDeadlineError(
                     float(config.search_deadline_s), elapsed,
                     n_remaining=int((~pctx["done"]).sum()))
@@ -3578,6 +3475,17 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             _telemetry.note_protection("shed", len(idx))
             return True
 
+        def live_chunks(plan):
+            """The plan's chunks that still have to launch, as (lo, hi,
+            chunk_id); a journalled chunk is replayed and an expired one
+            shed on the way — the same for both dispatch paths."""
+            for lo, hi, chunk_id, rec in plan["chunks"]:
+                idx = plan["group"].candidate_indices[lo:hi]
+                if rec is not None:
+                    replay_chunk(idx, rec)
+                elif not shed_chunk(idx, chunk_id):
+                    yield lo, hi, chunk_id
+
         def scan_plan_items(plan):
             """The plan's live chunks as scan-segment LaunchItems: each
             segment stacks its member chunks' operands along a leading
@@ -3591,15 +3499,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             nc_batch = plan["nc_batch"]
             lanes = nc_batch * n_folds
             repeat = n_folds if task_batched else 1
-            live = []
-            for lo, hi, chunk_id, rec in plan["chunks"]:
-                idx = group.candidate_indices[lo:hi]
-                if rec is not None:
-                    replay_chunk(idx, rec)
-                    continue
-                if shed_chunk(idx, chunk_id):
-                    continue
-                live.append((lo, hi, chunk_id))
+            live = list(live_chunks(plan))
             if not live:
                 return
             # device-resident rung elimination is gated to the shapes
@@ -3690,12 +3590,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         idx_st = _dataplane.upload(
                             idx_rows, repl_shard, label="dyn.scan.idx")
                         w = group_masks(plan)
-                        with stage_lock:
-                            done = plan.setdefault("staged_ids", set())
-                            for _, _, cid in members:
-                                done.add(cid)
-                            if len(done) >= plan["n_live"]:
-                                plan.pop("w_task_dev", None)
+                        note_staged(plan, [cid for _, _, cid in members])
                         # heartbeat segment registration happens at
                         # stage time (before dispatch) so a launch
                         # that never produces a beat still shows up
@@ -3718,37 +3613,20 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     with get_tracer().span(
                             "chunkloop.scan", group=plan["gi"],
                             n_chunks=n_steps, topk=seg_topk):
-                        if tok is not None:
-                            # token as RUNTIME operand — the compiled
-                            # scan program is shared across searches
-                            return build_scan(
-                                plan, n_steps, seg_topk, hb=True)(
-                                dyn, idx_st, plan_data(plan), w, test_dev,
-                                train_sc_dev, test_unw_dev,
-                                train_unw_dev,
-                                np.asarray(tok, np.int32))
-                        return build_scan(plan, n_steps, seg_topk)(
-                            dyn, idx_st, plan_data(plan), w, test_dev,
-                            train_sc_dev, test_unw_dev, train_unw_dev)
+                        # the heartbeat token is a RUNTIME operand — the
+                        # compiled scan program is shared across searches
+                        hb_ops = () if tok is None \
+                            else (np.asarray(tok, np.int32),)
+                        return build_scan(
+                            plan, n_steps, seg_topk, hb=tok is not None)(
+                            dyn, idx_st, plan_data(plan), w, *score_ops,
+                            *hb_ops)
 
                 def gather(out, members=members, seg_topk=seg_topk):
                     ys, surv = out
-                    te_st, tr_st, bad_st, im_st, isum_st = ys
-                    te_h = {s: np.asarray(mesh_lib.device_get_tree(v))
-                            for s, v in te_st.items()}
-                    tr_h = {s: np.asarray(mesh_lib.device_get_tree(v))
-                            for s, v in tr_st.items()}
-                    bad_h = np.asarray(mesh_lib.device_get_tree(bad_st))
-                    im_h = np.asarray(mesh_lib.device_get_tree(im_st))
-                    isum_h = np.asarray(
-                        mesh_lib.device_get_tree(isum_st))
-                    chunks = []
-                    for i in range(len(members)):
-                        chunks.append((
-                            {s: v[i] for s, v in te_h.items()},
-                            {s: v[i] for s, v in tr_h.items()},
-                            bad_h[i], _iters_max_host(im_h[i]),
-                            _iters_sum_host(isum_h[i])))
+                    ys_h = ys.to_host()
+                    chunks = [ys_h.step(i).slice(0, hi - lo, n_folds)
+                              for i, (lo, hi, _) in enumerate(members)]
                     surv_h = (np.asarray(
                         mesh_lib.device_get_tree(surv))
                         if seg_topk else None)
@@ -3781,9 +3659,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     wall = tm.dispatch_s + tm.compute_s + tm.gather_s
                     total_real = sum((hi - lo) * n_folds
                                      for lo, hi, _ in members)
-                    for (lo, hi, chunk_id), \
-                            (te, tr, bad, im, isum) in \
-                            zip(members, chunks):
+                    for (lo, hi, chunk_id), res in zip(members, chunks):
                         idx = plan["group"].candidate_indices[lo:hi]
                         n_real = (hi - lo) * n_folds
                         # the melted boundary makes per-chunk walls
@@ -3793,11 +3669,11 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         # block) — time columns are estimates, scores
                         # are exact
                         t_fit = wall * n_real / max(1, total_real)
-                        fit_failed[idx, :] |= np.asarray(
-                            bad[:hi - lo], bool)
-                        record_iters(plan, im, isum, lanes, idx)
-                        write_cells(plan, idx, lo, hi, chunk_id, te,
-                                    tr, t_fit, 0.0, count_launch=False)
+                        fit_failed[idx, :] |= res.bad
+                        record_launch(plan, res, lanes, idx)
+                        write_cells(plan, idx, lo, hi, chunk_id, res.test,
+                                    res.train, t_fit, 0.0,
+                                    count_launch=False)
                     metrics.counter("n_launches").inc()
                     rec = per_group_rec(plan)
                     rec["n_launches"] += 1
@@ -3846,74 +3722,20 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 #: which the (serial, in-order) finalize stream runs
                 #: before any fused chunk of the group finalizes
                 gstate = {"sspt": None}
-                live_seen = 0
-                for lo, hi, chunk_id, rec in plan["chunks"]:
+                for live_seen, (lo, hi, chunk_id) in enumerate(
+                        live_chunks(plan), 1):
                     idx = group.candidate_indices[lo:hi]
-                    if rec is not None:
-                        replay_chunk(idx, rec)
-                        continue
-                    if shed_chunk(idx, chunk_id):
-                        continue
-                    live_seen += 1
                     n_real = (hi - lo) * n_folds
 
                     def stage(lo=lo, hi=hi, plan=plan, chunk_id=chunk_id):
-                        dyn = {}
-                        repeat = n_folds if task_batched else 1
-                        for k, arr in plan["group"].dynamic_params.items():
-                            if ring is not None:
-                                # donate mode: pad into a reused host
-                                # buffer (double-buffer ring) instead of
-                                # allocating per chunk; the slot blocks
-                                # on its previous consumer before reuse
-                                slot = ring.slot(
-                                    (plan["gi"], k),
-                                    (plan["nc_batch"] * repeat,)
-                                    + arr.shape[1:], arr.dtype)
-                                host = pad_chunk(
-                                    arr, lo, hi, plan["nc_batch"],
-                                    repeat, out=slot.array)
-                                dev = _dataplane.upload(
-                                    host, task_shard, label="dyn")
-                                slot.commit(dev)
-                            else:
-                                dev = _dataplane.upload(
-                                    pad_chunk(arr, lo, hi,
-                                              plan["nc_batch"], repeat),
-                                    task_shard, label="dyn")
-                            dyn[k] = dev
-                        if not dyn and not task_batched:
-                            # all-static group: vmap still needs a
-                            # batched operand to define the candidate
-                            # axis (families ignore unknown keys).  The
-                            # plane caches the zeros across chunks AND
-                            # searches — except under donation, where a
-                            # cached operand would be invalidated by the
-                            # launch that consumed it
-                            dyn["_pad"] = (
-                                plane.zeros(plan["nc_batch"], dtype,
-                                            task_shard,
-                                            tenant=sched_tenant)
-                                if plane is not None and not donate else
-                                _dataplane.upload(
-                                    np.zeros(plan["nc_batch"],
-                                             dtype=dtype),
-                                    task_shard, label="dyn.pad"))
-                        w = group_masks(plan)
-                        # once the group's last live chunk has staged,
-                        # drop the plan's tiled-mask reference (each
-                        # payload keeps its own) so one group's masks
-                        # never outlive its launches.  Tracked as a set
-                        # of chunk ids under a lock: the supervisor's
-                        # transient retries re-stage on the recovering
-                        # thread, concurrent with the stage thread, and
-                        # a re-staged chunk must not count twice
-                        with stage_lock:
-                            done = plan.setdefault("staged_ids", set())
-                            done.add(chunk_id)
-                            if len(done) >= plan["n_live"]:
-                                plan.pop("w_task_dev", None)
+                        dyn, w = stage_operands(
+                            plan, plan["group"].dynamic_params, lo, hi,
+                            None, "dyn", ring=ring)
+                        note_staged(plan, [chunk_id])
                         return dyn, w
+
+                    def gather(out, n=hi - lo):
+                        return out.to_host(n, n_folds)
 
                     if fused_mode and live_seen > 1:
                         # steady state: ONE fused launch per chunk
@@ -3921,22 +3743,11 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         def launch(payload, plan=plan):
                             dyn, w = payload
                             return resolve_fused(plan)(
-                                dyn, plan_data(plan), w, test_dev,
-                                train_sc_dev, test_unw_dev, train_unw_dev)
-
-                        def gather(out):
-                            te, tr, bad, it_max, it_sum = out
-                            return (mesh_lib.device_get_tree(te),
-                                    mesh_lib.device_get_tree(tr),
-                                    np.asarray(
-                                        mesh_lib.device_get_tree(bad)),
-                                    _iters_max_host(it_max),
-                                    _iters_sum_host(it_sum))
+                                dyn, plan_data(plan), w, *score_ops)
 
                         def finalize(host, tm, plan=plan, idx=idx, lo=lo,
                                      hi=hi, chunk_id=chunk_id,
                                      gstate=gstate, lanes=lanes):
-                            te, tr, bad, im, isum = host
                             wall = tm.dispatch_s + tm.compute_s \
                                 + tm.gather_s
                             # one launch: attribute the group's measured
@@ -3949,11 +3760,11 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                             t_score = min((gstate["sspt"] or 0.0) * lanes,
                                           wall)
                             t_fit = wall - t_score
-                            fit_failed[idx, :] |= np.asarray(
-                                bad[:hi - lo], bool)
-                            record_iters(plan, im, isum, lanes, idx)
+                            fit_failed[idx, :] |= host.bad
+                            record_launch(plan, host, lanes, idx)
                             write_cells(plan, idx, lo, hi, chunk_id,
-                                        te, tr, t_fit, t_score)
+                                        host.test, host.train, t_fit,
+                                        t_score)
 
                         yield LaunchItem(
                             key=chunk_id, kind="fused", group=gi,
@@ -3975,46 +3786,17 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
 
                     def launch_fit(payload, plan=plan, cstate=cstate):
                         dyn, w = payload
-                        models = build_programs(plan)["fit"](
+                        models, stats = build_programs(plan)["fit"](
                             dyn, plan_data(plan), w)
                         cstate["models"] = models
-                        bad = _models_health(models)
-                        it_arr = None
-                        if isinstance(models, dict) and (
-                                "n_iter" in models
-                                or "n_iter_exec" in models):
-                            # prefer the solver's true executed count
-                            # over any sklearn-facing rescale (FISTA
-                            # reports n_iter on the caller's max_iter
-                            # axis but runs a larger internal budget)
-                            it_arr = models.get("n_iter_exec",
-                                                models.get("n_iter"))
-                        return (models, bad, it_arr,
-                                models.get("ls_second_pass")
-                                if it_arr is not None else None)
+                        return LaunchResult({}, {}, _models_health(models),
+                                            stats)
 
-                    def gather_fit(out):
-                        _, bad, it_arr, ls2_arr = out
-                        bad_h = (np.asarray(mesh_lib.device_get_tree(bad))
-                                 if bad is not None else None)
-                        it_h = (np.asarray(
-                            mesh_lib.device_get_tree(it_arr))
-                            if it_arr is not None else None)
-                        ls2_h = (np.asarray(
-                            mesh_lib.device_get_tree(ls2_arr))
-                            if ls2_arr is not None else None)
-                        return bad_h, it_h, ls2_h
-
-                    def fin_fit(host, tm, plan=plan, idx=idx, lo=lo,
-                                hi=hi, cstate=cstate, lanes=lanes):
-                        bad_h, it_h, ls2_h = host
-                        if bad_h is not None:
-                            fit_failed[idx, :] |= bad_h[:hi - lo]
-                        if it_h is not None:
-                            im = np.max(it_h)
-                            if ls2_h is not None:
-                                im = [im, np.max(ls2_h)]
-                            record_iters(plan, im, it_h, lanes, idx)
+                    def fin_fit(host, tm, plan=plan, idx=idx,
+                                cstate=cstate, lanes=lanes):
+                        if host.bad is not None:
+                            fit_failed[idx, :] |= host.bad
+                        record_launch(plan, host, lanes, idx)
                         cstate["t_fit"] = tm.dispatch_s + tm.compute_s
 
                     def host_fb_fit(idx=idx, cstate=cstate):
@@ -4022,25 +3804,24 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         # per-candidate host execution; the score item
                         # consumes the stashed cells instead of
                         # launching
-                        te, tr = host_eval(idx)
-                        cstate["host"] = (te, tr)
-                        return (None, None, None)
+                        cstate["host"] = host_eval(idx)
+                        return LaunchResult.host_fill(
+                            *cstate["host"], len(idx), n_folds)
 
                     yield LaunchItem(
                         key=chunk_id + ":fit", kind="fit", group=gi,
                         n_tasks=n_real, stage=stage, launch=launch_fit,
-                        gather=gather_fit, finalize=fin_fit,
+                        gather=gather, finalize=fin_fit,
                         host_fallback=host_fb_fit,
                         bisect=(make_bisect_fit(plan, lo, hi, chunk_id,
-                                                cstate, lanes)
+                                                cstate)
                                 if quarantine_armed else None))
 
                     def launch_score(payload, plan=plan, cstate=cstate):
                         if "host" in cstate:
                             return None   # chunk recovered on the host
                         return build_programs(plan)["score"](
-                            cstate["models"], plan_data(plan), test_dev,
-                            train_sc_dev, test_unw_dev, train_unw_dev)
+                            cstate["models"], plan_data(plan), *score_ops)
 
                     def gather_score(out, cstate=cstate):
                         if out is None and "host" in cstate:
@@ -4093,9 +3874,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                 gstate["cal_skip"] = True
                                 return None
                             return build_programs(plan)["score"](
-                                models, plan_data(plan), test_dev,
-                                train_sc_dev, test_unw_dev,
-                                train_unw_dev)
+                                models, plan_data(plan), *score_ops)
 
                         def host_fb_cal(cstate=cstate, gstate=gstate):
                             cstate.pop("models", None)
@@ -4195,9 +3974,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             # hooks own it) and raise-mode searches propagate
             # unchanged.
             degradable = (
-                pctx is not None
-                and str(getattr(config, "partial_results", "raise")
-                        or "raise") == "best_effort"
+                pctx is not None and best_effort
                 and not getattr(exc, "_sst_cancelled", False)
                 and not _faults.is_oom(exc))
             if not degradable:

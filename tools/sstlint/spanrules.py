@@ -222,7 +222,11 @@ def check_report_keys(ctx: Context) -> Iterable[Finding]:
     (``metrics.counter("...")`` etc.) must be declared in
     ``SEARCH_REPORT_SCHEMA``, and every declared top-level key must be
     written somewhere — the full ``search_report`` surface stays
-    pinned in one table."""
+    pinned in one table.  A row that names a launch ``stat`` is written
+    by the one loop over those rows (``search/launch.py::record_stats``),
+    not by a literal: it counts as written where some module outside
+    the schema's own spells the stat's name, i.e. a family hook reports
+    it."""
     if not ctx.project.metrics_path or \
             not ctx.project.metrics_path.is_file():
         return
@@ -233,8 +237,16 @@ def check_report_keys(ctx: Context) -> Iterable[Finding]:
         return
     used: Set[str] = set()
     first_use = {}
+    by_stat = {d.stat: d.name
+               for d in metrics.SEARCH_REPORT_SCHEMA
+               if getattr(d, "stat", None)}
+    metrics_rel = _rel(ctx, ctx.project.metrics_path)
     for mod in ctx.modules:
+        reports_stats = bool(by_stat) and mod.relpath != metrics_rel
         for node in ast.walk(mod.tree):
+            if reports_stats and isinstance(node, ast.Constant) \
+                    and node.value in by_stat:
+                used.add(by_stat[node.value])
             if not isinstance(node, ast.Call) or not node.args:
                 continue
             if not isinstance(node.func, ast.Attribute):
