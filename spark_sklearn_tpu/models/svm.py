@@ -23,6 +23,23 @@ to zero, where K and the centred K are the same quadratic form.
 Multi-class follows sklearn: one-vs-one over all k(k-1)/2 pairs with
 majority voting (confidence-scaled tie-break like _ovr_decision_function).
 
+**The layout of a dual.**  The dual of pair (i, j) has alphas on the rows of
+classes i and j only.  Where the search's task-batched fit sees three or
+more classes, one shared X (no per-fold inputs) and class counts balanced
+enough that k blocks of the largest class, rounded up to a multiple of 8,
+hold at most 1.25 n rows (`_block_rows`), it takes the rows in class order
+once a launch (`_class_sorted`), builds each candidate's kernel matrix on
+them, and keeps every dual's iterate, signs and bounds as its two classes'
+blocks side by side, `(folds * pairs, 2 * n_b)`: the projection, step,
+momentum and residual walk those, and the product contracts each dual over
+its own rows (`_BlockKernel`).  Everywhere else (binary problems, skewed
+class counts, a compiled Pipeline's per-fold kernels, the standalone SVC)
+a dual is a full `(n,)` row with bound 0 outside its pair, and the product
+is `Z @ K` (`_DenseKernel`).  One algorithm on two layouts:
+`fista_dual_ascent` and `nu_dual_ascent` take the product as an operator
+and share every other line; `search_report["dual_rows_per_launch"]` says
+which layout a launch ran.
+
 Deviation from libsvm (documented, tested at the accuracy level): a
 fixed iteration budget (300 where `max_iter` is -1) beside the `tol` exit
 on the prox-gradient residual.  In exact float32 (XLA:CPU) `tol` ends the
@@ -63,7 +80,151 @@ def _kernel(X1, X2, kind, gamma, degree, coef0):
     return jnp.exp(-gamma * jnp.maximum(d2, 0.0))
 
 
-def _power_step(K, n, dtype, centred=False):
+#: a block is the largest class rounded up to the chip's sublane tile, so
+#: that the (k, n_b, k n_b) view of the kernel matrix is the matrix as it
+#: lies.  Not the lane tile of 128: the product is bound by the bytes of
+#: the matrix, and 2 048-row blocks for classes of 2 000 read 4.9 % more
+#: (traced windows of the benchmark's cell: 6.76 s a search against 7.02 s;
+#: PERF.md, PR 32)
+_BLOCK_TILE = 8
+#: k * n_b over n at most: padding costs under 1.6 x the kernel matrix
+_BLOCK_PAD_MAX = 1.25
+
+
+def _block_rows(meta, n):
+    """The block size `n_b` of the class-sorted, block-compact layout for
+    `n` rows with `meta["class_counts"]`, or None where a dual keeps a
+    dense `(n,)` row: fewer than three classes (one pair holds every
+    row), counts that are not this data's, or classes so unequal that k
+    blocks of the largest hold over 1.25 n rows."""
+    counts = meta.get("class_counts")
+    if counts is None or len(counts) < 3 or sum(counts) != n:
+        return None
+    n_b = -(-max(counts) // _BLOCK_TILE) * _BLOCK_TILE
+    return n_b if len(counts) * n_b <= _BLOCK_PAD_MAX * n else None
+
+
+def _class_sorted(y, counts, n_b):
+    """Rows in class order, each class padded to a block of `n_b`
+    (traced; `counts` static, `y` the encoded labels they count).
+    Returns `rows` (k * n_b,): the caller's row that each slot holds (a
+    pad slot holds some real row: whatever reads it masks it), `valid`
+    (k, n_b): 1 on the slots that hold a row of their class, and `slot`
+    (n,): where each of the caller's rows went."""
+    n = y.shape[0]
+    counts = np.asarray(counts, np.int32)
+    first = np.concatenate([[0], np.cumsum(counts)[:-1]]).astype(np.int32)
+    order = jnp.argsort(y, stable=True).astype(jnp.int32)
+    r = jnp.arange(n_b, dtype=jnp.int32)[None, :]
+    valid = r < counts[:, None]
+    rows = order[jnp.where(valid, first[:, None] + r, 0).reshape(-1)]
+    rank = jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32))
+    slot = y * n_b + rank - jnp.asarray(first)[y]
+    return rows, valid, slot
+
+
+class _DenseKernel:
+    """The duals' product with a kernel matrix whose rows are the duals'
+    columns: a dual is a full row, zero outside its pair."""
+
+    def __init__(self, K):
+        self.K = K
+        self.dtype = K.dtype
+
+    def own(self, V):
+        """V K on the columns a dual's iterate holds."""
+        return V @ self.K
+
+    def all(self, V):
+        """V K on every row of the data."""
+        return V @ self.K
+
+    def restrict(self, R):
+        """`own` out of the result of `all`."""
+        return R
+
+
+class _BlockKernel:
+    """The product for duals in the block-compact layout.  `K` is the
+    kernel matrix of the class-sorted, padded rows, so viewed as
+    `(k, n_b, k, n_b)` its block `[a, :, b, :]` is class a against class
+    b.  `V` is `(folds * pairs, 2 * n_b)`, fold-major: the blocks of the
+    pair's two classes side by side.  The product goes by source class:
+    the class-a blocks of the k - 1 pairs x folds that hold class a are
+    one `(k * folds, n_b)` left-hand side against the `(n_b, k * n_b)`
+    rows of class a (the k - 1 and a block of zeros where b = a), so K is
+    read once and each dual is contracted over its own 2 n_b rows."""
+
+    def __init__(self, K, pairs, n_folds, n_b):
+        self.dtype = K.dtype
+        self.k = k = K.shape[0] // n_b
+        self.n_b, self.F, self.P = n_b, n_folds, len(pairs)
+        self.K3 = K.reshape(k, n_b, k * n_b)
+        self.pairs = pairs = np.asarray(pairs)
+        # the (2 P) class blocks of a fold, (pair, side)-major, into the
+        # (k, k) grid [class, partner] and back; 2 P is the block of zeros
+        grid = np.full((k, k), 2 * self.P, np.int32)
+        grid[pairs[:, 0], pairs[:, 1]] = 2 * np.arange(self.P)
+        grid[pairs[:, 1], pairs[:, 0]] = 2 * np.arange(self.P) + 1
+        self.to_grid = grid.reshape(-1)
+        self.from_grid = np.stack(
+            [pairs[:, 0] * k + pairs[:, 1],
+             pairs[:, 1] * k + pairs[:, 0]], axis=1).astype(np.int32)
+
+    def _by_class(self, V):
+        """(k, k * F, k * n_b): [a, (b, f)] the class-a block of the dual
+        of pair {a, b} and fold f against all of K's columns."""
+        k, n_b, F, P = self.k, self.n_b, self.F, self.P
+        blocks = jnp.transpose(V.reshape(F, P, 2, n_b), (1, 2, 0, 3))
+        blocks = jnp.concatenate(
+            [blocks.reshape(2 * P, F, n_b),
+             jnp.zeros((1, F, n_b), V.dtype)])[self.to_grid]
+        return jnp.einsum("amr,arn->amn",
+                          blocks.reshape(k, k * F, n_b), self.K3)
+
+    def _to_rows(self, G, width):
+        """[class, partner] grids of (F, width) back to fold-major rows."""
+        G = G.reshape(self.k * self.k, self.F, width)[
+            self.from_grid.reshape(-1)]
+        return jnp.transpose(
+            G.reshape(self.P, 2, self.F, width), (2, 0, 1, 3))
+
+    def own(self, V):
+        """On the block of class a of a dual: what its class-a block and
+        its partner's block contribute there.  Sliced out of the product
+        as it lies (a 5-d view of it is a copy of all of it)."""
+        k, n_b, F = self.k, self.n_b, self.F
+        R = self._by_class(V)
+        cols = [slice(a * n_b, (a + 1) * n_b) for a in range(k)]
+        mine = jnp.stack([R[a, :, cols[a]] for a in range(k)])
+        partners = jnp.stack(
+            [R[:, a * F:(a + 1) * F, cols[a]] for a in range(k)])
+        G = mine.reshape(k, k, F, n_b) + partners
+        return self._to_rows(G, n_b).reshape(F * self.P, 2 * n_b)
+
+    def all(self, V):
+        """(folds * pairs, k * n_b), the rows in class-sorted order."""
+        R = self._to_rows(self._by_class(V), self.k * self.n_b)
+        return jnp.sum(R, axis=2).reshape(self.F * self.P, -1)
+
+    def restrict(self, R):
+        R = R.reshape(self.F, self.P, self.k, self.n_b)
+        return R[:, np.arange(self.P)[:, None], self.pairs, :].reshape(
+            self.F * self.P, 2 * self.n_b)
+
+
+def _as_product(K):
+    """A kernel matrix as the dense product; an operator as it is."""
+    return K if hasattr(K, "own") else _DenseKernel(K)
+
+
+def _power_start(n, dtype):
+    """The centred power iteration's first vector."""
+    return jax.random.normal(jax.random.PRNGKey(0), (n,), dtype)
+
+
+def _power_step(K, n, dtype, centred=False, start=None, valid=None):
     """1/lambda_max(K) via power iteration — a safe ascent step for every
     masked/sign-flipped subproblem (principal submatrices of a PSD matrix
     cannot have a larger top eigenvalue).
@@ -79,19 +240,30 @@ def _power_step(K, n, dtype, centred=False):
     lambda_max falls from 11 787 to 223 at gamma 0.004 and from 432 to 57
     at gamma 0.03, and with it the iterations a dual needs (PERF.md,
     PR 28).  A 10 % margin there, since the spectrum under the constant
-    is flatter and the estimate comes from below."""
+    is flatter and the estimate comes from below.
+
+    `valid` (with `centred`): K is padded, `n` of its rows are real and
+    `valid` is 1 on them; the vector stays 0 on the pads and the mean is
+    over the real rows, so the pads move nothing (a block of identical pad
+    rows would otherwise be an eigenvalue of its own).  `start`: the first
+    vector, where the caller wants the one of another row order."""
+    def centre(v):
+        if valid is None:
+            return v - jnp.mean(v)
+        return (v - jnp.sum(v) / n) * valid
+
     def apply(v):
         if not centred:
             return K @ v
-        w = K @ (v - jnp.mean(v))
-        return w - jnp.mean(w)
+        return centre(K @ centre(v))
 
     def power(i, v):
         v = apply(v)
         return v / (jnp.linalg.norm(v) + 1e-12)
 
-    v0 = (jax.random.normal(jax.random.PRNGKey(0), (n,), dtype) if centred
-          else jnp.ones((n,), dtype) / jnp.sqrt(n))
+    v0 = start if start is not None else (
+        _power_start(n, dtype) if centred
+        else jnp.ones((n,), dtype) / jnp.sqrt(n))
     v = jax.lax.fori_loop(0, 20, power, v0)
     margin = 1.1 if centred else 1.0
     return 1.0 / (margin * jnp.dot(v, apply(v)) + 1e-6)
@@ -267,8 +439,10 @@ def nu_dual_ascent(K, yb, bound, nu, step, max_iter, tol=None):
     rho = (r1-r2)/2 / r).  Returns per-subproblem full-set decision rows;
     infeasible subproblems (nu*l/2 exceeding a half's box capacity — the
     case where sklearn raises 'specified nu is infeasible') come back as
-    NaN rows for the engine's failed-fit detector.
+    NaN rows for the engine's failed-fit detector.  `K`: the kernel
+    matrix, or the product with it as an operator (`_BlockKernel`).
     """
+    K = _as_product(K)
     pos_b = jnp.where(yb > 0, bound, 0.0)
     neg_b = jnp.where(yb < 0, bound, 0.0)
     l_sub = jnp.sum(bound > 0, axis=1).astype(K.dtype)
@@ -281,14 +455,14 @@ def nu_dual_ascent(K, yb, bound, nu, step, max_iter, tol=None):
             _project_box_sum(Zt, neg_b, target)
 
     def grad(Z):
-        return yb * ((Z * yb) @ K)
+        return yb * K.own(Z * yb)
 
     A, n_it = _run_dual(grad, project, project(jnp.zeros_like(bound)),
                         step, max_iter, tol, K.dtype)
 
     with jax.named_scope("sst.svc.decision"):
-        V = (A * yb) @ K
-    G = yb * V                         # gradient of 0.5 a'Qa
+        V = K.all(A * yb)
+    G = yb * K.restrict(V)             # gradient of 0.5 a'Qa
     inb = bound > 0
     at_lo = A <= bound * 1e-6
     at_hi = A >= bound * (1.0 - 1e-6)
@@ -308,8 +482,9 @@ def nu_dual_ascent(K, yb, bound, nu, step, max_iter, tol=None):
 def _kkt_intercept(K, A, yb, bound):
     """Per-subproblem intercept b from the KKT conditions (libsvm's -rho):
     mean of E_i = y_i - f0(x_i) over free SVs; when every alpha sits at a
-    bound, the midpoint of the feasible [max lower, min upper] interval."""
-    V = (A * yb) @ K                                     # (M, n)
+    bound, the midpoint of the feasible [max lower, min upper] interval.
+    `K`: the product as an operator, A / yb / bound in its layout."""
+    V = K.own(A * yb)                        # (M, the duals' columns)
     E = yb - V
     inb = bound > 0
     at_lo = A <= bound * 1e-6
@@ -341,7 +516,9 @@ def fista_dual_ascent(K, yb, bound, step, max_iter, tol=None):
     bounds carry both the subproblem box mask and class_weight-scaled C).
     K: (n, n) kernel; yb/bound: (M, n) signed labels and box bounds for M
     subproblems advanced together — every iteration is ONE (M, n) @ (n, n)
-    matmul plus a vectorized hyperplane projection.  Returns
+    matmul plus a vectorized hyperplane projection.  Or K the product as
+    an operator and yb/bound in its layout (`_BlockKernel`: a dual's own
+    two class blocks).  Returns
     (A, b, n_iter): alphas, the KKT intercept per subproblem, and the
     executed iteration count (== max_iter when tol is None; with `tol`,
     the per-lane prox-gradient-residual exit stops when every subproblem
@@ -350,8 +527,10 @@ def fista_dual_ascent(K, yb, bound, step, max_iter, tol=None):
     carries).  Shared by the search's task-batched fit and the
     standalone SVC so the numerics live once."""
 
+    K = _as_product(K)
+
     def grad(Z):                       # descent form of the ascent grad
-        return -(1.0 - yb * ((Z * yb) @ K))
+        return -(1.0 - yb * K.own(Z * yb))
 
     A, n_it = _run_dual(
         grad, lambda Zt: _project_box_hyperplane(Zt, yb, bound),
@@ -507,14 +686,18 @@ class SVCFamily(Family):
     @classmethod
     def _pair_dec(cls, K, p_c, base_bound, yb, step, max_iter, tol=None):
         """Solve the M stacked pair subproblems and return their (M, n)
-        full-set decision rows plus the executed iteration count.  `p_c`
-        is the candidate's primary scalar (C here: scales the box),
-        `base_bound` the fold/weight/pair box mask; `tol` enables the
-        per-lane residual exit (libsvm's eps stopping rule)."""
+        full-set decision rows plus the executed iteration count.  `K`
+        is the product with the kernel matrix (`_DenseKernel`, or
+        `_BlockKernel` with `base_bound` and `yb` in its layout and the
+        decision rows in class-sorted order), `p_c` the candidate's
+        primary scalar (C here: scales the box), `base_bound` the
+        fold/weight/pair box mask; `tol` enables the per-lane residual
+        exit (libsvm's eps stopping rule)."""
+        K = _as_product(K)
         bound = p_c * base_bound
         A, b, n_it = fista_dual_ascent(K, yb, bound, step, max_iter, tol)
         with jax.named_scope("sst.svc.decision"):
-            return (A * yb) @ K + b[:, None], n_it
+            return K.all(A * yb) + b[:, None], n_it
 
     # kernel matrices + per-task decision caches are the memory hot spot;
     # tell the search to keep task batches small
@@ -529,31 +712,47 @@ class SVCFamily(Family):
     def launch_workspace(n_samples: int, meta, n_folds: int,
                          itemsize: int = 4):
         """What a launch holds besides its arguments, for the memory
-        ledger (read off the launch compiled for a v5e at 20 000 rows:
-        5.03 GB at 16 candidates, 4.57 GB at 8, 4.22 GB at 4).  Whatever
-        the width, since candidates are scanned: ONE candidate's kernel
-        matrix, its copy in the other layout and the bfloat16 copy the
-        MXU reads, and eight (folds x pairs, n) arrays of the dual
+        ledger (read off the launch compiled for a v5e at 20 000 rows x
+        16 candidates: 5.03 GB with dense duals, 3.45 GB with
+        block-compact ones).  Whatever the width, since candidates are
+        scanned: ONE candidate's kernel matrix and the bfloat16 copy the
+        MXU reads, and eight (folds x pairs, columns of a dual) arrays
         (iterates, signs, bounds, gradient, the projection's
-        temporaries).  A candidate: its cached (folds, n, pairs) pair
-        decisions, about three times over (the scan's stacked output,
-        its transpose, the task-major copy)."""
+        temporaries).  Dense duals: n columns, and the matrix a third
+        time in the layout the product wants.  Block-compact duals: the
+        matrix of the k n_b sorted and padded rows, 2 n_b columns, and
+        the (k, k folds, k n_b) result of the product by class.  A
+        candidate: its cached (folds, n, pairs) pair decisions, about
+        three times over (the scan's stacked output, its transpose, the
+        task-major copy)."""
         k = meta["n_classes"]
         m = n_folds * max(1, k * (k - 1) // 2)
         n = int(n_samples)
-        return {"fixed_bytes": n * n * (2 * itemsize + 2)
-                + 8 * m * n * itemsize,
+        n_b = _block_rows(meta, n)
+        if n_b is None:
+            fixed = n * n * (2 * itemsize + 2) + 8 * m * n * itemsize
+        else:
+            n_p = k * n_b
+            fixed = n_p * n_p * (itemsize + 2) + (
+                8 * m * 2 * n_b + k * k * n_folds * n_p) * itemsize
+        return {"fixed_bytes": fixed,
                 "per_candidate_bytes": 3 * m * n * itemsize}
 
     @classmethod
     def launch_facts(cls, static, meta, n_candidates, n_folds):
         """Kernel matrices built and dual subproblems advanced by a
         launch of `n_candidates` (padding included: a padded candidate
-        is computed)."""
+        is computed), and the columns one dual's iterate holds: its two
+        class blocks in the block-compact layout, every row otherwise."""
         k = meta["n_classes"]
-        return {"gram_builds": n_candidates,
-                "dual_subproblems":
-                    n_candidates * n_folds * max(1, k * (k - 1) // 2)}
+        facts = {"gram_builds": n_candidates,
+                 "dual_subproblems":
+                     n_candidates * n_folds * max(1, k * (k - 1) // 2)}
+        if "class_counts" in meta:
+            n = sum(meta["class_counts"])
+            n_b = _block_rows(meta, n)
+            facts["dual_rows"] = n if n_b is None else 2 * n_b
+        return facts
 
     @classmethod
     def launch_stats(cls, models, static, meta):
@@ -595,7 +794,10 @@ class SVCFamily(Family):
         meta = {"n_classes": int(k), "classes": classes,
                 "n_features": int(X.shape[1]),
                 "x_var": float(np.var(np.asarray(X))),
-                "pairs": _pairs(k)}
+                "pairs": _pairs(k),
+                # static: what decides a dual's layout (_block_rows)
+                "class_counts": tuple(
+                    int(c) for c in np.bincount(y_enc, minlength=k))}
         return data, meta
 
     @classmethod
@@ -604,9 +806,12 @@ class SVCFamily(Family):
         One `lax.scan` step per candidate: its kernel matrix is built once
         and shared by every (fold x pair) subproblem, which are advanced
         together — each ascent iteration is a single (F*P, n) @ (n, n)
-        matmul.  Returns per-task full-dataset pair decisions (the search
-        scores on masked rows of the training X, so caching decisions
-        avoids rebuilding kernels in the scoring phase)."""
+        matmul, or in the block-compact layout (module docstring) a
+        product by class that contracts each dual over its own rows.
+        Returns per-task full-dataset pair decisions in the caller's row
+        order (the search scores on masked rows of the training X, so
+        caching decisions avoids rebuilding kernels in the scoring
+        phase)."""
         X = data["X"]
         y = data["y"]
         n, d = X.shape
@@ -669,9 +874,46 @@ class SVCFamily(Family):
         if cw_fold is None:
             cw_fold = jnp.ones((n_folds, n), X.dtype)
 
+        n_b = None if X_folds is not None else _block_rows(meta, n)
+        if n_b is not None:
+            # class-sorted, block-compact duals: the rows in class order
+            # and everything the boxes are made of, once a launch
+            with jax.named_scope("sst.svc.compact"):
+                rows, valid, slot = _class_sorted(
+                    y, meta["class_counts"], n_b)
+                valid = valid.astype(X.dtype)                 # (k, n_b)
+                valid_row = valid.reshape(-1)
+                X_s = X[rows]                                 # (k n_b, d)
+                w_cand = (jnp.take(train_w, rows, axis=1).reshape(
+                    nc, n_folds, k, n_b) * valid)
+                cw_s = jnp.take(cw_fold, rows, axis=1).reshape(
+                    n_folds, k, n_b)
+                v0_s = _power_start(n, X.dtype)[rows] * valid_row
+                # +1 on the rows of pairs[p, 0], -1 on those of
+                # pairs[p, 1], 0 on the pads
+                yb_s = jnp.broadcast_to(
+                    (valid[meta["pairs"]] * jnp.asarray(
+                        [1.0, -1.0], X.dtype)[None, :, None])[None],
+                    (n_folds, P, 2, n_b)).reshape(-1, 2 * n_b)
+
         def one_candidate(carry, inp):
             C_c, g_c, w_f = inp                               # w_f (F, n)
-            if X_folds is None:
+            if n_b is not None:
+                with jax.named_scope("sst.svc.gram"):
+                    K = _kernel(X_s, X_s, kind, g_c, degree, coef0)
+                with jax.named_scope("sst.svc.power_step"):
+                    step = _power_step(K, n, X.dtype, centred=True,
+                                       start=v0_s, valid=valid_row)
+                with jax.named_scope("sst.svc.compact"):
+                    base = (w_f * cw_s)[:, meta["pairs"], :].reshape(
+                        -1, 2 * n_b)
+                dec, it = cls._pair_dec(
+                    _BlockKernel(K, meta["pairs"], n_folds, n_b),
+                    C_c, base, yb_s, step, max_iter, tol_exit)
+                with jax.named_scope("sst.svc.compact"):
+                    dec = jnp.take(dec, slot, axis=1)
+                dec = dec.reshape(n_folds, P, n)
+            elif X_folds is None:
                 with jax.named_scope("sst.svc.gram"):
                     K = _kernel(X, X, kind, g_c, degree, coef0)   # (n, n)
                 with jax.named_scope("sst.svc.power_step"):

@@ -153,6 +153,15 @@ SEARCH_REPORT_SCHEMA = (
         "one-vs-one pairs.",
         stat="dual_subproblems", combine="fact"),
     MetricDef(
+        "dual_rows_per_launch", "series",
+        "Per launch of a kernel-dual family: the columns one dual's "
+        "iterate holds.  2 x the class block (the largest class, rounded "
+        "up to a multiple of 8) where the duals ran in the class-sorted, "
+        "block-compact layout (three or more balanced classes), the "
+        "number of rows where a dual is a dense row (binary problems, "
+        "skewed class counts).",
+        stat="dual_rows", combine="fact"),
+    MetricDef(
         "dual_iters_per_candidate", "series",
         "Kernel-dual families: executed _box_fista iterations of each "
         "candidate, in cv_results_ order (the candidate's folds and "

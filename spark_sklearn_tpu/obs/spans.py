@@ -348,7 +348,9 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
             layer="solvers"),
     SpanDef("sst.box_fista.gradient", "scope", "models.svm",
             "_box_fista: the caller's gradient at the momentum point, "
-            "for the kernel duals one (subproblems, n) @ (n, n) product.",
+            "for the kernel duals one (subproblems, n) @ (n, n) product, "
+            "or in the block-compact layout the product by class and "
+            "the selection of each dual's two blocks.",
             layer="solvers"),
     SpanDef("sst.box_fista.project", "scope", "models.svm",
             "_box_fista: the gradient step and the caller's projection "
@@ -371,6 +373,11 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
     SpanDef("sst.svc.decision", "scope", "models.svm",
             "SVCFamily: every pair's decision value on all rows, the "
             "cache the scoring epilogue votes on.",
+            layer="solvers"),
+    SpanDef("sst.svc.compact", "scope", "models.svm",
+            "SVCFamily, block-compact duals: the rows into class order "
+            "once a launch, each candidate's boxes in the compact "
+            "layout, and its decisions back into the caller's row order.",
             layer="solvers"),
     # async virtual tracks (name prefixes)
     SpanDef("launch", "async", "parallel.pipeline",

@@ -21,10 +21,15 @@ callers lower to the text they lowered to before the staging
 
 The kernel-dual launch (``SVCFamily.fit_task_batched``) is compiled at the
 shape of the ``svc_rbf_mnist20k.c4_gamma4`` cell — 20 000 rows x 16
-candidates x 5 folds, 45 pairs: every ``sst.svc.*`` / ``sst.box_fista.*``
-scope names device operations, the dual's loop carries ONE copy of the
-kernel matrix, and the memory ledger's price of the launch is within a
-quarter of what the compiler allots.
+candidates x 5 folds, 45 pairs, ten classes of 2 000 rows, so the duals run
+in the class-sorted, block-compact layout: every ``sst.svc.*`` /
+``sst.box_fista.*`` scope names device operations, the dual's loop carries
+``(225, 2 x 2000)`` iterates and ONE copy of the kernel matrix, and the
+memory ledger's price of the launch is within a quarter of what the
+compiler allots.  Where the duals keep dense rows (binary problems, skewed
+class counts, a compiled Pipeline's per-fold kernels) the launch lowers to
+the text of the commit before the layout
+(``test_dense_svc_launches_lower_to_the_parents_text``).
 
 Nothing runs on a device here and nothing is timed.  The topology is
 described inside a fixture (never at import time: only one process may
@@ -234,6 +239,66 @@ PARENT_LOWERED_SHA256 = {
 }
 
 
+def _lowered_svc_fit(case, n=64, d=6, folds=3, cands=2):
+    """StableHLO text of one small task-batched SVC / NuSVC fit."""
+    from spark_sklearn_tpu.models.svm import (
+        NuSVCFamily, SVCFamily, _pairs)
+    family, counts, fold_inputs = {
+        "binary": (SVCFamily, (32, 32), False),
+        "skewed": (SVCFamily, (48, 8, 8), False),
+        "pipeline": (SVCFamily, (22, 21, 21), True),
+        "nu_skewed": (NuSVCFamily, (48, 8, 8), False),
+        "balanced": (SVCFamily, (22, 21, 21), False),
+    }[case]
+    k = len(counts)
+    meta = {"n_classes": k, "classes": np.arange(k), "n_features": d,
+            "x_var": 1.0, "pairs": _pairs(k), "class_counts": counts}
+    static = {"kernel": "rbf", "__n_folds__": folds}
+    lanes = cands * folds
+    S = jax.ShapeDtypeStruct
+    data = {"X": S((n, d), jnp.float32), "y": S((n,), jnp.int32)}
+    if fold_inputs:
+        data["X_folds"] = S((folds, n, d), jnp.float32)
+    return jax.jit(lambda dyn, data, w: family.fit_task_batched(
+        dyn, static, data, w, meta)).lower(
+        {family.primary_param: S((lanes,), jnp.float32),
+         "gamma": S((lanes,), jnp.float32)}, data,
+        S((lanes, n), jnp.float32)).as_text()
+
+
+#: sha256 of ``_lowered_svc_fit(case)`` at the commit before the duals got
+#: their block-compact layout (PR 31, 1ed5b2c), on the one supported
+#: installation: the launches whose duals keep dense rows.
+PARENT_LOWERED_SVC_SHA256 = {
+    "binary":
+        "8eba3148ce9edb264a13dd662e0c206e72b0929c6663414c4ad50903e3fc8415",
+    "skewed":
+        "8d46d39753f673d085b016088a0266cc97598c9df86094e0b1f21881f63359a1",
+    "pipeline":
+        "ebba796f0e382b2c969565f66e7086dcef617fc9b8f6508f9b98318485ea3742",
+    "nu_skewed":
+        "bb58f67f0857008d5864f2315a176d5bc3423052014850c97eb418b3b02fa53a",
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_LOWERED_SVC_SHA256))
+def test_dense_svc_launches_lower_to_the_parents_text(case):
+    """Binary problems (one pair holds every row), skewed class counts
+    (k blocks of the largest class hold over 1.25 n rows) and a compiled
+    Pipeline's per-fold kernels keep dense duals, and their launch is the
+    text of the commit before the block-compact layout."""
+    import hashlib
+    text = _lowered_svc_fit(case)
+    assert "stablehlo.sort" not in text          # no class sort
+    assert (hashlib.sha256(text.encode()).hexdigest()
+            == PARENT_LOWERED_SVC_SHA256[case])
+
+
+def test_balanced_svc_launch_sorts_rows_by_class():
+    """... and the same shapes with balanced classes do not."""
+    assert "stablehlo.sort" in _lowered_svc_fit("balanced")
+
+
 @pytest.mark.parametrize("name", sorted(PARENT_LOWERED_SHA256))
 def test_generic_callers_lower_to_the_parents_text(name, monkeypatch):
     """The staged line search is for a caller that hands an evaluator.
@@ -348,7 +413,8 @@ def svc_launch(topo, no_compile_cache):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     meta = {"n_classes": K, "classes": np.arange(K), "n_features": D,
-            "x_var": 0.0857, "pairs": _pairs(K)}
+            "x_var": 0.0857, "pairs": _pairs(K),
+            "class_counts": (SVC_N // K,) * K}
     static = {"kernel": "rbf", "__n_folds__": FOLDS}
     lanes = SVC_CANDIDATES * FOLDS
     compiled = jax.jit(
@@ -363,8 +429,8 @@ def svc_launch(topo, no_compile_cache):
 def test_svc_scopes_are_the_vocabularys():
     assert SVC_SCOPES == [
         "sst.box_fista.gradient", "sst.box_fista.momentum",
-        "sst.box_fista.project", "sst.svc.decision", "sst.svc.gram",
-        "sst.svc.intercept", "sst.svc.power_step"]
+        "sst.box_fista.project", "sst.svc.compact", "sst.svc.decision",
+        "sst.svc.gram", "sst.svc.intercept", "sst.svc.power_step"]
 
 
 @pytest.mark.parametrize("scope", SVC_SCOPES)
@@ -374,19 +440,27 @@ def test_svc_compiled_op_names_carry_scope(svc_launch, scope):
 
 
 def test_svc_dual_loop_carries_one_kernel_matrix(svc_launch):
-    """The loop of ``_box_fista`` (of the loops whose state holds (225, n)
-    iterates the one with a matrix) carries the kernel matrix once — the bfloat16 copy its
-    product reads — and no float32 [n, n] beside it: an iteration then
-    reads 0.8 GB, not 2.4 GB."""
+    """The loop of ``_box_fista`` (of the loops whose state holds the
+    duals' iterates the one with a matrix) carries the kernel matrix once
+    — the bfloat16 copy its product reads, as the (class, row of the
+    class, column) view the product by class contracts — and no float32
+    matrix beside it: an iteration then reads 0.8 GB, not 2.4 GB.  The
+    iterates are block-compact: (225, 2 x 2000), and no loop of the
+    launch carries a (225, 20000) array."""
     text = svc_launch[0].as_text()
-    iterate = "f32[%d,%d]" % (FOLDS * 45, SVC_N)
-    loops = [line.split(" while(")[0] for line in text.splitlines()
-             if " while(" in line and iterate in line.split(" while(")[0]]
-    carried = sorted(
-        re.findall(r'\b(f32|bf16)\[%d,%d\]' % (SVC_N, SVC_N), loop)
-        for loop in loops)
+    n_b = SVC_N // K
+    n_p = K * n_b
+    iterate = "f32[%d,%d]" % (FOLDS * 45, 2 * n_b)
+    states = [line.split(" while(")[0] for line in text.splitlines()
+              if " while(" in line]
+    loops = [state for state in states if iterate in state]
+    matrix = re.compile(r'\b(f32|bf16)\[(?:%d,%d|%d,%d,%d)\]'
+                        % (n_p, n_p, K, n_b, n_p))
+    carried = sorted(matrix.findall(loop) for loop in loops)
     # the projection's bisection (nested, no matrix) and the dual's loop
     assert carried == [[], ["bf16"]]
+    assert not [state for state in states
+                if "f32[%d,%d]" % (FOLDS * 45, SVC_N) in state]
 
 
 def test_ledger_prices_the_svc_launch(svc_launch):
@@ -408,5 +482,7 @@ def test_ledger_prices_the_svc_launch(svc_launch):
     assert lanes * SVC_N * 4 == modeled["mask_bytes"]
     assert abs(modeled["chunk_bytes"] + resident - allotted) \
         < 0.25 * allotted
-    # a quarter of the chip and more: the cell's size (PERF.md section 4)
-    assert allotted > 0.25 * 16.909e9
+    # a fifth of the chip and more: the cell's size (PERF.md section 4;
+    # over a quarter until the block-compact layout dropped the matrix's
+    # third copy and four fifths of the duals' columns)
+    assert allotted > 0.2 * 16.909e9

@@ -129,9 +129,13 @@ def test_search_report_counts_the_duals(searched):
     assert 5 * sum(iters) <= sum(rep["solver_iters_sum_per_launch"]) \
         <= 5 * (sum(iters) + padding * max(iters))
     assert max(rep["solver_iters_per_launch"]) == max(iters)
-    # the ledger prices one candidate's Gram matrix and the decision cache
+    # three balanced classes: the duals ran block-compact, on their own
+    # two blocks of 200 rows
+    assert rep["dual_rows_per_launch"] == [400] * len(lanes)
+    # the ledger prices one candidate's Gram matrix, its bfloat16 copy and
+    # the decision cache
     group = rep["memory"]["groups"][0]
-    assert group["fixed_bytes"] >= 600 * 600 * 8
+    assert group["fixed_bytes"] >= 600 * 600 * 6
     assert group["chunk_bytes"] >= group["workspace_bytes"] > 0
 
 
@@ -142,7 +146,7 @@ def test_other_families_report_no_dual_counters():
                            cv=3, backend="tpu", refit=False).fit(
                                X[:, :20], y).search_report
     for name in ("gram_builds_per_launch", "dual_subproblems_per_launch",
-                 "dual_iters_per_candidate"):
+                 "dual_rows_per_launch", "dual_iters_per_candidate"):
         assert name not in rep
     assert "workspace_bytes" not in rep["memory"]["groups"][0]
 

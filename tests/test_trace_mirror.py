@@ -234,6 +234,11 @@ def lowered_dual_launch():
         # compiled ahead
         from sklearn.svm import SVC
         X, y = _problem(n=90, d=5)
+        # three classes of 30 rows: balanced, so the duals run in the
+        # block-compact layout and `sst.svc.compact` is in the launch
+        order = np.argsort(y, kind="stable")
+        X, y = X[order], y[order]
+        y[:] = np.arange(90) // 30
         sst.GridSearchCV(
             SVC(kernel="rbf", max_iter=5),
             {"C": np.logspace(-1, 2, 20).tolist()},
