@@ -229,12 +229,51 @@ class Family:
         Default: nothing."""
         return {}
 
+    @classmethod
+    def launch_workspace(cls, n_samples: int, meta, n_folds: int,
+                         itemsize: int = 4, *, static=None,
+                         row_sets: int = 1) -> Dict[str, int]:
+        """On the host, once a compile group (`static` is the group's):
+        what a launch holds on the device besides its arguments, for the
+        memory ledger (``memledger.model_group_footprint(workspace=)``):
+        ``fixed_bytes`` whatever the launch's width and
+        ``per_candidate_bytes`` a candidate, all its folds.  `row_sets`
+        is how many matrices of rows the launch's fits read: one, or one
+        a fold behind a Pipeline's transformers.  Default: nothing is
+        priced."""
+        return {}
+
     # --- interop ---------------------------------------------------------
     @classmethod
     def sklearn_attrs(cls, model, static, meta) -> Dict[str, Any]:
         """Fitted-attribute dict (coef_, intercept_, classes_...) used by
         Converter and by refit write-back."""
         raise NotImplementedError
+
+
+#: the name of the candidate axis in the engine's per-task fit launch
+#: (``search/grid.py``: ``vmap(one_candidate, axis_name=CANDIDATE_AXIS)``)
+CANDIDATE_AXIS = "sst_candidates"
+
+
+def any_candidate(flag):
+    """Traced: whether `flag` holds in ANY lane of the launch's candidate
+    axis, one value for all of them — or the lane's own flag where no
+    such axis is bound (a direct ``family.fit``, the keyed fleet).
+
+    A ``lax.while_loop`` whose condition comes through here is not
+    batched over candidates by ``vmap``, so neither is what the loop
+    carries and no candidate changes (an epoch counter, a PRNG key, the
+    minibatch they draw): JAX batches EVERY carried value of a loop
+    whose condition is batched."""
+    import jax
+    import jax.numpy as jnp
+
+    try:
+        jax.lax.axis_size(CANDIDATE_AXIS)
+    except NameError:       # the axis is not bound here
+        return flag
+    return jax.lax.pmax(flag.astype(jnp.int32), CANDIDATE_AXIS) > 0
 
 
 def encode_labels(y):

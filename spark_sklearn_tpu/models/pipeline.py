@@ -30,12 +30,6 @@ class PipelineFamily:
     #: host path so that contract is reproduced, not silently reinvented
     accepts_sample_weight = False
 
-    #: what a launch reports (the protocol's two hooks, at their
-    #: defaults): the default reads the model's own top-level leaves and
-    #: this family's model nests the final step's, so nothing
-    launch_stats = Family.launch_stats
-    launch_facts = Family.launch_facts
-
     def __init__(self, steps: List[Tuple[str, Any]], final_name: str,
                  final_family):
         self.steps = steps              # [(name, StepImpl), ...] transformers
@@ -75,11 +69,63 @@ class PipelineFamily:
                            {**data, "X": Xt}, meta, w)
             self.default_scorer = default_scorer
 
+    # -- identity --------------------------------------------------------
+    # A family is built anew for every search's Pipeline instance, and the
+    # cross-search program cache keys on the family: two families of the
+    # same steps and final step are the same family (the steps' PARAMS are
+    # statics and key the programs themselves), so a second search of the
+    # same Pipeline finds the first one's programs and builds none.
+    def _identity(self):
+        return (type(self), self.name, self.final_name, self.final,
+                tuple(self.steps))
+
+    def __eq__(self, other):
+        return isinstance(other, PipelineFamily) \
+            and self._identity() == other._identity()
+
+    def __hash__(self):
+        return hash(self._identity())
+
     def has_per_task_fit(self) -> bool:
         # task-batched-only finals (SVC) have no per-task fit to compose:
         # dispatchers that vmap one fit per lane (the keyed fleet) must
         # take their host path instead of tracing into NotImplementedError
         return self.final.has_per_task_fit()
+
+    # -- what a launch reports, and holds --------------------------------
+    # The final step's own hooks on the final step's model and statics: a
+    # scaler in front changes nothing of what its solver counts.  Not for
+    # a task-batched-only final (SVC), whose facts describe its bare
+    # launch (one kernel matrix a candidate; here one a fold as well).
+    def launch_stats(self, models, static, meta):
+        if not self.final.has_per_task_fit():
+            return {}
+        return self.final.launch_stats(
+            models["final"], self._final_static(static), meta)
+
+    def launch_facts(self, static, meta, n_candidates, n_folds):
+        if not self.final.has_per_task_fit():
+            return {}
+        return self.final.launch_facts(
+            self._final_static(static), meta, n_candidates, n_folds)
+
+    def launch_workspace(self, n_samples, meta, n_folds, itemsize=4, *,
+                         static, row_sets=1):
+        """The final step's workspace, told that it reads one matrix of
+        rows a fold, and, fixed, those rows themselves: the shared-prefix
+        stage's (folds, n, d) buffer (the fused fit holds the same as a
+        temporary)."""
+        if not self.final.has_per_task_fit():
+            return {}
+        sets = n_folds if self.steps else row_sets
+        ws = dict(self.final.launch_workspace(
+            n_samples, meta, n_folds, itemsize,
+            static=self._final_static(static), row_sets=sets))
+        if ws and self.steps:
+            ws["fixed_bytes"] = ws.get("fixed_bytes", 0) + (
+                n_folds * int(n_samples) * int(meta["n_features"])
+                * itemsize)
+        return ws
 
     # -- host side -------------------------------------------------------
     def extract_params(self, estimator) -> Dict[str, Any]:
@@ -145,7 +191,8 @@ class PipelineFamily:
                 X = step.apply(per_step[sname], st, X)
             return X
 
-        return jax.vmap(tf)(fold_w)                    # (F, n, d')
+        with jax.named_scope("sst.prefix.transform"):
+            return jax.vmap(tf)(fold_w)                # (F, n, d')
 
     def suffix_family(self) -> "PipelineFamily":
         """The final-step-only family the shared-prefix scheduler fans
@@ -261,6 +308,7 @@ class BinnedInvariantPipelineFamily:
     #: iteration leaves are reported as a bare tree family's are
     launch_stats = Family.launch_stats
     launch_facts = Family.launch_facts
+    launch_workspace = Family.launch_workspace
 
     def __init__(self, final_name: str, final_family):
         self.final_name = final_name

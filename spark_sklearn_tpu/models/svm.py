@@ -710,7 +710,7 @@ class SVCFamily(Family):
 
     @staticmethod
     def launch_workspace(n_samples: int, meta, n_folds: int,
-                         itemsize: int = 4):
+                         itemsize: int = 4, *, static=None, row_sets=1):
         """What a launch holds besides its arguments, for the memory
         ledger (read off the launch compiled for a v5e at 20 000 rows x
         16 candidates: 5.03 GB with dense duals, 3.45 GB with

@@ -171,6 +171,34 @@ SEARCH_REPORT_SCHEMA = (
         "host.",
         stat="dual_iters", combine="per_candidate"),
     MetricDef(
+        "minibatch_steps_per_launch", "series",
+        "Per launch of a minibatch family (MLPClassifier, MLPRegressor, "
+        "alone or as a compiled Pipeline's final step): minibatch steps "
+        "the launch executed in lockstep, its epochs x the steps of an "
+        "epoch (ceil(training rows / batch_size) of the fold with the "
+        "most).",
+        stat="minibatch_steps", combine="max"),
+    MetricDef(
+        "minibatch_rows_per_launch", "series",
+        "Per launch of a minibatch family: the training rows of the "
+        "launch's fullest minibatch.  batch_size where a step trains on "
+        "training rows only; fewer where a step's batch is padded with "
+        "rows of weight zero.",
+        stat="minibatch_rows", combine="max"),
+    MetricDef(
+        "mlp_params_per_lane", "series",
+        "Per launch of a minibatch family: weights and intercepts one "
+        "(candidate, fold) lane trains, from the group's "
+        "hidden_layer_sizes.",
+        stat="mlp_params", combine="fact"),
+    MetricDef(
+        "epochs_per_candidate", "series",
+        "Minibatch families: epochs each candidate ran before sklearn's "
+        "stopping rules or max_iter ended it (the most over its folds' "
+        "first), in cv_results_ order.  -1: the candidate was restored "
+        "from a checkpoint or fitted on the host.",
+        stat="epochs", combine="per_candidate"),
+    MetricDef(
         "padding_waste", "histogram",
         "Per-launch fraction of computed lanes that were padding "
         "(chunk tail repeated to the group's uniform width) — the "

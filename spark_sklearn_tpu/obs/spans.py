@@ -379,6 +379,33 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
             "once a launch, each candidate's boxes in the compact "
             "layout, and its decisions back into the caller's row order.",
             layer="solvers"),
+    SpanDef("sst.mlp.gather", "scope", "models.mlp",
+            "A minibatch step: the batch's rows and targets gathered "
+            "from the fold's training rows in the epoch's order.",
+            layer="solvers"),
+    SpanDef("sst.mlp.forward", "scope", "models.mlp",
+            "A minibatch step: every layer's activations, the batch's "
+            "loss and its regulariser.",
+            layer="solvers"),
+    SpanDef("sst.mlp.backward", "scope", "models.mlp",
+            "A minibatch step: sklearn's _backprop, the deltas and "
+            "every layer's gradients.",
+            layer="solvers"),
+    SpanDef("sst.mlp.update", "scope", "models.mlp",
+            "A minibatch step: the optimiser (sklearn's AdamOptimizer "
+            "or momentum SGD) on weights and moments.",
+            layer="solvers"),
+    SpanDef("sst.mlp.epoch", "scope", "models.mlp",
+            "Once an epoch: the order of the training rows, the "
+            "epoch's loss or validation score, and sklearn's stopping "
+            "rules.",
+            layer="solvers"),
+    SpanDef("sst.prefix.transform", "scope", "models.pipeline",
+            "PipelineFamily.prefix_transform: the transformer chain "
+            "fitted on each fold's training rows and applied to all "
+            "rows, the (folds, n, d) buffer the shared-prefix stage "
+            "caches (prefix.stage is its host span).",
+            layer="search API"),
     # async virtual tracks (name prefixes)
     SpanDef("launch", "async", "parallel.pipeline",
             "Whole-launch span (dispatch..finalize) per chunk, on the "

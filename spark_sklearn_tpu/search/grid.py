@@ -56,7 +56,8 @@ from sklearn.utils.metadata_routing import (
 from sklearn.utils.metaestimators import available_if
 from sklearn.utils.validation import _check_method_params, check_is_fitted
 
-from spark_sklearn_tpu.models.base import NotCompiledError, resolve_family
+from spark_sklearn_tpu.models.base import (
+    CANDIDATE_AXIS, NotCompiledError, resolve_family)
 from spark_sklearn_tpu.parallel import mesh as mesh_lib
 from spark_sklearn_tpu.parallel import ownership as _ownership
 from spark_sklearn_tpu.parallel.mesh import TpuConfig, build_mesh
@@ -2251,27 +2252,32 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     mask_itemsize=int(fit_masks.dtype.itemsize),
                     n_scorers=len(scorers), return_train=return_train,
                     dtype_itemsize=int(np.dtype(dtype).itemsize))
-                ws_hook = getattr(family, "launch_workspace", None)
-                ws_fixed = 0
-                if ws_hook is not None:
-                    # the family's own launch workspace (the kernel
-                    # duals' Gram matrix and decision cache)
-                    mem_kw["workspace"] = ws_hook(
+
+                def group_mem_kw(plan):
+                    """`mem_kw` with what the family says a launch of
+                    this compile group holds besides its arguments (the
+                    kernel duals' Gram matrix and decision cache; an MLP
+                    lane's state, which follows the group's hidden
+                    widths)."""
+                    ws = family.launch_workspace(
                         int(fit_masks.shape[1]), meta, n_folds,
-                        int(np.dtype(dtype).itemsize))
-                    ws_fixed = int(mem_kw["workspace"]["fixed_bytes"])
+                        int(np.dtype(dtype).itemsize),
+                        static=plan["static"])
+                    return {**mem_kw, "workspace": ws} if ws else mem_kw
+
                 budget = int(mem_ctx.get("budget_bytes", 0)) \
                     if mem_ctx is not None else 0
                 if budget:
-                    mem_caps = [
-                        _memledger.width_cap(
-                            budget, resident_est + ws_fixed,
-                            _memledger.model_group_footprint(
-                                p["group"].dynamic_params, 1, n_folds,
-                                **mem_kw)["per_candidate_bytes"],
-                            n_task_shards, max_cand_per_batch,
-                            ledger.safety_margin)
-                        for p in plans]
+                    mem_caps = []
+                    for p in plans:
+                        fp1 = _memledger.model_group_footprint(
+                            p["group"].dynamic_params, 1, n_folds,
+                            **group_mem_kw(p))
+                        mem_caps.append(_memledger.width_cap(
+                            budget,
+                            resident_est + fp1.get("fixed_bytes", 0),
+                            fp1["per_candidate_bytes"], n_task_shards,
+                            max_cand_per_batch, ledger.safety_margin))
             geo_kwargs = dict(
                 sizes=[p["nc"] for p in plans],
                 sorted_caps=[p["sorted_cap"] for p in plans],
@@ -2477,7 +2483,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 for plan, gg in zip(plans, geo.groups):
                     fp = _memledger.model_group_footprint(
                         plan["group"].dynamic_params, plan["nc_batch"],
-                        n_folds, **mem_kw)
+                        n_folds, **group_mem_kw(plan))
                     rec = {"group": cid_ns + str(plan["gi"]),
                            "width": int(plan["nc_batch"]),
                            "capped": bool(getattr(gg, "capped", False)),
@@ -2662,7 +2668,11 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                           meta)
                     return jax.vmap(one_fold)(train_m)
                 with jax.named_scope("sst.fit"):
-                    return jax.vmap(one_cand)(dyn_arrs)
+                    # the candidate axis has a name, so that a family's
+                    # lockstep loop can ask whether ANY candidate still
+                    # runs (models/base.py::any_candidate)
+                    return jax.vmap(one_cand,
+                                    axis_name=CANDIDATE_AXIS)(dyn_arrs)
 
             def with_stats(fit_fn):
                 # the unfused fit launch's program: the models and, from
@@ -2683,18 +2693,23 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     lambda l: l.reshape((n_tasks,) + l.shape[2:]), models)
                 views = {}
                 if px:
-                    # suffix views: task t scores on its fold's cached
-                    # matrix — the per-task gather X_folds[t % nf]
-                    # fuses into the view matmul under vmap, so no
-                    # (T, n, d') operand ever materializes
-                    fi_all = jnp.arange(n_tasks, dtype=jnp.int32) % nf
+                    # suffix views: fold f's models score on fold f's
+                    # cached matrix, candidates over folds as the fit
+                    # vmaps them.  (Flat over tasks with a gather
+                    # X_folds[t % nf] a task, XLA:TPU materialized the
+                    # (T, n, d') operand it was meant to fuse away:
+                    # 6.6 GB at 60 tasks of 70 000 x 784; compiled for a
+                    # v5e the launch's scratch is 7.17 GB that way and
+                    # 0.04 GB this way, PERF.md, PR 33.)
                     xf = data_d["X_folds"]
                     for name in needed_views:
-                        views[name] = jax.vmap(
-                            lambda m, fi, name=name: build_view(
-                                name, suffix_fam, m, static,
-                                _fold_data(data_d, xf[fi]), meta))(
-                                    flat, fi_all)
+                        views[name] = jax.tree_util.tree_map(
+                            lambda v: v.reshape((n_tasks,) + v.shape[2:]),
+                            jax.vmap(lambda m_c, name=name: jax.vmap(
+                                lambda m, Xf: build_view(
+                                    name, suffix_fam, m, static,
+                                    _fold_data(data_d, Xf), meta))(
+                                        m_c, xf))(models))
                 else:
                     wide = getattr(family, "views_task_batched", None)
                     if wide is not None:

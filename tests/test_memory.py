@@ -437,3 +437,30 @@ class TestTools:
             memledger.get_ledger().deactivate()
             if not was:
                 tracer.disable()
+
+
+# --- Family.launch_workspace: one protocol -----------------------------------
+
+def _registered_families():
+    import spark_sklearn_tpu.models  # noqa: F401  (registers every family)
+    from spark_sklearn_tpu.models.base import _FAMILIES_BY_CLASSNAME
+    return sorted(set(_FAMILIES_BY_CLASSNAME.values()),
+                  key=lambda f: f.__name__)
+
+
+@pytest.mark.parametrize("family", _registered_families(),
+                         ids=lambda f: f.__name__)
+def test_every_family_takes_the_engines_workspace_call(family):
+    """The engine asks once a compile group, with the group's `static`
+    (a Pipeline adds `row_sets` for its final step): every family takes
+    that call and answers with the ledger's two keys or with nothing."""
+    meta = {"n_classes": 3, "n_features": 5, "n_targets": 1,
+            "class_counts": (40, 40, 40)}
+    ws = family.launch_workspace(120, meta, 3, 4, static={}, row_sets=3)
+    assert set(ws) <= {"fixed_bytes", "per_candidate_bytes"}
+    assert all(int(v) >= 0 for v in ws.values())
+    if ws:
+        footprint = memledger.model_group_footprint(
+            {}, 4, 3, task_batched=False, n_samples=120, workspace=ws)
+        assert footprint["fixed_bytes"] == ws.get("fixed_bytes", 0)
+        assert footprint["chunk_bytes"] >= footprint["workspace_bytes"]

@@ -13,12 +13,20 @@ from spark_sklearn_tpu.models.base import resolve_family
 
 class TestMLP:
     def test_mlp_classifier_learns(self, digits):
+        """Re-read against sklearn when the minibatches became sklearn's
+        (PR 33: 6 steps an epoch on a fold's 1 198 training rows, where
+        the all-rows batches took 9): sklearn itself scores 0.9004 /
+        0.9060 / 0.9082 on random_state 0 / 1 / 2 here."""
+        from sklearn.model_selection import GridSearchCV
         X, y = digits
-        gs = sst.GridSearchCV(
-            MLPClassifier(hidden_layer_sizes=(64,), max_iter=30,
-                          random_state=0),
-            {"alpha": [1e-4, 1e-2]}, cv=3, backend="tpu").fit(X, y)
-        assert gs.cv_results_["mean_test_score"].max() > 0.9
+        est = MLPClassifier(hidden_layer_sizes=(64,), max_iter=30,
+                            random_state=0)
+        grid = {"alpha": [1e-4, 1e-2]}
+        gs = sst.GridSearchCV(est, grid, cv=3, backend="tpu").fit(X, y)
+        sk = GridSearchCV(est, grid, cv=3, refit=False).fit(X, y)
+        ours = gs.cv_results_["mean_test_score"]
+        assert ours.max() > 0.88
+        assert np.abs(ours - sk.cv_results_["mean_test_score"]).max() < 0.02
         assert gs.best_estimator_ is not None
 
     def test_mlp_regressor_learns(self, diabetes):

@@ -31,6 +31,15 @@ class counts, a compiled Pipeline's per-fold kernels) the launch lowers to
 the text of the commit before the layout
 (``test_dense_svc_launches_lower_to_the_parents_text``).
 
+The minibatch perceptron's launch (``MLPClassifierFamily.fit`` under the
+engine's two ``vmap``s, the candidate axis named) is compiled at the widest
+group of the ``mlp_mnist.arch_alpha`` cell — 12 alpha x 5 folds of
+``hidden_layer_sizes`` (1000,) on 70 000 x 784: with and without scopes the
+same optimized HLO, every ``sst.mlp.*`` scope on a device operation, the
+step loop carrying the weights and Adam's two moments and nothing else of
+that size, one gather of minibatch rows a fold, and the ledger's price
+within a quarter of the compiler's allotment.
+
 Nothing runs on a device here and nothing is timed.  The topology is
 described inside a fixture (never at import time: only one process may
 load the TPU's library), and where it cannot be described the tests skip.
@@ -486,3 +495,119 @@ def test_ledger_prices_the_svc_launch(svc_launch):
     # over a quarter until the block-compact layout dropped the matrix's
     # third copy and four fifths of the duals' columns)
     assert allotted > 0.2 * 16.909e9
+
+
+# --- the minibatch perceptron's launch ---------------------------------------
+
+MLP_N, MLP_CANDIDATES, MLP_HIDDEN, MLP_BATCH = 70_000, 12, [1000], 200
+MLP_SCOPES = sorted(s for s in known_scope_names()
+                    if s.startswith("sst.mlp."))
+
+
+def _compiled_mlp(one_chip):
+    """The per-task MLPClassifier fit launch as the engine vmaps it
+    (candidates, named; folds over the shared-prefix stage's per-fold
+    rows) at the widest group of ``mlp_mnist.arch_alpha``: 12 alpha x 5
+    folds of hidden_layer_sizes (1000,) on 70 000 x 784."""
+    from spark_sklearn_tpu.models.base import CANDIDATE_AXIS
+    from spark_sklearn_tpu.models.mlp import MLPClassifierFamily
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    meta = {"n_classes": K, "classes": np.arange(K), "n_features": D}
+    static = {"hidden_layer_sizes": MLP_HIDDEN, "max_iter": 8,
+              "random_state": 0}
+
+    def launch(alpha, X_folds, y, y1h, w):
+        def one_cand(a):
+            def one_fold(wf, Xf):
+                return MLPClassifierFamily.fit(
+                    {"alpha": a}, static, {"X": Xf, "y": y, "y1h": y1h},
+                    wf, meta)
+            return jax.vmap(one_fold)(w, X_folds)
+        with jax.named_scope("sst.fit"):
+            return jax.vmap(one_cand, axis_name=CANDIDATE_AXIS)(alpha)
+
+    return jax.jit(launch).lower(
+        arg((MLP_CANDIDATES,)), arg((FOLDS, MLP_N, D)),
+        arg((MLP_N,), jnp.int32), arg((MLP_N, K)),
+        arg((FOLDS, MLP_N))).compile(), meta, static
+
+
+@pytest.fixture(scope="module")
+def mlp_pair(topo, no_compile_cache):
+    from jax.sharding import SingleDeviceSharding
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    scoped, meta, static = _compiled_mlp(one_chip)
+    real = jax.named_scope
+    jax.named_scope = lambda name: contextlib.nullcontext()
+    try:
+        bare = _compiled_mlp(one_chip)[0].as_text()
+    finally:
+        jax.named_scope = real
+    return scoped, bare, meta, static
+
+
+def test_mlp_scopes_are_the_vocabularys():
+    assert MLP_SCOPES == [
+        "sst.mlp.backward", "sst.mlp.epoch", "sst.mlp.forward",
+        "sst.mlp.gather", "sst.mlp.update"]
+
+
+def test_mlp_scopes_change_no_instruction(mlp_pair):
+    scoped, bare = mlp_pair[0].as_text(), mlp_pair[1]
+    assert "sst.mlp." in scoped and "sst.mlp." not in bare
+    assert _instructions(scoped) == _instructions(bare)
+    assert scoped.count("fusion(") > 20 and scoped.count(" while(") >= 2
+
+
+@pytest.mark.parametrize("scope", MLP_SCOPES)
+def test_mlp_compiled_op_names_carry_scope(mlp_pair, scope):
+    text = mlp_pair[0].as_text()
+    assert re.search(r'op_name="[^"]*/' + re.escape(scope) + r'[/"]', text)
+
+
+def test_mlp_step_gathers_once_a_fold_and_carries_three_copies(mlp_pair):
+    """The step loop's state: the first layer's weights and Adam's two
+    moments, (folds, candidates, 784, 1000) each, and no fourth array of
+    that size (no best weights without early_stopping, no gradient kept
+    from step to step).  The minibatch is gathered once a fold — 5 x 200
+    rows — never once a lane."""
+    text = mlp_pair[0].as_text()
+    first = re.compile(r'f32\[(?:%d,%d|%d,%d),%d,%d\]' % (
+        FOLDS, MLP_CANDIDATES, MLP_CANDIDATES, FOLDS, D, MLP_HIDDEN[0]))
+    states = [line.split(" while(")[0] for line in text.splitlines()
+              if " while(" in line]
+    assert max(len(first.findall(state)) for state in states) == 3
+    lanes = MLP_CANDIDATES * FOLDS
+    assert re.search(r'\[%d,%d\]' % (FOLDS * MLP_BATCH, D), text)
+    assert not re.search(r'\[(?:%d,%d,%d|%d),%d\]' % (
+        MLP_CANDIDATES, FOLDS, MLP_BATCH, lanes * MLP_BATCH, D), text)
+
+
+def test_ledger_prices_the_mlp_launch(mlp_pair):
+    """``MLPClassifierFamily.launch_workspace`` through the Pipeline's
+    (what ``search_report["memory"]`` models the cell's widest group at)
+    against the compiler's own allotment."""
+    from spark_sklearn_tpu.models.mlp import MLPClassifierFamily
+    from spark_sklearn_tpu.models.pipeline import PipelineFamily
+    from spark_sklearn_tpu.models.preprocessing import STEP_REGISTRY
+    from spark_sklearn_tpu.parallel.memledger import model_group_footprint
+    compiled, _, meta, static = mlp_pair
+    stats = compiled.memory_analysis()
+    allotted = (stats.temp_size_in_bytes + stats.argument_size_in_bytes
+                + stats.output_size_in_bytes)
+    family = PipelineFamily([("scale", STEP_REGISTRY["StandardScaler"])],
+                            "mlp", MLPClassifierFamily)
+    modeled = model_group_footprint(
+        {"mlp__alpha": np.zeros(MLP_CANDIDATES, np.float32)},
+        MLP_CANDIDATES, FOLDS, task_batched=False, n_samples=MLP_N,
+        workspace=family.launch_workspace(
+            MLP_N, meta, FOLDS,
+            static={f"mlp__{k}": v for k, v in static.items()}))
+    # a lane's weights are megabytes: 795 010 parameters, 8 copies
+    assert modeled["per_candidate_bytes"] > FOLDS * 8 * 795_010 * 4
+    assert abs(modeled["chunk_bytes"] - allotted) < 0.25 * allotted
+    # an eighth of the chip and more: the cell's size (PERF.md section 4)
+    assert allotted > 0.125 * 16.909e9

@@ -247,10 +247,48 @@ def lowered_dual_launch():
     return _lowered_launches(search)
 
 
+#: the scopes of a compiled Pipeline(StandardScaler, MLPClassifier) search
+MLP_SCOPES = [s for s in SCOPES
+              if s.startswith(("sst.mlp.", "sst.prefix."))]
+
+
+@pytest.fixture(scope="module")
+def lowered_mlp_launch():
+    import jax
+    from sklearn.neural_network import MLPClassifier
+    from sklearn.pipeline import Pipeline
+    from sklearn.preprocessing import StandardScaler
+    from spark_sklearn_tpu.models.base import resolve_family
+
+    pipe = Pipeline([("scale", StandardScaler()),
+                     ("mlp", MLPClassifier(hidden_layer_sizes=(4,),
+                                           max_iter=2, random_state=0))])
+    X, y = _problem(n=90, d=5)
+
+    def search():
+        # narrow chunks and more candidates than one holds, so that the
+        # suffix family's fused program is compiled ahead
+        sst.GridSearchCV(
+            pipe, {"mlp__alpha": np.logspace(-4, -1, 20).tolist()},
+            cv=3, refit=False, backend="tpu",
+            config=sst.TpuConfig(max_tasks_per_batch=3)).fit(X, y)
+    # the shared-prefix stage's program is launched where it is built,
+    # not handed to the compile thread: lowered here
+    family = resolve_family(pipe)
+    stage = jax.jit(lambda data, w: family.prefix_transform({}, data, w))
+    return _lowered_launches(search) + stage.lower(
+        {"X": X}, np.ones((3, len(X)), np.float32)).as_text(debug_info=True)
+
+
+def _launch_fixture(scope):
+    return ("lowered_dual_launch" if scope in DUAL_SCOPES else
+            "lowered_mlp_launch" if scope in MLP_SCOPES else
+            "lowered_launch")
+
+
 @pytest.mark.parametrize("scope", SCOPES)
 def test_lowered_launch_holds_scope(request, scope):
-    text = request.getfixturevalue(
-        "lowered_dual_launch" if scope in DUAL_SCOPES else "lowered_launch")
+    text = request.getfixturevalue(_launch_fixture(scope))
     # a scope opens a name stack inside a scanned body: no slash before it
     assert re.search(r'["/]' + re.escape(scope) + "/", text)
 
