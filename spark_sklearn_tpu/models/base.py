@@ -243,6 +243,24 @@ class Family:
         priced."""
         return {}
 
+    @classmethod
+    def launch_layout(cls, dynamic_params, static, meta, n_folds: int):
+        """On the host, once a compile group, before its chunks are cut:
+        an order of the group's candidates that the family's task-batched
+        launch can use, and the static facts that say so.  `dynamic_params`
+        holds the group's numpy arrays, one entry a candidate.  Returns
+        ``(order, facts, run)`` — the engine permutes the group's
+        candidates by `order` (``cv_results_`` is written through their
+        indices, so its order does not move; a checkpoint's chunk ids say
+        that a layout cut them) and `facts` join the group's ``static``,
+        and with it the program's key — or None: the candidates stay as
+        they came.  `run`: the group in that order is whole runs of `run`
+        candidates, and the facts hold for a launch of whole runs.  The
+        engine hands them to no other launch: a recovery's range or a
+        cross-search fuse that cuts a run gets the program built without
+        them.  Default: None."""
+        return None
+
     # --- interop ---------------------------------------------------------
     @classmethod
     def sklearn_attrs(cls, model, static, meta) -> Dict[str, Any]:

@@ -127,6 +127,11 @@ class PipelineFamily:
                 * itemsize)
         return ws
 
+    def launch_layout(self, dynamic_params, static, meta, n_folds):
+        """None: behind transformers a final step's fits read one matrix
+        of rows a fold, and nothing of them is shared across candidates."""
+        return None
+
     # -- host side -------------------------------------------------------
     def extract_params(self, estimator) -> Dict[str, Any]:
         out = {}
@@ -309,6 +314,7 @@ class BinnedInvariantPipelineFamily:
     launch_stats = Family.launch_stats
     launch_facts = Family.launch_facts
     launch_workspace = Family.launch_workspace
+    launch_layout = Family.launch_layout
 
     def __init__(self, final_name: str, final_family):
         self.final_name = final_name

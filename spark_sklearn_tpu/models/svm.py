@@ -40,6 +40,22 @@ is `Z @ K` (`_DenseKernel`).  One algorithm on two layouts:
 and share every other line; `search_report["dual_rows_per_launch"]` says
 which layout a launch ran.
 
+**One kernel matrix for the C of a gamma.**  The launch scans kernel
+matrices, not candidates.  A C x gamma grid has as many kernels as
+gammas: where the candidates the engine hands over fall into runs of one
+length S >= 2 that share gamma and differ in C (nu) only,
+`SVCFamily.launch_layout` orders them kernel-major on the host and hands
+the launch S as a static fact.  A scan step then builds the matrix, its
+bfloat16 copy and the step size once and advances the S candidates'
+subproblems stacked on the duals' leading axis, each row's box scaled by
+its own candidate's C: in either layout the product reads the matrix once
+for all of them.  A candidate stays what it is alone: the `tol` exit is
+judged a candidate, one that is done stops moving while the loop runs on
+for the others, and it reports its own count (`_stacked_tol`, `_box_fista`).
+Ragged runs, distinct gammas, a chunk that is not made of whole runs and
+per-fold kernels build a kernel a candidate, the program they always ran;
+`search_report["gram_builds_per_launch"]` says which.
+
 Deviation from libsvm (documented, tested at the accuracy level): a
 fixed iteration budget (300 where `max_iter` is -1) beside the `tol` exit
 on the prox-gradient residual.  In exact float32 (XLA:CPU) `tol` ends the
@@ -219,6 +235,22 @@ def _as_product(K):
     return K if hasattr(K, "own") else _DenseKernel(K)
 
 
+#: the key of the static fact `SVCFamily.launch_layout` hands the launch:
+#: the run length S of candidates that share a kernel matrix
+_KERNEL_RUN = "__kernel_run__"
+
+
+def _kernel_run(static, n_candidates, fold_inputs=False):
+    """The run length a launch of `n_candidates` groups its candidates by:
+    the layout's fact, else 1 — no fact (a direct caller, a grid with
+    nothing to share, a launch the engine knows not to be made of whole
+    runs: `Family.launch_layout`), a width that is not a multiple of it
+    (a group cut into narrower chunks), per-fold rows (a compiled
+    Pipeline: a kernel a candidate and fold)."""
+    S = int((static or {}).get(_KERNEL_RUN, 1))
+    return S if S > 1 and not fold_inputs and n_candidates % S == 0 else 1
+
+
 def _power_start(n, dtype):
     """The centred power iteration's first vector."""
     return jax.random.normal(jax.random.PRNGKey(0), (n,), dtype)
@@ -286,7 +318,16 @@ def _box_fista(grad_fn, project, x0, step, max_iter, tol=None):
     test would spuriously fire on the first iteration whenever
     1/lambda_max(Gram) < tol).  Not liblinear's dual-violation bound,
     but a real measurement rather than an assumed one.  Exits once every
-    lane has converged and returns (x, n_iter, converged)."""
+    lane has converged and returns (x, n_iter, converged).
+
+    A `tol` of two axes, (problems, lanes of each): the lanes are those
+    of several problems, stacked on x0's leading axis to share the
+    gradient's one product, and each problem ends as it would alone.
+    Alone its loop ends with the iteration after which all of ITS lanes
+    have been under `tol`: here its lanes stop moving there (a select on
+    the update) while the loop goes on for the others, so its `x` and
+    its lanes' counts are those of its own solve; `n_iter` and
+    `converged` come back in `tol`'s shape."""
     dtype = x0.dtype
 
     def advance(x, z, t):
@@ -305,8 +346,8 @@ def _box_fista(grad_fn, project, x0, step, max_iter, tol=None):
             (x0, x0, jnp.asarray(1.0, dtype)))
         return x
 
+    lanes = jnp.shape(tol)
     lane_axes = tuple(range(1, x0.ndim))
-    B = x0.shape[0]
 
     def cond(carry):
         *_, it, _n, done = carry
@@ -318,15 +359,21 @@ def _box_fista(grad_fn, project, x0, step, max_iter, tol=None):
         x_new, z_new, t_new = advance(x, z, t)
         with jax.named_scope("sst.box_fista.momentum"):
             resid = jnp.max(jnp.abs(x_new - z), axis=lane_axes) / step
-            done_new = jnp.logical_or(done, resid <= tol)
+            done_new = jnp.logical_or(done, resid.reshape(lanes) <= tol)
             n_iter = jnp.where(jnp.logical_and(jnp.logical_not(done),
                                                done_new), it + 1, n_iter)
+            if len(lanes) == 2:
+                live = jnp.repeat(
+                    jnp.logical_not(jnp.all(done, axis=1)),
+                    lanes[1]).reshape((-1,) + (1,) * len(lane_axes))
+                x_new = jnp.where(live, x_new, x)
+                z_new = jnp.where(live, z_new, z)
         return x_new, z_new, t_new, it + 1, n_iter, done_new
 
     x, _, _, it, n_iter, done = jax.lax.while_loop(
         cond, body,
         (x0, x0, jnp.asarray(1.0, dtype), jnp.asarray(0, jnp.int32),
-         jnp.full((B,), max_iter, jnp.int32), jnp.zeros((B,), bool)))
+         jnp.full(lanes, max_iter, jnp.int32), jnp.zeros(lanes, bool)))
     n_iter = jnp.where(done, n_iter, it)
     return x, n_iter, done
 
@@ -397,13 +444,30 @@ def _run_dual(grad, project, x0, step, max_iter, tol, dtype):
     """Shared tol dispatch for the kernel duals: `tol=None` runs the
     fixed count; otherwise `_box_fista`'s per-lane residual exit (the
     batched analog of libsvm's eps rule) with the executed-iteration
-    max reported for accounting."""
+    max reported for accounting.  `tol` a scalar: x0's rows are the
+    subproblems of one candidate.  `tol` of shape (candidates,
+    subproblems of each) (`_stacked_tol`) IS how a caller says that the
+    rows are those of several candidates of one kernel, stacked: each is
+    judged and counted by its own subproblems, and the count is one a
+    candidate.  Every solver between `fit_task_batched` and here hands
+    `tol` on as it got it."""
     if tol is None:
         x = _box_fista(grad, project, x0, step, max_iter)
         return x, jnp.asarray(max_iter, jnp.int32)
-    x, n_it, _ = _box_fista(grad, project, x0, step, max_iter,
-                            tol=jnp.full((x0.shape[0],), tol, dtype))
-    return x, jnp.max(n_it).astype(jnp.int32)
+    x, n_it, _ = _box_fista(
+        grad, project, x0, step, max_iter,
+        tol=jnp.full(jnp.shape(tol) or (x0.shape[0],), tol, dtype))
+    return x, jnp.max(n_it, axis=-1).astype(jnp.int32)
+
+
+def _stacked_tol(tol, candidates, subproblems, dtype):
+    """`tol` for the stacked subproblems of `candidates` candidates of
+    one kernel: the shape (candidates, subproblems of each) is what
+    tells `_run_dual` and `_box_fista` so.  A solve without `tol` has
+    nothing to judge a candidate by and keeps None."""
+    if tol is None:
+        return None
+    return jnp.full((candidates, subproblems), tol, dtype)
 
 
 def _tol_or_default(static):
@@ -441,6 +505,8 @@ def nu_dual_ascent(K, yb, bound, nu, step, max_iter, tol=None):
     case where sklearn raises 'specified nu is infeasible') come back as
     NaN rows for the engine's failed-fit detector.  `K`: the kernel
     matrix, or the product with it as an operator (`_BlockKernel`).
+    `nu`: a scalar, or one value a subproblem; `tol`: a scalar, or
+    `_stacked_tol`'s where M stacks several candidates' subproblems.
     """
     K = _as_product(K)
     pos_b = jnp.where(yb > 0, bound, 0.0)
@@ -524,8 +590,10 @@ def fista_dual_ascent(K, yb, bound, step, max_iter, tol=None):
     the per-lane prox-gradient-residual exit stops when every subproblem
     is below it — the batched analog of libsvm's eps stopping rule,
     which defaults to the same 1e-3 the sklearn `tol` parameter
-    carries).  Shared by the search's task-batched fit and the
-    standalone SVC so the numerics live once."""
+    carries; `_stacked_tol`'s where the M subproblems are several
+    candidates' stacked: `n_iter` is then one count a candidate).  Shared
+    by the search's task-batched fit and the standalone SVC so the
+    numerics live once."""
 
     K = _as_product(K)
 
@@ -692,9 +760,12 @@ class SVCFamily(Family):
         decision rows in class-sorted order), `p_c` the candidate's
         primary scalar (C here: scales the box), `base_bound` the
         fold/weight/pair box mask; `tol` enables the per-lane residual
-        exit (libsvm's eps stopping rule)."""
+        exit (libsvm's eps stopping rule).  Where the subproblems are
+        those of several candidates of one kernel, candidate-major, `p_c`
+        is (M,), each row its candidate's, and `tol` `_stacked_tol`'s:
+        the count is then one a candidate."""
         K = _as_product(K)
-        bound = p_c * base_bound
+        bound = (p_c[:, None] if jnp.ndim(p_c) else p_c) * base_bound
         A, b, n_it = fista_dual_ascent(K, yb, bound, step, max_iter, tol)
         with jax.named_scope("sst.svc.decision"):
             return K.all(A * yb) + b[:, None], n_it
@@ -714,26 +785,29 @@ class SVCFamily(Family):
         """What a launch holds besides its arguments, for the memory
         ledger (read off the launch compiled for a v5e at 20 000 rows x
         16 candidates: 5.03 GB with dense duals, 3.45 GB with
-        block-compact ones).  Whatever the width, since candidates are
-        scanned: ONE candidate's kernel matrix and the bfloat16 copy the
-        MXU reads, and eight (folds x pairs, columns of a dual) arrays
-        (iterates, signs, bounds, gradient, the projection's
-        temporaries).  Dense duals: n columns, and the matrix a third
+        block-compact ones, 3.53 GB with those of 4 candidates a kernel
+        stacked).  Whatever the width, since kernels are scanned: ONE
+        kernel matrix and the bfloat16 copy the MXU reads, and eight
+        (folds x pairs, columns of a dual) arrays (iterates, signs,
+        bounds, gradient, the projection's temporaries) for each of the
+        S candidates of a kernel (`launch_layout`'s fact in `static`;
+        1 without it).  Dense duals: n columns, and the matrix a third
         time in the layout the product wants.  Block-compact duals: the
         matrix of the k n_b sorted and padded rows, 2 n_b columns, and
-        the (k, k folds, k n_b) result of the product by class.  A
+        the (k, S k folds, k n_b) result of the product by class.  A
         candidate: its cached (folds, n, pairs) pair decisions, about
         three times over (the scan's stacked output, its transpose, the
         task-major copy)."""
         k = meta["n_classes"]
+        S = 1 if row_sets > 1 else int((static or {}).get(_KERNEL_RUN, 1))
         m = n_folds * max(1, k * (k - 1) // 2)
         n = int(n_samples)
         n_b = _block_rows(meta, n)
         if n_b is None:
-            fixed = n * n * (2 * itemsize + 2) + 8 * m * n * itemsize
+            fixed = n * n * (2 * itemsize + 2) + 8 * S * m * n * itemsize
         else:
             n_p = k * n_b
-            fixed = n_p * n_p * (itemsize + 2) + (
+            fixed = n_p * n_p * (itemsize + 2) + S * (
                 8 * m * 2 * n_b + k * k * n_folds * n_p) * itemsize
         return {"fixed_bytes": fixed,
                 "per_candidate_bytes": 3 * m * n * itemsize}
@@ -742,10 +816,13 @@ class SVCFamily(Family):
     def launch_facts(cls, static, meta, n_candidates, n_folds):
         """Kernel matrices built and dual subproblems advanced by a
         launch of `n_candidates` (padding included: a padded candidate
-        is computed), and the columns one dual's iterate holds: its two
-        class blocks in the block-compact layout, every row otherwise."""
+        is computed; one matrix for the S candidates of a run where the
+        launch groups them, `_kernel_run`), and the columns one dual's
+        iterate holds: its two class blocks in the block-compact layout,
+        every row otherwise."""
         k = meta["n_classes"]
-        facts = {"gram_builds": n_candidates,
+        facts = {"gram_builds":
+                     n_candidates // _kernel_run(static, n_candidates),
                  "dual_subproblems":
                      n_candidates * n_folds * max(1, k * (k - 1) // 2)}
         if "class_counts" in meta:
@@ -753,6 +830,29 @@ class SVCFamily(Family):
             n_b = _block_rows(meta, n)
             facts["dual_rows"] = n if n_b is None else 2 * n_b
         return facts
+
+    @classmethod
+    def launch_layout(cls, dynamic_params, static, meta, n_folds):
+        """The group's candidates kernel-major, and the run length S
+        the launch may stack: where they fall into runs of one length
+        >= 2 that share `gamma` (by float32 value; the other kernel
+        parameters are static, equal within a compile group) the order
+        goes by gamma, then by the primary scalar, and S is the run
+        length.  None where the runs are ragged or every gamma is its
+        own: the launch then builds a kernel a candidate.  What S
+        candidates' stacked duals hold is `launch_workspace`'s to price
+        and the ledger's to bound: a group cut narrower than a run
+        builds a kernel a candidate too (`_kernel_run`)."""
+        nc = max((np.size(v) for v in dynamic_params.values()), default=0)
+        gamma, primary = (
+            np.broadcast_to(np.asarray(
+                dynamic_params.get(name, 0.0), np.float32), (nc,))
+            for name in ("gamma", cls.primary_param))
+        counts = np.unique(gamma, return_counts=True)[1]
+        if nc < 2 or counts[0] < 2 or np.any(counts != counts[0]):
+            return None
+        S = int(counts[0])
+        return np.lexsort((primary, gamma)), {_KERNEL_RUN: S}, S
 
     @classmethod
     def launch_stats(cls, models, static, meta):
@@ -803,11 +903,18 @@ class SVCFamily(Family):
     @classmethod
     def fit_task_batched(cls, dynamic, static, data, train_w, meta):
         """Tasks arrive candidate-major (task t = (cand t//F, fold t%F)).
-        One `lax.scan` step per candidate: its kernel matrix is built once
-        and shared by every (fold x pair) subproblem, which are advanced
+        One `lax.scan` step per kernel matrix: it is built once and
+        shared by every (fold x pair) subproblem, which are advanced
         together — each ascent iteration is a single (F*P, n) @ (n, n)
         matmul, or in the block-compact layout (module docstring) a
         product by class that contracts each dual over its own rows.
+        A kernel is a candidate's, or — where `static` carries
+        `launch_layout`'s fact and the launch is made of whole runs
+        (`_kernel_run`) — that of S consecutive candidates that differ
+        in the primary scalar only: their S x F x P subproblems are
+        stacked on the duals' leading axis, each row's box scaled by
+        its own candidate's scalar, and every candidate ends at its own
+        count (`_stacked_tol`, `_box_fista`).
         Returns per-task full-dataset pair decisions in the caller's row
         order (the search scores on masked rows of the training X, so
         caching decisions avoids rebuilding kernels in the scoring
@@ -829,9 +936,11 @@ class SVCFamily(Family):
         if max_iter in (-1, 0):
             max_iter = 300
         # libsvm's eps stopping rule (sklearn tol, default 1e-3): each
-        # candidate's dual solve exits at ITS convergence inside the
-        # per-candidate scan — easy (small-C) candidates stop in tens of
-        # iterations instead of paying max_iter
+        # candidate's dual solve ends at ITS convergence — an easy
+        # (small-C) candidate stops in tens of iterations; with a scan
+        # step of its own it exits there, stacked on a kernel's other
+        # candidates it stops moving there and the step runs as long as
+        # the slowest of them
         tol_exit = _tol_or_default(static)
         # tasks are candidate-major with a fixed fold count injected by the
         # engine; the candidate count is B // n_folds
@@ -874,6 +983,17 @@ class SVCFamily(Family):
         if cw_fold is None:
             cw_fold = jnp.ones((n_folds, n), X.dtype)
 
+        # candidates a kernel: the scan below goes over nc // S kernels,
+        # and S x F takes the place of the folds in all that a step stacks
+        S = _kernel_run(static, nc, X_folds is not None)
+        SF = S * n_folds
+        if S > 1:
+            tol_exit = _stacked_tol(tol_exit, S, n_folds * P, X.dtype)
+            C_cand = jnp.repeat(C_cand, n_folds * P).reshape(nc // S, -1)
+            g_cand = g_cand[::S]
+            w_cand = w_cand.reshape(nc // S, SF, n)
+            cw_fold = jnp.tile(cw_fold, (S, 1))
+
         n_b = None if X_folds is not None else _block_rows(meta, n)
         if n_b is not None:
             # class-sorted, block-compact duals: the rows in class order
@@ -885,19 +1005,20 @@ class SVCFamily(Family):
                 valid_row = valid.reshape(-1)
                 X_s = X[rows]                                 # (k n_b, d)
                 w_cand = (jnp.take(train_w, rows, axis=1).reshape(
-                    nc, n_folds, k, n_b) * valid)
+                    nc // S, SF, k, n_b) * valid)
                 cw_s = jnp.take(cw_fold, rows, axis=1).reshape(
-                    n_folds, k, n_b)
+                    SF, k, n_b)
                 v0_s = _power_start(n, X.dtype)[rows] * valid_row
                 # +1 on the rows of pairs[p, 0], -1 on those of
                 # pairs[p, 1], 0 on the pads
                 yb_s = jnp.broadcast_to(
                     (valid[meta["pairs"]] * jnp.asarray(
                         [1.0, -1.0], X.dtype)[None, :, None])[None],
-                    (n_folds, P, 2, n_b)).reshape(-1, 2 * n_b)
+                    (SF, P, 2, n_b)).reshape(-1, 2 * n_b)
 
-        def one_candidate(carry, inp):
-            C_c, g_c, w_f = inp                               # w_f (F, n)
+        def one_kernel(carry, inp):
+            # C_c: the candidate's scalar, or a row's; w_f (S F, n)
+            C_c, g_c, w_f = inp
             if n_b is not None:
                 with jax.named_scope("sst.svc.gram"):
                     K = _kernel(X_s, X_s, kind, g_c, degree, coef0)
@@ -908,11 +1029,21 @@ class SVCFamily(Family):
                     base = (w_f * cw_s)[:, meta["pairs"], :].reshape(
                         -1, 2 * n_b)
                 dec, it = cls._pair_dec(
-                    _BlockKernel(K, meta["pairs"], n_folds, n_b),
+                    _BlockKernel(K, meta["pairs"], SF, n_b),
                     C_c, base, yb_s, step, max_iter, tol_exit)
                 with jax.named_scope("sst.svc.compact"):
-                    dec = jnp.take(dec, slot, axis=1)
-                dec = dec.reshape(n_folds, P, n)
+                    if S > 1:
+                        # a candidate at a time, the gather the ungrouped
+                        # launch takes: XLA:TPU (libtpu 0.0.34) compiles
+                        # ONE gather of the stacked (900, 20000) rows to
+                        # a program that reads NaN in 1 071 columns and
+                        # other rows' values beside them (PERF.md, PR 34)
+                        dec = jnp.concatenate([
+                            jnp.take(d, slot, axis=1)
+                            for d in jnp.split(dec, S)])
+                    else:
+                        dec = jnp.take(dec, slot, axis=1)
+                dec = dec.reshape(SF, P, n)
             elif X_folds is None:
                 with jax.named_scope("sst.svc.gram"):
                     K = _kernel(X, X, kind, g_c, degree, coef0)   # (n, n)
@@ -922,10 +1053,10 @@ class SVCFamily(Family):
                 base = ((w_f * cw_fold)[:, None, :]
                         * in_pair[None, :, :]).reshape(-1, n)
                 yb = jnp.broadcast_to(
-                    ybin[None], (n_folds, P, n)).reshape(-1, n)
+                    ybin[None], (SF, P, n)).reshape(-1, n)
                 dec, it = cls._pair_dec(
                     K, C_c, base, yb, step, max_iter, tol_exit)
-                dec = dec.reshape(n_folds, P, n)
+                dec = dec.reshape(SF, P, n)
             else:
                 # pipeline mode: each fold has its own transformed X, so
                 # kernels are per (candidate, fold); the P pair
@@ -956,15 +1087,17 @@ class SVCFamily(Family):
                 dec, its = jax.vmap(per_fold)(
                     X_folds, w_f, cw_fold)                # (F,P,n), (F,)
                 it = jnp.max(its)
-            return carry, (jnp.transpose(dec, (0, 2, 1)), it)  # (F,n,P)
+            # one count a candidate (a solve without `tol` has one for all)
+            it = jnp.broadcast_to(it, (S,)) if S > 1 else it
+            return carry, (jnp.transpose(dec, (0, 2, 1)), it)  # (SF,n,P)
 
         _, (decs, its) = jax.lax.scan(
-            one_candidate, 0.0, (C_cand, g_cand, w_cand))
-        # (nc, F, n, P) -> task-major (B, n, P); per-candidate executed
-        # dual iterations repeat across the fold axis for the engine's
-        # per-launch accounting
+            one_kernel, 0.0, (C_cand, g_cand, w_cand))
+        # (nc / S, S F, n, P) -> task-major (B, n, P); per-candidate
+        # executed dual iterations repeat across the fold axis for the
+        # engine's per-launch accounting
         model = {"pair_dec": decs.reshape(B, n, P),
-                 "n_iter": jnp.repeat(its, n_folds)}
+                 "n_iter": jnp.repeat(its.reshape(-1), n_folds)}
         if _probability_on(static):
             # compiled Platt scaling: calibrate a sigmoid on the
             # TRAIN-fold decision values per task, stored with the model

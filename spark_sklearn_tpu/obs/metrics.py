@@ -142,9 +142,13 @@ SEARCH_REPORT_SCHEMA = (
     MetricDef(
         "gram_builds_per_launch", "series",
         "Per launch of a kernel-dual family (SVC, NuSVC): kernel "
-        "matrices the launch built, one per candidate it computed "
-        "(padding included).  Absent where a compiled Pipeline wraps "
-        "the estimator (a matrix per candidate and fold there).",
+        "matrices the launch built (padding included).  One for each "
+        "run of candidates that share gamma and differ in C (nu) only, "
+        "where the launch is made of whole runs of one length >= 2 "
+        "(the 4 C of a gamma in a C x gamma grid: a quarter of the "
+        "candidates); one per candidate otherwise.  Absent where a "
+        "compiled Pipeline wraps the estimator (a matrix per candidate "
+        "and fold there).",
         stat="gram_builds", combine="fact"),
     MetricDef(
         "dual_subproblems_per_launch", "series",

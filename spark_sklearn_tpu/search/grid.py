@@ -2145,6 +2145,15 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
         # ------------------------------------------------------------------
         # group plans: chunk geometry + (lazily built) programs
         # ------------------------------------------------------------------
+        def reorder(group, order):
+            """The group's candidates in another order (cv_results_ is
+            written through candidate_indices, so its order stays)."""
+            group.candidate_indices = np.asarray(
+                group.candidate_indices)[order]
+            group.dynamic_params = {
+                k: np.asarray(v)[order]
+                for k, v in group.dynamic_params.items()}
+
         with get_tracer().span("fit.plan", n_groups=len(groups)):
             plans = []
             for gi, group in enumerate(groups):
@@ -2171,13 +2180,25 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         if len(proxy) >= getattr(
                                 family, "min_sort_candidates", 32) \
                                 and np.unique(proxy).size > 1:
-                            order = np.argsort(proxy, kind="stable")
-                            group.candidate_indices = np.asarray(
-                                group.candidate_indices)[order]
-                            group.dynamic_params = {
-                                k: np.asarray(v)[order]
-                                for k, v in group.dynamic_params.items()}
+                            reorder(group,
+                                    np.argsort(proxy, kind="stable"))
                             sorted_chunks = True
+
+                # a launch layout (Family.launch_layout): an order the
+                # family's task-batched launch can use — SVC's candidates
+                # kernel-major, so that those of one gamma share ONE kernel
+                # matrix — applied as the sort above is, and static facts
+                # that tell the launch so and key its program.  The facts
+                # hold for a launch made of whole runs of `run` candidates
+                # of that order: the group's own chunks (build_programs
+                # gives every other launch the static without them)
+                layout = None if sorted_chunks else family.launch_layout(
+                    group.dynamic_params, static, meta, n_folds)
+                static_plain, run = static, 1
+                if layout is not None:
+                    order, facts, run = layout
+                    reorder(group, order)
+                    static = {**static, **facts}
 
                 sorted_cap = None
                 if sorted_chunks:
@@ -2191,7 +2212,9 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                                 -(-nc // _SORTED_LAUNCHES), n_task_shards)))
                 plans.append({
                     "gi": gi, "group": group, "static": static, "nc": nc,
-                    "sorted": sorted_chunks, "sorted_cap": sorted_cap})
+                    "sorted": sorted_chunks, "sorted_cap": sorted_cap,
+                    "laid_out": layout is not None, "run": int(run),
+                    "static_plain": static_plain})
 
             # per-group prefix digests (stage-1 grouping): groups map
             # many-to-one onto digests — groups differing only in
@@ -2458,13 +2481,15 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     hi = min(lo + nc_batch, nc)
                     # sorted chunks write cells through a PERMUTED index set:
                     # a checkpoint from an unsorted run must not resume into
-                    # them (and vice versa), so the id carries the mode.
+                    # them (and vice versa), so the id carries the mode;
+                    # a family's launch layout permutes them too (":k").
                     # Halving rungs prefix their namespace ("r2:...") so the
                     # journal, fault events and trace stay rung-addressable
                     # and supervisor bisection keys can never collide
                     # across rungs
                     chunk_id = cid_ns + f"{gi}:{lo}:{hi}" + \
-                        (":s" if sorted_chunks else "")
+                        (":s" if sorted_chunks else
+                         ":k" if plan["laid_out"] else "")
                     rec = ckpt.get(chunk_id) if ckpt is not None else None
                     if rec is not None and return_train and \
                             rec.get("train") is None:
@@ -2609,18 +2634,22 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             px_state["prefix_wall_s"] += round(
                 time.perf_counter() - t_px0, 6)
 
-        def build_programs(plan, width=None):
+        def build_programs(plan, width=None, whole_runs=True):
             """The group's jitted programs (cross-search cached); built
             on first need so fully-resumed groups never trace.  `width`
             overrides the group's uniform chunk width — the supervisor's
             OOM bisection relaunches at half width, which is a distinct
-            compiled program."""
+            compiled program.  `whole_runs` False: the launch's rows are
+            not whole runs of the group's launch layout (`made_of_runs`
+            below), so its program is built from the static without the
+            layout's facts — the one a group without a layout runs."""
             nc_batch = width or plan["nc_batch"]
+            plain = not whole_runs and plan["laid_out"]
             cache = plan.setdefault("progs_by_width", {})
-            progs = cache.get(nc_batch)
+            progs = cache.get((nc_batch, plain))
             if progs is not None:
                 return progs
-            static = plan["static"]
+            static = plan["static_plain"] if plain else plan["static"]
             donate_kw = {"donate_argnums": (0,)} if donate else {}
             # prefix-staged plans fit/score the SUFFIX family over the
             # cached per-fold matrices (data_d["X_folds"][fold]); the
@@ -2863,8 +2892,16 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                      # the raw (un-jitted) fused body: the scan program
                      # below wraps it as its lax.scan step function
                      "fused_body": fused_batch if fused_mode else None}
-            cache[nc_batch] = progs
+            cache[(nc_batch, plain)] = progs
             return progs
+
+        def made_of_runs(plan, lo, *sizes):
+            """Whether rows cut from the group's candidates at `lo` in
+            consecutive pieces of `sizes` are whole runs of its launch
+            layout (every group's own chunks are, or their width is no
+            multiple of the run and the family's launch sees that; a
+            bisected range and a fuse's seam need not be)."""
+            return all(n % plan["run"] == 0 for n in (lo,) + sizes)
 
         def build_scan(plan, n_steps, topk_k=0, hb=False):
             """ONE jitted program executing `n_steps` chunks of the
@@ -3193,7 +3230,11 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             host-side LaunchResult with exactly
             hi - lo real rows — per-lane results are bit-identical to
             the full-width launch (vmap lanes are independent), so a
-            successful recovery keeps cv_results_ exact."""
+            successful recovery keeps cv_results_ exact.  Under a launch
+            layout the lanes of a run are NOT independent (they share
+            what the family builds once a run): a range that cuts a run
+            is launched by the program without the layout's facts, and
+            its cells are those of the search without the layout."""
             group = plan["group"]
             n = hi - lo
             width = max(n_task_shards,
@@ -3203,8 +3244,10 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             def attempt():
                 dyn, w = stage_operands(plan, group.dynamic_params, lo, hi,
                                         width, "dyn.recover")
-                out = build_programs(plan, width=width)["fused"](
-                    dyn, plan_data(plan), w, *score_ops)
+                out = build_programs(
+                    plan, width=width,
+                    whole_runs=made_of_runs(plan, lo, n))["fused"](
+                        dyn, plan_data(plan), w, *score_ops)
                 out = sup.wait_ready(out, key=key, group=plan["gi"])
                 return out.to_host(n, n_folds)
 
@@ -3309,8 +3352,13 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         [np.asarray(r[k]) for r in member_rows])
                         for k in sorted(member_rows[0])},
                     0, total, width, "dyn.fuse")
-                return build_programs(plan, width=width)["fused"](
-                    dyn, plan_data(plan), w, *score_ops)
+                # a member is a whole chunk of its group: it starts on a
+                # run of the layout if it is made of whole runs, and
+                # the seams between members then fall between runs
+                return build_programs(
+                    plan, width=width, whole_runs=made_of_runs(
+                        plan, 0, *(int(s.n) for s in specs)))["fused"](
+                            dyn, plan_data(plan), w, *score_ops)
 
             # the fused width may legitimately exceed one chunk's solo
             # batch bound (that is the point of fusion); the honest

@@ -127,6 +127,9 @@ def test_every_path_reports_what_the_default_path_reports(family, path):
     if family != "multinomial":
         assert want["linesearch_second_pass_per_launch"] == [0] * n_launches
     if family == "svc_rbf":
+        # gamma is static here, so the 19 candidates are ONE run of one
+        # kernel; a chunk of 8 is not made of whole runs of 19 and builds
+        # a kernel a candidate (tests/test_svc_kernel_groups.py)
         assert want["gram_builds_per_launch"] == [8] * n_launches
         assert want["dual_subproblems_per_launch"] == \
             [8 * FOLDS * 3] * n_launches

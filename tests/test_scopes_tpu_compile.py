@@ -29,7 +29,11 @@ memory ledger's price of the launch is within a quarter of what the
 compiler allots.  Where the duals keep dense rows (binary problems, skewed
 class counts, a compiled Pipeline's per-fold kernels) the launch lowers to
 the text of the commit before the layout
-(``test_dense_svc_launches_lower_to_the_parents_text``).
+(``test_dense_svc_launches_lower_to_the_parents_text``).  Handed the
+layout's fact that its 16 candidates are 4 runs of one gamma
+(``SVCFamily.launch_layout``), the same launch scans 4 kernels, its loop
+carries ``(900, 2 x 2000)`` iterates and still ONE copy of the matrix, and
+the ledger prices that too.
 
 The minibatch perceptron's launch (``MLPClassifierFamily.fit`` under the
 engine's two ``vmap``s, the candidate axis named) is compiled at the widest
@@ -411,8 +415,7 @@ SVC_SCOPES = sorted(s for s in known_scope_names()
                     if s.startswith(("sst.svc.", "sst.box_fista.")))
 
 
-@pytest.fixture(scope="module")
-def svc_launch(topo, no_compile_cache):
+def _compiled_svc(topo, static):
     """One task-batched SVC(rbf) fit launch at the cell's shape, compiled."""
     from jax.sharding import SingleDeviceSharding
     from spark_sklearn_tpu.models.svm import SVCFamily, _pairs
@@ -424,7 +427,7 @@ def svc_launch(topo, no_compile_cache):
     meta = {"n_classes": K, "classes": np.arange(K), "n_features": D,
             "x_var": 0.0857, "pairs": _pairs(K),
             "class_counts": (SVC_N // K,) * K}
-    static = {"kernel": "rbf", "__n_folds__": FOLDS}
+    static = {"kernel": "rbf", "__n_folds__": FOLDS, **static}
     lanes = SVC_CANDIDATES * FOLDS
     compiled = jax.jit(
         lambda dyn, data, w: SVCFamily.fit_task_batched(
@@ -433,6 +436,20 @@ def svc_launch(topo, no_compile_cache):
         {"X": arg((SVC_N, D)), "y": arg((SVC_N,), jnp.int32)},
         arg((lanes, SVC_N))).compile()
     return compiled, meta
+
+
+@pytest.fixture(scope="module")
+def svc_launch(topo, no_compile_cache):
+    return _compiled_svc(topo, {})
+
+
+#: what ``SVCFamily.launch_layout`` hands the cell's launch: 4 C a gamma
+SVC_RUN = {"__kernel_run__": 4}
+
+
+@pytest.fixture(scope="module")
+def svc_grouped_launch(topo, no_compile_cache):
+    return _compiled_svc(topo, SVC_RUN)
 
 
 def test_svc_scopes_are_the_vocabularys():
@@ -472,12 +489,49 @@ def test_svc_dual_loop_carries_one_kernel_matrix(svc_launch):
                 if "f32[%d,%d]" % (FOLDS * 45, SVC_N) in state]
 
 
-def test_ledger_prices_the_svc_launch(svc_launch):
+def test_svc_grouped_launch_reads_one_matrix_for_four_candidates(
+        svc_grouped_launch):
+    """The candidates of one gamma stacked: the dual's loop carries
+    (4 x 225, 2 x 2000) iterates and ONE bfloat16 matrix, its product by
+    class is (10, 200, 2000) against (10, 2000, 20000) — one read for four
+    candidates — and the scan takes 4 steps of 4 candidates x 5 folds."""
+    text = svc_grouped_launch[0].as_text()
+    n_b = SVC_N // K
+    n_p = K * n_b
+    run = SVC_RUN["__kernel_run__"]
+    iterate = "f32[%d,%d]" % (run * FOLDS * 45, 2 * n_b)
+    states = [line.split(" while(")[0] for line in text.splitlines()
+              if " while(" in line]
+    loops = [state for state in states if iterate in state]
+    matrix = re.compile(r'\b(f32|bf16)\[(?:%d,%d|%d,%d,%d)\]'
+                        % (n_p, n_p, K, n_b, n_p))
+    # the projection's bisection (nested, no matrix) and the dual's loop
+    assert sorted(matrix.findall(loop) for loop in loops) == [[], ["bf16"]]
+    assert not [state for state in states
+                if "f32[%d,%d]" % (FOLDS * 45, 2 * n_b) in state]
+    assert "f32[%d,%d,%d]" % (K, run * K * FOLDS, n_p) in text
+    assert "f32[%d,%d,%d]" % (K, K * FOLDS, n_p) not in text
+    stacked = "f32[%d,%d,%d,45]" % (
+        SVC_CANDIDATES // run, run * FOLDS, SVC_N)
+    assert [state for state in states if stacked in state]
+    assert "f32[%d,%d,%d,45]" % (SVC_CANDIDATES, FOLDS, SVC_N) not in text
+    # the decisions go back into the caller's row order a candidate at a
+    # time: ONE gather of the stacked (900, 20000) rows read NaN and other
+    # rows' values on the chip (PERF.md, PR 34)
+    gathers = re.findall(r'= f32\[(\d+),%d\]\S* gather\(' % SVC_N, text)
+    assert gathers.count(str(FOLDS * 45)) == run
+    assert str(run * FOLDS * 45) not in gathers
+
+
+@pytest.mark.parametrize("launch", ["svc_launch", "svc_grouped_launch"])
+def test_ledger_prices_the_svc_launch(launch, request):
     """``SVCFamily.launch_workspace`` (what ``search_report["memory"]``
-    models a launch at) against the compiler's own allotment."""
+    models a launch at) against the compiler's own allotment, a kernel a
+    candidate and a kernel for the four candidates of a gamma."""
     from spark_sklearn_tpu.models.svm import SVCFamily
     from spark_sklearn_tpu.parallel.memledger import model_group_footprint
-    compiled, meta = svc_launch
+    compiled, meta = request.getfixturevalue(launch)
+    static = SVC_RUN if launch == "svc_grouped_launch" else {}
     stats = compiled.memory_analysis()
     allotted = (stats.temp_size_in_bytes + stats.argument_size_in_bytes
                 + stats.output_size_in_bytes)
@@ -486,7 +540,8 @@ def test_ledger_prices_the_svc_launch(svc_launch):
         {"C": np.zeros(SVC_CANDIDATES, np.float32),
          "gamma": np.zeros(SVC_CANDIDATES, np.float32)},
         SVC_CANDIDATES, FOLDS, task_batched=True, n_samples=SVC_N,
-        workspace=SVCFamily.launch_workspace(SVC_N, meta, FOLDS))
+        workspace=SVCFamily.launch_workspace(SVC_N, meta, FOLDS,
+                                             static=static))
     resident = SVC_N * D * 4        # X, broadcast once
     assert lanes * SVC_N * 4 == modeled["mask_bytes"]
     assert abs(modeled["chunk_bytes"] + resident - allotted) \

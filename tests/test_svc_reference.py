@@ -119,7 +119,9 @@ def test_search_report_counts_the_duals(searched):
     search = searched[0]
     rep = search.search_report
     lanes = rep["lanes_per_launch"]
-    assert rep["gram_builds_per_launch"] == [n // 5 for n in lanes]
+    # one kernel matrix for the two C of a gamma (padding included: a
+    # padded candidate repeats the last one, so pads pair up as well)
+    assert rep["gram_builds_per_launch"] == [n // 5 // 2 for n in lanes]
     assert rep["dual_subproblems_per_launch"] == [n * 3 for n in lanes]
     iters = rep["dual_iters_per_candidate"]
     assert len(iters) == 4 and min(iters) > 0
