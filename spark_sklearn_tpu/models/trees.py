@@ -1,19 +1,23 @@
 """Gradient boosting and random forest families on binned trees.
 
 Reference counterpart: sklearn's GradientBoostingRegressor and
-RandomForestClassifier running whole inside Spark tasks (BASELINE configs
-#3/#4).  Exact-CART is replaced by the histogram grower in ops/trees.py;
-the boosting/bagging layers are `lax.scan`/`vmap` programs:
+RandomForestClassifier running whole inside Spark tasks (BASELINE.json
+configs[3] and configs[2]).  Exact-CART is replaced by the histogram grower
+in ops/trees.py, whose level histograms and routing are ops/tree_hist.py's
+(grouped one-hot product kernels on a TPU, segment sums elsewhere); the
+boosting/bagging layers are `lax.while_loop`/`vmap` programs:
 
-  - GBDT: scan over trees, carry the prediction vector F on the FULL
+  - GBDT: a loop over trees, carry the prediction vector F on the FULL
     dataset (fold masks only weight the gradients), per-class trees for
     multiclass.  `n_estimators` is DYNAMIC: the program always grows the
     grid's maximum tree count and masks each tree's contribution by
     `t < n_estimators` — boosting is prefix-stable (tree t only depends on
     trees < t), so one compiled program serves every n_estimators value in
     the grid instead of one compile group per value.
-  - Random forest: `vmap` over trees (independent by construction),
-    Poisson(1) bootstrap weights (the standard streaming approximation of
+  - Random forest: a loop over trees to the lane's own `n_estimators`
+    (one tree's level histograms live at a time: 268 MB a lane at depth
+    10 and covtype's width, priced by `launch_workspace`), Poisson(1)
+    bootstrap weights (the standard streaming approximation of
     sampling with replacement), per-level random feature subsets, one-hot
     targets so the variance criterion matches gini up to scaling.
 
@@ -32,17 +36,35 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_sklearn_tpu.models.base import Family, encode_labels, register_family
-from spark_sklearn_tpu.ops.trees import Tree, grow_tree, predict_tree
+from spark_sklearn_tpu.ops import tree_hist
+from spark_sklearn_tpu.ops.trees import grow_tree
 
 N_BINS = 256
+#: of a lane's deepest level of histograms, how many the launch compiled
+#: for a v5e holds at once (the kernel's output, and the share of it that
+#: the pass over it for the gains keeps beside it), and of a row's sorted
+#: bytes (read off `memory_analysis` of the covtype cell's deepest launch:
+#: 6.57 GB for 15 lanes; PERF.md section 4)
+_HIST_COPIES = 1.45
+_ROW_COPIES = 3
 #: fixed-shape compiled growers need a static depth bound
 MAX_COMPILED_DEPTH = 10
 
 
 def _prep_codes(X, dtype):
+    """Bin edges and the rows' codes, a byte a cell: what goes to the
+    device (the grower widens a code where it computes with it)."""
     from spark_sklearn_tpu.utils.native import quantile_bin
     edges, codes = quantile_bin(np.asarray(X, np.float32), N_BINS)
-    return edges, codes.astype(np.int32)
+    return edges, np.ascontiguousarray(codes, dtype=np.uint8)
+
+
+def _own_rows(tree):
+    """A grown tree's prediction for the rows it was grown on (fit rows
+    and masked-out rows alike: every row is routed), from the node each
+    ended in."""
+    with jax.named_scope("sst.tree.predict"):
+        return tree_hist.take_rows(tree.value, tree.leaf)
 
 
 def _seed(static):
@@ -167,12 +189,13 @@ class GradientBoostingRegressorFamily(Family):
             k_t = keys[t]
             g = (F - y)[:, None]                      # d(0.5(F-y)^2)/dF
             h = jnp.ones((n,), jnp.float32)
-            w_t = train_w * (
-                jax.random.uniform(k_t, (n,)) < subsample).astype(
-                jnp.float32)
+            with jax.named_scope("sst.tree.bootstrap"):
+                w_t = train_w * (
+                    jax.random.uniform(k_t, (n,)) < subsample).astype(
+                    jnp.float32)
             tree = grow_tree(codes, g, h, w_t, depth, N_BINS,
                              min_child_weight=min_leaf, reg_lambda=1e-6)
-            delta = predict_tree(tree, codes, depth)[:, 0]
+            delta = _own_rows(tree)[:, 0]
             live = (t < n_est).astype(jnp.float32)
             return t + 1, F + lr * live * delta
 
@@ -185,6 +208,8 @@ class GradientBoostingRegressorFamily(Family):
     @classmethod
     def predict(cls, model, static, X, meta):
         # the search scores on the training X: cached full-dataset preds
+        # (every stage adds its trees' leaf values at the node each row
+        # ended in, `_own_rows`: no tree is walked a second time)
         return model["pred"]
 
     @classmethod
@@ -242,9 +267,10 @@ class GradientBoostingClassifierFamily(GradientBoostingRegressorFamily):
             t, F = carry
             k_t = keys[t]
             P = jax.nn.softmax(F, axis=1)
-            w_t = train_w * (
-                jax.random.uniform(k_t, (n,)) < subsample).astype(
-                jnp.float32)
+            with jax.named_scope("sst.tree.bootstrap"):
+                w_t = train_w * (
+                    jax.random.uniform(k_t, (n,)) < subsample).astype(
+                    jnp.float32)
 
             def per_class(g_c, h_c):
                 return grow_tree(codes, g_c[:, None], h_c, w_t, depth,
@@ -254,9 +280,8 @@ class GradientBoostingClassifierFamily(GradientBoostingRegressorFamily):
             G = (P - y1h)                              # (n, k)
             H = P * (1.0 - P)                          # (n, k)
             trees_k = jax.vmap(per_class, in_axes=(1, 1))(G, H)
-            delta = jax.vmap(
-                lambda tr: predict_tree(tr, codes, depth)[:, 0],
-                in_axes=0, out_axes=1)(trees_k)        # (n, k)
+            delta = jax.vmap(lambda tr: _own_rows(tr)[:, 0],
+                             in_axes=0, out_axes=1)(trees_k)   # (n, k)
             live = (t < n_est).astype(jnp.float32)
             return t + 1, F + lr * live * delta
 
@@ -360,19 +385,24 @@ class RandomForestClassifierFamily(Family):
         def one_tree(carry):
             ti, acc = carry
             k_t = keys[ti]
-            if bootstrap:
-                w_t = train_w * jax.random.poisson(
-                    k_t, 1.0, (n,)).astype(jnp.float32)
-            else:
-                w_t = train_w
+            with jax.named_scope("sst.tree.bootstrap"):
+                if bootstrap:
+                    w_t = train_w * jax.random.poisson(
+                        k_t, 1.0, (n,)).astype(jnp.float32)
+                else:
+                    w_t = train_w
+                # a lane past its own count is carried along by the
+                # launch's lockstep loop: its tree counts no row
+                w_t = jnp.where(ti < n_est, w_t, 0.0)
             # squared loss from F=0: grad = -target, hess = 1 -> leaf
             # value = weighted mean target (class distribution / mean y)
             tree = grow_tree(codes, -t, jnp.ones((n,), jnp.float32), w_t,
                              depth, N_BINS, min_child_weight=min_leaf,
                              reg_lambda=1e-9,
                              feat_mask_key=jax.random.fold_in(k_t, 7),
-                             max_features=mf, n_out=n_out)
-            pred = predict_tree(tree, codes, depth)     # (n, n_out)
+                             max_features=mf, n_out=n_out,
+                             integer_stats=cls._integer_stats(meta))
+            pred = _own_rows(tree)                      # (n, n_out)
             live = (ti < n_est).astype(jnp.float32)
             return ti + 1, acc + live * pred
 
@@ -389,6 +419,61 @@ class RandomForestClassifierFamily(Family):
     def _finalize(cls, avg):
         return {"proba": avg,
                 "pred": jnp.argmax(avg, axis=1).astype(jnp.int32)}
+
+    @classmethod
+    def _integer_stats(cls, meta):
+        """Whether a row's statistics are small integers: the fold's 0/1
+        mask (no sample_weight: the engine's word) times a bootstrap
+        count, times a one-hot class."""
+        return bool(meta.get("unit_fit_weights", False))
+
+    @classmethod
+    def _n_stats(cls, meta):
+        """Statistics a row carries into a histogram: its hessian and a
+        gradient an output (a class of the one-hot target, or y)."""
+        return 1 + int(meta.get("n_classes", 1))
+
+    @classmethod
+    def launch_stats(cls, models, static, meta):
+        """The default's lockstep trees (maximum and sum over lanes),
+        each task's own, and what the launch executed: every lane is
+        carried through the launch's largest tree count, a level at a
+        time."""
+        stats = super().launch_stats(models, static, meta)
+        trees = models["n_iter"].astype(jnp.int32).reshape(-1)
+        slots = jnp.max(trees) * trees.size
+        stats["trees"] = trees
+        stats["tree_slots"] = slots
+        stats["tree_levels"] = slots * _depth(static, cls._default_depth)
+        return stats
+
+    @classmethod
+    def _hist_bytes(cls, static, meta):
+        """Bytes of one lane's deepest level of histograms."""
+        return tree_hist.level_histogram_bytes(
+            _depth(static, cls._default_depth), meta["n_features"],
+            cls._n_stats(meta), N_BINS)
+
+    @classmethod
+    def launch_facts(cls, static, meta, n_candidates, n_folds):
+        return {"hist_bytes": cls._hist_bytes(static, meta)}
+
+    @classmethod
+    def launch_workspace(cls, n_samples, meta, n_folds, itemsize=4, *,
+                         static, row_sets=1):
+        """What a launch holds besides its arguments, for the memory
+        ledger.  A lane (candidates x folds of them): `_HIST_COPIES` of
+        its deepest level's histograms (the kernel's blocks and the
+        gains' share beside them), and by row the vote accumulator with a
+        tree's leaf distributions, the statistics, and the level's sorted
+        copy of codes and statistics (`_ROW_COPIES` of a row's bytes)."""
+        n_stats = cls._n_stats(meta)
+        row = (3 * n_stats * 4                 # votes, leaf values, stats
+               + _ROW_COPIES * tree_hist.row_bytes(
+                   meta["n_features"], n_stats, cls._integer_stats(meta)))
+        lane = int(_HIST_COPIES * cls._hist_bytes(static, meta)
+                   + int(n_samples) * row)
+        return {"fixed_bytes": 0, "per_candidate_bytes": n_folds * lane}
 
     @classmethod
     def predict(cls, model, static, X, meta):
@@ -437,6 +522,10 @@ class RandomForestRegressorFamily(RandomForestClassifierFamily):
     @classmethod
     def _targets(cls, data):
         return data["y_target"]
+
+    @classmethod
+    def _integer_stats(cls, meta):
+        return False             # a row's gradient is its target
 
     @classmethod
     def _finalize(cls, avg):

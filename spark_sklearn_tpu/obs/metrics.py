@@ -203,6 +203,37 @@ SEARCH_REPORT_SCHEMA = (
         "from a checkpoint or fitted on the host.",
         stat="epochs", combine="per_candidate"),
     MetricDef(
+        "tree_slots_per_launch", "series",
+        "Per launch of a forest family (RandomForestClassifier, "
+        "RandomForestRegressor): trees the launch executed, its lanes "
+        "(padding included) x the largest n_estimators among them: "
+        "every lane is carried through the launch's lockstep loop, so "
+        "the slots past a lane's own count are spent on a lane already "
+        "done.",
+        stat="tree_slots", combine="sum"),
+    MetricDef(
+        "tree_levels_per_launch", "series",
+        "Per launch of a forest family: tree levels the launch "
+        "executed, tree_slots_per_launch x the group's compiled depth "
+        "(a level = one partition, one histogram pass, one split and "
+        "one routing of every lane).",
+        stat="tree_levels", combine="sum"),
+    MetricDef(
+        "hist_bytes_per_lane", "series",
+        "Per launch of a forest family: bytes of one lane's deepest "
+        "level of (node, feature, statistic, bin) float32 histograms "
+        "as the launch writes them: 2^(depth - 1) nodes x features x "
+        "(1 + outputs) x 256 x 4, features and statistics padded to "
+        "the kernel's blocks on a TPU.",
+        stat="hist_bytes", combine="fact"),
+    MetricDef(
+        "trees_per_candidate", "series",
+        "Forest families: trees each candidate grew (its own "
+        "n_estimators, capped at the grid's largest), in cv_results_ "
+        "order.  -1: the candidate was restored from a checkpoint or "
+        "fitted on the host.",
+        stat="trees", combine="per_candidate"),
+    MetricDef(
         "padding_waste", "histogram",
         "Per-launch fraction of computed lanes that were padding "
         "(chunk tail repeated to the group's uniform width) — the "

@@ -400,6 +400,36 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
             "epoch's loss or validation score, and sklearn's stopping "
             "rules.",
             layer="solvers"),
+    SpanDef("sst.tree.bootstrap", "scope", "models.trees",
+            "Once a tree (or boosting stage): the rows' weights, the "
+            "fold's mask times the forest's Poisson(1) bootstrap counts "
+            "or the boosters' subsample draw.",
+            layer="solvers"),
+    SpanDef("sst.tree.partition", "scope", "ops.tree_hist",
+            "A tree level on a TPU: the counted rows sorted into node "
+            "order, the items (tile, node, first and last row) of the "
+            "level, and the sorted copy of codes and statistics.",
+            layer="solvers"),
+    SpanDef("sst.tree.histogram", "scope", "ops.tree_hist",
+            "A tree level's (node, feature, bin) histograms: the "
+            "grouped one-hot product kernel on a TPU, segment sums "
+            "elsewhere.",
+            layer="solvers"),
+    SpanDef("sst.tree.split", "scope", "ops.trees",
+            "A tree level: cumulative sums over bins, the gains, the "
+            "nodes' feature subsets, each node's best (feature, bin) "
+            "and its children's sums; at the tree's end the leaf "
+            "values.",
+            layer="solvers"),
+    SpanDef("sst.tree.route", "scope", "ops.trees",
+            "A tree level: every row that is not in a leaf to the left "
+            "or right child of its node.",
+            layer="solvers"),
+    SpanDef("sst.tree.predict", "scope", "models.trees",
+            "Once a tree: its leaf values at the node each row ended "
+            "in, added to the votes (forests) or the staged "
+            "predictions (boosting).",
+            layer="solvers"),
     SpanDef("sst.prefix.transform", "scope", "models.pipeline",
             "PipelineFamily.prefix_transform: the transformer chain "
             "fitted on each fold's training rows and applied to all "

@@ -8,11 +8,12 @@ all segment-sums and cumulative sums over fixed shapes:
 
   - features are pre-binned host-side to uint8 codes (native quantile_bin,
     see native/tpusk_native.cpp);
-  - a tree grows level-by-level (static python loop over max_depth): one
-    `segment_sum` builds the (node, feature, bin) gradient/hessian
-    histograms for the whole level at once, a cumsum turns them into
-    left/right split statistics, and the best (feature, bin) per node is an
-    argmax — no per-node control flow;
+  - a tree grows level-by-level (static python loop over max_depth): the
+    (node, feature, bin) gradient/hessian histograms of the whole level,
+    cumulative over the bins, are the left/right split statistics
+    (ops/tree_hist.py: a grouped one-hot product kernel on a TPU, one
+    `segment_sum` a statistic and a cumsum elsewhere), and the best
+    (feature, bin) per node is an argmax — no per-node control flow;
   - nodes live in a heap-indexed array (children of i at 2i+1/2i+2) so the
     tree is a pytree of fixed arrays: feat, thresh_bin, leaf flag, value.
 
@@ -23,12 +24,12 @@ one-hot-target trick approximates gini for classification forests.
 
 from __future__ import annotations
 
-from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from spark_sklearn_tpu.ops import tree_hist
 
 
 class Tree(NamedTuple):
@@ -37,127 +38,121 @@ class Tree(NamedTuple):
                              # code <= thresh)
     value: jnp.ndarray       # (max_nodes, n_out) leaf values
     is_leaf: jnp.ndarray     # (max_nodes,) bool
+    leaf: jnp.ndarray        # (n,) int32: the node each grown-on row ends
+                             # in, so value[leaf] is the tree's prediction
+                             # for its own rows without a second walk
 
 
 def grow_tree(codes, g, h, w, max_depth, n_bins, min_child_weight=1e-3,
               reg_lambda=1.0, feat_mask_key=None, max_features=None,
-              n_out=1):
+              n_out=1, integer_stats=False):
     """Grow one tree on binned features.
 
-    codes: (n, d) int32 bin codes.  g/h: (n, n_out)/(n,) gradient & hessian
-    per sample (hessian shared across outputs).  w: (n,) sample weights
-    (0 excludes — CV fold masks and bootstrap weights both enter here).
-    Returns a Tree whose value column holds the Newton leaf step per output.
+    codes: (n, d) uint8 (or int32) bin codes.  g/h: (n, n_out)/(n,)
+    gradient & hessian per sample (hessian shared across outputs).  w: (n,)
+    sample weights (0 excludes — CV fold masks and bootstrap weights both
+    enter here).  `integer_stats`: the caller's word that every g * w and
+    h * w is an integer of at most 256 in size (a forest's bootstrap count
+    x one-hot class), which one bfloat16 part holds exactly.  Returns a
+    Tree whose value column holds the Newton leaf step per output.
+
+    A level's cumulative (node, feature, bin) histograms and the rows'
+    way down come from ``ops/tree_hist.py`` (the grouped kernels on a TPU,
+    the plain ``segment_sum`` form elsewhere); every node's sums, and with
+    them the leaf values, are read off the histograms (the root's totals,
+    then each split's left and right side), so that no row is scattered.
     """
-    n, d = codes.shape
-    max_nodes = 2 ** (max_depth + 1) - 1
-    n_level_max = 2 ** max_depth
+    d = codes.shape[1]
+    n_last = 2 ** max_depth                   # nodes below the last level
+    feat, thresh, is_leaf = [], [], []        # a level's (nodes,) each
 
-    feat = jnp.full((max_nodes,), -1, jnp.int32)
-    thresh = jnp.zeros((max_nodes,), jnp.int32)
-    is_leaf = jnp.zeros((max_nodes,), bool)
-
-    gw = g * w[:, None]                       # (n, n_out)
-    hw = h * w                                # (n,)
-    node = jnp.zeros((n,), jnp.int32)         # current node per sample
-    frozen = jnp.zeros((n,), bool)            # sample sits in a leaf
+    # the statistics of a row: its hessian, then a gradient an output
+    stats = jnp.concatenate([(h * w)[:, None], g * w[:, None]],
+                            axis=1).astype(jnp.float32)   # (n, 1 + n_out)
+    rows = tree_hist.levels_of(codes, stats, n_bins, integer_stats)
+    sums = []                                 # a level's (nodes, 1 + n_out)
 
     for level in range(max_depth):
         n_nodes = 2 ** level
-        offset = n_nodes - 1
-        local = node - offset                 # (n,) 0..n_nodes-1
+        # (nodes, d, 1 + n_out, bins), or wider in features and
+        # statistics where the kernel pads them: the padding reads zero,
+        # so no row is on either side of a split there
+        cum = rows.histograms(level)
+        d_wide = cum.shape[1]
 
-        # (node, feature, bin) histograms in one segment-sum per stat
-        ids = (local[:, None] * d + jnp.arange(d, dtype=jnp.int32)[None, :]
-               ) * n_bins + codes             # (n, d)
-        ids = jnp.where(frozen[:, None], 0, ids)
-        num_seg = n_nodes * d * n_bins
-        live = jnp.logical_not(frozen)
+        with jax.named_scope("sst.tree.split"):
+            # a node's sums are known before its histograms: the root's
+            # from its own last bin, a child's from its parent's split
+            if level == 0:
+                sums.append(cum[:, 0, :1 + n_out, -1])
+            whole = jnp.pad(
+                sums[-1], ((0, 0), (0, cum.shape[2] - 1 - n_out))
+            )[:, None, :, None]                             # (nodes,1,S,1)
+            # every statistic's left, right and whole in one pass over
+            # the histograms (a statistic is a row of every (8, bins)
+            # tile: a slice a statistic would walk the array each time)
+            rest = whole - cum
+            left_h, tot_h = cum[:, :, :1, :], whole[:, :, :1, :]
+            right_h = tot_h - left_h
+            terms = (cum ** 2 / (left_h + reg_lambda)
+                     + rest ** 2 / (right_h + reg_lambda)
+                     - whole ** 2 / (tot_h + reg_lambda))
+            # gain summed over outputs (multi-output = one-hot targets:
+            # the sum is the full variance-reduction criterion, not just
+            # class 0's), in their order; row 0 is the hessian's own
+            stat = jnp.arange(cum.shape[2])
+            is_output = (stat >= 1) & (stat <= n_out)
+            gain = jnp.sum(
+                jnp.where(is_output[None, None, :, None], terms, 0.0),
+                axis=2)
+            left_h, right_h = left_h[:, :, 0, :], right_h[:, :, 0, :]
+            ok = (left_h >= min_child_weight) & (right_h >= min_child_weight)
+            gain = jnp.where(ok, gain, -jnp.inf)
+            # never split on the last bin (empty right side by
+            # construction)
+            gain = gain.at[..., -1].set(-jnp.inf)
 
-        def hist(v):                          # v: (n,)
-            vals = jnp.where(live, v, 0.0)
-            flat = jnp.broadcast_to(vals[:, None], (n, d)).reshape(-1)
-            return jax.ops.segment_sum(
-                flat, ids.reshape(-1), num_segments=num_seg
-            ).reshape(n_nodes, d, n_bins)
+            if feat_mask_key is not None and max_features is not None and \
+                    max_features < d:
+                # per-(node) random feature subset, fresh every level — the
+                # forest analog of sklearn's per-split max_features
+                k_lvl = jax.random.fold_in(feat_mask_key, level)
+                scores = jax.random.uniform(k_lvl, (n_nodes, d))
+                kth = jnp.sort(scores, axis=1)[:, max_features - 1][:, None]
+                fmask = jnp.pad(scores <= kth, ((0, 0), (0, d_wide - d)))
+                gain = jnp.where(fmask[:, :, None], gain, -jnp.inf)
 
-        Hh = hist(hw)                                       # hessians
-        cum_h = jnp.cumsum(Hh, axis=2)
-        tot_h = cum_h[..., -1:]
-        left_h = cum_h
-        right_h = tot_h - left_h
+            flat_gain = gain.reshape(n_nodes, d_wide * n_bins)
+            best = jnp.argmax(flat_gain, axis=1)            # (n_nodes,)
+            best_gain = jnp.take_along_axis(
+                flat_gain, best[:, None], axis=1)[:, 0]
+            bf = (best // n_bins).astype(jnp.int32)
+            bb = (best % n_bins).astype(jnp.int32)
+            do_split = best_gain > 1e-7
 
-        # gain summed over outputs (multi-output = one-hot targets: the sum
-        # is the full variance-reduction criterion, not just class 0's)
-        gain = jnp.zeros_like(cum_h)
-        for o in range(n_out):
-            cum_g = jnp.cumsum(hist(gw[:, o]), axis=2)
-            tot_g = cum_g[..., -1:]
-            left_g = cum_g
-            right_g = tot_g - left_g
-            gain = gain + (left_g ** 2 / (left_h + reg_lambda)
-                           + right_g ** 2 / (right_h + reg_lambda)
-                           - tot_g ** 2 / (tot_h + reg_lambda))
-        ok = (left_h >= min_child_weight) & (right_h >= min_child_weight)
-        gain = jnp.where(ok, gain, -jnp.inf)
-        # never split on the last bin (empty right side by construction)
-        gain = gain.at[..., -1].set(-jnp.inf)
+            feat.append(jnp.where(do_split, bf, -1))
+            thresh.append(bb)
+            is_leaf.append(jnp.logical_not(do_split))
 
-        if feat_mask_key is not None and max_features is not None and \
-                max_features < d:
-            # per-(node) random feature subset, fresh every level — the
-            # forest analog of sklearn's per-split max_features
-            k_lvl = jax.random.fold_in(feat_mask_key, level)
-            scores = jax.random.uniform(k_lvl, (n_nodes, d))
-            kth = jnp.sort(scores, axis=1)[:, max_features - 1][:, None]
-            fmask = scores <= kth
-            gain = jnp.where(fmask[:, :, None], gain, -jnp.inf)
+            # the sums of the children a split makes: left at the chosen
+            # (feature, bin), right the rest of the node's
+            total = sums[-1]
+            left = jnp.take_along_axis(
+                jnp.take_along_axis(
+                    cum, bf[:, None, None, None], axis=1)[:, 0],
+                bb[:, None, None], axis=2)[:, :1 + n_out, 0]  # (nodes, S)
+            sums.append(jnp.stack([left, total - left], axis=1).reshape(
+                2 * n_nodes, -1))
 
-        flat_gain = gain.reshape(n_nodes, d * n_bins)
-        best = jnp.argmax(flat_gain, axis=1)                # (n_nodes,)
-        best_gain = jnp.take_along_axis(
-            flat_gain, best[:, None], axis=1)[:, 0]
-        bf = (best // n_bins).astype(jnp.int32)
-        bb = (best % n_bins).astype(jnp.int32)
-        do_split = best_gain > 1e-7
+        rows.route(level, bf, bb, do_split)
 
-        node_ids = offset + jnp.arange(n_nodes)
-        feat = feat.at[node_ids].set(jnp.where(do_split, bf, -1))
-        thresh = thresh.at[node_ids].set(bb)
-        is_leaf = is_leaf.at[node_ids].set(jnp.logical_not(do_split))
-
-        # route samples
-        nf = bf[local]                         # (n,) feature per sample
-        code_at = jnp.take_along_axis(codes, nf[:, None], axis=1)[:, 0]
-        go_right = code_at > bb[local]
-        splitting = do_split[local] & jnp.logical_not(frozen)
-        node = jnp.where(splitting,
-                         2 * node + 1 + go_right.astype(jnp.int32), node)
-        frozen = frozen | jnp.logical_not(do_split[local])
-
-    # everything still unfrozen at the last level is a leaf
-    is_leaf = is_leaf.at[node].set(True)
-
-    # leaf values: Newton step per output, aggregated at the final node ids
-    sum_h = jax.ops.segment_sum(hw, node, num_segments=max_nodes)
-    value = []
-    for o in range(n_out):
-        sum_g = jax.ops.segment_sum(gw[:, o], node, num_segments=max_nodes)
-        value.append(-sum_g / (sum_h + reg_lambda))
-    value = jnp.stack(value, axis=1)           # (max_nodes, n_out)
-    return Tree(feat=feat, thresh=thresh, value=value, is_leaf=is_leaf)
-
-
-def predict_tree(tree: Tree, codes, max_depth):
-    """(n, d) codes -> (n, n_out) leaf values (vectorised level walk)."""
-    n = codes.shape[0]
-    node = jnp.zeros((n,), jnp.int32)
-    for _ in range(max_depth):
-        f = tree.feat[node]
-        stop = tree.is_leaf[node] | (f < 0)
-        code_at = jnp.take_along_axis(
-            codes, jnp.maximum(f, 0)[:, None], axis=1)[:, 0]
-        go_right = code_at > tree.thresh[node]
-        nxt = 2 * node + 1 + go_right.astype(jnp.int32)
-        node = jnp.where(stop, node, nxt)
-    return tree.value[node]                    # (n, n_out)
+    with jax.named_scope("sst.tree.split"):
+        # whatever sits below the last level is a leaf
+        feat = jnp.concatenate(feat + [jnp.full((n_last,), -1, jnp.int32)])
+        thresh = jnp.concatenate(thresh + [jnp.zeros((n_last,), jnp.int32)])
+        is_leaf = jnp.concatenate(is_leaf + [jnp.ones((n_last,), bool)])
+        # leaf values: Newton step per output from the node's sums
+        node_sums = jnp.concatenate(sums, axis=0)           # (max_nodes, S)
+        value = -node_sums[:, 1:] / (node_sums[:, :1] + reg_lambda)
+    return Tree(feat=feat, thresh=thresh, value=value, is_leaf=is_leaf,
+                leaf=rows.leaves())

@@ -1263,6 +1263,9 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             # observe_candidates, so both backends raise on the same grids
             meta["min_fold_train_count"] = int(
                 np.sum(train_masks > 0, axis=1).min())
+            # ... and families whose arithmetic is exact on 0/1 masks (the
+            # forests' integer histograms) that no sample_weight scales them
+            meta["unit_fit_weights"] = fit_weight is None
             n_folds = len(splits)
             n_cand = len(candidates)
             return_train = self.return_train_score

@@ -44,6 +44,15 @@ step loop carrying the weights and Adam's two moments and nothing else of
 that size, one gather of minibatch rows a fold, and the ledger's price
 within a quarter of the compiler's allotment.
 
+The forest's launch (``RandomForestClassifierFamily.fit`` under the
+engine's two ``vmap``s) is compiled at the deepest group of the
+``forest_covtype145k.depth3_trees3`` cell — 3 n_estimators x 5 folds of
+max_depth 10 on 145 253 x 54 — with the grower on the kernels' form: both
+Mosaic kernels compile for the chip at these widths, every ``sst.tree.*``
+scope names device operations, rows are gathered at two levels of ten and
+scattered at none, and the ledger's price is within a quarter of the
+compiler's allotment.
+
 Nothing runs on a device here and nothing is timed.  The topology is
 described inside a fixture (never at import time: only one process may
 load the TPU's library), and where it cannot be described the tests skip.
@@ -663,6 +672,122 @@ def test_ledger_prices_the_mlp_launch(mlp_pair):
             static={f"mlp__{k}": v for k, v in static.items()}))
     # a lane's weights are megabytes: 795 010 parameters, 8 copies
     assert modeled["per_candidate_bytes"] > FOLDS * 8 * 795_010 * 4
+    assert abs(modeled["chunk_bytes"] - allotted) < 0.25 * allotted
+    # an eighth of the chip and more: the cell's size (PERF.md section 4)
+    assert allotted > 0.125 * 16.909e9
+
+
+# --- the forest's launch -----------------------------------------------------
+
+TREE_N, TREE_D, TREE_K, TREE_CANDIDATES, TREE_DEPTH = 145_253, 54, 7, 3, 10
+TREE_SCOPES = sorted(s for s in known_scope_names()
+                     if s.startswith("sst.tree."))
+
+
+def _compiled_forest(one_chip):
+    """The per-task RandomForestClassifier fit launch as the engine vmaps
+    it (candidates, named; folds) at the deepest group of
+    ``forest_covtype145k.depth3_trees3``: 3 n_estimators x 5 folds of
+    max_depth 10 on 145 253 x 54, seven classes, the grower on the
+    kernels' form (the platform's choice on a TPU)."""
+    from spark_sklearn_tpu.models.base import CANDIDATE_AXIS
+    from spark_sklearn_tpu.models.trees import RandomForestClassifierFamily
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    meta = {"n_classes": TREE_K, "classes": np.arange(TREE_K),
+            "n_features": TREE_D, "max_estimators": 40,
+            "unit_fit_weights": True}
+    static = {"max_depth": TREE_DEPTH, "random_state": 0}
+
+    def launch(n_estimators, codes, y, y1h, w):
+        def one_cand(count):
+            def one_fold(wf):
+                return RandomForestClassifierFamily.fit(
+                    {"n_estimators": count}, static,
+                    {"codes": codes, "y": y, "y1h": y1h}, wf, meta)
+            return jax.vmap(one_fold)(w)
+        with jax.named_scope("sst.fit"):
+            return jax.vmap(one_cand, axis_name=CANDIDATE_AXIS)(
+                n_estimators)
+
+    return jax.jit(launch).lower(
+        arg((TREE_CANDIDATES,), jnp.int32),
+        arg((TREE_N, TREE_D), jnp.uint8), arg((TREE_N,), jnp.int32),
+        arg((TREE_N, TREE_K)), arg((FOLDS, TREE_N))).compile(), meta, static
+
+
+@pytest.fixture(scope="module")
+def forest_launch(topo, no_compile_cache):
+    from jax.sharding import SingleDeviceSharding
+    from spark_sklearn_tpu.ops import tree_hist
+    real = tree_hist.on_tpu
+    tree_hist.on_tpu = lambda: True     # the platform compiled for
+    try:
+        return _compiled_forest(SingleDeviceSharding(topo.devices[0]))
+    finally:
+        tree_hist.on_tpu = real
+
+
+def test_tree_scopes_are_the_vocabularys():
+    assert TREE_SCOPES == [
+        "sst.tree.bootstrap", "sst.tree.histogram", "sst.tree.partition",
+        "sst.tree.predict", "sst.tree.route", "sst.tree.split"]
+
+
+@pytest.mark.parametrize("scope", TREE_SCOPES)
+def test_tree_compiled_op_names_carry_scope(forest_launch, scope):
+    text = forest_launch[0].as_text()
+    assert re.search(r'op_name="[^"]*/' + re.escape(scope) + r'[/"]', text)
+
+
+def test_forest_launch_is_two_kernels_a_level_and_two_sorts_a_tree(
+        forest_launch):
+    """The Mosaic kernels compile for the chip at the cell's widths (a
+    histogram and a routing call a level, the 15 lanes a grid axis of
+    each), the rows are gathered into node order at levels 0 and 5 only,
+    and no level scatters."""
+    text = forest_launch[0].as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"', text)
+    assert len(calls) == 2 * TREE_DEPTH
+    lanes = TREE_CANDIDATES * FOLDS
+    for level in (0, TREE_DEPTH - 1):
+        assert re.search(r'f32\[%d,%d,64,8,256\]\S* custom-call\(' % (
+            lanes, 2 ** level), text)
+    assert " scatter(" not in text
+    gathered = re.findall(r'= s32\[%d,(\d+)\]\S* gather\(' % (
+        lanes * TREE_N), text)
+    # the codes' 16 words and the statistics' 4, at each of the two sorts,
+    # every lane's rows by one gather of one table
+    assert sorted(gathered) == ["16", "16", "4", "4"]
+
+
+def test_ledger_prices_the_forest_launch(forest_launch):
+    """``RandomForestClassifierFamily.launch_workspace`` (what
+    ``search_report["memory"]`` models the cell's deepest group at)
+    against the compiler's own allotment."""
+    from spark_sklearn_tpu.models.trees import RandomForestClassifierFamily
+    from spark_sklearn_tpu.ops import tree_hist
+    from spark_sklearn_tpu.parallel.memledger import model_group_footprint
+    compiled, meta, static = forest_launch
+    stats = compiled.memory_analysis()
+    allotted = (stats.temp_size_in_bytes + stats.argument_size_in_bytes
+                + stats.output_size_in_bytes)
+    real = tree_hist.on_tpu
+    tree_hist.on_tpu = lambda: True
+    try:
+        workspace = RandomForestClassifierFamily.launch_workspace(
+            TREE_N, meta, FOLDS, static=static)
+    finally:
+        tree_hist.on_tpu = real
+    modeled = model_group_footprint(
+        {"n_estimators": np.zeros(TREE_CANDIDATES, np.int32)},
+        TREE_CANDIDATES, FOLDS, task_batched=False, n_samples=TREE_N,
+        workspace=workspace)
+    # a lane's histograms are hundreds of megabytes: 512 nodes x 64 x 8 x
+    # 256 floats at level 9, and the gains beside them
+    assert modeled["per_candidate_bytes"] > FOLDS * 1.4 * 268_435_456
     assert abs(modeled["chunk_bytes"] - allotted) < 0.25 * allotted
     # an eighth of the chip and more: the cell's size (PERF.md section 4)
     assert allotted > 0.125 * 16.909e9

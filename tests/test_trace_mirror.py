@@ -280,9 +280,39 @@ def lowered_mlp_launch():
         {"X": X}, np.ones((3, len(X)), np.float32)).as_text(debug_info=True)
 
 
+#: the scopes of the binned tree grower
+TREE_SCOPES = [s for s in SCOPES if s.startswith("sst.tree.")]
+
+
+@pytest.fixture(scope="module")
+def lowered_tree_launch():
+    """A forest search with the grower on the kernels' form (interpreted:
+    XLA:CPU runs the plain form, which sorts no rows and so opens no
+    ``sst.tree.partition``)."""
+    import functools
+    from sklearn.ensemble import RandomForestClassifier
+    from spark_sklearn_tpu.ops import tree_hist
+
+    def search():
+        X, y = _problem(n=90, d=5)
+        sst.GridSearchCV(
+            RandomForestClassifier(max_depth=2, random_state=0),
+            {"n_estimators": list(range(1, 21))}, cv=3, refit=False,
+            backend="tpu",
+            config=sst.TpuConfig(max_tasks_per_batch=3)).fit(X, y)
+    real = tree_hist.levels_of
+    tree_hist.levels_of = functools.partial(
+        tree_hist.GroupedLevels, tile=128, interpret=True)
+    try:
+        return _lowered_launches(search)
+    finally:
+        tree_hist.levels_of = real
+
+
 def _launch_fixture(scope):
     return ("lowered_dual_launch" if scope in DUAL_SCOPES else
             "lowered_mlp_launch" if scope in MLP_SCOPES else
+            "lowered_tree_launch" if scope in TREE_SCOPES else
             "lowered_launch")
 
 
