@@ -326,6 +326,11 @@ class BinnedInvariantPipelineFamily:
             f"{final_name}__{k}": v
             for k, v in final_family.dynamic_params.items()
         }
+        if hasattr(final_family, "fit_task_batched"):
+            # a forest's launch shares its trees across the candidates
+            # (models/trees.py): the codes are the bare family's, so is
+            # the launch
+            self.fit_task_batched = self._fit_task_batched
 
     def has_per_task_fit(self) -> bool:
         return True
@@ -354,6 +359,12 @@ class BinnedInvariantPipelineFamily:
     def fit(self, dynamic, static, data, train_w, meta):
         return self.final.fit(self._strip(dynamic), self._strip(static),
                               data, train_w, meta)
+
+    def _fit_task_batched(self, dynamic, static, data, train_w, meta):
+        return self.final.fit_task_batched(
+            self._strip(dynamic),
+            {**self._strip(static), "__n_folds__": static["__n_folds__"]},
+            data, train_w, meta)
 
     def predict(self, model, static, X, meta):
         return self.final.predict(model, self._strip(static), X, meta)

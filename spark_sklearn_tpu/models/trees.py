@@ -14,12 +14,35 @@ boosting/bagging layers are `lax.while_loop`/`vmap` programs:
     `t < n_estimators` — boosting is prefix-stable (tree t only depends on
     trees < t), so one compiled program serves every n_estimators value in
     the grid instead of one compile group per value.
-  - Random forest: a loop over trees to the lane's own `n_estimators`
-    (one tree's level histograms live at a time: 268 MB a lane at depth
-    10 and covtype's width, priced by `launch_workspace`), Poisson(1)
-    bootstrap weights (the standard streaming approximation of
-    sampling with replacement), per-level random feature subsets, one-hot
-    targets so the variance criterion matches gini up to scaling.
+  - Random forest: ONE forest a fold, grown once a launch and read at every
+    `n_estimators` of the launch's candidates (`fit_task_batched`; PR 36).
+    `fit` draws `keys = split(PRNGKey(random_state), t_max)` with `t_max`
+    the GRID's largest count, and tree `ti` is made from `keys[ti]` and
+    its fold's mask alone: Poisson(1) bootstrap weights (the standard
+    streaming approximation of sampling with replacement) from `keys[ti]`,
+    per-level random feature subsets from `fold_in(keys[ti], 7)`, one-hot
+    targets so the variance criterion matches gini up to scaling.  So tree
+    `ti` of a fold is bit for bit the same tree whatever count it is grown
+    for, the forest of a smaller count is the first trees of a larger
+    one's (scikit-learn's own forests nest the same way: tree t's seed is
+    the t-th draw of the forest's RNG), and a candidate's votes are the
+    float32 sum `acc + 1.0 * pred` over its own first trees in the order
+    it always had.  A compile group differs in `n_estimators` alone, so
+    any chunk of it may share: the launch runs a `while_loop` to the
+    largest count among its candidates over lanes = folds (one tree's
+    level histograms a fold live at a time: 268 MB at depth 10 and
+    covtype's width, priced by `launch_workspace` whatever the width), and
+    keeps a vote accumulator a candidate and fold.  Grading a group's
+    launches by tree count would only regrow the shared first trees, so
+    the forest families have no `convergence_proxy`: a group is one launch
+    wherever memory allows (the boosters keep theirs).  `fit` itself is
+    that loop for one fold, read at one count or at a vector of them: the
+    direct call and the launch run the same body.  A task axis sharded
+    over devices hands XLA's partitioner candidate-major tasks and their
+    tiled masks; on the suite's 8-device mesh it grows the forests
+    partitioned by fold and keeps the votes by candidate, bit-equal to one
+    device.  `BinnedInvariantPipelineFamily` (monotone scalers + forest)
+    forwards `fit_task_batched`: its launch is the bare forest's.
 
 Known deviations from sklearn (accuracy-level parity, tested):
   256-bin quantile splits instead of exact; Poisson bootstrap;
@@ -40,13 +63,18 @@ from spark_sklearn_tpu.ops import tree_hist
 from spark_sklearn_tpu.ops.trees import grow_tree
 
 N_BINS = 256
-#: of a lane's deepest level of histograms, how many the launch compiled
+#: of a forest's deepest level of histograms, how many the launch compiled
 #: for a v5e holds at once (the kernel's output, and the share of it that
 #: the pass over it for the gains keeps beside it), and of a row's sorted
-#: bytes (read off `memory_analysis` of the covtype cell's deepest launch:
-#: 6.57 GB for 15 lanes; PERF.md section 4)
-_HIST_COPIES = 1.45
-_ROW_COPIES = 3
+#: bytes (the tables the sorts gather from, the gathered, padded and
+#: transposed copies); read off `memory_analysis` of the covtype cell's
+#: launches, 5 forests x 3 candidates: 2.04 GB of scratch at depth 10,
+#: 0.63 GB at depth 8 and at depth 6 alike (PERF.md section 4)
+_HIST_COPIES = 1.4
+_ROW_COPIES = 5
+#: copies of a candidate's votes: the loop's carry and its update, the
+#: average, the model's output
+_VOTE_COPIES = 4
 #: fixed-shape compiled growers need a static depth bound
 MAX_COMPILED_DEPTH = 10
 
@@ -332,9 +360,6 @@ class RandomForestClassifierFamily(Family):
                 "max_estimators": None}
         return data, meta
 
-    min_sort_candidates = 4
-    convergence_proxy = GradientBoostingRegressorFamily.convergence_proxy
-
     @classmethod
     def observe_candidates(cls, candidates, base_params, meta):
         _observe_tree_candidates(cls, candidates, base_params, meta)
@@ -357,7 +382,19 @@ class RandomForestClassifierFamily(Family):
         return data["y1h"]
 
     @classmethod
+    def _n_estimators(cls, dynamic, static):
+        return jnp.asarray(dynamic.get(
+            "n_estimators", static.get("n_estimators", 100)), jnp.int32)
+
+    @classmethod
     def fit(cls, dynamic, static, data, train_w, meta):
+        """One fold's forest, read at `n_estimators`: the model of one
+        count (a direct call), or of each of a vector of counts `(C,)`,
+        its leaves led by `(C,)` (`fit_task_batched`).  Grown to the
+        largest count; tree `ti`'s votes go to the accumulator of every
+        count above `ti`."""
+        n_est = cls._n_estimators(dynamic, static)
+        counts = n_est.reshape(-1)
         codes = data["codes"]
         t = cls._targets(data)                          # (n, n_out)
         n, d = codes.shape
@@ -365,22 +402,20 @@ class RandomForestClassifierFamily(Family):
         depth = _depth(static, cls._default_depth)
         t_max = int(meta.get("max_estimators")
                     or static.get("n_estimators", 100))
-        n_est = jnp.asarray(dynamic.get(
-            "n_estimators", static.get("n_estimators", 100)), jnp.int32)
         bootstrap = bool(static.get("bootstrap", True))
         min_leaf = float(static.get("min_samples_leaf", 1))
         mf = cls._max_features(static, d)
         key = jax.random.PRNGKey(_seed(static))
 
         # while_loop (not scan/vmap) over trees: level histograms are the
-        # memory hot spot, one tree's workspace stays live — and the
-        # per-lane trip count `i < n_est` means a candidate stops paying
-        # for trees past ITS n_estimators (under vmap, jax's while
-        # batching freezes finished lanes' carries; the launch runs the
-        # max over its lanes, which convergence-sorted chunking makes
-        # tight per launch instead of the grid maximum)
+        # memory hot spot, one tree's workspace stays live.  Tree `ti` is
+        # drawn from `keys[ti]` and the fold's mask, whatever the count
+        # it is grown for: the forest of a smaller count is the first
+        # trees of a larger one's.  The bound is no fold's own, so under
+        # the folds' vmap the loop's counter and the draws stay one
         keys = jax.random.split(key, t_max)
-        n_lim = jnp.minimum(n_est, t_max)
+        n_lim = jnp.minimum(counts, t_max)              # (C,)
+        n_trees = jnp.max(n_lim)
 
         def one_tree(carry):
             ti, acc = carry
@@ -391,9 +426,6 @@ class RandomForestClassifierFamily(Family):
                         k_t, 1.0, (n,)).astype(jnp.float32)
                 else:
                     w_t = train_w
-                # a lane past its own count is carried along by the
-                # launch's lockstep loop: its tree counts no row
-                w_t = jnp.where(ti < n_est, w_t, 0.0)
             # squared loss from F=0: grad = -target, hess = 1 -> leaf
             # value = weighted mean target (class distribution / mean y)
             tree = grow_tree(codes, -t, jnp.ones((n,), jnp.float32), w_t,
@@ -403,22 +435,45 @@ class RandomForestClassifierFamily(Family):
                              max_features=mf, n_out=n_out,
                              integer_stats=cls._integer_stats(meta))
             pred = _own_rows(tree)                      # (n, n_out)
-            live = (ti < n_est).astype(jnp.float32)
-            return ti + 1, acc + live * pred
+            live = (ti < counts).astype(jnp.float32)
+            return ti + 1, acc + live[:, None, None] * pred
 
-        acc0 = jnp.zeros((n, n_out), jnp.float32)
+        acc0 = jnp.zeros((counts.shape[0], n, n_out), jnp.float32)
         _, acc = jax.lax.while_loop(
-            lambda c: c[0] < n_lim, one_tree,
+            lambda c: c[0] < n_trees, one_tree,
             (jnp.asarray(0, jnp.int32), acc0))
-        avg = acc / jnp.maximum(n_lim.astype(jnp.float32), 1.0)
-        out = cls._finalize(avg)
-        out["n_iter"] = n_lim   # executed trees, for launch accounting
+        out = cls._finalize(
+            acc / jnp.maximum(n_lim.astype(jnp.float32), 1.0)[
+                :, None, None])
+        out["n_iter"] = n_lim   # a task's own trees, for launch accounting
+        if n_est.ndim == 0:
+            out = jax.tree_util.tree_map(lambda leaf: leaf[0], out)
         return out
+
+    @classmethod
+    def fit_task_batched(cls, dynamic, static, data, train_w, meta):
+        """A launch's (candidate x fold) tasks, candidate-major, as ONE
+        forest a fold read at every candidate's count: `fit` a fold, with
+        the launch's counts.  The candidates of a compile group differ in
+        `n_estimators` alone, so whatever chunk of it a launch holds, its
+        forests are theirs to share; the folds' masks are the first
+        candidate's rows of `train_w` (every candidate's are the same).
+        A padded lane repeats the chunk's last candidate, so it raises no
+        bound."""
+        n_folds = int(static["__n_folds__"])
+        counts = jnp.broadcast_to(
+            cls._n_estimators(dynamic, static),
+            train_w.shape[:1]).reshape(-1, n_folds)[:, 0]
+        models = jax.vmap(
+            lambda w: cls.fit({"n_estimators": counts}, static, data, w,
+                              meta), out_axes=1)(train_w[:n_folds])
+        return jax.tree_util.tree_map(
+            lambda leaf: leaf.reshape((-1,) + leaf.shape[2:]), models)
 
     @classmethod
     def _finalize(cls, avg):
         return {"proba": avg,
-                "pred": jnp.argmax(avg, axis=1).astype(jnp.int32)}
+                "pred": jnp.argmax(avg, axis=-1).astype(jnp.int32)}
 
     @classmethod
     def _integer_stats(cls, meta):
@@ -435,16 +490,16 @@ class RandomForestClassifierFamily(Family):
 
     @classmethod
     def launch_stats(cls, models, static, meta):
-        """The default's lockstep trees (maximum and sum over lanes),
-        each task's own, and what the launch executed: every lane is
-        carried through the launch's largest tree count, a level at a
-        time."""
+        """The default's lockstep trees (maximum and sum over tasks),
+        each task's own, and what the launch executed: one forest a fold
+        grown to the launch's largest tree count, a level at a time."""
         stats = super().launch_stats(models, static, meta)
-        trees = models["n_iter"].astype(jnp.int32).reshape(-1)
-        slots = jnp.max(trees) * trees.size
-        stats["trees"] = trees
-        stats["tree_slots"] = slots
-        stats["tree_levels"] = slots * _depth(static, cls._default_depth)
+        trees = models["n_iter"].astype(jnp.int32)      # (candidates, folds)
+        grown = jnp.max(trees) * trees.shape[1]
+        stats["trees"] = trees.reshape(-1)
+        stats["trees_grown"] = grown
+        stats["tree_slots"] = grown
+        stats["tree_levels"] = grown * _depth(static, cls._default_depth)
         return stats
 
     @classmethod
@@ -462,18 +517,22 @@ class RandomForestClassifierFamily(Family):
     def launch_workspace(cls, n_samples, meta, n_folds, itemsize=4, *,
                          static, row_sets=1):
         """What a launch holds besides its arguments, for the memory
-        ledger.  A lane (candidates x folds of them): `_HIST_COPIES` of
-        its deepest level's histograms (the kernel's blocks and the
-        gains' share beside them), and by row the vote accumulator with a
-        tree's leaf distributions, the statistics, and the level's sorted
-        copy of codes and statistics (`_ROW_COPIES` of a row's bytes)."""
+        ledger.  Whatever its width, a forest a fold: the larger of
+        `_HIST_COPIES` of its deepest level's histograms (the kernel's
+        blocks and the gains' share beside them) and what the sorts hold
+        by row (a tree's leaf distributions, the statistics, and
+        `_ROW_COPIES` of the sorted copy of codes and statistics): the
+        compiler gives the histograms the sorts' space.  A candidate:
+        `_VOTE_COPIES` of its votes by fold and row."""
         n_stats = cls._n_stats(meta)
-        row = (3 * n_stats * 4                 # votes, leaf values, stats
+        row = (2 * n_stats * 4                 # leaf values, stats
                + _ROW_COPIES * tree_hist.row_bytes(
                    meta["n_features"], n_stats, cls._integer_stats(meta)))
-        lane = int(_HIST_COPIES * cls._hist_bytes(static, meta)
-                   + int(n_samples) * row)
-        return {"fixed_bytes": 0, "per_candidate_bytes": n_folds * lane}
+        forest = max(int(_HIST_COPIES * cls._hist_bytes(static, meta)),
+                     int(n_samples) * row)
+        votes = _VOTE_COPIES * (n_stats - 1) * 4 * int(n_samples)
+        return {"fixed_bytes": n_folds * forest,
+                "per_candidate_bytes": n_folds * votes}
 
     @classmethod
     def predict(cls, model, static, X, meta):
@@ -529,7 +588,7 @@ class RandomForestRegressorFamily(RandomForestClassifierFamily):
 
     @classmethod
     def _finalize(cls, avg):
-        return {"pred": avg[:, 0]}
+        return {"pred": avg[..., 0]}
 
     @classmethod
     def predict(cls, model, static, X, meta):

@@ -205,22 +205,21 @@ SEARCH_REPORT_SCHEMA = (
     MetricDef(
         "tree_slots_per_launch", "series",
         "Per launch of a forest family (RandomForestClassifier, "
-        "RandomForestRegressor): trees the launch executed, its lanes "
-        "(padding included) x the largest n_estimators among them: "
-        "every lane is carried through the launch's lockstep loop, so "
-        "the slots past a lane's own count are spent on a lane already "
-        "done.",
+        "RandomForestRegressor): trees the launch executed, its forests "
+        "(one a fold, shared by every candidate of the launch) x the "
+        "largest n_estimators among its candidates.  (Before PR 36 a "
+        "forest a lane: lanes x the largest count.)",
         stat="tree_slots", combine="sum"),
     MetricDef(
         "tree_levels_per_launch", "series",
         "Per launch of a forest family: tree levels the launch "
         "executed, tree_slots_per_launch x the group's compiled depth "
         "(a level = one partition, one histogram pass, one split and "
-        "one routing of every lane).",
+        "one routing of every forest).",
         stat="tree_levels", combine="sum"),
     MetricDef(
         "hist_bytes_per_lane", "series",
-        "Per launch of a forest family: bytes of one lane's deepest "
+        "Per launch of a forest family: bytes of one forest's deepest "
         "level of (node, feature, statistic, bin) float32 histograms "
         "as the launch writes them: 2^(depth - 1) nodes x features x "
         "(1 + outputs) x 256 x 4, features and statistics padded to "
@@ -233,6 +232,16 @@ SEARCH_REPORT_SCHEMA = (
         "order.  -1: the candidate was restored from a checkpoint or "
         "fitted on the host.",
         stat="trees", combine="per_candidate"),
+    MetricDef(
+        "trees_grown_per_launch", "series",
+        "Per launch of a forest family: trees the launch's loop grew, "
+        "its forests (one a fold) x the trees the loop ran.  Tree t of a "
+        "fold is the same tree at every n_estimators, so the launch "
+        "grows it once and every candidate with a larger count reads "
+        "it: the sum over a search against the sum of "
+        "trees_per_candidate x folds is how many candidates a grown "
+        "tree served.",
+        stat="trees_grown", combine="sum"),
     MetricDef(
         "padding_waste", "histogram",
         "Per-launch fraction of computed lanes that were padding "

@@ -33,7 +33,8 @@ import reference_forest    # noqa: E402
 FOLDS = 3
 CONFIG = {"estimator": {"params": {"random_state": 0}}}
 COUNTERS = ("tree_slots_per_launch", "tree_levels_per_launch",
-            "hist_bytes_per_lane", "trees_per_candidate")
+            "hist_bytes_per_lane", "trees_per_candidate",
+            "trees_grown_per_launch")
 
 
 def make_data(n, d, k, seed):
@@ -139,17 +140,24 @@ def test_counters_of_a_forest_search():
     gs = program(X, y, {"max_depth": [3, 5], "n_estimators": [3, 6, 4]})
     rep = gs.search_report
     # a launch a depth: its lanes (3 candidates x 3 folds, padded to the
-    # mesh) carried to 6 trees
+    # mesh) read ONE forest a fold, grown to 6 trees
     lanes = rep["lanes_per_launch"]
     assert len(lanes) == 2 and min(lanes) >= 9
-    assert rep["tree_slots_per_launch"] == [6 * n for n in lanes]
+    assert rep["tree_slots_per_launch"] == [6 * FOLDS] * 2
+    assert rep["trees_grown_per_launch"] == [6 * FOLDS] * 2
     assert rep["tree_levels_per_launch"] == [
-        6 * n * depth for n, depth in zip(lanes, (3, 5))]
+        6 * FOLDS * depth for depth in (3, 5)]
     assert rep["trees_per_candidate"] == [3, 6, 4, 3, 6, 4]
     assert rep["hist_bytes_per_lane"] == [
         2 ** (depth - 1) * 20 * 5 * 256 * 4 for depth in (3, 5)]
+    # the ledger: the forests' histograms whatever the width, a
+    # candidate's votes (four copies, by fold and row) on top of its masks
     groups = rep["memory"]["groups"]
-    assert [g["per_candidate_bytes"] > 3 * 1.4 * h
+    assert [g["fixed_bytes"] > FOLDS * 1.39 * h
+            for g, h in zip(groups, rep["hist_bytes_per_lane"])] == [True] * 2
+    assert [g["per_candidate_bytes"] > FOLDS * 600 * (4 + 4 * 4 * 4)
+            for g in groups] == [True] * 2
+    assert [g["per_candidate_bytes"] < h
             for g, h in zip(groups, rep["hist_bytes_per_lane"])] == [True] * 2
     assert all(g["capped"] is False for g in groups)
 
@@ -174,3 +182,129 @@ def test_codes_go_to_the_device_as_bytes():
         data, _ = family.prepare_data(X, y)
         assert data["codes"].dtype == np.uint8
         assert data["codes"].flags["C_CONTIGUOUS"]
+
+
+# --- one forest a fold, read at every count of the launch ---------------------
+
+def search(estimator, X, y, grid, refit=False, one_device=False, **config):
+    import jax
+    from sklearn.model_selection import KFold
+    if one_device:
+        config["devices"] = jax.devices()[:1]
+    cv = StratifiedKFold(FOLDS) if y.dtype.kind == "i" else KFold(FOLDS)
+    return sst.GridSearchCV(
+        estimator, grid, cv=cv, backend="tpu", refit=refit,
+        config=sst.TpuConfig(**config) if config else None).fit(X, y)
+
+
+def rows_by_params(gs):
+    scores = split_scores(gs)
+    return {tuple(sorted(p.items())): scores[i]
+            for i, p in enumerate(gs.cv_results_["params"])}
+
+
+def forest_problem(kind):
+    from sklearn.ensemble import RandomForestRegressor
+    X, y = make_data(500, 10, 3, 11)
+    if kind == "classifier":
+        return RandomForestClassifier, X, y
+    return RandomForestRegressor, X, (X[:, 0] * 2 + X[:, 1] * X[:, 2]
+                                      ).astype(np.float32)
+
+
+@pytest.mark.parametrize("bootstrap", [True, False])
+@pytest.mark.parametrize("kind", ["classifier", "regressor"])
+def test_a_grid_scores_what_each_candidate_scores_alone(kind, bootstrap):
+    """Tree ti of a fold is the same tree at every count, and a
+    candidate's votes are added in the same order: a depth x count grid's
+    split scores, and the refit it picks, are those of its candidates
+    searched one at a time, to the bit."""
+    cls, X, y = forest_problem(kind)
+    est = cls(random_state=0, bootstrap=bootstrap)
+    grid = search(est, X, y, {"max_depth": [2, 4], "n_estimators": [3, 5, 2]},
+                  refit=True)
+    together = rows_by_params(grid)
+    assert len(grid.search_report["lanes_per_launch"]) == 2
+    alone = [dict(grid.best_params_), {"max_depth": 4, "n_estimators": 2},
+             {"max_depth": 2, "n_estimators": 5}]
+    for i, params in enumerate(alone):
+        solo = search(est, X, y, {k: [v] for k, v in params.items()},
+                      refit=i == 0)
+        assert np.array_equal(split_scores(solo)[0],
+                              together[tuple(sorted(params.items()))])
+        if i == 0:
+            read = "predict_proba" if kind == "classifier" else "predict"
+            assert np.array_equal(
+                getattr(solo.best_estimator_, read)(X),
+                getattr(grid.best_estimator_, read)(X))
+
+
+@pytest.mark.parametrize("config,launches", [
+    ({}, 1),                                   # padded to the mesh's width
+    ({"one_device": True}, 1),                 # the group's own width
+    ({"one_device": True, "max_tasks_per_batch": 2 * FOLDS}, 3),
+])
+def test_a_group_of_counts_is_one_launch_where_it_fits(config, launches):
+    """Five counts (once enough for chunks graded by tree count): one
+    launch that grows the largest count's trees once.  Where the width
+    is forced below the group, each chunk grows its own largest, a padded
+    lane raises none, and every score is what the whole group's launch
+    gives."""
+    counts = [4, 2, 6, 3, 5]
+    X, y = make_data(400, 10, 3, 4)
+    est = RandomForestClassifier(random_state=0, max_depth=3)
+    gs = search(est, X, y, {"n_estimators": counts}, **config)
+    rep = gs.search_report
+    assert len(rep["lanes_per_launch"]) == launches
+    assert rep["trees_per_candidate"] == counts
+    chunks = [counts[i:i + 2] for i in range(0, 5, 2)] \
+        if launches == 3 else [counts]
+    assert rep["trees_grown_per_launch"] == [FOLDS * max(c) for c in chunks]
+    assert rep["tree_slots_per_launch"] == rep["trees_grown_per_launch"]
+    assert not any(g["sorted"] for g in rep["geometry"]["groups"])
+    whole = search(est, X, y, {"n_estimators": counts}, one_device=True)
+    assert np.array_equal(split_scores(gs), split_scores(whole))
+
+
+@pytest.mark.parametrize("kind", ["classifier", "regressor"])
+def test_fit_is_the_launchs_loop_with_one_forest(kind):
+    """`fit` (a direct call) and `fit_task_batched` (a search's launch)
+    run one loop: a task of the launch is the model `fit` returns for its
+    candidate and fold."""
+    import jax
+    cls, X, y = forest_problem(kind)
+    family = sst.models.base.resolve_family(cls())
+    data, meta = family.prepare_data(X, y)
+    family.observe_candidates([{"n_estimators": 3}, {"n_estimators": 5}],
+                              {"random_state": 0, "max_depth": 3}, meta)
+    static = {"random_state": 0, "max_depth": 3}
+    rng = np.random.default_rng(0)
+    masks = (rng.random((2, len(y))) < 0.7).astype(np.float32)
+    counts = np.asarray([3, 5], np.int32)
+    launch = jax.jit(lambda n, w: family.fit_task_batched(
+        {"n_estimators": n}, {**static, "__n_folds__": 2}, data, w, meta))(
+            np.repeat(counts, 2), np.tile(masks, (2, 1)))
+    for c, count in enumerate(counts):
+        for f in range(2):
+            one = jax.jit(lambda n, w: family.fit(
+                {"n_estimators": n}, static, data, w, meta))(count, masks[f])
+            for leaf in one:
+                assert np.array_equal(np.asarray(one[leaf]),
+                                      np.asarray(launch[leaf][2 * c + f]))
+
+
+def test_scalers_in_front_of_a_forest_share_its_launch():
+    """Binning is invariant under the scalers, so the pipeline's launch
+    is the bare forest's: the same scores, one launch for its counts, and
+    nothing the shared-prefix stage could stage."""
+    from sklearn.pipeline import Pipeline
+    from sklearn.preprocessing import StandardScaler
+    X, y = make_data(400, 10, 3, 4)
+    forest = RandomForestClassifier(random_state=0, max_depth=3)
+    bare = search(forest, X, y, {"n_estimators": [4, 2, 5]})
+    piped = search(Pipeline([("scale", StandardScaler()), ("rf", forest)]),
+                   X, y, {"rf__n_estimators": [4, 2, 5]})
+    assert np.array_equal(split_scores(piped), split_scores(bare))
+    rep = piped.search_report
+    assert len(rep["lanes_per_launch"]) == 1
+    assert rep["prefix"]["fallbacks"] == ["not-a-compiled-pipeline"]

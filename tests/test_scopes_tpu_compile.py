@@ -44,8 +44,9 @@ step loop carrying the weights and Adam's two moments and nothing else of
 that size, one gather of minibatch rows a fold, and the ledger's price
 within a quarter of the compiler's allotment.
 
-The forest's launch (``RandomForestClassifierFamily.fit`` under the
-engine's two ``vmap``s) is compiled at the deepest group of the
+The forest's launch (``RandomForestClassifierFamily.fit_task_batched``:
+one forest a fold, read at each candidate's count) is compiled at the
+deepest group of the
 ``forest_covtype145k.depth3_trees3`` cell — 3 n_estimators x 5 folds of
 max_depth 10 on 145 253 x 54 — with the grower on the kernels' form: both
 Mosaic kernels compile for the chip at these widths, every ``sst.tree.*``
@@ -685,12 +686,12 @@ TREE_SCOPES = sorted(s for s in known_scope_names()
 
 
 def _compiled_forest(one_chip):
-    """The per-task RandomForestClassifier fit launch as the engine vmaps
-    it (candidates, named; folds) at the deepest group of
-    ``forest_covtype145k.depth3_trees3``: 3 n_estimators x 5 folds of
-    max_depth 10 on 145 253 x 54, seven classes, the grower on the
-    kernels' form (the platform's choice on a TPU)."""
-    from spark_sklearn_tpu.models.base import CANDIDATE_AXIS
+    """The RandomForestClassifier fit launch as the engine builds it
+    (``fit_task_batched`` on the candidate-major tasks and their tiled
+    masks) at the deepest group of ``forest_covtype145k.depth3_trees3``:
+    3 n_estimators x 5 folds of max_depth 10 on 145 253 x 54, seven
+    classes, the grower on the kernels' form (the platform's choice on a
+    TPU)."""
     from spark_sklearn_tpu.models.trees import RandomForestClassifierFamily
 
     def arg(shape, dtype=jnp.float32):
@@ -700,22 +701,19 @@ def _compiled_forest(one_chip):
             "n_features": TREE_D, "max_estimators": 40,
             "unit_fit_weights": True}
     static = {"max_depth": TREE_DEPTH, "random_state": 0}
+    tasks = TREE_CANDIDATES * FOLDS
 
     def launch(n_estimators, codes, y, y1h, w):
-        def one_cand(count):
-            def one_fold(wf):
-                return RandomForestClassifierFamily.fit(
-                    {"n_estimators": count}, static,
-                    {"codes": codes, "y": y, "y1h": y1h}, wf, meta)
-            return jax.vmap(one_fold)(w)
         with jax.named_scope("sst.fit"):
-            return jax.vmap(one_cand, axis_name=CANDIDATE_AXIS)(
-                n_estimators)
+            return RandomForestClassifierFamily.fit_task_batched(
+                {"n_estimators": n_estimators},
+                {**static, "__n_folds__": FOLDS},
+                {"codes": codes, "y": y, "y1h": y1h}, w, meta)
 
     return jax.jit(launch).lower(
-        arg((TREE_CANDIDATES,), jnp.int32),
+        arg((tasks,), jnp.int32),
         arg((TREE_N, TREE_D), jnp.uint8), arg((TREE_N,), jnp.int32),
-        arg((TREE_N, TREE_K)), arg((FOLDS, TREE_N))).compile(), meta, static
+        arg((TREE_N, TREE_K)), arg((tasks, TREE_N))).compile(), meta, static
 
 
 @pytest.fixture(scope="module")
@@ -745,13 +743,13 @@ def test_tree_compiled_op_names_carry_scope(forest_launch, scope):
 def test_forest_launch_is_two_kernels_a_level_and_two_sorts_a_tree(
         forest_launch):
     """The Mosaic kernels compile for the chip at the cell's widths (a
-    histogram and a routing call a level, the 15 lanes a grid axis of
-    each), the rows are gathered into node order at levels 0 and 5 only,
-    and no level scatters."""
+    histogram and a routing call a level, the 5 forests of the 15 tasks a
+    grid axis of each), the rows are gathered into node order at levels 0
+    and 5 only, and no level scatters."""
     text = forest_launch[0].as_text()
     calls = re.findall(r'custom_call_target="tpu_custom_call"', text)
     assert len(calls) == 2 * TREE_DEPTH
-    lanes = TREE_CANDIDATES * FOLDS
+    lanes = FOLDS
     for level in (0, TREE_DEPTH - 1):
         assert re.search(r'f32\[%d,%d,64,8,256\]\S* custom-call\(' % (
             lanes, 2 ** level), text)
@@ -783,11 +781,13 @@ def test_ledger_prices_the_forest_launch(forest_launch):
         tree_hist.on_tpu = real
     modeled = model_group_footprint(
         {"n_estimators": np.zeros(TREE_CANDIDATES, np.int32)},
-        TREE_CANDIDATES, FOLDS, task_batched=False, n_samples=TREE_N,
+        TREE_CANDIDATES, FOLDS, task_batched=True, n_samples=TREE_N,
         workspace=workspace)
-    # a lane's histograms are hundreds of megabytes: 512 nodes x 64 x 8 x
-    # 256 floats at level 9, and the gains beside them
-    assert modeled["per_candidate_bytes"] > FOLDS * 1.4 * 268_435_456
-    assert abs(modeled["chunk_bytes"] - allotted) < 0.25 * allotted
+    # a forest's histograms are hundreds of megabytes: 512 nodes x 64 x 8
+    # x 256 floats at level 9, and the gains beside them; a forest a fold
+    # whatever the candidates, whose own share is their masks and votes
+    assert modeled["fixed_bytes"] > FOLDS * 1.39 * 268_435_456
+    assert modeled["per_candidate_bytes"] < 0.05 * modeled["fixed_bytes"]
+    assert abs(modeled["chunk_bytes"] - allotted) < 0.1 * allotted
     # an eighth of the chip and more: the cell's size (PERF.md section 4)
     assert allotted > 0.125 * 16.909e9

@@ -70,19 +70,20 @@ class TestSortedChunking:
 
 
 class TestTreeSortedChunking:
-    def test_forest_launches_grow_their_own_tree_counts(self):
-        """Round 4: tree fits are lane-bounded while_loops — a launch
-        grows max-over-lanes(n_estimators) trees, and sorting by
-        n_estimators makes that max tight per launch instead of the
-        grid maximum's (measured 2.4x on the config-3 shape)."""
+    def test_forest_counts_share_one_launch(self):
+        """Round 4 graded a forest's launches by n_estimators (lockstep
+        lanes: a launch grew max-over-lanes trees for every lane).  Since
+        PR 36 a launch grows ONE forest a fold and reads it at every
+        count, so chunks graded by count would only regrow the prefix:
+        the forest families have no convergence proxy, and the group is
+        one launch whatever `sort_candidates` says."""
         from sklearn.ensemble import RandomForestClassifier
 
         rng = np.random.RandomState(0)
         X = rng.randn(300, 8).astype(np.float32)
         y = rng.randint(0, 3, size=300)
-        # 32 candidates: launches pad to the task-shard multiple (8 on
-        # the virtual test mesh), so sorting yields 4 launches of 8
-        # whose tree counts are each block's own maximum
+        # 32 candidates: once 4 graded launches of 8 on the virtual test
+        # mesh (12, 20, 28 and 36 trees)
         grid = {"n_estimators": list(range(5, 37))}
 
         runs = {}
@@ -94,14 +95,14 @@ class TestTreeSortedChunking:
                 config=cfg).fit(X, y)
             runs[sort] = gs
 
-        rs = runs[True].search_report
-        ru = runs[False].search_report
-        assert rs["solver_iters_per_launch"] == [12, 20, 28, 36]
-        assert ru["solver_iters_per_launch"] == [36]
-        # identical results either way (masked lanes are frozen)
-        np.testing.assert_allclose(
+        for gs in runs.values():
+            rep = gs.search_report
+            assert rep["solver_iters_per_launch"] == [36]
+            assert rep["trees_grown_per_launch"] == [2 * 36]
+            assert rep["trees_per_candidate"] == grid["n_estimators"]
+        assert np.array_equal(
             runs[True].cv_results_["mean_test_score"],
-            runs[False].cv_results_["mean_test_score"], atol=1e-6)
+            runs[False].cv_results_["mean_test_score"])
 
     def test_constant_proxy_stays_single_launch(self):
         # a grid varying only in OTHER params must not pay the launch

@@ -273,9 +273,11 @@ def test_the_ledger_prices_what_the_kernel_allocates(depth, d, n_classes,
     lane_bytes = int(np.prod(out.shape)) * out.dtype.itemsize
     facts = family.launch_facts(static, meta, 3, 5)
     assert facts == {"hist_bytes": lane_bytes}
+    # a forest a fold whatever the launch's width; a candidate adds votes
     ws = family.launch_workspace(n, meta, 5, static=static)
-    assert ws["per_candidate_bytes"] >= 5 * 1.4 * lane_bytes
-    assert ws["per_candidate_bytes"] < 5 * (2 * lane_bytes + n * 1024)
+    assert ws["fixed_bytes"] >= 5 * 1.39 * lane_bytes
+    assert ws["fixed_bytes"] < 5 * (2 * lane_bytes + n * 1024)
+    assert ws["per_candidate_bytes"] == 5 * n * 4 * n_classes * 4
     if (depth, d) == (10, 54):
         # the issue's 226 MB, 54 features padded to the kernel's 64
         assert lane_bytes == 512 * 64 * 8 * 256 * 4
