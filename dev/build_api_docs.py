@@ -51,6 +51,7 @@ MODULES = [
     "spark_sklearn_tpu.obs.export",
     "spark_sklearn_tpu.obs.metrics",
     "spark_sklearn_tpu.obs.log",
+    "spark_sklearn_tpu.obs.process",
     "spark_sklearn_tpu.obs.spans",
     "spark_sklearn_tpu.obs.telemetry",
     "spark_sklearn_tpu.obs.fleet",

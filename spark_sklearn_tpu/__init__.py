@@ -19,7 +19,42 @@ Public API (mirrors the reference's __init__.py exports):
 
 __version__ = "0.5.0"
 
-import spark_sklearn_tpu.models  # noqa: F401 — registers Tier-A families
+# The import is part of every process's set-up, so it is stamped: the
+# first and last line, and the first import of each third-party root
+# the package's own modules would otherwise pull in wherever they fall
+# (numpy under jax; scipy, pandas and pyarrow under scikit-learn's
+# ``utils.fixes``).  Nothing here is imported that the package's own
+# modules do not import anyway.  ``obs/process.py`` keeps the stamps:
+# ``search_report["process"]["import_by_root"]``.
+import time as _time
+
+_IMPORT_STAMPS = [("", _time.perf_counter())]
+
+
+def _stamp(root):
+    _IMPORT_STAMPS.append((root, _time.perf_counter()))
+
+
+import numpy  # noqa: E402,F401
+_stamp("numpy")
+import jax  # noqa: E402,F401
+import jax.numpy  # noqa: E402,F401
+_stamp("jax")
+import jax.experimental.pallas  # noqa: E402,F401 — ops/tree_hist.py's
+import jax.experimental.pallas.tpu  # noqa: E402,F401
+_stamp("jax.experimental.pallas")
+import scipy.sparse  # noqa: E402,F401
+import scipy.special  # noqa: E402,F401
+import scipy.stats  # noqa: E402,F401
+_stamp("scipy")
+import pandas  # noqa: E402,F401 — keyed/'s, and sklearn.utils.fixes'
+_stamp("pandas")
+import sklearn.base  # noqa: E402,F401
+import sklearn.callback  # noqa: E402,F401
+import sklearn.model_selection  # noqa: E402,F401
+_stamp("sklearn")
+
+import spark_sklearn_tpu.models  # noqa: F401,E402 — registers Tier-A families
 from spark_sklearn_tpu.models.base import NotCompiledError
 from spark_sklearn_tpu.search.grid import GridSearchCV, RandomizedSearchCV
 from spark_sklearn_tpu.search.halving import (
@@ -68,3 +103,8 @@ __all__ = [
     "init_distributed",
     "__version__",
 ]
+
+from spark_sklearn_tpu.obs import process as _process  # noqa: E402
+
+_stamp("")
+_process.note_import(_IMPORT_STAMPS)

@@ -35,6 +35,14 @@ problem of MPMD pipeline schedulers (arXiv:2412.14374).  Four pieces:
     block), enabled with ``TpuConfig(heartbeat=True)`` /
     ``SST_HEARTBEAT`` — off is an exact no-op.
 
+  - ``obs.process`` — the process ledger: what a process pays once
+    (the import by third-party root, the first call, the first fits,
+    every program traced, lowered, compiled or loaded, the seconds a
+    dispatching thread waited for a build), recorded always and
+    bounded; ``search_report["process"]`` and
+    ``obs.process_report()``.  The program's one set of jax-monitoring
+    listeners lives there.
+
 Enable tracing per search with ``TpuConfig(trace=True)`` (record only)
 or ``TpuConfig(trace="out.json")`` (record + export), or process-wide
 with the ``SST_TRACE`` environment variable (``1`` or a path).
@@ -55,6 +63,7 @@ from spark_sklearn_tpu.obs.metrics import (
     search_registry,
 )
 from spark_sklearn_tpu.obs.log import StructuredLogger, get_logger
+from spark_sklearn_tpu.obs.process import process_report
 from spark_sklearn_tpu.obs.telemetry import (
     FlightRecorder,
     TelemetryService,
@@ -92,6 +101,7 @@ __all__ = [
     "schema_markdown",
     "StructuredLogger",
     "get_logger",
+    "process_report",
     "FlightRecorder",
     "TelemetryService",
     "flight_recorder",

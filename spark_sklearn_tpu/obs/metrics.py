@@ -42,6 +42,7 @@ __all__ = [
     "ATTRIBUTION_BLOCK_SCHEMA",
     "PROTECTION_BLOCK_SCHEMA",
     "HEARTBEAT_BLOCK_SCHEMA",
+    "PROCESS_BLOCK_SCHEMA",
     "RECOVERY_BLOCK_SCHEMA",
     "TELEMETRY_SNAPSHOT_SCHEMA",
     "search_registry",
@@ -362,6 +363,17 @@ SEARCH_REPORT_SCHEMA = (
         "overhead estimate (obs/heartbeat.py).  Absent when the "
         "heartbeat is off (TpuConfig.heartbeat / SST_HEARTBEAT "
         "unset) — the byte-identical beacon-less report shape."),
+    MetricDef(
+        "process", "struct",
+        "The process ledger's view at the end of this search (see the "
+        "process-block schema below): what the PROCESS paid once, up "
+        "to now — the import by third-party root, the first call into "
+        "the program, the first fits, and every program traced, "
+        "lowered, compiled or loaded from the persistent cache, with "
+        "the seconds dispatching threads waited for builds "
+        "(obs/process.py).  Cumulative, not this search's delta: the "
+        "block of a process's second search holds its whole set-up.",
+        backends="tpu,host"),
     MetricDef(
         "n_tasks", "gauge",
         "Host tier: number of (candidate, fold) fit-and-score tasks.",
@@ -1118,6 +1130,79 @@ HEARTBEAT_BLOCK_SCHEMA = (
 )
 
 
+#: sub-keys of ``search_report["process"]`` (rendered by
+#: ``obs.process.ProcessLedger.report``; the same object from
+#: ``obs.process_report()``).  Every ``*_s`` that is a point in time is
+#: on ``time.perf_counter()`` relative to the first line of the
+#: package's ``__init__``; every total is cumulative for the process.
+PROCESS_BLOCK_SCHEMA = (
+    MetricDef("import_s", "gauge",
+              "Seconds from the first to the last line of "
+              "`import spark_sklearn_tpu`."),
+    MetricDef("import_own_s", "gauge",
+              "The part of import_s spent in the package's own modules "
+              "(and whatever third-party module they import that "
+              "`__init__` did not import first)."),
+    MetricDef("import_by_root", "struct",
+              "The rest of import_s by third-party root, each charged "
+              "with what its first import pulled in: numpy, jax (with "
+              "jaxlib), jax.experimental.pallas, scipy, pandas (with "
+              "pyarrow), sklearn."),
+    MetricDef("first_call_s", "gauge",
+              "When the program was first called after its import "
+              "(the first `enable_persistent_cache` or `fit`); "
+              "first_call_s - import_s is the caller's own time in "
+              "between — under the benchmark, the TPU client's "
+              "start-up.  None before any call."),
+    MetricDef("fits", "series",
+              "The process's first eight `fit` calls: search (the "
+              "search's number), t0_s, t1_s (None while it runs)."),
+    MetricDef("n_programs", "counter",
+              "Programs that went through jax's back end: compiled, "
+              "or loaded from the persistent cache."),
+    MetricDef("n_cache_hits", "counter",
+              "Of those, loaded from the persistent cache."),
+    MetricDef("n_cache_misses", "counter",
+              "Of those, compiled after the cache was consulted "
+              "(whether or not jax then wrote an entry: a compile "
+              "under the cache's thresholds writes none and is absent "
+              "from the pipeline block's persistent_cache_misses)."),
+    MetricDef("trace_s", "gauge",
+              "Seconds of python -> jaxpr tracing, each thread's wall "
+              "counted once (a trace nested in another is taken out of "
+              "the outer one)."),
+    MetricDef("lower_s", "gauge",
+              "Seconds of jaxpr -> MLIR lowering, counted likewise.  "
+              "trace_s + lower_s is all an AOT artifact store "
+              "(parallel/programstore.py) could ever remove."),
+    MetricDef("xla_s", "gauge",
+              "Seconds inside jax's back-end compile less the cache "
+              "retrieval recorded inside it: the XLA compile (and the "
+              "cache write) on a miss, jax's bookkeeping on a hit; "
+              "never negative.  A program-store miss or publish adds "
+              "its seconds here."),
+    MetricDef("cache_load_s", "gauge",
+              "Seconds of persistent-cache retrieval (read, "
+              "decompress, deserialize, load onto the devices), each "
+              "load once; a program-store hit adds its seconds here."),
+    MetricDef("build_union_s", "gauge",
+              "Seconds with at least one build in flight on any "
+              "thread (the union of the records' intervals)."),
+    MetricDef("build_blocked_s", "gauge",
+              "What building cost the wall: the `compile.wait` spans "
+              "(a dispatching thread joined a build on sst-compile) "
+              "plus the seconds of every build that ran on a thread "
+              "inside `fit` other than sst-compile."),
+    MetricDef("builds", "series",
+              "The 64 longest records, by t0_s: name (jax's fun_name, "
+              "or programstore.load / .save), label (the `compile` "
+              "span's, where the build ran under one), thread, search, "
+              "t0_s, t1_s, trace_s, lower_s, cache_load_s, xla_s, cache "
+              "(hit / miss / off), blocking.  The totals above stay "
+              "exact when records are dropped."),
+)
+
+
 #: pinned keys of the telemetry snapshot's ``recovery`` block — the
 #: crash-safe service's counters (``serve/journal.py``: durable
 #: submission WAL under ``TpuConfig.service_journal_dir`` /
@@ -1528,6 +1613,19 @@ def schema_markdown() -> str:
         "`obs/heartbeat.py`).\n")
     out.append("\n| key | kind | description |\n|---|---|---|\n")
     for d in HEARTBEAT_BLOCK_SCHEMA:
+        out.append(f"| `{d.name}` | {d.kind} | {d.description} |\n")
+    out.append("\n### `search_report[\"process\"]` block\n")
+    out.append(
+        "\nWhat the process paid once, up to the end of this search "
+        "(`obs/process.py`); always present, on both tiers.  An "
+        "operator reads the same object from "
+        "`spark_sklearn_tpu.obs.process_report()` at any time: \"why "
+        "did my first search take a minute\" is `import_s`, "
+        "`first_call_s - import_s`, `cache_load_s` / `xla_s` and "
+        "`build_blocked_s`, and the records of `builds` with "
+        "`blocking` true.\n")
+    out.append("\n| key | kind | description |\n|---|---|---|\n")
+    for d in PROCESS_BLOCK_SCHEMA:
         out.append(f"| `{d.name}` | {d.kind} | {d.description} |\n")
     out.append("\n### telemetry `recovery` block\n")
     out.append(

@@ -233,7 +233,19 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
             "Result writes / checkpoint append, dispatch order.",
             layer="chunk pipeline", mirror=True),
     SpanDef("compile", "span", "parallel.pipeline",
-            "AOT lower+compile on the sst-compile thread.",
+            "AOT lower+compile on the sst-compile thread (carries "
+            "label and, from the process ledger, what the build was: "
+            "cache hit/miss/off, cache_load_s, trace_s, lower_s).",
+            layer="program build", mirror=True),
+    # obs/process.py
+    SpanDef("compile.wait", "span", "obs.process",
+            "A dispatching thread standing for a build still in flight "
+            "on sst-compile: a group's first fused dispatch "
+            "(where=dispatch, inside `dispatch`), a rung barrier "
+            "(where=drain) or the join after the last launch "
+            "(where=close, inside `fit.report`).  Its seconds reach "
+            "search_report[\"process\"][\"build_blocked_s\"] whatever "
+            "the tracer's state.",
             layer="program build", mirror=True),
     # parallel/faults.py
     SpanDef("launch.retry", "span", "parallel.faults",

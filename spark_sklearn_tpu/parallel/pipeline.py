@@ -49,6 +49,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 
 from spark_sklearn_tpu.obs import heartbeat as _heartbeat
+from spark_sklearn_tpu.obs import process as _process
 from spark_sklearn_tpu.obs import telemetry as _telemetry
 from spark_sklearn_tpu.obs.log import get_logger
 from spark_sklearn_tpu.obs.trace import (
@@ -81,42 +82,17 @@ __all__ = [
 # Persistent XLA compilation cache
 # ---------------------------------------------------------------------------
 
-#: process-wide persistent-cache traffic, fed by jax's monitoring events
-#: (compiler.py records /jax/compilation_cache/cache_{hits,misses} on
-#: every compile request once a cache dir is configured)
-_CACHE_EVENTS = {"hits": 0, "misses": 0}
+#: the persistent cache's hit / miss counters live with the program's
+#: other jax-monitoring listeners in ``obs/process.py``, registered at
+#: the package's import; the names stay importable from here
+_CACHE_EVENTS = _process._LEDGER.cache_events
 _LISTENER_LOCK = named_lock("pipeline._LISTENER_LOCK")
-_LISTENER_INSTALLED = False
+persistent_cache_counts = _process.persistent_cache_counts
 
 
 def _install_cache_listener() -> None:
-    global _LISTENER_INSTALLED
-    with _LISTENER_LOCK:
-        if _LISTENER_INSTALLED:
-            return
-        # no ImportError guard: if jax moves this module the hit/miss
-        # counters must fail loudly, not read a silent zero
-        from jax._src import monitoring
-
-        def _on_event(event: str, **kwargs) -> None:
-            # jax may fire this from whichever thread compiles (the
-            # sst-compile worker or the dispatching main thread), so
-            # the read-modify-write increments need the lock
-            if event == "/jax/compilation_cache/cache_hits":
-                with _LISTENER_LOCK:
-                    _CACHE_EVENTS["hits"] += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                with _LISTENER_LOCK:
-                    _CACHE_EVENTS["misses"] += 1
-
-        monitoring.register_event_listener(_on_event)
-        _LISTENER_INSTALLED = True
-
-
-def persistent_cache_counts() -> Dict[str, int]:
-    """Cumulative persistent-compile-cache hits/misses this process.
-    Callers snapshot before/after a search and report the delta."""
-    return dict(_CACHE_EVENTS)
+    """Kept for callers of the old name: the listeners are installed
+    where ``obs/process.py`` is imported, once."""
 
 
 #: where the persistent cache lives when neither the environment nor
@@ -171,8 +147,9 @@ def enable_persistent_cache(config=None) -> str:
     (a `TpuSession`, or the first search, does) — a process that
     compiled with no directory set keeps no cache."""
     global _BOUND_CACHE_DIR
-    _install_cache_listener()
     wanted = resolve_compile_cache_dir(config)
+    if _BOUND_CACHE_DIR is None:
+        _process.touch()       # the first call into the program
     with _LISTENER_LOCK:
         if _BOUND_CACHE_DIR is None:
             preset = jax.config.jax_compilation_cache_dir
@@ -459,12 +436,15 @@ class ChunkPipeline:
             return None
         if self._compile_executor is None:
             self._compile_executor = ThreadPoolExecutor(
-                max_workers=1, thread_name_prefix="sst-compile")
+                max_workers=1,
+                thread_name_prefix=_process.COMPILE_THREAD)
 
         def job():
             set_correlation(self._corr, self._search)
-            with self._tracer.span("compile", label=label):
+            with self._tracer.span("compile", label=label) as span, \
+                    _process.building(label) as built:
                 exe = precompile(jit_fn, *args)
+                span.set(**built.attrs())
             self._n_precompiled += 1
             # device-memory ledger: harvest the compiled executable's
             # XLA memory_analysis (argument/output/temp bytes) where
@@ -513,18 +493,23 @@ class ChunkPipeline:
         stays warm for the next rung's programs.  `run()` may be
         called again afterwards — the timeline and wall accumulate, so
         one report covers every rung."""
+        self._join_builds("drain")
+        self._compile_futures = []
+
+    def _join_builds(self, where: str) -> None:
+        """Stand (under ``compile.wait``) for every queued build that
+        is still in flight."""
         for fut in self._compile_futures:
             if fut.cancelled():
                 continue
             try:
-                fut.result()
+                _process.join_build(fut, self._tracer, where=where)
             # AOT compile-ahead is an optimization only: a failed
             # future's consumer already fell back to the jit path, and
             # an unconsumed failure means nothing needed the executable
             # sstlint: disable=launch-except-taxonomy,swallowed-exception
             except Exception:
                 pass
-        self._compile_futures = []
 
     def close(self) -> None:
         """Join the compile thread (AOT jobs trace under the caller's
@@ -533,6 +518,7 @@ class ChunkPipeline:
         if self._compile_executor is not None:
             for fut in self._compile_futures:
                 fut.cancel()
+            self._join_builds("close")
             self._compile_executor.shutdown(wait=True)
             self._compile_executor = None
             self._compile_futures = []

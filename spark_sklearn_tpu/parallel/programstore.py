@@ -70,6 +70,7 @@ import jax
 
 from spark_sklearn_tpu.obs import telemetry as _telemetry
 from spark_sklearn_tpu.obs.log import get_logger
+from spark_sklearn_tpu.obs import process as _process
 from spark_sklearn_tpu.obs.trace import get_tracer
 # crash-safe publish (tmp + fsync + os.replace): the one hardened
 # write path every store file (artifacts, plans.json, manifests) goes
@@ -334,8 +335,10 @@ class ProgramStore:
             else:
                 self._counts["misses"] += 1
         _telemetry.note_programstore("hit" if ex is not None else "miss")
+        t1 = time.perf_counter()
+        _process.note_store("load", t0, t1, hit=ex is not None)
         get_tracer().record_span(
-            "programstore.load", t0, time.perf_counter(), key=name,
+            "programstore.load", t0, t1, key=name,
             bytes=nbytes, hit=ex is not None, source=hit_kind,
             kind=kind, family=str(family))
         return ex
@@ -372,8 +375,10 @@ class ProgramStore:
                 self._counts["bytes_saved"] += len(blob)
                 self._mem[name] = ex
             _telemetry.note_programstore("publish")
+            t1 = time.perf_counter()
+            _process.note_store("save", t0, t1)
             get_tracer().record_span(
-                "programstore.save", t0, time.perf_counter(), key=name,
+                "programstore.save", t0, t1, key=name,
                 bytes=len(blob), kind=kind, family=str(family))
             return ex
         except Exception as exc:

@@ -74,6 +74,7 @@ from spark_sklearn_tpu.utils.native import fold_masks
 from spark_sklearn_tpu.obs import telemetry as _telemetry
 from spark_sklearn_tpu.obs.log import get_logger
 from spark_sklearn_tpu.obs.metrics import search_registry
+from spark_sklearn_tpu.obs import process as _process
 from spark_sklearn_tpu.obs.trace import get_tracer, search_tracing
 from spark_sklearn_tpu.parallel import faults as _faults
 
@@ -522,10 +523,20 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             # span tracing scoped to this search: recording only when
             # TpuConfig(trace=...)/SST_TRACE asks; exact no-op otherwise
             with search_tracing(self.config) as tracer:
-                with tracer.span(
-                        "search.fit", search=type(self).__name__,
-                        estimator=type(self.estimator).__name__):
-                    return self._fit_impl(X, y, params)
+                # the process ledger's two stamps (obs/process.py): the
+                # report's "process" block is everything this process
+                # paid once, up to the end of this search
+                _process.fit_begin()
+                try:
+                    with tracer.span(
+                            "search.fit", search=type(self).__name__,
+                            estimator=type(self.estimator).__name__):
+                        return self._fit_impl(X, y, params)
+                finally:
+                    block = _process.fit_end()
+                    metrics = getattr(self, "_search_metrics", None)
+                    if metrics is not None:
+                        metrics.put("process", block)
 
     def _fit_impl(self, X, y, params):
         estimator = self.estimator
@@ -3182,7 +3193,9 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             fut = plan.pop("aot_future", None)
             if fut is not None:
                 try:
-                    exe = fut.result()
+                    exe = _process.join_build(
+                        fut, get_tracer(), where="dispatch",
+                        label=f"fused group {plan['gi']}")
 
                     def call(*args, _exe=exe, _jit=jit_fn, _plan=plan):
                         try:

@@ -232,10 +232,15 @@ class Project:
                 SharedState("parallel/dataplane.py",
                             "dataplane.StagingRing._lock",
                             cls="StagingRing", attrs=("_rings",)),
-                # pipeline: persistent-cache event counters
-                SharedState("parallel/pipeline.py",
-                            "pipeline._LISTENER_LOCK",
-                            name="_CACHE_EVENTS"),
+                # the process ledger: totals, records and the
+                # persistent-cache event counters, fed by jax's
+                # monitoring callbacks on whichever thread builds
+                SharedState("obs/process.py",
+                            "process.ProcessLedger._lock",
+                            cls="ProcessLedger",
+                            attrs=("totals", "cache_events", "wait_s",
+                                   "blocking_s", "calls", "fits",
+                                   "_builds", "_union_s")),
                 # faults: the supervisor's recovery bookkeeping
                 SharedState("parallel/faults.py",
                             "faults.LaunchSupervisor._lock",
@@ -414,6 +419,10 @@ class Project:
                 BlockSpec("heartbeat", "HEARTBEAT_BLOCK_SCHEMA", (
                     Producer("dict-keys", "obs/heartbeat.py",
                              "heartbeat_block"),
+                )),
+                BlockSpec("process", "PROCESS_BLOCK_SCHEMA", (
+                    Producer("dict-keys", "obs/process.py",
+                             "ProcessLedger.report"),
                 )),
                 BlockSpec("recovery", "RECOVERY_BLOCK_SCHEMA", (
                     Producer("dict-keys", "obs/telemetry.py",
