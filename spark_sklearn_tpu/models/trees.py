@@ -30,8 +30,10 @@ boosting/bagging layers are `lax.while_loop`/`vmap` programs:
     it always had.  A compile group differs in `n_estimators` alone, so
     any chunk of it may share: the launch runs a `while_loop` to the
     largest count among its candidates over lanes = folds (one tree's
-    level histograms a fold live at a time: 268 MB at depth 10 and
-    covtype's width, priced by `launch_workspace` whatever the width), and
+    level histograms a fold live at a time, at each node's own
+    `max_features` features, drawn before the histograms: 33.5 MB at
+    depth 10 under covtype's 7 of 54, 268 MB where a node may split on
+    any; priced by `launch_workspace` whatever the width), and
     keeps a vote accumulator a candidate and fold.  Grading a group's
     launches by tree count would only regrow the shared first trees, so
     the forest families have no `convergence_proxy`: a group is one launch
@@ -68,8 +70,10 @@ N_BINS = 256
 #: the pass over it for the gains keeps beside it), and of a row's sorted
 #: bytes (the tables the sorts gather from, the gathered, padded and
 #: transposed copies); read off `memory_analysis` of the covtype cell's
-#: launches, 5 forests x 3 candidates: 2.04 GB of scratch at depth 10,
-#: 0.63 GB at depth 8 and at depth 6 alike (PERF.md section 4)
+#: launches, 5 forests x 3 candidates, with every feature's histograms:
+#: 2.04 GB of scratch at depth 10, 0.63 GB at depth 8 and at depth 6
+#: alike; with a node's own 7 of 54, 0.63 GB at every depth, the rows'
+#: share (PERF.md section 4)
 _HIST_COPIES = 1.4
 _ROW_COPIES = 5
 #: copies of a candidate's votes: the loop's carry and its update, the
@@ -232,6 +236,11 @@ class GradientBoostingRegressorFamily(Family):
             (jnp.asarray(0, jnp.int32), F))
         return {"pred": F, "f0": F0, "lr": lr, "n_est": n_est,
                 "n_iter": n_lim}
+
+    @classmethod
+    def launch_facts(cls, static, meta, n_candidates, n_folds):
+        # a stage's trees may split on any feature
+        return {"hist_features": int(meta["n_features"])}
 
     @classmethod
     def predict(cls, model, static, X, meta):
@@ -503,15 +512,25 @@ class RandomForestClassifierFamily(Family):
         return stats
 
     @classmethod
+    def _hist_features(cls, static, meta):
+        """Features a node's histograms hold: its own `max_features`
+        where that is a subset (`grow_tree` builds no others), every one
+        otherwise."""
+        d = int(meta["n_features"])
+        return min(cls._max_features(static, d), d)
+
+    @classmethod
     def _hist_bytes(cls, static, meta):
         """Bytes of one lane's deepest level of histograms."""
         return tree_hist.level_histogram_bytes(
             _depth(static, cls._default_depth), meta["n_features"],
-            cls._n_stats(meta), N_BINS)
+            cls._n_stats(meta), N_BINS,
+            slots=cls._hist_features(static, meta))
 
     @classmethod
     def launch_facts(cls, static, meta, n_candidates, n_folds):
-        return {"hist_bytes": cls._hist_bytes(static, meta)}
+        return {"hist_bytes": cls._hist_bytes(static, meta),
+                "hist_features": cls._hist_features(static, meta)}
 
     @classmethod
     def launch_workspace(cls, n_samples, meta, n_folds, itemsize=4, *,

@@ -69,6 +69,9 @@ class MetricDef:
     combine: Optional[str] = None
     #: the entry of a launch that reports solver stats and not this one
     fill: Optional[int] = None
+    #: a fact of the compile group whatever a launch's width: also
+    #: written, under ``stat``, on the group's ``per_group`` record
+    group: bool = False
 
 
 #: the pinned schema of ``BaseSearchTPU.search_report``
@@ -107,8 +110,11 @@ SEARCH_REPORT_SCHEMA = (
         "per_group", "struct",
         "Per-compile-group record: static_params (repr), n_launches, "
         "fit_wall_s, score_wall_s, score_path "
-        "(scan-fused/wide-fused/wide/nested) and, when fused chunks "
-        "calibrated, score_s_per_task_calibrated."),
+        "(scan-fused/wide-fused/wide/nested), when fused chunks "
+        "calibrated, score_s_per_task_calibrated, the data's n_features "
+        "where the family's meta names it and, of a tree family, "
+        "hist_features (of those n_features, the features a node's level "
+        "histograms hold)."),
     MetricDef(
         "solver_iters_per_launch", "series",
         "Per-launch max executed solver iterations over the launch's "
@@ -222,10 +228,20 @@ SEARCH_REPORT_SCHEMA = (
         "hist_bytes_per_lane", "series",
         "Per launch of a forest family: bytes of one forest's deepest "
         "level of (node, feature, statistic, bin) float32 histograms "
-        "as the launch writes them: 2^(depth - 1) nodes x features x "
-        "(1 + outputs) x 256 x 4, features and statistics padded to "
-        "the kernel's blocks on a TPU.",
+        "as the launch writes them: 2^(depth - 1) nodes x "
+        "hist_features_per_node x (1 + outputs) x 256 x 4, features and "
+        "statistics padded to the kernel's blocks on a TPU.",
         stat="hist_bytes", combine="fact"),
+    MetricDef(
+        "hist_features_per_node", "series",
+        "Per launch of a tree family: features a node's level "
+        "histograms hold.  A forest whose max_features is a subset "
+        "draws each node's own features BEFORE the level's histograms "
+        "and builds those and no others (7 of 54 at covtype's width "
+        "under 'sqrt'); every feature where a node may split on any (a "
+        "booster, max_features = n_features).  Also on the group's "
+        "per_group record, beside n_features.",
+        stat="hist_features", combine="fact", group=True),
     MetricDef(
         "trees_per_candidate", "series",
         "Forest families: trees each candidate grew (its own "

@@ -2,15 +2,25 @@
 sums of every statistic over the rows that sit in the node, as cumulative
 sums over the bins (``bin <= b``: the left side of a split at ``b``).
 
+Which features: every one of the ``d`` (a booster; a forest whose
+``max_features`` is ``d``), or, where each node of the level draws its own
+subset (``sel (nodes, slots)``, a node's features ascending), the node's
+own ``slots`` features and no others.  The feature axis of what is built is
+then the node's *slot* ``j``: a row adds to slot ``j`` under the code of
+ITS node's ``j``-th feature, so a level costs ``slots`` products a tile
+where every feature costs ``d``, and its histograms hold ``slots / d`` of
+the bytes.  A gain is only ever read at a node's own features, so nothing
+that could be chosen is left out.
+
 Two forms of one step of ``ops/trees.py::grow_tree``:
 
-- :class:`PlainLevels`: a ``segment_sum`` over ``n x d`` flat ids a
+- :class:`PlainLevels`: a ``segment_sum`` over ``n x slots`` flat ids a
   statistic and a ``cumsum`` over the bins, every row sent to its child by
   gathers.  The plain form: what the kernels are tested against, and what
   XLA:CPU runs.  XLA:TPU serialises scatters, so at 10^5 rows it is seconds
   a level there.
 - :class:`GroupedLevels`: rows **grouped by node**, so that a level costs
-  ``2 * rows * n_bins * d * S`` product FLOPs whatever its node count.  A
+  ``2 * rows * n_bins * slots * S`` product FLOPs whatever its node count.  A
   tree's rows are put into node order at every fifth level only (one sort,
   one gather of a row's packed codes and statistics): between two sorts the
   nodes of a *group* (16 nodes that descend from neighbours in the sorted
@@ -19,8 +29,11 @@ Two forms of one step of ``ops/trees.py::grow_tree``:
   the row tile and at every group's first row; each piece is one *item*
   ``(tile, group, first row, last row)`` in scalar prefetch.  A grid step
   takes one item, builds in VMEM the ``(16 nodes x statistics, T)`` operand
-  (a row's statistics in its own node's rows, zeros elsewhere) and, a
-  feature at a time, the ``(n_bins, T)`` mask ``code <= bin``, and adds
+  (a row's statistics in its own node's rows, zeros elsewhere), with a
+  ``sel`` the ``(slots, T)`` slot codes (one small product of the group's
+  ``(16 nodes x slots, d)`` one-hot of ``sel`` with the tile's codes, then
+  each row's own node's rows of it) and, a slot at a time, the
+  ``(n_bins, T)`` mask ``code <= bin``, and adds
   their product to the group's block, which stays resident while
   consecutive items name the same group.  Rows that count for nothing (a
   fold's test rows, a tree's out-of-bag rows) are sorted behind the others
@@ -50,7 +63,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 #: rows a grid step takes
 ROW_TILE = 512
-#: features a grid step's blocks hold (a uint8 tile is 32 sublanes)
+#: features a grid step's blocks hold at most (a uint8 tile is 32
+#: sublanes): the histogram kernel's output block, and with every feature
+#: built its block of codes; the codes are padded to a multiple of it
 FEATURE_BLOCK = 32
 #: nodes of one group: with 8 statistic rows a node, the 128 rows of one
 #: pass of the MXU
@@ -74,15 +89,31 @@ def _group_shape(level):
     return k, 2 ** level // k
 
 
+def _slot_blocks(n_slots, subset):
+    """(slots a block, blocks) of the histogram kernel's feature axis:
+    every feature in blocks of ``FEATURE_BLOCK``; a node's own features
+    (``subset``) in one block of whole sublane tiles where that is no
+    more, in blocks of ``FEATURE_BLOCK`` otherwise."""
+    block = FEATURE_BLOCK
+    if subset:
+        block = min(_round_up(n_slots, 8), block)
+    return block, -(-int(n_slots) // block)
+
+
 # ---------------------------------------------------------------------------
 # the plain form
 # ---------------------------------------------------------------------------
 
-def plain_level_histograms(codes, stats, local, live, n_nodes, n_bins):
-    """``(n_nodes, d, S, n_bins)`` plain (not cumulative) histograms: one
-    ``segment_sum`` a statistic over the ``n x d`` flat (node, feature,
-    bin) ids."""
-    n, d = codes.shape
+def plain_level_histograms(codes, stats, local, live, n_nodes, n_bins,
+                           sel=None):
+    """``(n_nodes, slots, S, n_bins)`` plain (not cumulative) histograms:
+    one ``segment_sum`` a statistic over the ``n x slots`` flat (node,
+    slot, bin) ids.  A slot is a feature (``slots = d``) or, with ``sel
+    (n_nodes, slots)``, the row's own node's feature ``sel[node, slot]``."""
+    n = codes.shape[0]
+    if sel is not None:
+        codes = jnp.take_along_axis(codes, sel[local], axis=1)
+    d = codes.shape[1]
     ids = (local[:, None] * d + jnp.arange(d, dtype=jnp.int32)[None, :]
            ) * n_bins + codes.astype(jnp.int32)             # (n, d)
     ids = jnp.where(live[:, None], ids, 0).reshape(-1)
@@ -113,12 +144,15 @@ class PlainLevels:
         # any node's split below
         return jnp.clip(self.node - (n_nodes - 1), 0, n_nodes - 1)
 
-    def histograms(self, level):
+    def histograms(self, level, sel=None):
+        """``(2^level, d, S, n_bins)``, or ``(2^level, slots, S, n_bins)``
+        at each node's own features ``sel (2^level, slots)``."""
         n_nodes = 2 ** level
         with jax.named_scope("sst.tree.histogram"):
             return jnp.cumsum(plain_level_histograms(
                 self.codes, self.stats, self._local(n_nodes),
-                jnp.logical_not(self.frozen), n_nodes, self.n_bins), axis=3)
+                jnp.logical_not(self.frozen), n_nodes, self.n_bins, sel),
+                axis=3)
 
     def route(self, level, feature, threshold, splits):
         with jax.named_scope("sst.tree.route"):
@@ -141,9 +175,18 @@ class PlainLevels:
 # ---------------------------------------------------------------------------
 
 def _hist_kernel(tile_ref, group_ref, lo_ref, hi_ref, codes_ref, stats_ref,
-                 state_ref, out_ref, codes_i32, *, n_items, n_feat, s8,
-                 parts, n_bins, k_nodes, n_groups, offset):
+                 state_ref, *rest, n_items, n_slots, s8, parts, n_bins,
+                 k_nodes, n_groups, offset):
+    """One item's share of its group's block ``(k_nodes, slots a block,
+    s8, n_bins)``.  ``codes_ref`` holds the block's own features of the
+    tile, which ARE its slot codes; or, with ``pick_ref`` (the group's
+    ``(k_nodes x slots a block, d_pad)`` one-hot of its nodes' own
+    features), every feature of the tile, and a row's slot codes are its
+    own node's rows of ``pick @ codes``.  Slots past ``n_slots`` are never
+    built and read zero."""
     del tile_ref
+    pick_ref = rest[0] if len(rest) == 3 else None
+    out_ref, slot_codes = rest[-2:]
     lane, fb, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     at = lane * n_items + i
     group = jnp.minimum(group_ref[at], n_groups - 1)
@@ -171,14 +214,30 @@ def _hist_kernel(tile_ref, group_ref, lo_ref, hi_ref, codes_ref, stats_ref,
         if len(blocks) * st.shape[0] % 16:      # bfloat16 packs 16 rows
             blocks.append(jnp.zeros_like(st))
         operand = jnp.concatenate(blocks, axis=0).astype(jnp.bfloat16)
-        codes_i32[...] = codes_ref[0].astype(jnp.int32)
+        codes = codes_ref[0].astype(jnp.int32)
+        block = slot_codes.shape[0]
+        if pick_ref is None:
+            slot_codes[...] = codes
+        else:
+            # codes and the one-hot are exact in bfloat16, their product
+            # in float32: (k_nodes * block, T), node k's slots' codes of
+            # every row; a row keeps its own node's
+            picked = jax.lax.dot_general(
+                pick_ref[0, 0],
+                codes.astype(jnp.float32).astype(jnp.bfloat16),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            own = picked[:block]
+            for k in range(1, k_nodes):
+                own = jnp.where(mine == k,
+                                picked[k * block:(k + 1) * block], own)
+            slot_codes[...] = own.astype(jnp.int32)
         bins = jax.lax.broadcasted_iota(jnp.int32, (n_bins, rows), 0)
-        # features of the last block past the data's own are never read
-        n_here = jnp.minimum(codes_i32.shape[0],
-                             n_feat - fb * codes_i32.shape[0])
+        # slots of the last block past the level's own are never read
+        n_here = jnp.minimum(block, n_slots - fb * block)
 
-        def one_feature(f, carry):
-            row = codes_i32[pl.ds(f, 1), :]                 # (1, T)
+        def one_slot(f, carry):
+            row = slot_codes[pl.ds(f, 1), :]                # (1, T)
             mask = jnp.where(row <= bins, 1.0, 0.0).astype(jnp.bfloat16)
             # the mask is the product's stationary operand: with the
             # statistics there instead, and the mask's rows streamed, a
@@ -192,7 +251,7 @@ def _hist_kernel(tile_ref, group_ref, lo_ref, hi_ref, codes_ref, stats_ref,
                 out_ref[0, k, f] += functools.reduce(jnp.add, part)
             return carry
 
-        jax.lax.fori_loop(0, n_here, one_feature, 0)
+        jax.lax.fori_loop(0, n_here, one_slot, 0)
 
 
 def _route_kernel(tile_ref, group_ref, lo_ref, hi_ref, word_ref, codes_ref,
@@ -239,52 +298,70 @@ def _flat(tables):
     return flat[0], flat[1], flat[2], flat[3]
 
 
-def _hist_lanes_impl(tables, codes_t, stats_t, state, *, level, n_feat,
-                     n_bins, parts, tile, interpret):
+def _hist_lanes_impl(tables, codes_t, stats_t, state, *pick, level,
+                     n_slots, n_bins, parts, tile, interpret):
     """``tables (L, 4, items)`` int32, ``codes_t (L, d_pad, n_pad)`` uint8,
     ``stats_t (L, parts * S8, n_pad)`` bfloat16, ``state (L, 8, n_pad)``
-    int32 -> ``(L, 2^level, d_pad, S8, n_bins)`` float32, cumulative over
-    the bins."""
+    int32 -> ``(L, 2^level, blocks x slots a block, S8, n_bins)`` float32,
+    cumulative over the bins.  The slots are the ``n_slots = d`` features,
+    or with one more operand, ``pick (L, blocks, 2^level x slots a block,
+    d_pad)`` bfloat16 (a node's slot's feature, one-hot), each node's own
+    ``n_slots``."""
+    (pick,) = pick or (None,)
     n_lanes, _, n_items = tables.shape
     d_pad, r = codes_t.shape[1], stats_t.shape[1]
     s8 = r // parts
     k_nodes, n_groups = _group_shape(level)
+    block, n_blocks = _slot_blocks(n_slots, pick is not None)
+    # a block's own features, or every feature whatever the block
+    code_rows = block if pick is None else d_pad
 
     def at(lane, i):
         return lane * n_items + i
 
+    def group(g, lane, i):
+        return jnp.minimum(g[at(lane, i)], n_groups - 1)
+
+    in_specs = [
+        pl.BlockSpec((1, code_rows, tile),
+                     lambda l, fb, i, t, g, lo, hi: (
+                         l, fb if pick is None else 0, t[at(l, i)])),
+        pl.BlockSpec((1, r, tile),
+                     lambda l, fb, i, t, g, lo, hi: (l, 0, t[at(l, i)])),
+        pl.BlockSpec((1, 8, tile),
+                     lambda l, fb, i, t, g, lo, hi: (l, 0, t[at(l, i)])),
+    ]
+    operands = [codes_t, stats_t, state]
+    if pick is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, 1, k_nodes * block, d_pad),
+            lambda l, fb, i, t, g, lo, hi: (l, fb, group(g, l, i), 0)))
+        operands.append(pick)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(n_lanes, d_pad // FEATURE_BLOCK, n_items),
-        in_specs=[
-            pl.BlockSpec((1, FEATURE_BLOCK, tile),
-                         lambda l, fb, i, t, g, lo, hi: (l, fb, t[at(l, i)])),
-            pl.BlockSpec((1, r, tile),
-                         lambda l, fb, i, t, g, lo, hi: (l, 0, t[at(l, i)])),
-            pl.BlockSpec((1, 8, tile),
-                         lambda l, fb, i, t, g, lo, hi: (l, 0, t[at(l, i)])),
-        ],
+        grid=(n_lanes, n_blocks, n_items),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, k_nodes, FEATURE_BLOCK, s8, n_bins),
-            lambda l, fb, i, t, g, lo, hi: (
-                l, jnp.minimum(g[at(l, i)], n_groups - 1), fb, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((FEATURE_BLOCK, tile), jnp.int32)],
+            (1, k_nodes, block, s8, n_bins),
+            lambda l, fb, i, t, g, lo, hi: (l, group(g, l, i), fb, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((block, tile), jnp.int32)],
     )
     kernel = functools.partial(
-        _hist_kernel, n_items=n_items, n_feat=n_feat, s8=s8, parts=parts,
+        _hist_kernel, n_items=n_items, n_slots=n_slots, s8=s8, parts=parts,
         n_bins=n_bins, k_nodes=k_nodes, n_groups=n_groups,
         offset=2 ** level - 1)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(
-            (n_lanes, 2 ** level, d_pad, s8, n_bins), jnp.float32),
+            (n_lanes, 2 ** level, n_blocks * block, s8, n_bins),
+            jnp.float32),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=48 * 2 ** 20),
         interpret=interpret,
         name="sst_tree_histogram",
-    )(*_flat(tables), codes_t, stats_t, state)
+    )(*_flat(tables), *operands)
 
 
 def _route_lanes_impl(tables, words, codes_t, state, *, level, tile,
@@ -329,9 +406,13 @@ def _route_lanes_impl(tables, words, codes_t, state, *, level, tile,
 @functools.lru_cache(maxsize=None)
 def _lanes(impl, **static):
     """A kernel call with the lanes in front of every operand, and
-    ``jax.vmap`` of it as one call over the merged lanes."""
+    ``jax.vmap`` of it as one call over the merged lanes.  The call is a
+    ``jit`` of its own so that its trace is kept by shape: batching a
+    ``while_loop``'s body walks it to a fixed point, which traced every
+    kernel of every level four times where two will do (the lane-less
+    shapes once, the merged lanes' once)."""
     call = jax.custom_batching.custom_vmap(
-        functools.partial(impl, **static))
+        jax.jit(functools.partial(impl, **static)))
 
     @call.def_vmap
     def _(axis_size, in_batched, *operands):
@@ -431,6 +512,20 @@ def group_items(starts, tile, n_tiles, n_items):
     return jnp.stack([tile_of, group, lo, hi]).astype(jnp.int32)
 
 
+def _pick(sel, d_pad):
+    """``sel (nodes, slots)`` -> ``(blocks, nodes x slots a block, d_pad)``
+    bfloat16: 1 where the column is the feature of the row's (node, slot);
+    a slot past the node's own is a row of zeros."""
+    n_nodes, slots = sel.shape
+    block, n_blocks = _slot_blocks(slots, True)
+    feature = jnp.arange(d_pad, dtype=jnp.int32)
+    pick = jnp.pad(sel[:, :, None] == feature[None, None, :],
+                   ((0, 0), (0, n_blocks * block - slots), (0, 0)))
+    return pick.reshape(n_nodes, n_blocks, block, d_pad).transpose(
+        1, 0, 2, 3).reshape(n_blocks, n_nodes * block, d_pad).astype(
+        jnp.bfloat16)
+
+
 class GroupedLevels:
     """A tree's rows in the kernels' order: sorted every
     ``LEVELS_PER_SORT`` levels by (counts for nothing, node), their codes
@@ -497,19 +592,25 @@ class GroupedLevels:
                            self.n_tiles, self.n_tiles + 2 * n_groups)
 
     # -- a level -------------------------------------------------------------
-    def histograms(self, level):
-        """``(2^level, d_pad, S8, n_bins)``: features and statistics past
-        the data's own read zero."""
+    def histograms(self, level, sel=None):
+        """``(2^level, d_pad, S8, n_bins)``, or at each node's own
+        features ``sel (2^level, slots)`` ``(2^level, slots padded to the
+        kernel's blocks, S8, n_bins)``: features, slots and statistics
+        past the data's own read zero."""
         if level % LEVELS_PER_SORT == 0:
             self._sort(level)
         with jax.named_scope("sst.tree.partition"):
             self.tables = self._items(level)
         with jax.named_scope("sst.tree.histogram"):
-            call = _lanes(_hist_lanes_impl, level=level, n_feat=self.d,
+            call = _lanes(_hist_lanes_impl, level=level,
+                          n_slots=self.d if sel is None else sel.shape[1],
                           n_bins=self.n_bins, parts=self.parts,
                           tile=self.tile, interpret=self.interpret)
-            return call(self.tables[None], self.codes_t[None],
-                        self.stats_t[None], self._state()[None])[0]
+            operands = [self.tables, self.codes_t, self.stats_t,
+                        self._state()]
+            if sel is not None:
+                operands.append(_pick(sel, self.codes_t.shape[0]))
+            return call(*(x[None] for x in operands))[0]
 
     def route(self, level, feature, threshold, splits):
         with jax.named_scope("sst.tree.route"):
@@ -539,13 +640,17 @@ def levels_of(codes, stats, n_bins, integer_stats=False):
     return form(codes, stats, n_bins, integer_stats)
 
 
-def level_histogram_bytes(depth, n_features, n_stats, n_bins=256):
-    """Bytes of the deepest level's histograms of one lane: as the kernel
-    writes them on a TPU (features and statistics padded to its blocks),
-    as the plain form does elsewhere."""
-    d, s = int(n_features), int(n_stats)
+def level_histogram_bytes(depth, n_features, n_stats, n_bins=256,
+                          slots=None):
+    """Bytes of the deepest level's histograms of one lane, every feature
+    of ``n_features`` or, where that is fewer, each node's own ``slots``:
+    as the kernel writes them on a TPU (features or slots, and statistics,
+    padded to its blocks), as the plain form does elsewhere."""
+    subset = slots is not None and slots < n_features
+    d, s = int(slots if subset else n_features), int(n_stats)
     if on_tpu():
-        d, s = _padded_features(d), _round_up(s, 8)
+        block, n_blocks = _slot_blocks(d, subset)
+        d, s = block * n_blocks, _round_up(s, 8)
     return (2 ** max(int(depth) - 1, 0) * d * s * int(n_bins)
             * np.dtype(np.float32).itemsize)
 

@@ -14,6 +14,11 @@ all segment-sums and cumulative sums over fixed shapes:
     (ops/tree_hist.py: a grouped one-hot product kernel on a TPU, one
     `segment_sum` a statistic and a cumsum elsewhere), and the best
     (feature, bin) per node is an argmax — no per-node control flow;
+  - where a node may split on a random subset of the features only (a
+    forest's `max_features`), the subsets of a level are drawn BEFORE its
+    histograms, which are built at each node's own features and no
+    others: the feature axis of everything a level computes is then the
+    node's slot, `max_features` wide instead of `d`;
   - nodes live in a heap-indexed array (children of i at 2i+1/2i+2) so the
     tree is a pytree of fixed arrays: feat, thresh_bin, leaf flag, value.
 
@@ -43,6 +48,27 @@ class Tree(NamedTuple):
                              # for its own rows without a second walk
 
 
+def feature_subsets(key, level, n_nodes, d, max_features):
+    """``(n_nodes, max_features)`` int32: each node's own features of a
+    level, ascending.  A node takes the features whose score
+    ``uniform(fold_in(key, level), (n_nodes, d))[node]`` is no larger than
+    its ``max_features``-th smallest, and of those (more than
+    ``max_features`` only where two float32 scores tie there) the first
+    ``max_features`` in feature order."""
+    scores = jax.random.uniform(jax.random.fold_in(key, level),
+                                (n_nodes, d))
+    kth = jnp.sort(scores, axis=1)[:, max_features - 1][:, None]
+    chosen = scores <= kth
+    # a chosen feature's place among the node's chosen: its slot
+    place = jnp.cumsum(chosen, axis=1, dtype=jnp.int32) - 1
+    slot = jnp.arange(max_features, dtype=jnp.int32)
+    feature = jnp.arange(d, dtype=jnp.int32)
+    return jnp.sum(
+        jnp.where(chosen[:, None, :]
+                  & (place[:, None, :] == slot[None, :, None]),
+                  feature[None, None, :], 0), axis=2)
+
+
 def grow_tree(codes, g, h, w, max_depth, n_bins, min_child_weight=1e-3,
               reg_lambda=1.0, feat_mask_key=None, max_features=None,
               n_out=1, integer_stats=False):
@@ -56,7 +82,16 @@ def grow_tree(codes, g, h, w, max_depth, n_bins, min_child_weight=1e-3,
     x one-hot class), which one bfloat16 part holds exactly.  Returns a
     Tree whose value column holds the Newton leaf step per output.
 
-    A level's cumulative (node, feature, bin) histograms and the rows'
+    With `feat_mask_key` and `max_features < d`, a node splits on one of
+    its own `max_features` features, a fresh subset a node and level
+    (`feature_subsets`: the forest analog of sklearn's per-split
+    max_features), and a level's histograms are built at those and no
+    others: their feature axis is the node's slot.  Slots ascend by
+    feature, so the first largest gain in (slot, bin) order is the first
+    in (feature, bin) order over the subset.  Otherwise a slot is a
+    feature.
+
+    A level's cumulative (node, slot, bin) histograms and the rows'
     way down come from ``ops/tree_hist.py`` (the grouped kernels on a TPU,
     the plain ``segment_sum`` form elsewhere); every node's sums, and with
     them the leaf values, are read off the histograms (the root's totals,
@@ -65,6 +100,9 @@ def grow_tree(codes, g, h, w, max_depth, n_bins, min_child_weight=1e-3,
     d = codes.shape[1]
     n_last = 2 ** max_depth                   # nodes below the last level
     feat, thresh, is_leaf = [], [], []        # a level's (nodes,) each
+    subset = (feat_mask_key is not None and max_features is not None
+              and max_features < d)
+    n_slots = max_features if subset else d
 
     # the statistics of a row: its hessian, then a gradient an output
     stats = jnp.concatenate([(h * w)[:, None], g * w[:, None]],
@@ -74,11 +112,15 @@ def grow_tree(codes, g, h, w, max_depth, n_bins, min_child_weight=1e-3,
 
     for level in range(max_depth):
         n_nodes = 2 ** level
-        # (nodes, d, 1 + n_out, bins), or wider in features and
-        # statistics where the kernel pads them: the padding reads zero,
-        # so no row is on either side of a split there
-        cum = rows.histograms(level)
-        d_wide = cum.shape[1]
+        sel = None
+        if subset:
+            with jax.named_scope("sst.tree.split"):
+                sel = feature_subsets(feat_mask_key, level, n_nodes, d,
+                                      max_features)
+        # (nodes, slots, 1 + n_out, bins), or wider in slots and
+        # statistics where the kernel pads them: the padding reads zero
+        cum = rows.histograms(level, sel)
+        wide = cum.shape[1]
 
         with jax.named_scope("sst.tree.split"):
             # a node's sums are known before its histograms: the root's
@@ -106,28 +148,23 @@ def grow_tree(codes, g, h, w, max_depth, n_bins, min_child_weight=1e-3,
                 jnp.where(is_output[None, None, :, None], terms, 0.0),
                 axis=2)
             left_h, right_h = left_h[:, :, 0, :], right_h[:, :, 0, :]
+            # no split with too little on a side
             ok = (left_h >= min_child_weight) & (right_h >= min_child_weight)
+            if wide > n_slots:      # nor at a slot of the padding
+                ok = ok & (jnp.arange(wide) < n_slots)[None, :, None]
             gain = jnp.where(ok, gain, -jnp.inf)
             # never split on the last bin (empty right side by
             # construction)
             gain = gain.at[..., -1].set(-jnp.inf)
 
-            if feat_mask_key is not None and max_features is not None and \
-                    max_features < d:
-                # per-(node) random feature subset, fresh every level — the
-                # forest analog of sklearn's per-split max_features
-                k_lvl = jax.random.fold_in(feat_mask_key, level)
-                scores = jax.random.uniform(k_lvl, (n_nodes, d))
-                kth = jnp.sort(scores, axis=1)[:, max_features - 1][:, None]
-                fmask = jnp.pad(scores <= kth, ((0, 0), (0, d_wide - d)))
-                gain = jnp.where(fmask[:, :, None], gain, -jnp.inf)
-
-            flat_gain = gain.reshape(n_nodes, d_wide * n_bins)
+            flat_gain = gain.reshape(n_nodes, wide * n_bins)
             best = jnp.argmax(flat_gain, axis=1)            # (n_nodes,)
             best_gain = jnp.take_along_axis(
                 flat_gain, best[:, None], axis=1)[:, 0]
-            bf = (best // n_bins).astype(jnp.int32)
+            slot = (best // n_bins).astype(jnp.int32)
             bb = (best % n_bins).astype(jnp.int32)
+            bf = slot if sel is None else jnp.take_along_axis(
+                sel, slot[:, None], axis=1)[:, 0]
             do_split = best_gain > 1e-7
 
             feat.append(jnp.where(do_split, bf, -1))
@@ -135,11 +172,11 @@ def grow_tree(codes, g, h, w, max_depth, n_bins, min_child_weight=1e-3,
             is_leaf.append(jnp.logical_not(do_split))
 
             # the sums of the children a split makes: left at the chosen
-            # (feature, bin), right the rest of the node's
+            # (slot, bin), right the rest of the node's
             total = sums[-1]
             left = jnp.take_along_axis(
                 jnp.take_along_axis(
-                    cum, bf[:, None, None, None], axis=1)[:, 0],
+                    cum, slot[:, None, None, None], axis=1)[:, 0],
                 bb[:, None, None], axis=2)[:, :1 + n_out, 0]  # (nodes, S)
             sums.append(jnp.stack([left, total - left], axis=1).reshape(
                 2 * n_nodes, -1))

@@ -3485,7 +3485,9 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 "n_launches": 0, "fit_wall_s": 0.0, "score_wall_s": 0.0,
                 "score_path": ("scan-fused" if scan_mode else
                                "wide-fused" if fused_mode else
-                               "wide" if all_cores else "nested")})
+                               "wide" if all_cores else "nested"),
+                **({"n_features": int(meta["n_features"])}
+                   if "n_features" in meta else {})})
 
         def record_launch(plan, res, lanes, idx):
             """One launch's solver stats into the report: what the
@@ -3498,7 +3500,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             facts = family.launch_facts(plan["static"], meta,
                                         lanes // n_folds, n_folds)
             record_stats(metrics, {**facts, **res.stats}, idx,
-                         len(candidates), n_folds)
+                         len(candidates), n_folds, per_group_rec(plan))
 
         def replay_chunk(idx, rec):
             """Write a journalled chunk's cells back — shared by the

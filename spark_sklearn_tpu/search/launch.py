@@ -101,14 +101,18 @@ jax.export.register_pytree_node_serialization(
     serialize_auxdata=lambda aux: b"", deserialize_auxdata=lambda b: ())
 
 
-def record_stats(metrics, stats, idx, n_candidates, n_folds) -> None:
+def record_stats(metrics, stats, idx, n_candidates, n_folds,
+                 group_rec) -> None:
     """Append one launch's ``stats`` (the family's host facts merged in)
-    to the ``search_report`` series ``LAUNCH_STATS`` names for them.
+    to the ``search_report`` series ``LAUNCH_STATS`` names for them, and
+    write the compile group's own facts on its ``group_rec``.
     ``idx``: the cv_results_ positions of the launch's real candidates."""
     for stat, d in LAUNCH_STATS.items():
         value = stats.get(stat, d.fill)
         if value is None:
             continue
+        if d.group:
+            group_rec[stat] = int(value)
         series = metrics.series(d.name)
         if d.combine != "per_candidate":
             series.append(int(value))

@@ -34,7 +34,7 @@ FOLDS = 3
 CONFIG = {"estimator": {"params": {"random_state": 0}}}
 COUNTERS = ("tree_slots_per_launch", "tree_levels_per_launch",
             "hist_bytes_per_lane", "trees_per_candidate",
-            "trees_grown_per_launch")
+            "trees_grown_per_launch", "hist_features_per_node")
 
 
 def make_data(n, d, k, seed):
@@ -148,8 +148,12 @@ def test_counters_of_a_forest_search():
     assert rep["tree_levels_per_launch"] == [
         6 * FOLDS * depth for depth in (3, 5)]
     assert rep["trees_per_candidate"] == [3, 6, 4, 3, 6, 4]
+    # a node's histograms hold its own sqrt(20) = 4 features of the 20
+    assert rep["hist_features_per_node"] == [4, 4]
+    assert [(g["hist_features"], g["n_features"])
+            for g in rep["per_group"].values()] == [(4, 20)] * 2
     assert rep["hist_bytes_per_lane"] == [
-        2 ** (depth - 1) * 20 * 5 * 256 * 4 for depth in (3, 5)]
+        2 ** (depth - 1) * 4 * 5 * 256 * 4 for depth in (3, 5)]
     # the ledger: the forests' histograms whatever the width, a
     # candidate's votes (four copies, by fold and row) on top of its masks
     groups = rep["memory"]["groups"]
@@ -157,8 +161,8 @@ def test_counters_of_a_forest_search():
             for g, h in zip(groups, rep["hist_bytes_per_lane"])] == [True] * 2
     assert [g["per_candidate_bytes"] > FOLDS * 600 * (4 + 4 * 4 * 4)
             for g in groups] == [True] * 2
-    assert [g["per_candidate_bytes"] < h
-            for g, h in zip(groups, rep["hist_bytes_per_lane"])] == [True] * 2
+    assert [g["per_candidate_bytes"] < g["fixed_bytes"]
+            for g in groups] == [True] * 2
     assert all(g["capped"] is False for g in groups)
 
 
