@@ -7,13 +7,20 @@ in ops/trees.py, whose level histograms and routing are ops/tree_hist.py's
 (grouped one-hot product kernels on a TPU, segment sums elsewhere); the
 boosting/bagging layers are `lax.while_loop`/`vmap` programs:
 
-  - GBDT: a loop over trees, carry the prediction vector F on the FULL
-    dataset (fold masks only weight the gradients), per-class trees for
-    multiclass.  `n_estimators` is DYNAMIC: the program always grows the
-    grid's maximum tree count and masks each tree's contribution by
-    `t < n_estimators` — boosting is prefix-stable (tree t only depends on
-    trees < t), so one compiled program serves every n_estimators value in
-    the grid instead of one compile group per value.
+  - GBDT: a loop over stages (`_boost`), carry the raw scores F on the
+    FULL dataset (fold masks only weight the gradients).  A stage is ONE
+    tree for a regressor and for two classes (scikit-learn's half-binomial
+    loss on the log-odds of class 1), a tree a class on the softmax's
+    gradients for three or more.  `n_estimators` is DYNAMIC: the program
+    is built for the grid's maximum stage count and a lane stops at its
+    own — boosting is prefix-stable (stage t only depends on stages < t),
+    so one compiled program serves every n_estimators value in the grid
+    instead of one compile group per value.  A lane is a (candidate,
+    fold) of its own (its gradients are real numbers and differ by
+    learning rate, so every feature's histograms of every node are built
+    from three bfloat16 parts a statistic: `ops/tree_hist.py`), priced by
+    `launch_workspace`; the engine cuts a group's launches where the
+    counts change (`convergence_proxy`), so no lane idles.
   - Random forest: ONE forest a fold, grown once a launch and read at every
     `n_estimators` of the launch's candidates (`fit_task_batched`; PR 36).
     `fit` draws `keys = split(PRNGKey(random_state), t_max)` with `t_max`
@@ -79,6 +86,10 @@ _ROW_COPIES = 5
 #: copies of a candidate's votes: the loop's carry and its update, the
 #: average, the model's output
 _VOTE_COPIES = 4
+#: float32 vectors a boosting lane holds a raw score beside its tree: F in
+#: the loop's carry and its update, the mean, the gradient, the hessian
+#: and the stage's row weights
+_STAGE_COPIES = 6
 #: fixed-shape compiled growers need a static depth bound
 MAX_COMPILED_DEPTH = 10
 
@@ -184,14 +195,41 @@ class GradientBoostingRegressorFamily(Family):
 
     @classmethod
     def convergence_proxy(cls, dynamic_params, static):
-        """A launch's while_loop grows max-over-lanes(n_estimators)
-        trees; sorting by n_estimators makes that max tight per
-        launch."""
+        """A launch's while_loop runs max-over-lanes(n_estimators)
+        stages; sorting by n_estimators makes that max tight per
+        launch, and a grid's few distinct counts are whole runs of
+        equal proxy: a launch a count, in which no lane idles."""
         return dynamic_params.get("n_estimators")
 
+    # -- the loss: squared error on y -------------------------------------
     @classmethod
-    def fit(cls, dynamic, static, data, train_w, meta):
-        codes, y = data["codes"], data["y"]
+    def _start(cls, data, train_w, meta):
+        """Every row's raw score before the first stage: the weighted
+        mean of the fold's training targets."""
+        y = data["y"]
+        wsum = jnp.sum(train_w) + 1e-12
+        return jnp.full(y.shape, jnp.sum(train_w * y) / wsum, jnp.float32)
+
+    @classmethod
+    def _mean(cls, F):
+        """The loss's mean function of the raw scores."""
+        return F
+
+    @classmethod
+    def _grad_hess(cls, mean, data):
+        """d loss / dF and d2 loss / dF2 a row (and, for several trees a
+        stage, a column): 0.5 (F - y)^2 here."""
+        return mean - data["y"], jnp.ones(mean.shape, jnp.float32)
+
+    @classmethod
+    def _boost(cls, dynamic, static, data, train_w, meta):
+        """The stage loop.  Carries the raw scores F on the FULL data
+        set, ``(n,)`` for one tree a stage and ``(n, k)`` for a tree a
+        class; a stage takes the loss's gradient and hessian at F, grows
+        its trees on them over the fold's (subsampled) training rows and
+        adds ``learning_rate`` x each row's leaf value.  A lane stops at
+        ITS ``n_estimators``.  Returns F and the stages the lane ran."""
+        codes = data["codes"]
         n = codes.shape[0]
         depth = _depth(static, cls._default_depth)
         t_max = int(meta.get("max_estimators")
@@ -205,42 +243,113 @@ class GradientBoostingRegressorFamily(Family):
         min_leaf = float(static.get("min_samples_leaf", 1))
         key = jax.random.PRNGKey(_seed(static))
 
-        wsum = jnp.sum(train_w) + 1e-12
-        F0 = jnp.sum(train_w * y) / wsum
-        F = jnp.full((n,), F0, jnp.float32)
+        F = cls._start(data, train_w, meta)
 
         # while_loop with a per-lane trip count: a candidate stops
-        # growing trees past ITS n_estimators (the stacked per-stage
-        # trees were returned but never consumed — dropped, which also
-        # cuts the model pytree by t_max tree buffers per lane)
+        # growing trees past ITS n_estimators
         keys = jax.random.split(key, t_max)
         n_lim = jnp.minimum(n_est, t_max)
 
-        def one_tree(carry):
+        def grow(g_c, h_c, w_t):
+            return grow_tree(codes, g_c[:, None], h_c, w_t, depth, N_BINS,
+                             min_child_weight=min_leaf, reg_lambda=1e-6)
+
+        def one_stage(carry):
             t, F = carry
             k_t = keys[t]
-            g = (F - y)[:, None]                      # d(0.5(F-y)^2)/dF
-            h = jnp.ones((n,), jnp.float32)
-            with jax.named_scope("sst.tree.bootstrap"):
+            with jax.named_scope("sst.boost.gradient"):
+                mean = cls._mean(F)
                 w_t = train_w * (
                     jax.random.uniform(k_t, (n,)) < subsample).astype(
                     jnp.float32)
-            tree = grow_tree(codes, g, h, w_t, depth, N_BINS,
-                             min_child_weight=min_leaf, reg_lambda=1e-6)
-            delta = _own_rows(tree)[:, 0]
-            live = (t < n_est).astype(jnp.float32)
-            return t + 1, F + lr * live * delta
+                G, H = cls._grad_hess(mean, data)
+            if F.ndim == 1:
+                delta = _own_rows(grow(G, H, w_t))[:, 0]
+            else:                                   # a tree a class
+                trees_k = jax.vmap(lambda g_c, h_c: grow(g_c, h_c, w_t),
+                                   in_axes=(1, 1))(G, H)
+                delta = jax.vmap(lambda tr: _own_rows(tr)[:, 0],
+                                 in_axes=0, out_axes=1)(trees_k)   # (n, k)
+            with jax.named_scope("sst.boost.update"):
+                live = (t < n_est).astype(jnp.float32)
+                return t + 1, F + lr * live * delta
 
-        _, F = jax.lax.while_loop(
-            lambda c: c[0] < n_lim, one_tree,
+        t_end, F = jax.lax.while_loop(
+            lambda c: c[0] < n_lim, one_stage,
             (jnp.asarray(0, jnp.int32), F))
-        return {"pred": F, "f0": F0, "lr": lr, "n_est": n_est,
-                "n_iter": n_lim}
+        # a lane's own stages, of those the loop ran (under the lanes'
+        # vmap the loop's counter is the launch's largest count).  Read
+        # off the counter, the counts reach the launch's statistics when
+        # the loop has ended: on a mesh of devices their reductions over
+        # the tasks are collectives, and XLA:CPU starts independent
+        # collectives in any order (eight virtual devices waited in the
+        # loop's and in the statistics' at once, and for good)
+        return F, jnp.minimum(n_lim, t_end)
+
+    @classmethod
+    def fit(cls, dynamic, static, data, train_w, meta):
+        F, n_iter = cls._boost(dynamic, static, data, train_w, meta)
+        return {"pred": F, "n_iter": n_iter}
+
+    # -- what a launch reports, and what it holds --------------------------
+    @classmethod
+    def _trees_per_stage(cls, meta):
+        return 1
+
+    @classmethod
+    def launch_stats(cls, models, static, meta):
+        """The default's lockstep stages (maximum and sum over tasks),
+        each task's own count, and what the launch executed: every lane,
+        padding included, carried through the launch's largest count."""
+        stats = super().launch_stats(models, static, meta)
+        stages = models["n_iter"].astype(jnp.int32)     # (candidates, folds)
+        # from the default's maximum: a second reduction over the tasks
+        # would be a second collective on a mesh of devices
+        steps = stats["solver_iters"] * stages.size
+        trees = steps * cls._trees_per_stage(meta)
+        stats["trees"] = stages.reshape(-1)
+        stats["tree_steps"] = steps
+        stats["tree_slots"] = trees
+        stats["trees_grown"] = trees
+        stats["tree_levels"] = trees * _depth(static, cls._default_depth)
+        return stats
 
     @classmethod
     def launch_facts(cls, static, meta, n_candidates, n_folds):
         # a stage's trees may split on any feature
-        return {"hist_features": int(meta["n_features"])}
+        return {"hist_features": int(meta["n_features"]),
+                "hist_bytes": cls._hist_bytes(static, meta)}
+
+    @classmethod
+    def _hist_bytes(cls, static, meta):
+        """Bytes of one tree's deepest level of histograms: every
+        feature, the hessian and the one gradient."""
+        return tree_hist.level_histogram_bytes(
+            _depth(static, cls._default_depth), meta["n_features"], 2,
+            N_BINS)
+
+    @classmethod
+    def launch_workspace(cls, n_samples, meta, n_folds, itemsize=4, *,
+                         static, row_sets=1):
+        """What a launch holds besides its arguments, for the memory
+        ledger.  A lane is a (candidate, fold) of its own, with its own
+        order of the rows after every sort: a tree of it holds what a
+        forest's holds by row (leaf values and statistics, `_ROW_COPIES`
+        of the sorted copy of codes and the three-part statistics) or,
+        where that is more, `_HIST_COPIES` of its deepest level's
+        histograms; a tree a class where a stage grows as many.  Beside
+        the trees `_STAGE_COPIES` float32 vectors a raw score: F in the
+        loop's carry and its update, the mean, gradient, hessian and the
+        stage's row weights.  Nothing is shared across candidates."""
+        trees = cls._trees_per_stage(meta)
+        row = (2 * 2 * 4                        # leaf values, stats
+               + _ROW_COPIES * tree_hist.row_bytes(
+                   meta["n_features"], 2, integer_stats=False))
+        tree = max(int(_HIST_COPIES * cls._hist_bytes(static, meta)),
+                   int(n_samples) * row)
+        stage = _STAGE_COPIES * 4 * int(n_samples) * trees
+        return {"fixed_bytes": 0,
+                "per_candidate_bytes": n_folds * (trees * tree + stage)}
 
     @classmethod
     def predict(cls, model, static, X, meta):
@@ -255,6 +364,11 @@ class GradientBoostingRegressorFamily(Family):
 
 
 class GradientBoostingClassifierFamily(GradientBoostingRegressorFamily):
+    """Log-loss boosting as scikit-learn runs it: for two classes ONE
+    tree a stage on the log-odds of class 1 (half-binomial loss: raw score
+    F, p = sigmoid(F), g = p - y, h = p (1 - p), F0 the prior's log-odds;
+    ``_gb.py``: ``n_trees_per_iteration_ = 1 if n_classes <= 2``), for
+    more a tree a class on the softmax's."""
     name = "gradient_boosting_classifier"
     is_classifier = True
     #: sklearn's staged decision/proba arrays are float64 regardless of X
@@ -273,75 +387,49 @@ class GradientBoostingClassifierFamily(GradientBoostingRegressorFamily):
         return data, meta
 
     @classmethod
-    def fit(cls, dynamic, static, data, train_w, meta):
-        codes, y1h = data["codes"], data["y1h"]
-        n = codes.shape[0]
-        k = meta["n_classes"]
-        depth = _depth(static, cls._default_depth)
-        t_max = int(meta.get("max_estimators")
-                    or static.get("n_estimators", 100))
-        lr = jnp.asarray(dynamic.get(
-            "learning_rate", static.get("learning_rate", 0.1)), jnp.float32)
-        n_est = jnp.asarray(dynamic.get(
-            "n_estimators", static.get("n_estimators", 100)), jnp.int32)
-        subsample = jnp.asarray(dynamic.get(
-            "subsample", static.get("subsample", 1.0)), jnp.float32)
-        min_leaf = float(static.get("min_samples_leaf", 1))
-        key = jax.random.PRNGKey(_seed(static))
+    def _trees_per_stage(cls, meta):
+        k = int(meta["n_classes"])
+        return 1 if k == 2 else k
 
+    @classmethod
+    def _start(cls, data, train_w, meta):
+        y1h = data["y1h"]
+        n, k = y1h.shape
         wsum = jnp.sum(train_w) + 1e-12
         prior = jnp.clip(
             (train_w[:, None] * y1h).sum(0) / wsum, 1e-6, 1 - 1e-6)
-        F = jnp.broadcast_to(jnp.log(prior)[None, :], (n, k)).astype(
+        if cls._trees_per_stage(meta) == 1:     # the log-odds of class 1
+            return jnp.full((n,), jnp.log(prior[1] / (1.0 - prior[1])),
+                            jnp.float32)
+        return jnp.broadcast_to(jnp.log(prior)[None, :], (n, k)).astype(
             jnp.float32) + jnp.zeros((n, k), jnp.float32)
 
-        # per-lane trip count, as in the regressor (stacked stage trees
-        # were never consumed — dropped)
-        keys = jax.random.split(key, t_max)
-        n_lim = jnp.minimum(n_est, t_max)
-
-        def one_stage(carry):
-            t, F = carry
-            k_t = keys[t]
-            P = jax.nn.softmax(F, axis=1)
-            with jax.named_scope("sst.tree.bootstrap"):
-                w_t = train_w * (
-                    jax.random.uniform(k_t, (n,)) < subsample).astype(
-                    jnp.float32)
-
-            def per_class(g_c, h_c):
-                return grow_tree(codes, g_c[:, None], h_c, w_t, depth,
-                                 N_BINS, min_child_weight=min_leaf,
-                                 reg_lambda=1e-6)
-
-            G = (P - y1h)                              # (n, k)
-            H = P * (1.0 - P)                          # (n, k)
-            trees_k = jax.vmap(per_class, in_axes=(1, 1))(G, H)
-            delta = jax.vmap(lambda tr: _own_rows(tr)[:, 0],
-                             in_axes=0, out_axes=1)(trees_k)   # (n, k)
-            live = (t < n_est).astype(jnp.float32)
-            return t + 1, F + lr * live * delta
-
-        _, F = jax.lax.while_loop(
-            lambda c: c[0] < n_lim, one_stage,
-            (jnp.asarray(0, jnp.int32), F))
-        return {"pred": jnp.argmax(F, axis=1).astype(jnp.int32),
-                "logits": F, "n_est": n_est, "lr": lr, "n_iter": n_lim}
+    @classmethod
+    def _mean(cls, F):
+        return jax.nn.sigmoid(F) if F.ndim == 1 else jax.nn.softmax(F, axis=1)
 
     @classmethod
-    def predict(cls, model, static, X, meta):
-        return model["pred"]
+    def _grad_hess(cls, mean, data):
+        y = data["y1h"][:, 1] if mean.ndim == 1 else data["y1h"]
+        return mean - y, mean * (1.0 - mean)
+
+    @classmethod
+    def fit(cls, dynamic, static, data, train_w, meta):
+        F, n_iter = cls._boost(dynamic, static, data, train_w, meta)
+        pred = (F > 0) if F.ndim == 1 else jnp.argmax(F, axis=1)
+        return {"pred": pred.astype(jnp.int32), "logits": F,
+                "n_iter": n_iter}
 
     @classmethod
     def decision(cls, model, static, X, meta):
-        if meta["n_classes"] == 2:
-            # scorer contract: binary decision is a 1-D margin
-            return model["logits"][:, 1] - model["logits"][:, 0]
+        # the raw scores: for two classes the log-odds, a 1-D margin (the
+        # scorers' contract)
         return model["logits"]
 
     @classmethod
     def predict_proba(cls, model, static, X, meta):
-        return jax.nn.softmax(model["logits"], axis=1)
+        p = cls._mean(model["logits"])
+        return jnp.stack([1.0 - p, p], axis=1) if p.ndim == 1 else p
 
 
 class RandomForestClassifierFamily(Family):

@@ -215,14 +215,16 @@ SEARCH_REPORT_SCHEMA = (
         "RandomForestRegressor): trees the launch executed, its forests "
         "(one a fold, shared by every candidate of the launch) x the "
         "largest n_estimators among its candidates.  (Before PR 36 a "
-        "forest a lane: lanes x the largest count.)",
+        "forest a lane: lanes x the largest count.)  Of a boosting "
+        "family: tree_steps_per_launch x the trees of a stage.",
         stat="tree_slots", combine="sum"),
     MetricDef(
         "tree_levels_per_launch", "series",
         "Per launch of a forest family: tree levels the launch "
         "executed, tree_slots_per_launch x the group's compiled depth "
         "(a level = one partition, one histogram pass, one split and "
-        "one routing of every forest).",
+        "one routing of every forest).  Of a boosting family the same, "
+        "of every lane.",
         stat="tree_levels", combine="sum"),
     MetricDef(
         "hist_bytes_per_lane", "series",
@@ -230,7 +232,9 @@ SEARCH_REPORT_SCHEMA = (
         "level of (node, feature, statistic, bin) float32 histograms "
         "as the launch writes them: 2^(depth - 1) nodes x "
         "hist_features_per_node x (1 + outputs) x 256 x 4, features and "
-        "statistics padded to the kernel's blocks on a TPU.",
+        "statistics padded to the kernel's blocks on a TPU.  Of a "
+        "boosting family: one tree's, every feature x (hessian, "
+        "gradient).",
         stat="hist_bytes", combine="fact"),
     MetricDef(
         "hist_features_per_node", "series",
@@ -244,10 +248,10 @@ SEARCH_REPORT_SCHEMA = (
         stat="hist_features", combine="fact", group=True),
     MetricDef(
         "trees_per_candidate", "series",
-        "Forest families: trees each candidate grew (its own "
-        "n_estimators, capped at the grid's largest), in cv_results_ "
-        "order.  -1: the candidate was restored from a checkpoint or "
-        "fitted on the host.",
+        "Forest and boosting families: trees (boosting: stages) each "
+        "candidate grew (its own n_estimators, capped at the grid's "
+        "largest), in cv_results_ order.  -1: the candidate was "
+        "restored from a checkpoint or fitted on the host.",
         stat="trees", combine="per_candidate"),
     MetricDef(
         "trees_grown_per_launch", "series",
@@ -257,8 +261,21 @@ SEARCH_REPORT_SCHEMA = (
         "grows it once and every candidate with a larger count reads "
         "it: the sum over a search against the sum of "
         "trees_per_candidate x folds is how many candidates a grown "
-        "tree served.",
+        "tree served.  Of a boosting family every executed tree is "
+        "grown: tree_slots_per_launch.",
         stat="trees_grown", combine="sum"),
+    MetricDef(
+        "tree_steps_per_launch", "series",
+        "Per launch of a boosting family (GradientBoostingClassifier, "
+        "GradientBoostingRegressor): lane-stages the launch executed, "
+        "its lanes (candidate x fold, padding included) x the largest "
+        "n_estimators among them (lockstep: every lane is carried "
+        "through the launch's longest loop).  Against the sum of "
+        "trees_per_candidate x folds: the stages spent on lanes already "
+        "done; none where a launch's candidates share one count.  A "
+        "stage is one tree (a regressor, two classes) or a tree a "
+        "class: tree_slots_per_launch counts the trees.",
+        stat="tree_steps", combine="sum"),
     MetricDef(
         "padding_waste", "histogram",
         "Per-launch fraction of computed lanes that were padding "

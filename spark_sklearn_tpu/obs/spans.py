@@ -413,9 +413,8 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
             "rules.",
             layer="solvers"),
     SpanDef("sst.tree.bootstrap", "scope", "models.trees",
-            "Once a tree (or boosting stage): the rows' weights, the "
-            "fold's mask times the forest's Poisson(1) bootstrap counts "
-            "or the boosters' subsample draw.",
+            "Once a forest's tree: the rows' weights, the fold's mask "
+            "times the Poisson(1) bootstrap counts.",
             layer="solvers"),
     SpanDef("sst.tree.partition", "scope", "ops.tree_hist",
             "A tree level on a TPU: the counted rows sorted into node "
@@ -441,6 +440,17 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
             "Once a tree: its leaf values at the node each row ended "
             "in, added to the votes (forests) or the staged "
             "predictions (boosting).",
+            layer="solvers"),
+    SpanDef("sst.boost.gradient", "scope", "models.trees",
+            "A boosting stage, before its trees: the loss's mean of the "
+            "raw scores F (sigmoid, softmax), each row's gradient and "
+            "hessian, and the stage's row weights (the fold's mask "
+            "times the subsample draw).",
+            layer="solvers"),
+    SpanDef("sst.boost.update", "scope", "models.trees",
+            "A boosting stage, after its trees: F += learning_rate x "
+            "the trees' leaf values, on the lanes whose own "
+            "n_estimators the stage is under.",
             layer="solvers"),
     SpanDef("sst.prefix.transform", "scope", "models.pipeline",
             "PipelineFamily.prefix_transform: the transformer chain "

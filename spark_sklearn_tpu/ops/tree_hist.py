@@ -482,9 +482,14 @@ def split_parts(stats, s8, parts):
                    ((0, 0), (0, s8 - stats.shape[1])))
     out = []
     for _ in range(parts):
-        part = rest.astype(jnp.bfloat16)
-        out.append(part)
-        rest = rest - part.astype(jnp.float32)
+        # rounded by an operation of its own: a convert to bfloat16 and
+        # back is one XLA:TPU may leave out (excess precision is allowed
+        # it), and then `rest - part` is zero and so is every part after
+        # the first
+        part = jax.lax.reduce_precision(rest, exponent_bits=8,
+                                        mantissa_bits=7)
+        out.append(part.astype(jnp.bfloat16))
+        rest = rest - part
     return jnp.concatenate(out, axis=1)
 
 

@@ -128,6 +128,20 @@ def _cache_evict(fam=None):
 _SORTED_LAUNCHES = 8
 
 
+def _sorted_launch_width(proxy, graded):
+    """Candidates a launch of a convergence-sorted group holds: `graded`
+    (the group over `_SORTED_LAUNCHES`), or a whole run of equal proxies
+    where the sorted `proxy` is runs of one length that is no shorter.
+    Lanes of one proxy value stop together (the boosters' proxy is
+    `n_estimators` itself, a handful of distinct counts), so a launch a
+    run idles no lane, and a cut inside a run buys no grading: it only
+    pays the launch's fixed cost again.  A group whose proxies all
+    differ is runs of one candidate, and keeps `graded`."""
+    runs = np.unique(proxy, return_counts=True)[1]
+    run = int(runs[0])
+    return run if run >= graded and np.all(runs == run) else graded
+
+
 def _cached_program(key, build, store_parts=None, store=None,
                     check_fields=None):
     """Cross-search cache of jitted callables.
@@ -2216,14 +2230,17 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
 
                 sorted_cap = None
                 if sorted_chunks:
-                    # ~8 difficulty-graded launches per group (bounded below
-                    # by the task-shard multiple so sharding stays uniform)
+                    # ~8 difficulty-graded launches per group, or one a
+                    # run of equal proxies (bounded below by the
+                    # task-shard multiple so sharding stays uniform)
                     sorted_cap = min(
                         mesh_lib.pad_to_multiple(nc, n_task_shards),
                         max_cand_per_batch,
                         max(n_task_shards,
                             mesh_lib.pad_to_multiple(
-                                -(-nc // _SORTED_LAUNCHES), n_task_shards)))
+                                _sorted_launch_width(
+                                    proxy, -(-nc // _SORTED_LAUNCHES)),
+                                n_task_shards)))
                 plans.append({
                     "gi": gi, "group": group, "static": static, "nc": nc,
                     "sorted": sorted_chunks, "sorted_cap": sorted_cap,

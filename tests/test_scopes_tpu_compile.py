@@ -54,6 +54,16 @@ scope names device operations, rows are gathered at two levels of ten and
 scattered at none, and the ledger's price is within a quarter of the
 compiler's allotment.
 
+The boosting launch (``GradientBoostingClassifierFamily.fit`` under the
+engine's two ``vmap``s, the candidate axis named) is compiled at one launch
+of the ``gbc_covtype145k.lr5_stages3`` cell — the 5 learning rates of one
+n_estimators x 5 folds = 25 lanes of 145 253 x 54, two classes — with the
+grower on the kernels' form: the real-valued, every-feature path compiles
+for the chip (three bfloat16 parts a statistic, ``f32[25, nodes, 64, 8,
+256]`` histograms), both ``sst.boost.*`` scopes name device operations, a
+stage sorts its rows once, and the ledger's price is within 15 % of the
+compiler's allotment.
+
 Nothing runs on a device here and nothing is timed.  The topology is
 described inside a fixture (never at import time: only one process may
 load the TPU's library), and where it cannot be described the tests skip.
@@ -798,3 +808,119 @@ def test_ledger_prices_the_forest_launch(forest_launch):
     # under a GB where every feature's histograms held 2.13 GB (PERF.md
     # section 4)
     assert allotted < 1e9
+
+
+# --- the boosting launch -----------------------------------------------------
+
+BOOST_CANDIDATES, BOOST_DEPTH = 5, 3
+BOOST_SCOPES = sorted(s for s in known_scope_names()
+                      if s.startswith("sst.boost."))
+
+
+def _compiled_boost(one_chip):
+    """The GradientBoostingClassifier fit launch as the engine builds it
+    (``fit`` under a vmap over candidates, named, of a vmap over the folds'
+    masks) at one launch of ``gbc_covtype145k.lr5_stages3``: 5 learning
+    rates x 5 folds of one n_estimators on 145 253 x 54, two classes, the
+    grower on the kernels' form."""
+    from spark_sklearn_tpu.models.base import CANDIDATE_AXIS
+    from spark_sklearn_tpu.models.trees import (
+        GradientBoostingClassifierFamily)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    meta = {"n_classes": 2, "classes": np.arange(2), "n_features": TREE_D,
+            "max_estimators": 100}
+    static = {"random_state": 0}
+
+    def launch(dyn, codes, y, y1h, train_m):
+        data = {"codes": codes, "y": y, "y1h": y1h}
+
+        def one_candidate(d):
+            return jax.vmap(lambda w: GradientBoostingClassifierFamily.fit(
+                d, static, data, w, meta))(train_m)
+        with jax.named_scope("sst.fit"):
+            return jax.vmap(one_candidate, axis_name=CANDIDATE_AXIS)(dyn)
+
+    return jax.jit(launch).lower(
+        {"learning_rate": arg((BOOST_CANDIDATES,)),
+         "n_estimators": arg((BOOST_CANDIDATES,), jnp.int32)},
+        arg((TREE_N, TREE_D), jnp.uint8), arg((TREE_N,), jnp.int32),
+        arg((TREE_N, 2)), arg((FOLDS, TREE_N))).compile(), meta, static
+
+
+@pytest.fixture(scope="module")
+def boost_launch(topo, no_compile_cache):
+    from jax.sharding import SingleDeviceSharding
+    from spark_sklearn_tpu.ops import tree_hist
+    real = tree_hist.on_tpu
+    tree_hist.on_tpu = lambda: True     # the platform compiled for
+    try:
+        return _compiled_boost(SingleDeviceSharding(topo.devices[0]))
+    finally:
+        tree_hist.on_tpu = real
+
+
+def test_boost_scopes_are_the_vocabularys():
+    assert BOOST_SCOPES == ["sst.boost.gradient", "sst.boost.update"]
+
+
+@pytest.mark.parametrize("scope", BOOST_SCOPES + [
+    "sst.tree.histogram", "sst.tree.partition", "sst.tree.predict"])
+def test_boost_compiled_op_names_carry_scope(boost_launch, scope):
+    text = boost_launch[0].as_text()
+    assert re.search(r'op_name="[^"]*/' + re.escape(scope) + r'[/"]', text)
+
+
+def test_boost_launch_is_every_features_histograms_in_three_parts(
+        boost_launch):
+    """The path no forest takes since PR 38: a slot is a feature (64 with
+    the kernel's padding) at every node of the three levels, 25 lanes a
+    grid axis; a row's statistics travel as three bfloat16 parts of 8
+    statistic rows, 12 words beside the codes' 16; the rows are sorted once
+    a stage; no level scatters."""
+    text = boost_launch[0].as_text()
+    lanes = BOOST_CANDIDATES * FOLDS
+    calls = re.findall(r'custom_call_target="tpu_custom_call"', text)
+    assert len(calls) == 2 * BOOST_DEPTH
+    for level in range(BOOST_DEPTH):
+        assert re.search(r'f32\[%d,%d,64,8,256\]\S* custom-call\(' % (
+            lanes, 2 ** level), text)
+    assert " scatter(" not in text
+    gathered = re.findall(r'= s32\[%d,(\d+)\]\S* gather\(' % (
+        lanes * TREE_N), text)
+    assert sorted(gathered) == ["12", "16"]
+    assert re.search(r'bf16\[%d,24,\d+\]' % lanes, text)
+
+
+def test_ledger_prices_the_boost_launch(boost_launch):
+    """``GradientBoostingClassifierFamily.launch_workspace`` (what
+    ``search_report["memory"]`` models the cell's group at) against the
+    compiler's own allotment."""
+    from spark_sklearn_tpu.models.trees import (
+        GradientBoostingClassifierFamily)
+    from spark_sklearn_tpu.ops import tree_hist
+    from spark_sklearn_tpu.parallel.memledger import model_group_footprint
+    compiled, meta, static = boost_launch
+    stats = compiled.memory_analysis()
+    allotted = (stats.temp_size_in_bytes + stats.argument_size_in_bytes
+                + stats.output_size_in_bytes)
+    real = tree_hist.on_tpu
+    tree_hist.on_tpu = lambda: True
+    try:
+        workspace = GradientBoostingClassifierFamily.launch_workspace(
+            TREE_N, meta, FOLDS, static=static)
+    finally:
+        tree_hist.on_tpu = real
+    modeled = model_group_footprint(
+        {"learning_rate": np.zeros(BOOST_CANDIDATES, np.float32),
+         "n_estimators": np.zeros(BOOST_CANDIDATES, np.int32)},
+        BOOST_CANDIDATES, FOLDS, task_batched=False, n_samples=TREE_N,
+        workspace=workspace)
+    # nothing is shared across candidates: a lane is its rows, 110 MB
+    assert modeled["fixed_bytes"] == 0
+    assert modeled["per_candidate_bytes"] > FOLDS * 100e6
+    assert abs(modeled["chunk_bytes"] - allotted) < 0.15 * allotted
+    # an eighth of the chip and more: the cell's size (PERF.md section 4)
+    assert allotted > 0.125 * 16.909e9

@@ -309,10 +309,32 @@ def lowered_tree_launch():
         tree_hist.levels_of = real
 
 
+#: the scopes of the boosting stage loop
+BOOST_SCOPES = [s for s in SCOPES if s.startswith("sst.boost.")]
+
+
+@pytest.fixture(scope="module")
+def lowered_boost_launch():
+    """A boosting search: a stage's gradients before its tree, the update
+    of F after it."""
+    from sklearn.ensemble import GradientBoostingClassifier
+
+    def search():
+        X, y = _problem(n=90, d=5)
+        sst.GridSearchCV(
+            GradientBoostingClassifier(max_depth=2, random_state=0),
+            {"learning_rate": [0.1, 0.3], "n_estimators": [2, 3]}, cv=3,
+            refit=False, backend="tpu",
+            config=sst.TpuConfig(devices=jax.devices()[:1])  # a launch a count
+        ).fit(X, y > 0)
+    return _lowered_launches(search)
+
+
 def _launch_fixture(scope):
     return ("lowered_dual_launch" if scope in DUAL_SCOPES else
             "lowered_mlp_launch" if scope in MLP_SCOPES else
             "lowered_tree_launch" if scope in TREE_SCOPES else
+            "lowered_boost_launch" if scope in BOOST_SCOPES else
             "lowered_launch")
 
 
