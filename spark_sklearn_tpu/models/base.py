@@ -24,7 +24,7 @@ change — the same drop-in contract the reference had.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Type
+from typing import Any, Dict, Optional, Tuple, Type
 
 import numpy as np
 
@@ -84,6 +84,9 @@ class Family:
     name: str = "base"
     #: dynamic (vmap-batchable) hyperparameter names -> numpy dtype
     dynamic_params: Dict[str, Any] = {}
+    #: a dynamic parameter whose values are names: the names the traced
+    #: fit understands, each batched as its index here (KNN's `weights`)
+    dynamic_codes: Dict[str, Tuple[str, ...]] = {}
     #: True for classifiers (label-encode y, default scorer = accuracy)
     is_classifier: bool = False
 
@@ -241,6 +244,20 @@ class Family:
         is how many matrices of rows the launch's fits read: one, or one
         a fold behind a Pipeline's transformers.  Default: nothing is
         priced."""
+        return {}
+
+    @classmethod
+    def launch_tiles(cls, static, meta, n_folds: int, n_candidates: int, *,
+                     n_samples: int, budget_bytes: int, resident_bytes: int,
+                     return_train: bool) -> Dict[str, Any]:
+        """On the host, once a compile group of `n_candidates`, before its
+        chunks are cut: static facts that size what the group's launches
+        work through a tile at a time to the device memory the search's
+        budget (``budget_bytes``, 0 where there is none) leaves beside its
+        resident arrays, and say whether the search scores training rows
+        too.  They join the group's ``static``, so every program's key,
+        and `launch_workspace` prices the launch with them.  Default:
+        nothing."""
         return {}
 
     @classmethod

@@ -27,13 +27,12 @@ import numpy as np
 
 from spark_sklearn_tpu.models.base import (
     Family, NotCompiledError, register_family)
+from spark_sklearn_tpu.ops.pairwise import sq_dists
 
 
 def _sq_dists(X, C):
-    """(n, k) squared distances via the matmul identity."""
-    xx = jnp.sum(X * X, axis=1, keepdims=True)
-    cc = jnp.sum(C * C, axis=1)
-    return jnp.maximum(xx - 2.0 * (X @ C.T) + cc[None, :], 0.0)
+    """(n, k) squared distances via the matmul identity, clamped at 0."""
+    return jnp.maximum(sq_dists(X, C), 0.0)
 
 
 def _neg_inertia(family, model, static, data, meta, w):

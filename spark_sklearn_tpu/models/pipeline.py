@@ -425,9 +425,12 @@ def make_pipeline_family(pipeline):
     if not getattr(final_family, "keyed_compatible", True):
         # tree finals consume pre-binned "codes"; they compose only with
         # monotone per-feature steps, under which the codes are provably
-        # unchanged (anything else -> Tier B)
-        if all(getattr(s, "monotone_per_feature", False)
-               for _, s in resolved):
+        # unchanged.  Anything else -> Tier B: other finals that opt out
+        # read the scaled values themselves (a KNN's distances move with
+        # every scale)
+        if getattr(final_family, "quantile_binned", False) and all(
+                getattr(s, "monotone_per_feature", False)
+                for _, s in resolved):
             return BinnedInvariantPipelineFamily(final_name, final_family)
         return None
     if not final_family.has_per_task_fit() and not getattr(

@@ -75,6 +75,7 @@ import numpy as np
 
 from spark_sklearn_tpu.models.base import (
     Family, NotCompiledError, encode_labels, register_family)
+from spark_sklearn_tpu.ops.pairwise import sq_dists
 
 
 def _pairs(k: int) -> np.ndarray:
@@ -90,9 +91,7 @@ def _kernel(X1, X2, kind, gamma, degree, coef0):
     if kind == "sigmoid":
         return jnp.tanh(gamma * (X1 @ X2.T) + coef0)
     # rbf
-    sq1 = jnp.sum(X1 * X1, axis=1)
-    sq2 = jnp.sum(X2 * X2, axis=1)
-    d2 = sq1[:, None] - 2.0 * (X1 @ X2.T) + sq2[None, :]
+    d2 = sq_dists(X1, X2)
     return jnp.exp(-gamma * jnp.maximum(d2, 0.0))
 
 

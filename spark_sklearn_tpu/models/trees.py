@@ -220,6 +220,8 @@ class GradientBoostingRegressorFamily(Family):
     name = "gradient_boosting_regressor"
     is_classifier = False
     keyed_compatible = False   # consumes binned "codes", not raw "X"
+    #: a feature's quantile codes: a monotone scaler in front moves none
+    quantile_binned = True
     dynamic_params = {"learning_rate": np.float32,
                       "n_estimators": np.int32,
                       "subsample": np.float32}
@@ -560,6 +562,8 @@ class RandomForestClassifierFamily(Family):
     name = "random_forest_classifier"
     is_classifier = True
     keyed_compatible = False   # consumes binned "codes", not raw "X"
+    #: a feature's quantile codes: a monotone scaler in front moves none
+    quantile_binned = True
     #: sklearn's vote-averaged probas are float64 regardless of X
     proba_dtype_rule = "float64"
     dynamic_params = {"n_estimators": np.int32}

@@ -277,6 +277,39 @@ SEARCH_REPORT_SCHEMA = (
         "class: tree_slots_per_launch counts the trees.",
         stat="tree_steps", combine="sum"),
     MetricDef(
+        "knn_distance_entries_per_launch", "series",
+        "Per launch of a neighbours family (KNeighborsClassifier, "
+        "KNeighborsRegressor): row x column squared distances the "
+        "launch's neighbour search computed, its row blocks x the rows "
+        "of a block x every row (the last block may repeat rows of the "
+        "one before it).  The search is the launch's, whatever its "
+        "width: n x n for a search of one launch, not n x n a candidate "
+        "or a fold.",
+        stat="knn_distance_entries", combine="fact"),
+    MetricDef(
+        "knn_selections_per_launch", "series",
+        "Per launch of a neighbours family: (row, fold) selections of "
+        "the grid's largest n_neighbors nearest.  A row once, among the "
+        "training rows of the fold that holds it out, where every row is "
+        "held out by exactly one fold and no training rows are scored; "
+        "once a fold otherwise (return_train_score=True: a row against "
+        "each fold that trains on it too).",
+        stat="knn_selections", combine="fact"),
+    MetricDef(
+        "knn_blocks_per_launch", "series",
+        "Per launch of a neighbours family: row blocks its neighbour "
+        "search went through, each a block's distances to every row, "
+        "its selections and its votes.",
+        stat="knn_blocks", combine="sum"),
+    MetricDef(
+        "knn_block_rows_per_launch", "series",
+        "Per launch of a neighbours family: the rows of a block, the "
+        "largest the memory ledger's budget leaves beside the launch's "
+        "other arrays (KNeighborsClassifierFamily.launch_tiles; where no "
+        "budget applies, a block's distances hold at most 1 GiB).  Also "
+        "on the group's per_group record.",
+        stat="knn_block_rows", combine="fact", group=True),
+    MetricDef(
         "padding_waste", "histogram",
         "Per-launch fraction of computed lanes that were padding "
         "(chunk tail repeated to the group's uniform width) — the "

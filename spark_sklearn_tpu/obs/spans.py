@@ -452,6 +452,22 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
             "the trees' leaf values, on the lanes whose own "
             "n_estimators the stage is under.",
             layer="solvers"),
+    SpanDef("sst.knn.distance", "scope", "models.neighbors",
+            "A row block of the neighbour search: its squared distances "
+            "to every row, one float32-accurate product "
+            "(ops/pairwise.py).",
+            layer="solvers"),
+    SpanDef("sst.knn.select", "scope", "models.neighbors",
+            "A row block: the columns a row may not take masked out (its "
+            "own held-out fold's, or those a fold does not train on) and "
+            "one top_k of the grid's largest n_neighbors nearest, once a "
+            "row (or once a row and fold).",
+            layer="solvers"),
+    SpanDef("sst.knn.vote", "scope", "models.neighbors",
+            "A row block: its neighbours' cumulative votes over the rank, "
+            "uniform and 1/distance; after the blocks, each task's row of "
+            "them at its candidate's n_neighbors and weighting.",
+            layer="solvers"),
     SpanDef("sst.prefix.transform", "scope", "models.pipeline",
             "PipelineFamily.prefix_transform: the transformer chain "
             "fitted on each fold's training rows and applied to all "

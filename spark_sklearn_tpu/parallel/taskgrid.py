@@ -57,22 +57,29 @@ def build_compile_groups(
     candidate_params: Sequence[Mapping[str, Any]],
     dynamic_names: Optional[Sequence[str]] = None,
     dynamic_dtypes: Optional[Mapping[str, Any]] = None,
+    dynamic_codes: Optional[Mapping[str, Sequence[str]]] = None,
 ) -> List[CompileGroup]:
     """Partition candidates into compile groups by static signature.
 
     `dynamic_names`: param names the estimator family promises are pure
     numeric leaves of the traced fit (e.g. C, alpha, l1_ratio, tol,
     learning_rate_init).  Anything else — and any dynamic-name whose value is
-    non-numeric (e.g. C="auto") — is static for that candidate.
+    non-numeric (e.g. C="auto") — is static for that candidate, except a
+    name `dynamic_codes` lists for it (KNN's weights="distance"), which is
+    dynamic as its index there.
     """
     t_span0 = time.perf_counter()
     dynamic_names = set(dynamic_names or ())
     dynamic_dtypes = dict(dynamic_dtypes or {})
+    dynamic_codes = dict(dynamic_codes or {})
     groups: Dict[Tuple, Dict[str, Any]] = {}
     for idx, params in enumerate(candidate_params):
         static, dynamic = {}, {}
         for k, v in params.items():
-            if k in dynamic_names and (
+            codes = dynamic_codes.get(k, ())
+            if k in dynamic_names and isinstance(v, str) and v in codes:
+                dynamic[k] = codes.index(v)
+            elif k in dynamic_names and (
                 _is_dynamic_value(v)
                 or isinstance(v, (int, np.integer))
                 and not isinstance(v, bool)

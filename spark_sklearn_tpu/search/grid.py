@@ -1289,6 +1289,11 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             # observe_candidates, so both backends raise on the same grids
             meta["min_fold_train_count"] = int(
                 np.sum(train_masks > 0, axis=1).min())
+            # ... and families that can serve each row's test scores from
+            # ONE fold's fit (KNN's neighbour lists): every row is left out
+            # of exactly one fold's training rows, as K-fold splits leave it
+            meta["held_out_once"] = bool(
+                np.all(np.sum(train_masks <= 0, axis=0) == 1))
             # ... and families whose arithmetic is exact on 0/1 masks (the
             # forests' integer histograms) that no sample_weight scales them
             meta["unit_fit_weights"] = fit_weight is None
@@ -1363,7 +1368,8 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 family.observe_candidates(launch_candidates, base_params, meta)
             dyn_names = list(family.dynamic_params)
             groups = build_compile_groups(
-                launch_candidates, dyn_names, family.dynamic_params)
+                launch_candidates, dyn_names, family.dynamic_params,
+                getattr(family, "dynamic_codes", None))
             if launch_index is not None:
                 for g in groups:
                     g.candidate_indices = launch_index[
@@ -2286,6 +2292,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             mem_caps = None
             resident_est = 0
             mem_kw = None
+            budget = 0
             if ledger is not None:
                 seen_bufs = set()
                 for dev_arr in list(data_dev.values()) + [
@@ -2301,6 +2308,21 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     # the old getattr spelling produced
                     for leaf in jax.tree_util.tree_leaves(dev_arr):
                         resident_est += int(getattr(leaf, "nbytes", 0))
+                budget = int(mem_ctx.get("budget_bytes", 0)) \
+                    if mem_ctx is not None else 0
+            # what a family's launch works through a tile at a time, sized
+            # to what the budget leaves (Family.launch_tiles): facts of the
+            # group's static, laid out or not, so of every program's key
+            tiles_hook = getattr(family, "launch_tiles", None)
+            for p in plans if tiles_hook is not None else ():
+                tiles = tiles_hook(
+                    p["static"], meta, n_folds, p["nc"],
+                    n_samples=int(fit_masks.shape[1]), budget_bytes=budget,
+                    resident_bytes=resident_est, return_train=return_train)
+                if tiles:
+                    p["static"] = {**p["static"], **tiles}
+                    p["static_plain"] = {**p["static_plain"], **tiles}
+            if ledger is not None:
                 mem_kw = dict(
                     task_batched=task_batched,
                     n_samples=int(fit_masks.shape[1]),
@@ -2323,9 +2345,6 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                         int(fit_masks.shape[1]), meta, n_folds,
                         int(np.dtype(dtype).itemsize), static=static)
                     return {**mem_kw, "workspace": ws} if ws else mem_kw
-
-                budget = int(mem_ctx.get("budget_bytes", 0)) \
-                    if mem_ctx is not None else 0
 
                 def group_cap(p, width=None):
                     fp1 = _memledger.model_group_footprint(

@@ -75,11 +75,14 @@ def _family_matrix():
         "gnb": (lambda: GaussianNB(),
                 {"var_smoothing": np.logspace(-9, -3, 20).tolist()},
                 {"max_tasks_per_batch": 16}, 4),
-        # two compile groups (weights is static): group 1's launches
-        # are durable before hung@3 kills group 2's score launch
+        # ONE compile group (weights is a per-task choice): 20
+        # candidates chunked at width 8, a neighbour search a launch;
+        # the first chunk's fit/score/calibrate are durable before hung@3
+        # kills the second chunk's fused launch
         "knn": (lambda: KNeighborsClassifier(),
-                {"n_neighbors": [3, 5],
-                 "weights": ["uniform", "distance"]}, {}, 3),
+                {"n_neighbors": list(range(1, 11)),
+                 "weights": ["uniform", "distance"]},
+                {"max_tasks_per_batch": 16}, 3),
         # shared-prefix Pipeline: two compile groups (n_components is
         # shape-static), each fanned over one cached prefix; hung@3 =
         # group 2's score launch, with group 1's chunk AND both prefix

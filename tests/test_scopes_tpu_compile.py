@@ -65,6 +65,15 @@ histograms), both ``sst.boost.*`` scopes name device operations, a stage
 sorts its rows once, and the ledger's price is within 15 % of the
 compiler's allotment.
 
+The neighbour search (``KNeighborsClassifierFamily.fit_task_batched``) is
+compiled at the launch of the ``knn_mnist.k30_weights2`` cell —
+n_neighbors 1..30 x two weightings x 5 folds on 70 000 x 784, ten classes,
+with the row block the ledger's default budget leaves (``launch_tiles``:
+two blocks of 35 000 rows): no (70 000, 70 000) array, one top-k of a
+block's (35 000, 70 000) distances, the product at three bfloat16 passes,
+every ``sst.knn.*`` scope on a device operation, and the ledger's price
+within 10 % of the compiler's allotment, over a quarter of the chip.
+
 Nothing runs on a device here and nothing is timed.  The topology is
 described inside a fixture (never at import time: only one process may
 load the TPU's library), and where it cannot be described the tests skip.
@@ -923,3 +932,101 @@ def test_ledger_prices_the_boost_launch(boost_launch):
     assert abs(modeled["chunk_bytes"] - allotted) < 0.15 * allotted
     # an eighth of the chip and more: the cell's size (PERF.md section 4)
     assert allotted > 0.125 * 16.909e9
+
+
+# --- the neighbour search ----------------------------------------------------
+
+KNN_N, KNN_CANDIDATES, KNN_MAX_K = 70_000, 60, 30
+KNN_SCOPES = sorted(s for s in known_scope_names() if s.startswith("sst.knn."))
+#: the cell's budget: the ledger's default fraction of a v5e's 16.909 GB
+KNN_BUDGET = int(0.8 * 16.909e9)
+
+
+def _compiled_knn(one_chip):
+    """The KNeighborsClassifier fit launch as the engine builds it for
+    ``knn_mnist.k30_weights2``: n_neighbors 1..30 x two weightings x 5
+    folds on 70 000 x 784, ten classes, with the row block the ledger's
+    budget leaves (``launch_tiles``), followed by the prediction the
+    scorer reads."""
+    from spark_sklearn_tpu.models.neighbors import KNeighborsClassifierFamily
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    meta = {"n_classes": K, "classes": np.arange(K), "n_features": D,
+            "n_samples": KNN_N, "max_k": KNN_MAX_K, "held_out_once": True}
+    tasks = KNN_CANDIDATES * FOLDS
+    resident = KNN_N * D * 4 + KNN_N * 4 + 5 * FOLDS * KNN_N * 4
+    static = {"weights": "uniform", **KNeighborsClassifierFamily.launch_tiles(
+        {}, meta, FOLDS, KNN_CANDIDATES, n_samples=KNN_N,
+        budget_bytes=KNN_BUDGET, resident_bytes=resident,
+        return_train=False)}
+
+    def launch(dyn, X, y, train_w):
+        with jax.named_scope("sst.fit"):
+            model = KNeighborsClassifierFamily.fit_task_batched(
+                dyn, {**static, "__n_folds__": FOLDS}, {"X": X, "y": y},
+                train_w, meta)
+        return jnp.argmax(model["proba"], axis=-1)
+
+    return jax.jit(launch).lower(
+        {"n_neighbors": arg((tasks,), jnp.int32),
+         "weights": arg((tasks,), jnp.int32)},
+        arg((KNN_N, D)), arg((KNN_N,), jnp.int32),
+        arg((tasks, KNN_N))).compile(), meta, static
+
+
+@pytest.fixture(scope="module")
+def knn_launch(topo, no_compile_cache):
+    from jax.sharding import SingleDeviceSharding
+    return _compiled_knn(SingleDeviceSharding(topo.devices[0]))
+
+
+def test_knn_scopes_are_the_vocabularys():
+    assert KNN_SCOPES == ["sst.knn.distance", "sst.knn.select",
+                          "sst.knn.vote"]
+
+
+@pytest.mark.parametrize("scope", KNN_SCOPES)
+def test_knn_compiled_op_names_carry_scope(knn_launch, scope):
+    text = knn_launch[0].as_text()
+    assert re.search(r'op_name="[^"]*/' + re.escape(scope) + r'[/"]', text)
+
+
+def test_knn_launch_is_row_blocks_and_one_selection(knn_launch):
+    """Two blocks of 35 000 rows against every row: no (n, n) array, one
+    top-k of (35 000, 70 000) a block, and the product at three
+    bfloat16 passes."""
+    from spark_sklearn_tpu.models.neighbors import _BLOCK_ROWS
+    compiled, _, static = knn_launch
+    text = compiled.as_text()
+    R = static[_BLOCK_ROWS]
+    assert R == 35_000
+    assert not re.search(r"\[%d,%d\]" % (KNN_N, KNN_N), text)
+    assert re.search(r"f32\[%d,%d\]" % (R, KNN_N), text)
+    assert len(re.findall(r"TopK", text)) == 1
+    assert "algorithm=dot_bf16_bf16_f32_x3" in text \
+        or "operand_precision={high,high}" in text
+
+
+def test_ledger_prices_the_knn_launch(knn_launch):
+    """``KNeighborsClassifierFamily.launch_workspace`` (what
+    ``search_report["memory"]`` models the cell's group at) against the
+    compiler's own allotment, and the cell's size."""
+    from spark_sklearn_tpu.models.neighbors import KNeighborsClassifierFamily
+    from spark_sklearn_tpu.parallel.memledger import model_group_footprint
+    compiled, meta, static = knn_launch
+    stats = compiled.memory_analysis()
+    allotted = (stats.temp_size_in_bytes + stats.argument_size_in_bytes
+                + stats.output_size_in_bytes)
+    workspace = KNeighborsClassifierFamily.launch_workspace(
+        KNN_N, meta, FOLDS, static=static)
+    modeled = model_group_footprint(
+        {"n_neighbors": np.zeros(KNN_CANDIDATES, np.int32),
+         "weights": np.zeros(KNN_CANDIDATES, np.int32)},
+        KNN_CANDIDATES, FOLDS, task_batched=True, n_samples=KNN_N,
+        workspace=workspace)
+    assert abs(modeled["chunk_bytes"] + KNN_N * D * 4 - allotted) \
+        < 0.1 * allotted
+    # a quarter of the chip and more: the cell's size (PERF.md section 4)
+    assert allotted > 0.25 * 16.909e9

@@ -333,11 +333,34 @@ def lowered_boost_launch():
     return _lowered_launches(search)
 
 
+#: the scopes of the neighbour search
+KNN_SCOPES = [s for s in SCOPES if s.startswith("sst.knn.")]
+
+
+@pytest.fixture(scope="module")
+def lowered_knn_launch():
+    """A KNeighborsClassifier search: a row block's distances, its
+    selection and its votes, in narrow chunks so that a fused program is
+    compiled ahead."""
+    from sklearn.neighbors import KNeighborsClassifier
+
+    def search():
+        X, y = _problem(n=90, d=5)
+        sst.GridSearchCV(
+            KNeighborsClassifier(),
+            {"n_neighbors": list(range(1, 11)),
+             "weights": ["uniform", "distance"]}, cv=3, refit=False,
+            backend="tpu",
+            config=sst.TpuConfig(max_tasks_per_batch=3)).fit(X, y)
+    return _lowered_launches(search)
+
+
 def _launch_fixture(scope):
     return ("lowered_dual_launch" if scope in DUAL_SCOPES else
             "lowered_mlp_launch" if scope in MLP_SCOPES else
             "lowered_tree_launch" if scope in TREE_SCOPES else
             "lowered_boost_launch" if scope in BOOST_SCOPES else
+            "lowered_knn_launch" if scope in KNN_SCOPES else
             "lowered_launch")
 
 
