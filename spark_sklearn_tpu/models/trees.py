@@ -206,6 +206,16 @@ def _stage_runs(dynamic_params, static):
     return np.lexsort((count, sub, lr)), int(counts[0])
 
 
+def _fixed_rows(dynamic, static, meta):
+    """Whether every stage of a booster's lane counts the same rows, the
+    fold's training rows: `subsample` is 1.0 and no candidate of the
+    search names it (`observe_candidates`).  The stage loop then runs in
+    one row order a lane (`tree_hist.row_order`)."""
+    return ("subsample" not in dynamic
+            and not meta.get("subsample_searched", False)
+            and float(static.get("subsample", 1.0)) == 1.0)
+
+
 def _stage_run(static, n_candidates):
     """The run length a launch of `n_candidates` grows one stage loop a
     fold for: the layout's fact, else 1 — no fact (a direct caller, a
@@ -242,6 +252,8 @@ class GradientBoostingRegressorFamily(Family):
     @classmethod
     def observe_candidates(cls, candidates, base_params, meta):
         _observe_tree_candidates(cls, candidates, base_params, meta)
+        meta["subsample_searched"] = any("subsample" in c
+                                         for c in candidates)
 
     #: per-tree work is large (level histograms over all samples), so
     #: even small grids amortise the extra dispatches
@@ -304,7 +316,13 @@ class GradientBoostingRegressorFamily(Family):
         of ``(C,)`` counts (`fit_task_batched`; one count is a run of
         one): it stops at the largest and keeps F as it passes each.
         Returns the kept F, ``(C,) + F.shape``, and the stages each
-        count ran."""
+        count ran.
+
+        Where every stage counts the fold's training rows (`_fixed_rows`)
+        the loop runs in the lane's `tree_hist.row_order`, made once: F,
+        the targets and the weights are put in it before the loop and the
+        kept F back after it, and no tree sorts or gathers its rows at
+        level 0 or sorts them back.  A tree a class shares it."""
         codes = data["codes"]
         n = codes.shape[0]
         depth = _depth(static, cls._default_depth)
@@ -318,8 +336,17 @@ class GradientBoostingRegressorFamily(Family):
             "subsample", static.get("subsample", 1.0)), jnp.float32)
         min_leaf = float(static.get("min_samples_leaf", 1))
         key = jax.random.PRNGKey(_seed(static))
+        fixed = _fixed_rows(dynamic, static, meta)
 
+        # in the caller's order: its sums keep their bits
         F = cls._start(data, train_w, meta)
+        order = tree_hist.row_order(codes, train_w) if fixed else None
+        rows = data
+        if order is not None:
+            with jax.named_scope("sst.tree.partition"):
+                F, train_w = order.rows(F), order.rows(train_w)
+                rows = {**data, **{k: order.rows(data[k])
+                                   for k in ("y", "y1h") if k in data}}
 
         # while_loop with a per-lane trip count: a run stops growing
         # trees past its largest n_estimators
@@ -329,17 +356,19 @@ class GradientBoostingRegressorFamily(Family):
 
         def grow(g_c, h_c, w_t):
             return grow_tree(codes, g_c[:, None], h_c, w_t, depth, N_BINS,
-                             min_child_weight=min_leaf, reg_lambda=1e-6)
+                             min_child_weight=min_leaf, reg_lambda=1e-6,
+                             order=order)
 
         def one_stage(carry):
             t, F, kept = carry
             k_t = keys[t]
             with jax.named_scope("sst.boost.gradient"):
                 mean = cls._mean(F)
-                w_t = train_w * (
+                # at subsample 1.0 every draw is below it
+                w_t = train_w if fixed else train_w * (
                     jax.random.uniform(k_t, (n,)) < subsample).astype(
                     jnp.float32)
-                G, H = cls._grad_hess(mean, data)
+                G, H = cls._grad_hess(mean, rows)
             if F.ndim == 1:
                 delta = _own_rows(grow(G, H, w_t))[:, 0]
             else:                                   # a tree a class
@@ -358,6 +387,9 @@ class GradientBoostingRegressorFamily(Family):
             lambda c: c[0] < lim_top, one_stage,
             (jnp.asarray(0, jnp.int32), F,
              jnp.broadcast_to(F, n_lim.shape + F.shape)))
+        if order is not None:
+            with jax.named_scope("sst.tree.partition"):
+                kept = order.back(kept, axis=1)
         # a lane's own stages, of those the loop ran (under the lanes'
         # vmap the loop's counter is the launch's largest count).  Read
         # off the counter, the counts reach the launch's statistics when
@@ -419,7 +451,9 @@ class GradientBoostingRegressorFamily(Family):
         each task's own count, and what the launch executed: every lane
         it grew (a task, or a run of S candidates a fold where the launch
         is laid out), padding included, carried through the launch's
-        largest count."""
+        largest count, and the sorts of its rows into node order (a
+        lane's order once where `_fixed_rows`, a tree's at level 0
+        otherwise, and at levels 5 and 10)."""
         stats = super().launch_stats(models, static, meta)
         stages = models["n_iter"].astype(jnp.int32)     # (candidates, folds)
         lanes = stages.size // _stage_run(static, stages.shape[0])
@@ -431,7 +465,10 @@ class GradientBoostingRegressorFamily(Family):
         stats["tree_steps"] = steps
         stats["tree_slots"] = trees
         stats["trees_grown"] = trees
-        stats["tree_levels"] = trees * _depth(static, cls._default_depth)
+        depth = _depth(static, cls._default_depth)
+        stats["tree_levels"] = trees * depth
+        stats["row_sorts"] = tree_hist.row_sorts(
+            depth, trees, lanes, _fixed_rows({}, static, meta))
         return stats
 
     @classmethod
@@ -464,19 +501,30 @@ class GradientBoostingRegressorFamily(Family):
         (`launch_layout`'s fact in `static`) a lane is a run's, so a
         candidate is 1/S of one and `_KEPT_COPIES` of its own F; the
         ledger gives a width that is no multiple of S the price without
-        the fact, as the launch runs it."""
+        the fact, as the launch runs it.
+
+        Where every stage counts the fold's training rows (`_fixed_rows`)
+        the rows stay in one order a fold: a tree holds one copy of a
+        row's kernels' bytes and sorts none, and the launch holds a
+        fold's order (`tree_hist.order_row_bytes` a row)."""
         trees = cls._trees_per_stage(meta)
+        n = int(n_samples)
+        static = static or {}
+        fixed = _fixed_rows({}, static, meta)
+        row_bytes = tree_hist.row_bytes(meta["n_features"], 2,
+                                        integer_stats=False)
         row = (2 * 2 * 4                        # leaf values, stats
-               + _ROW_COPIES * tree_hist.row_bytes(
-                   meta["n_features"], 2, integer_stats=False))
+               + (1 if fixed else _ROW_COPIES) * row_bytes)
         tree = max(int(_HIST_COPIES * cls._hist_bytes(static, meta)),
-                   int(n_samples) * row)
-        stage = _STAGE_COPIES * 4 * int(n_samples) * trees
+                   n * row)
+        stage = _STAGE_COPIES * 4 * n * trees
         lane = trees * tree + stage
-        S = int((static or {}).get(_STAGE_RUN, 1))
+        S = int(static.get(_STAGE_RUN, 1))
         if S > 1:
-            lane = lane // S + _KEPT_COPIES * 4 * int(n_samples) * trees
-        return {"fixed_bytes": 0, "per_candidate_bytes": n_folds * lane}
+            lane = lane // S + _KEPT_COPIES * 4 * n * trees
+        orders = n_folds * n * tree_hist.order_row_bytes(
+            meta["n_features"]) if fixed else 0
+        return {"fixed_bytes": orders, "per_candidate_bytes": n_folds * lane}
 
     @classmethod
     def predict(cls, model, static, X, meta):

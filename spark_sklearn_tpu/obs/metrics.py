@@ -277,6 +277,17 @@ SEARCH_REPORT_SCHEMA = (
         "class: tree_slots_per_launch counts the trees.",
         stat="tree_steps", combine="sum"),
     MetricDef(
+        "row_sorts_per_launch", "series",
+        "Per launch of a boosting family on a TPU: sorts of a lane's "
+        "rows into node order that the launch executed.  A tree sorts its "
+        "rows at levels 0, 5 and 10 (those above its depth); where every "
+        "stage counts the fold's training rows (subsample 1.0, not "
+        "searched) a lane's rows are put in that order once and its trees "
+        "sort from level 5 on: one a lane, plus one a tree a level-5 or "
+        "level-10 sort.  Lanes of one fold share the one order's "
+        "computation.  0 on the plain form (no TPU), which sorts no rows.",
+        stat="row_sorts", combine="sum"),
+    MetricDef(
         "knn_distance_entries_per_launch", "series",
         "Per launch of a neighbours family (KNeighborsClassifier, "
         "KNeighborsRegressor): row x column squared distances the "

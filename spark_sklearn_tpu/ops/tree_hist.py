@@ -25,7 +25,14 @@ Two forms of one step of ``ops/trees.py::grow_tree``:
   one gather of a row's packed codes and statistics): between two sorts the
   nodes of a *group* (16 nodes that descend from neighbours in the sorted
   order) stay side by side, and the kernel tells a group's nodes apart in
-  the product's other operand.  The sorted axis is cut at every multiple of
+  the product's other operand.  At level 0, where every row is in the root,
+  the order only puts the rows that count first; where those rows are the
+  same for every tree of a loop (a booster whose ``subsample`` is 1.0: the
+  fold's training rows) the caller makes that order once
+  (:func:`row_order`, a :class:`RowOrder` a lane), hands its rows' statistics
+  in it to every tree, and the trees sort and gather nothing at level 0 and
+  sort nothing back: the first sort of such a tree is at level 5, relative
+  to the order.  The sorted axis is cut at every multiple of
   the row tile and at every group's first row; each piece is one *item*
   ``(tile, group, first row, last row)`` in scalar prefetch.  A grid step
   takes one item, builds in VMEM the ``(16 nodes x statistics, T)`` operand
@@ -54,6 +61,7 @@ switch: the kernels on a TPU, the plain form elsewhere.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -474,12 +482,14 @@ def pack_stats(stats, parts):
             stats.shape[0], parts * s8 // 2, 2), jnp.int32)
 
 
-def split_parts(stats, s8, parts):
+def split_parts(stats, s8, parts, axis=1):
     """``(n, S)`` float32 -> ``(n, parts * s8)`` bfloat16: the value's
     first bfloat16 part, or ``[hi | mid | lo]`` with ``hi + mid + lo ==
-    stats`` to the bit."""
-    rest = jnp.pad(stats.astype(jnp.float32),
-                   ((0, 0), (0, s8 - stats.shape[1])))
+    stats`` to the bit.  With ``axis=0`` the statistics are rows: ``(S,
+    n)`` -> ``(parts * s8, n)``, the kernels' layout."""
+    pad = [(0, 0), (0, 0)]
+    pad[axis] = (0, s8 - stats.shape[axis])
+    rest = jnp.pad(stats.astype(jnp.float32), pad)
     out = []
     for _ in range(parts):
         # rounded by an operation of its own: a convert to bfloat16 and
@@ -490,7 +500,7 @@ def split_parts(stats, s8, parts):
                                         mantissa_bits=7)
         out.append(part.astype(jnp.bfloat16))
         rest = rest - part
-    return jnp.concatenate(out, axis=1)
+    return jnp.concatenate(out, axis=axis)
 
 
 def group_items(starts, tile, n_tiles, n_items):
@@ -531,28 +541,100 @@ def _pick(sel, d_pad):
         jnp.bfloat16)
 
 
+class RowOrder(NamedTuple):
+    """A lane's rows in the kernels' level-0 order, made once for a loop
+    of trees whose rows that count are the same in every tree: those rows
+    first, then the others, each part in the caller's order (what the
+    level-0 sort of every such tree computed)."""
+    perm: jnp.ndarray        # (n,) int32: the caller's row at each place
+    inv: jnp.ndarray         # (n,) int32: the place of each caller's row
+    code_words: jnp.ndarray  # (n, d_pad / 4) int32: packed codes, in order
+    codes_t: jnp.ndarray     # (d_pad, n_pad) uint8: the kernels' codes
+    bounds: jnp.ndarray      # (3,) int32: 0, the rows that count, n
+
+    def rows(self, x):
+        """``x``, rows in the caller's order, in the order."""
+        return take_rows(x, self.perm)
+
+    def back(self, x, axis=0):
+        """``x``, rows along ``axis`` in the order, in the caller's."""
+        return jnp.moveaxis(take_rows(jnp.moveaxis(x, axis, 0), self.inv),
+                            0, axis)
+
+
+def launch_order(codes, w, tile=ROW_TILE):
+    """:class:`RowOrder` of the rows whose weight ``w`` is not 0: one
+    stable sort, one gather of the packed codes, their transpose."""
+    n = codes.shape[0]
+    n_pad = _round_up(n, tile)
+    with jax.named_scope("sst.tree.partition"):
+        counted = w != 0
+        _, perm = jax.lax.sort(
+            (jnp.logical_not(counted).astype(jnp.int32),
+             jnp.arange(n, dtype=jnp.int32)), num_keys=1)
+        n_counted = jnp.sum(counted, dtype=jnp.int32)
+        inv = jnp.where(
+            counted, jnp.cumsum(counted, dtype=jnp.int32) - 1,
+            n_counted + jnp.cumsum(jnp.logical_not(counted),
+                                   dtype=jnp.int32) - 1)
+        # written once, and not again inside the gather
+        code_words = take_rows(
+            jax.lax.optimization_barrier(pack_codes(codes)), perm)
+        codes_t = jax.lax.bitcast_convert_type(
+            jnp.pad(code_words, ((0, n_pad - n), (0, 0))),
+            jnp.uint8).reshape(n_pad, -1).T
+        bounds = jnp.stack([jnp.zeros((), jnp.int32), n_counted,
+                            jnp.asarray(n, jnp.int32)])
+    return RowOrder(perm, inv, code_words, codes_t, bounds)
+
+
 class GroupedLevels:
     """A tree's rows in the kernels' order: sorted every
     ``LEVELS_PER_SORT`` levels by (counts for nothing, node), their codes
     and statistics gathered into that order, the node each sits in beside
-    them."""
+    them.
+
+    With ``order`` (a :class:`RowOrder` of the rows that count, which are
+    then the rows of nonzero weight) the rows and their statistics arrive
+    in that order, which is level 0's: no sort and no gather there, the
+    statistics laid out for the kernels by elementwise work, and
+    :meth:`leaves` in the order's own layout.  Levels 5 and 10 sort
+    relative to it."""
 
     def __init__(self, codes, stats, n_bins, integer_stats=False,
-                 tile=ROW_TILE, interpret=False):
+                 tile=ROW_TILE, interpret=False, order=None):
         self.n, self.d = codes.shape
         self.n_bins = n_bins
         self.parts = 1 if integer_stats else 3
         self.tile, self.interpret = tile, interpret
         self.n_tiles = -(-self.n // tile)
         self.n_pad = self.n_tiles * tile
-        with jax.named_scope("sst.tree.partition"):
-            # the two tables the sorts gather from, each written once a
-            # tree and not again inside a gather
-            self.code_words, self.stat_words = jax.lax.optimization_barrier(
-                (pack_codes(codes), pack_stats(stats, self.parts)))
-            counted = jnp.any(stats != 0, axis=1)
-        self.perm = jnp.arange(self.n, dtype=jnp.int32)
+        self.stats = stats
+        # the caller's row at each place: None while the rows sit where
+        # the caller (or its order) has them
+        self.perm = None
         self.heap = jnp.zeros((self.n,), jnp.int32)
+        if order is None:
+            with jax.named_scope("sst.tree.partition"):
+                # the two tables the sorts gather from, each written once
+                # a tree and not again inside a gather
+                self.code_words, self.stat_words = \
+                    jax.lax.optimization_barrier(
+                        (pack_codes(codes), pack_stats(stats, self.parts)))
+                counted = jnp.any(stats != 0, axis=1)
+            self.sorted_level = None
+        else:
+            assert order.codes_t.shape[1] == self.n_pad, "another tile"
+            with jax.named_scope("sst.tree.partition"):
+                self.code_words, self.codes_t = order.code_words, \
+                    order.codes_t
+                self.bounds, self.sorted_level = order.bounds, 0
+                self.stat_words = None          # made where a sort needs it
+                s8 = _round_up(stats.shape[1], 8)
+                self.stats_t = jnp.pad(
+                    split_parts(stats.T, s8, self.parts, axis=0),
+                    ((0, 0), (0, self.n_pad - self.n)))
+                counted = jnp.arange(self.n) < order.bounds[1]
         # 0: the row sits in a leaf; on its way down: 2 where it counts,
         # 1 where it counts for nothing
         self.live = 1 + counted.astype(jnp.int32)
@@ -563,13 +645,18 @@ class GroupedLevels:
         last."""
         n_keys = 2 ** level
         with jax.named_scope("sst.tree.partition"):
+            if self.stat_words is None:
+                self.stat_words = jax.lax.optimization_barrier(
+                    pack_stats(self.stats, self.parts))
             local = self.heap - (n_keys - 1)
             key = jnp.where(
                 self.live > 0,
                 jnp.where(self.live > 1, 0, n_keys) + local,
                 2 * n_keys).astype(jnp.int32)
+            perm = (jnp.arange(self.n, dtype=jnp.int32) if self.perm is None
+                    else self.perm)
             key, self.perm, self.heap, self.live = jax.lax.sort(
-                (key, self.perm, self.heap, self.live), num_keys=1)
+                (key, perm, self.heap, self.live), num_keys=1)
             # each key's first position: the rows with a smaller key
             self.bounds = jnp.sum(
                 key[:, None] < jnp.arange(2 * n_keys + 1,
@@ -602,7 +689,7 @@ class GroupedLevels:
         features ``sel (2^level, slots)`` ``(2^level, slots padded to the
         kernel's blocks, S8, n_bins)``: features, slots and statistics
         past the data's own read zero."""
-        if level % LEVELS_PER_SORT == 0:
+        if level % LEVELS_PER_SORT == 0 and level != self.sorted_level:
             self._sort(level)
         with jax.named_scope("sst.tree.partition"):
             self.tables = self._items(level)
@@ -628,7 +715,10 @@ class GroupedLevels:
             self.heap, self.live = state[0, :self.n], state[1, :self.n]
 
     def leaves(self):
-        """The node each row ended in, in the caller's order."""
+        """The node each row ended in, in the caller's order (the
+        order's, where the rows came in one)."""
+        if self.perm is None:       # no sort moved them
+            return self.heap
         with jax.named_scope("sst.tree.route"):
             return jax.lax.sort((self.perm, self.heap), num_keys=1)[1]
 
@@ -639,10 +729,34 @@ def on_tpu():
     return jax.default_backend() == "tpu"
 
 
-def levels_of(codes, stats, n_bins, integer_stats=False):
-    """A tree's rows in the form the backend's platform runs."""
-    form = GroupedLevels if on_tpu() else PlainLevels
-    return form(codes, stats, n_bins, integer_stats)
+def row_order(codes, w):
+    """The rows of nonzero weight ``w`` first, made once for a loop of
+    trees that all count those rows (:class:`RowOrder`), on the kernels'
+    platform; None on the plain form's, whose rows stay where the caller
+    has them."""
+    return launch_order(codes, w) if on_tpu() else None
+
+
+def levels_of(codes, stats, n_bins, integer_stats=False, order=None):
+    """A tree's rows in the form the backend's platform runs, in the
+    :func:`row_order` ``order`` where there is one."""
+    if on_tpu():
+        return GroupedLevels(codes, stats, n_bins, integer_stats,
+                             order=order)
+    return PlainLevels(codes, stats, n_bins, integer_stats)
+
+
+def row_sorts(depth, trees, lanes, ordered):
+    """Sorts of rows into node order that ``trees`` trees of ``depth``
+    levels on ``lanes`` lanes execute: a tree's at every
+    ``LEVELS_PER_SORT``-th level, but level 0's once a lane (its
+    :class:`RowOrder`) where the trees grow in one (``ordered``); none on
+    the plain form."""
+    kernels = on_tpu()
+    first = LEVELS_PER_SORT if ordered else 0
+    per_tree = len(range(first, int(depth), LEVELS_PER_SORT)) if kernels \
+        else 0
+    return trees * per_tree + (lanes if ordered and kernels else 0)
 
 
 def level_histogram_bytes(depth, n_features, n_stats, n_bins=256,
@@ -667,3 +781,9 @@ def row_bytes(n_features, n_stats, integer_stats):
     parts = 1 if integer_stats else 3
     return (_padded_features(n_features)
             + parts * _round_up(n_stats, 8) * 2 + 8 * 4)
+
+
+def order_row_bytes(n_features):
+    """Bytes of one row as a :class:`RowOrder` holds it: its codes packed
+    and transposed, its place both ways."""
+    return 2 * _padded_features(n_features) + 2 * 4

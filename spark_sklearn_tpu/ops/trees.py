@@ -71,7 +71,7 @@ def feature_subsets(key, level, n_nodes, d, max_features):
 
 def grow_tree(codes, g, h, w, max_depth, n_bins, min_child_weight=1e-3,
               reg_lambda=1.0, feat_mask_key=None, max_features=None,
-              n_out=1, integer_stats=False):
+              n_out=1, integer_stats=False, order=None):
     """Grow one tree on binned features.
 
     codes: (n, d) uint8 (or int32) bin codes.  g/h: (n, n_out)/(n,)
@@ -81,6 +81,11 @@ def grow_tree(codes, g, h, w, max_depth, n_bins, min_child_weight=1e-3,
     h * w is an integer of at most 256 in size (a forest's bootstrap count
     x one-hot class), which one bfloat16 part holds exactly.  Returns a
     Tree whose value column holds the Newton leaf step per output.
+
+    `order`: ``tree_hist.row_order(codes, w)`` made once for every tree
+    of a loop whose `w` is the same (a booster's stages at `subsample`
+    1.0).  g, h and w then come in that order, and the tree's `leaf`
+    goes out in it; `codes` stay the caller's.
 
     With `feat_mask_key` and `max_features < d`, a node splits on one of
     its own `max_features` features, a fresh subset a node and level
@@ -107,7 +112,8 @@ def grow_tree(codes, g, h, w, max_depth, n_bins, min_child_weight=1e-3,
     # the statistics of a row: its hessian, then a gradient an output
     stats = jnp.concatenate([(h * w)[:, None], g * w[:, None]],
                             axis=1).astype(jnp.float32)   # (n, 1 + n_out)
-    rows = tree_hist.levels_of(codes, stats, n_bins, integer_stats)
+    rows = tree_hist.levels_of(codes, stats, n_bins, integer_stats,
+                               order=order)
     sums = []                                 # a level's (nodes, 1 + n_out)
 
     for level in range(max_depth):

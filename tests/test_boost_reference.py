@@ -132,11 +132,22 @@ def test_reference_states_the_two_class_model_only():
 
 # --- the program against the reference ---------------------------------------
 
-def kernels_form(codes, stats, n_bins, integer_stats=False):
+def kernels_form(codes, stats, n_bins, integer_stats=False, order=None):
     """The grower's rows in the kernels' form, interpreted: real-valued
-    statistics as three bfloat16 parts, every feature of every node."""
+    statistics as three bfloat16 parts, every feature of every node; in
+    a launch's row order (`kernels_order`) where the caller has one."""
     return tree_hist.GroupedLevels(codes, stats, n_bins, integer_stats,
-                                   tile=128, interpret=True)
+                                   tile=128, interpret=True, order=order)
+
+
+def kernels_order(made=None):
+    """`tree_hist.row_order` for `kernels_form`, whatever the platform;
+    notes each order it makes in `made`."""
+    def row_order(codes, w):
+        if made is not None:
+            made.append(codes.shape)
+        return tree_hist.launch_order(codes, w, tile=128)
+    return row_order
 
 
 @pytest.mark.parametrize("n,d,seed,rate", [(900, 8, 3, 0.4),
@@ -357,18 +368,29 @@ def test_launch_workspace_prices_a_lane_by_its_rows(family, k, trees):
     meta = {"n_features": 54, "max_estimators": 100}
     if k:
         meta["n_classes"] = k
-    ws = family.launch_workspace(145_253, meta, 5, static={})
+    # rows that change from stage to stage: a tree sorts its own
+    sampled = {"subsample": 0.8}
+    ws = family.launch_workspace(145_253, meta, 5, static=sampled)
     assert ws["fixed_bytes"] == 0
     row = 5 * tree_hist.row_bytes(54, 2, integer_stats=False) + 16
     assert row == 736
     assert ws["per_candidate_bytes"] == 5 * trees * 145_253 * (row + 24)
+    # the fold's training rows at every stage: one copy of a row a tree,
+    # and a fold's order (its codes twice, its place both ways)
+    fixed = family.launch_workspace(145_253, meta, 5, static={})
+    assert tree_hist.order_row_bytes(54) == 136
+    assert fixed["fixed_bytes"] == 5 * 145_253 * 136
+    assert fixed["per_candidate_bytes"] == 5 * trees * 145_253 * (160 + 24)
+    # ... unless the search names subsample
+    assert family.launch_workspace(
+        145_253, {**meta, "subsample_searched": True}, 5, static={}) == ws
     # deep trees on few rows: the deepest level's histograms decide
     deep = family.launch_workspace(1000, meta, 5, static={"max_depth": 10})
     assert deep["per_candidate_bytes"] > 5 * trees * 1.39 * (
         512 * 54 * 2 * 256 * 4)
     # laid out in runs of 3: a third of a lane and three copies of its F
     run = family.launch_workspace(
-        145_253, meta, 5, static={tree_models._STAGE_RUN: 3})
+        145_253, meta, 5, static={**sampled, tree_models._STAGE_RUN: 3})
     assert run["fixed_bytes"] == 0
     assert run["per_candidate_bytes"] == 5 * (
         trees * 145_253 * (row + 24) // 3 + 3 * 4 * 145_253 * trees)
@@ -384,10 +406,14 @@ def test_width_cap_binds_under_a_small_budget():
     est = GradientBoostingClassifier(random_state=0)
     free = search(est, X, y, grid, one_device=True)
     group = free.search_report["memory"]["groups"][0]
-    assert group["workspace_bytes"] > 0 and group["fixed_bytes"] == 0
+    # the launch's one thing whatever its width: a fold's row order
+    assert group["workspace_bytes"] > 0
+    assert group["fixed_bytes"] == FOLDS * 600 * tree_hist.order_row_bytes(10)
     lane = group["per_candidate_bytes"]
-    assert lane > FOLDS * 600 * 5 * tree_hist.row_bytes(10, 2, False)
-    budget = int(group["resident_bytes"] + 2.5 * lane)
+    # a tree of it holds a row's kernels' bytes (in the fold's one order)
+    assert lane > FOLDS * 600 * tree_hist.row_bytes(10, 2, False)
+    budget = int(group["resident_bytes"] + group["fixed_bytes"]
+                 + 2.5 * lane)
     capped = search(est, X, y, grid, one_device=True,
                     hbm_budget_bytes=budget)
     geometry = capped.search_report["geometry"]["groups"]
@@ -415,7 +441,7 @@ def test_width_cap_below_a_run_runs_the_plain_program(room, width, runs,
     free = search(est, X, y, grid, one_device=True)
     group = free.search_report["memory"]["groups"][0]
     assert group["width"] == 10 and not group["capped"]
-    budget = int(group["resident_bytes"]
+    budget = int(group["resident_bytes"] + group["fixed_bytes"]
                  + room * group["per_candidate_bytes"])
     seen = []
     real = tree_models._stage_run
@@ -556,10 +582,11 @@ def test_grids_that_are_not_whole_runs_plan_and_score_as_on_the_parent(
     assert np.array_equal(split_scores(ours), split_scores(theirs))
 
 
-def laid_out(family, X, y, cands, t_max):
+def launched(family, X, y, cands, t_max):
     """`fit_task_batched` over `cands` (dicts of learning_rate,
-    n_estimators, subsample) in `launch_layout`'s order with its fact,
-    beside each candidate's one-candidate `fit` on each fold."""
+    n_estimators, subsample) in `launch_layout`'s order with its fact:
+    the tasks' models, the candidates' parameters in that order, the run
+    length, and what a candidate's one fit takes (data, meta, masks)."""
     data, meta = family.prepare_data(X, y)
     meta["max_estimators"] = t_max
     dyn = {k: np.asarray([c[k] for c in cands],
@@ -571,6 +598,14 @@ def laid_out(family, X, y, cands, t_max):
         d, {**STATIC, **facts, "__n_folds__": FOLDS}, data, w, meta))(
         {k: np.repeat(v, FOLDS) for k, v in dyn.items()},
         np.tile(masks, (len(cands), 1)))
+    return tasks, dyn, run, (data, meta, masks)
+
+
+def laid_out(family, X, y, cands, t_max):
+    """`launched`, beside each candidate's one-candidate `fit` on each
+    fold."""
+    tasks, dyn, run, (data, meta, masks) = launched(family, X, y, cands,
+                                                    t_max)
     one = jax.jit(lambda d, m: family.fit(d, STATIC, data, m, meta))
     alone = [one({k: v[c] for k, v in dyn.items()}, masks[f])
              for c in range(len(cands)) for f in range(FOLDS)]
@@ -600,6 +635,92 @@ def test_a_laid_out_launch_is_each_candidates_own_fit(family, k, subsample):
         if k:
             assert np.array_equal(np.asarray(tasks["pred"][t]),
                                   np.asarray(model["pred"]))
+
+
+def per_stage_fit(dynamic, static, data, train_w, meta):
+    """The two-class stage loop as the commit before the launch-wide row
+    order had it (commit 405db69), written out for one count: every stage
+    draws its row weights, every tree sorts its rows at level 0 and back.
+    Returns F."""
+    codes, y1h = data["codes"], data["y1h"]
+    n = codes.shape[0]
+    t_max = int(meta["max_estimators"])
+    lr = jnp.asarray(dynamic["learning_rate"], jnp.float32)
+    n_est = jnp.asarray(dynamic["n_estimators"], jnp.int32)
+    subsample = jnp.asarray(dynamic.get(
+        "subsample", static.get("subsample", 1.0)), jnp.float32)
+    wsum = jnp.sum(train_w) + 1e-12
+    prior = jnp.clip(
+        (train_w[:, None] * y1h).sum(0) / wsum, 1e-6, 1 - 1e-6)
+    F = jnp.full((n,), jnp.log(prior[1] / (1.0 - prior[1])), jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(0), t_max)
+
+    def one_stage(carry):
+        t, F = carry
+        p = jax.nn.sigmoid(F)
+        w_t = train_w * (jax.random.uniform(keys[t], (n,)) < subsample
+                         ).astype(jnp.float32)
+        tree = grow_tree(codes, (p - y1h[:, 1])[:, None], p * (1.0 - p),
+                         w_t, 3, 256, min_child_weight=1.0,
+                         reg_lambda=1e-6)
+        return t + 1, F + lr * tree_models._own_rows(tree)[:, 0]
+
+    return jax.lax.while_loop(lambda c: c[0] < n_est, one_stage,
+                              (jnp.asarray(0, jnp.int32), F))[1]
+
+
+@pytest.mark.parametrize("subsample", [None, 0.6])
+def test_a_launch_order_keeps_f_to_the_bit(subsample, monkeypatch):
+    """2 learning rates x 3 counts x 3 folds on the kernels' form (what a
+    TPU runs).  At subsample 1.0, not searched, a lane's stage loop runs
+    in ONE row order made before it, and every candidate's F is bit for
+    bit the per-stage path's written out (a sort at level 0 and one back
+    in every tree); searched at 0.6, the loop makes no order and is that
+    path."""
+    made = []
+    monkeypatch.setattr(tree_hist, "levels_of", kernels_form)
+    monkeypatch.setattr(tree_hist, "row_order", kernels_order(made))
+    X, y = make_data(300, 8, 2, 17, separation=1.0)
+    cands = [{"learning_rate": lr, "n_estimators": m}
+             for lr in (0.3, 0.1) for m in (3, 1, 4)]
+    if subsample is not None:
+        cands = [{**c, "subsample": subsample} for c in cands]
+    tasks, dyn, run, (data, meta, masks) = launched(GBC, X, y, cands, 4)
+    assert run == 3
+    assert len(made) == (1 if subsample is None else 0)
+    written = jax.jit(jax.vmap(lambda d, w: per_stage_fit(
+        d, STATIC, data, w, meta)))(
+        {k: np.repeat(v, FOLDS) for k, v in dyn.items()},
+        np.tile(masks, (len(cands), 1)))
+    assert np.array_equal(np.asarray(tasks["logits"]), np.asarray(written))
+    assert tasks["n_iter"].tolist() == np.repeat(dyn["n_estimators"],
+                                                 FOLDS).tolist()
+
+
+@pytest.mark.parametrize("depth", [2, 6])
+@pytest.mark.parametrize("searched", [False, True])
+def test_row_sorts_count_a_lanes_order_once(depth, searched, monkeypatch,
+                                            fresh_programs):
+    """On the kernels' form: with subsample 1.0 and not searched, one sort
+    a lane (its order) and one a tree at level 5; searched (even at 1.0),
+    a tree sorts at level 0 and at level 5.  2 runs x 3 folds = 6 lanes of
+    3 stages, 18 trees.  Built anew (`fresh_programs`): the programs of
+    the kernels' form must not serve the plain form's searches."""
+    monkeypatch.setattr(tree_hist, "on_tpu", lambda: True)
+    monkeypatch.setattr(tree_hist, "levels_of", kernels_form)
+    monkeypatch.setattr(tree_hist, "row_order", kernels_order())
+    X, y = make_data(300, 8, 2, 9, separation=1.0)
+    grid = {"learning_rate": [0.1, 0.3], "n_estimators": [2, 3]}
+    if searched:
+        grid["subsample"] = [1.0]
+    gs = search(GradientBoostingClassifier(random_state=0, max_depth=depth),
+                X, y, grid, one_device=True, sort_candidates=False)
+    rep = gs.search_report
+    lanes, trees = 2 * FOLDS, 3 * 2 * FOLDS
+    assert rep["tree_steps_per_launch"] == [trees]
+    later = trees if depth > 5 else 0
+    assert rep["row_sorts_per_launch"] == [
+        trees + later if searched else lanes + later]
 
 
 @pytest.mark.parametrize("dyn,runs", [
@@ -771,6 +892,8 @@ def test_launch_counters_reach_the_report(kind, trees):
     assert rep["tree_slots_per_launch"] == [3 * lanes * trees]
     assert rep["trees_grown_per_launch"] == rep["tree_slots_per_launch"]
     assert rep["tree_levels_per_launch"] == [3 * lanes * trees * 2]
+    # the plain form sorts no rows
+    assert rep["row_sorts_per_launch"] == [0]
     assert rep["trees_per_candidate"] == [2, 3, 2, 3]
     assert rep["hist_features_per_node"] == [8]
     assert rep["hist_bytes_per_lane"] == [2 * 8 * 2 * 256 * 4]

@@ -61,9 +61,10 @@ one learning rate's 3 counts x 5 folds = 25 lanes of 145 253 x 54, two
 classes, each lane one stage loop read at 3 counts — with the grower on
 the kernels' form: the real-valued, every-feature path compiles for the
 chip (three bfloat16 parts a statistic, ``f32[25, nodes, 64, 8, 256]``
-histograms), both ``sst.boost.*`` scopes name device operations, a stage
-sorts its rows once, and the ledger's price is within 15 % of the
-compiler's allotment.
+histograms), both ``sst.boost.*`` scopes name device operations, a fold's
+rows are sorted and their codes gathered once a launch and no stage sorts
+or gathers them, and the ledger's price is within 15 % of the compiler's
+allotment.
 
 The neighbour search (``KNeighborsClassifierFamily.fit_task_batched``) is
 compiled at the launch of the ``knn_mnist.k30_weights2`` cell —
@@ -885,9 +886,10 @@ def test_boost_launch_is_every_features_histograms_in_three_parts(
         boost_launch):
     """The path no forest takes since PR 38: a slot is a feature (64 with
     the kernel's padding) at every node of the three levels, 25 lanes a
-    grid axis; a row's statistics travel as three bfloat16 parts of 8
-    statistic rows, 12 words beside the codes' 16; the rows are sorted once
-    a stage; no level scatters."""
+    grid axis; a row's statistics are laid out as three bfloat16 parts of
+    8 statistic rows; the rows of a fold are sorted, and their codes' 16
+    words gathered, once a launch (a fold's 5 runs share its order), and
+    no stage sorts or gathers codes or statistics; no level scatters."""
     text = boost_launch[0].as_text()
     lanes = BOOST_LANES
     calls = re.findall(r'custom_call_target="tpu_custom_call"', text)
@@ -896,9 +898,11 @@ def test_boost_launch_is_every_features_histograms_in_three_parts(
         assert re.search(r'f32\[%d,%d,64,8,256\]\S* custom-call\(' % (
             lanes, 2 ** level), text)
     assert " scatter(" not in text
-    gathered = re.findall(r'= s32\[%d,(\d+)\]\S* gather\(' % (
-        lanes * TREE_N), text)
-    assert sorted(gathered) == ["12", "16"]
+    gathered = [(rows, words) for rows, words in re.findall(
+        r'= s32\[(\d+),(\d+)\]\S* gather\(', text) if int(rows) >= TREE_N]
+    assert gathered == [(str(FOLDS * TREE_N), "16")]
+    sorted_rows = re.findall(r'= \((s32\[[\d,]+\])[^=]*\) sort\(', text)
+    assert sorted_rows == ["s32[%d,%d]" % (FOLDS, TREE_N)]
     assert re.search(r'bf16\[%d,24,\d+\]' % lanes, text)
 
 
@@ -926,12 +930,14 @@ def test_ledger_prices_the_boost_launch(boost_launch):
          "n_estimators": np.zeros(BOOST_CANDIDATES, np.int32)},
         BOOST_CANDIDATES, FOLDS, task_batched=True, n_samples=TREE_N,
         workspace=workspace)
-    # a lane is its rows, 110 MB, shared by a run's 3 candidates
-    assert modeled["fixed_bytes"] == 0
-    assert modeled["per_candidate_bytes"] > FOLDS * 100e6 / BOOST_RUN
+    # a lane is one copy of its rows' kernels' bytes, 26.7 MB, shared by
+    # a run's 3 candidates; a fold's order, 19.8 MB, is the launch's
+    assert modeled["fixed_bytes"] == FOLDS * TREE_N * 136
+    assert modeled["per_candidate_bytes"] > FOLDS * 26e6 / BOOST_RUN
     assert abs(modeled["chunk_bytes"] - allotted) < 0.15 * allotted
-    # an eighth of the chip and more: the cell's size (PERF.md section 4)
-    assert allotted > 0.125 * 16.909e9
+    # 2.72 GB where every stage sorted and gathered each lane's rows
+    # (PERF.md section 4)
+    assert allotted < 1.3e9
 
 
 # --- the neighbour search ----------------------------------------------------

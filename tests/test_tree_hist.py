@@ -383,16 +383,70 @@ def test_the_grower_grows_the_same_tree_on_either_form(
     assert np.array_equal(node, np.asarray(plain.leaf))
 
 
-def recording_levels(shapes):
+def recording_levels(shapes, hists=None):
     """``levels_of`` for the kernels' form in interpret mode, which notes
-    the shape of every level's histograms."""
+    the shape of every level's histograms (and, with ``hists``, the
+    histograms)."""
     class Recording(tree_hist.GroupedLevels):
         def histograms(self, level, sel=None):
             out = super().histograms(level, sel)
             shapes.append(out.shape)
+            if hists is not None:
+                hists.append(out)
             return out
 
     return functools.partial(Recording, tile=128, interpret=True)
+
+
+@pytest.mark.parametrize("depth", [3, 7])
+def test_a_launch_order_grows_the_per_tree_sorts_tree(depth, monkeypatch):
+    """A tree grown in a launch's row order (rows of nonzero weight first,
+    made once: ``launch_order``) builds every level's histograms bit for
+    bit as the tree that sorts its own rows at level 0, and its rows end
+    in the same leaves, read in the order's layout; at depth 7 its
+    level-5 sort is relative to the order.  Zero-weight rows are
+    scattered through; the statistics are a booster's, in three parts."""
+    rng = np.random.default_rng(depth)
+    n, d = 900, 10
+    codes = jnp.asarray(rng.integers(0, N_BINS, (n, d), dtype=np.uint8))
+    w = jnp.asarray((rng.random(n) >= 0.35).astype(np.float32))
+    p = rng.random(n).astype(np.float32)
+    g = jnp.asarray((p - (rng.random(n) < 0.5))[:, None], jnp.float32)
+    h = jnp.asarray(p * (1 - p))
+    kwargs = dict(min_child_weight=1.0, reg_lambda=1e-6)
+
+    def grow(ordered):
+        def run():
+            hists = []
+            monkeypatch.setattr(tree_hist, "levels_of",
+                                recording_levels([], hists))
+            if not ordered:
+                return grow_tree(codes, g, h, w, depth, N_BINS,
+                                 **kwargs), hists
+            order = tree_hist.launch_order(codes, w, tile=128)
+            tree = grow_tree(codes, order.rows(g), order.rows(h),
+                             order.rows(w), depth, N_BINS, order=order,
+                             **kwargs)
+            return tree._replace(leaf=order.back(tree.leaf)), hists
+        sorts = str(jax.make_jaxpr(run)()).count(" sort[")
+        tree, levels = jax.jit(run)()
+        return tree, [np.asarray(a) for a in levels], sorts
+
+    sorted_tree, sorted_levels, sorts = grow(False)
+    tree, levels, order_sorts = grow(True)
+    for name in tree._fields:
+        assert np.array_equal(np.asarray(getattr(tree, name)),
+                              np.asarray(getattr(sorted_tree, name))), name
+    assert len(levels) == len(sorted_levels) == depth
+    for a, b in zip(levels, sorted_levels):
+        assert np.array_equal(a, b)
+    feat = np.asarray(tree.feat)
+    assert feat[0] >= 0 and (feat >= 0).sum() > depth   # a grown tree
+    # a tree sorts at level 0 and back; in the order, the order's one
+    # sort; each at level 5 and back, relative to where it has the rows
+    deep = depth > tree_hist.LEVELS_PER_SORT
+    assert sorts == 2 + deep
+    assert order_sorts == 1 + 2 * deep
 
 
 @pytest.mark.parametrize("max_features,with_key", [
