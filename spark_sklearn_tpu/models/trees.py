@@ -473,9 +473,17 @@ class GradientBoostingRegressorFamily(Family):
 
     @classmethod
     def launch_facts(cls, static, meta, n_candidates, n_folds):
-        # a stage's trees may split on any feature
+        """A stage's trees may split on any feature.  Where every stage
+        counts the fold's training rows (`_fixed_rows`) a fold's runs (the
+        launch's candidates, or its runs of S) and a stage's trees read
+        the fold's one order, so its histograms' masks serve them all."""
+        runs, trees = 1, 1
+        if _fixed_rows({}, static, meta):
+            runs = n_candidates // _stage_run(static, n_candidates)
+            trees = cls._trees_per_stage(meta)
         return {"hist_features": int(meta["n_features"]),
-                "hist_bytes": cls._hist_bytes(static, meta)}
+                "hist_bytes": cls._hist_bytes(static, meta),
+                "mask_lanes": tree_hist.lanes_per_mask(runs, trees)}
 
     @classmethod
     def _hist_bytes(cls, static, meta):
@@ -505,14 +513,17 @@ class GradientBoostingRegressorFamily(Family):
 
         Where every stage counts the fold's training rows (`_fixed_rows`)
         the rows stay in one order a fold: a tree holds one copy of a
-        row's kernels' bytes and sorts none, and the launch holds a
-        fold's order (`tree_hist.order_row_bytes` a row)."""
+        row's kernels' bytes but its codes, which are its fold's order's
+        (the runs and trees of a fold share their code tiles), and sorts
+        none, and the launch holds a fold's order
+        (`tree_hist.order_row_bytes` a row)."""
         trees = cls._trees_per_stage(meta)
         n = int(n_samples)
         static = static or {}
         fixed = _fixed_rows({}, static, meta)
         row_bytes = tree_hist.row_bytes(meta["n_features"], 2,
-                                        integer_stats=False)
+                                        integer_stats=False,
+                                        own_codes=not fixed)
         row = (2 * 2 * 4                        # leaf values, stats
                + (1 if fixed else _ROW_COPIES) * row_bytes)
         tree = max(int(_HIST_COPIES * cls._hist_bytes(static, meta)),
@@ -793,8 +804,10 @@ class RandomForestClassifierFamily(Family):
 
     @classmethod
     def launch_facts(cls, static, meta, n_candidates, n_folds):
+        # a forest's trees draw their own rows: each its own code tiles
         return {"hist_bytes": cls._hist_bytes(static, meta),
-                "hist_features": cls._hist_features(static, meta)}
+                "hist_features": cls._hist_features(static, meta),
+                "mask_lanes": tree_hist.lanes_per_mask(1)}
 
     @classmethod
     def launch_workspace(cls, n_samples, meta, n_folds, itemsize=4, *,

@@ -288,6 +288,21 @@ SEARCH_REPORT_SCHEMA = (
         "computation.  0 on the plain form (no TPU), which sorts no rows.",
         stat="row_sorts", combine="sum"),
     MetricDef(
+        "hist_lanes_per_mask", "series",
+        "Per launch of a tree family on a TPU: lanes one code <= bin mask "
+        "of the histogram kernel serves at a tree's first level.  Lanes "
+        "whose rows are in one order with the same code tiles (a "
+        "booster's runs of one fold where every stage counts the fold's "
+        "training rows, and a stage's trees a class) share each grid "
+        "step's tile: its masks are built once and every lane's "
+        "statistics streamed through them, as many lanes a step as the "
+        "kernel's VMEM holds (fewer at deeper levels; from level 5 on a "
+        "tree's own sort gives every lane its own tiles).  1 where every "
+        "lane reads its own tiles (a forest; a booster at subsample < 1 "
+        "or searched); 0 on the plain form (no TPU), which builds no "
+        "mask.",
+        stat="mask_lanes", combine="fact"),
+    MetricDef(
         "knn_distance_entries_per_launch", "series",
         "Per launch of a neighbours family (KNeighborsClassifier, "
         "KNeighborsRegressor): row x column squared distances the "

@@ -46,7 +46,13 @@ Two forms of one step of ``ops/trees.py::grow_tree``:
   fold's test rows, a tree's out-of-bag rows) are sorted behind the others
   and left out of the product; a second, small kernel sends every row to
   its child from the same items.  Lanes of a launch are a grid axis
-  (``jax.vmap`` lands there through ``custom_vmap``).
+  (``jax.vmap`` lands there through ``custom_vmap``).  Where a ``vmap``
+  batches the statistics and the rows' nodes but not the codes and the
+  items (a booster's runs of one fold, whose rows stay in one order; its
+  classes), its lanes *share* the kernels' code tiles: a grid step takes
+  one tile and the statistics of every lane that shares it, builds each
+  feature's mask once and streams all their operands through it in one
+  product (a routing step reads the tile once for all of them).
 
 The statistics enter the product in bfloat16 against an exact mask and are
 accumulated in float32.  Where the caller says they are small integers (a
@@ -81,6 +87,9 @@ GROUP_NODES = 16
 #: levels between two sorts: a group's 16 nodes at the last of them
 #: descend from ONE node of the sorted level
 LEVELS_PER_SORT = 5
+#: VMEM a grid step holds for the lanes that share its code tile (of the
+#: kernels' 48 MiB limit): more lanes than fit take one step each group
+SHARED_VMEM = 32 * 2 ** 20
 
 
 def _round_up(x, m):
@@ -106,6 +115,28 @@ def _slot_blocks(n_slots, subset):
     if subset:
         block = min(_round_up(n_slots, 8), block)
     return block, -(-int(n_slots) // block)
+
+
+def _hist_lane_bytes(k_nodes, block, s8, parts, n_bins, tile):
+    """VMEM one more lane of a shared code tile costs a histogram grid
+    step: its output block and its statistics' and rows' blocks, each
+    twice (the pipeline's two buffers), its operand rows (float32, then
+    bfloat16) and their product with a mask."""
+    rows = k_nodes * parts * s8
+    return (2 * k_nodes * block * s8 * n_bins * 4
+            + 2 * tile * (parts * s8 * 2 + 8 * 4)
+            + rows * tile * (4 + 2) + rows * n_bins * 4)
+
+
+def _step_runs(runs, lane_bytes):
+    """``(a, b)``: the lanes of a shared code tile one grid step takes of
+    ``runs = (outer, inner)``, a divisor of each, as many as
+    ``SHARED_VMEM`` holds at ``lane_bytes`` a lane (one at least)."""
+    fit = max(1, SHARED_VMEM // lane_bytes)
+    outer, inner = ([k for k in range(1, n + 1) if n % k == 0]
+                    for n in runs)
+    return max(((a, b) for a in outer for b in inner if a * b <= fit),
+               key=lambda ab: ab[0] * ab[1])
 
 
 # ---------------------------------------------------------------------------
@@ -182,20 +213,34 @@ class PlainLevels:
 # the kernels
 # ---------------------------------------------------------------------------
 
+def _run_leads(step_runs):
+    """The leading index of each lane of a grid step in its per-lane
+    blocks: ``(0,)`` where a step is one lane, ``(a, 0, b)`` over the
+    step's ``(outer, inner)`` shared lanes."""
+    if step_runs is None:
+        return [(0,)]
+    return [(a, 0, b) for a in range(step_runs[0])
+            for b in range(step_runs[1])]
+
+
 def _hist_kernel(tile_ref, group_ref, lo_ref, hi_ref, codes_ref, stats_ref,
                  state_ref, *rest, n_items, n_slots, s8, parts, n_bins,
-                 k_nodes, n_groups, offset):
+                 k_nodes, n_groups, offset, step_runs):
     """One item's share of its group's block ``(k_nodes, slots a block,
-    s8, n_bins)``.  ``codes_ref`` holds the block's own features of the
-    tile, which ARE its slot codes; or, with ``pick_ref`` (the group's
-    ``(k_nodes x slots a block, d_pad)`` one-hot of its nodes' own
-    features), every feature of the tile, and a row's slot codes are its
-    own node's rows of ``pick @ codes``.  Slots past ``n_slots`` are never
-    built and read zero."""
+    s8, n_bins)``, of each lane the step takes.  ``codes_ref`` holds the
+    block's own features of the tile, which ARE its slot codes; or, with
+    ``pick_ref`` (the group's ``(k_nodes x slots a block, d_pad)`` one-hot
+    of its nodes' own features), every feature of the tile, and a row's
+    slot codes are its own node's rows of ``pick @ codes``.  Slots past
+    ``n_slots`` are never built and read zero.  With ``step_runs`` the
+    step's lanes share the tile: their operands are stacked, and a mask
+    is built once for all of them."""
     del tile_ref
     pick_ref = rest[0] if len(rest) == 3 else None
     out_ref, slot_codes = rest[-2:]
-    lane, fb, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    last = 2 if step_runs is None else 3            # the grid's last axis
+    lane = pl.program_id(0)
+    fb, i = pl.program_id(last - 1), pl.program_id(last)
     at = lane * n_items + i
     group = jnp.minimum(group_ref[at], n_groups - 1)
     before = jnp.minimum(group_ref[jnp.maximum(at - 1, lane * n_items)],
@@ -212,13 +257,17 @@ def _hist_kernel(tile_ref, group_ref, lo_ref, hi_ref, codes_ref, stats_ref,
     def _():
         rows = stats_ref.shape[-1]
         pos = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
-        heap, live = state_ref[0, 0:1, :], state_ref[0, 1:2, :]
-        mine = heap - (offset + group * k_nodes)            # node in group
-        counts = jnp.logical_and(jnp.logical_and(pos >= lo, pos < hi),
-                                 live > 0)
-        st = stats_ref[0].astype(jnp.float32)               # (parts*s8, T)
-        blocks = [jnp.where(jnp.logical_and(counts, mine == k), st, 0.0)
-                  for k in range(k_nodes)]
+        leads = _run_leads(step_runs)
+        blocks = []
+        for lead in leads:
+            heap = state_ref[lead + (slice(0, 1), slice(None))]
+            live = state_ref[lead + (slice(1, 2), slice(None))]
+            mine = heap - (offset + group * k_nodes)        # node in group
+            counts = jnp.logical_and(jnp.logical_and(pos >= lo, pos < hi),
+                                     live > 0)
+            st = stats_ref[lead].astype(jnp.float32)        # (parts*s8, T)
+            blocks += [jnp.where(jnp.logical_and(counts, mine == k), st, 0.0)
+                       for k in range(k_nodes)]
         if len(blocks) * st.shape[0] % 16:      # bfloat16 packs 16 rows
             blocks.append(jnp.zeros_like(st))
         operand = jnp.concatenate(blocks, axis=0).astype(jnp.bfloat16)
@@ -253,10 +302,12 @@ def _hist_kernel(tile_ref, group_ref, lo_ref, hi_ref, codes_ref, stats_ref,
             acc = jax.lax.dot_general(
                 operand, mask, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)   # (k*parts*s8, bins)
-            for k in range(k_nodes):
-                part = [acc[(k * parts + p) * s8:(k * parts + p + 1) * s8]
-                        for p in range(parts)]
-                out_ref[0, k, f] += functools.reduce(jnp.add, part)
+            for j, lead in enumerate(leads):
+                for k in range(k_nodes):
+                    base = (j * k_nodes + k) * parts
+                    part = [acc[(base + p) * s8:(base + p + 1) * s8]
+                            for p in range(parts)]
+                    out_ref[lead + (k, f)] += functools.reduce(jnp.add, part)
             return carry
 
         jax.lax.fori_loop(0, n_here, one_slot, 0)
@@ -264,8 +315,14 @@ def _hist_kernel(tile_ref, group_ref, lo_ref, hi_ref, codes_ref, stats_ref,
 
 def _route_kernel(tile_ref, group_ref, lo_ref, hi_ref, word_ref, codes_ref,
                   state_ref, out_ref, codes_i32, *, n_items, k_nodes,
-                  n_groups, offset):
-    lane, i = pl.program_id(0), pl.program_id(1)
+                  n_groups, offset, runs, n_lanes, step_runs):
+    """One item's rows of each lane the step takes, each to its child by
+    its own lane's words; lanes that share the tile read it once."""
+    lane = pl.program_id(0)
+    i = pl.program_id(1 if step_runs is None else 2)
+    # (outer, inner) of the step's lanes' group
+    a, b = (None, None) if step_runs is None else divmod(
+        pl.program_id(1), runs[1] // step_runs[1])
     at = lane * n_items + i
     before = tile_ref[jnp.maximum(at - 1, lane * n_items)]
 
@@ -280,23 +337,36 @@ def _route_kernel(tile_ref, group_ref, lo_ref, hi_ref, word_ref, codes_ref,
         rows = state_ref.shape[-1]
         pos = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
         inside = jnp.logical_and(pos >= lo, pos < hi)
-        heap, live = state_ref[0, 0:1, :], state_ref[0, 1:2, :]
+        leads = _run_leads(step_runs)
+        loaded = [(state_ref[lead + (slice(0, 1), slice(None))],
+                   state_ref[lead + (slice(1, 2), slice(None))])
+                  for lead in leads]
         # the rows that count for nothing sit in groups n_groups..2n-1
         first = (group_ref[at] % n_groups) * k_nodes
-        mine = heap - (offset + first)
+        state = [(heap, live, heap - (offset + first))
+                 for heap, live in loaded]
         codes_i32[...] = codes_ref[0].astype(jnp.int32)
-        new_heap, new_live = heap, jnp.zeros_like(live)
-        for k in range(k_nodes):
-            word = word_ref[lane * (n_groups * k_nodes) + first + k]
-            code_at = codes_i32[pl.ds(word >> 9, 1), :]     # (1, T)
-            here = jnp.logical_and(mine == k, ((word >> 8) & 1) > 0)
-            child = 2 * heap + 1 + (code_at > (word & 0xFF)).astype(
-                jnp.int32)
-            new_heap = jnp.where(here, child, new_heap)
-            new_live = jnp.where(here, live, new_live)
-        moves = jnp.logical_and(inside, live > 0)
-        out_ref[0, 0:1, :] = jnp.where(moves, new_heap, out_ref[0, 0:1, :])
-        out_ref[0, 1:2, :] = jnp.where(moves, new_live, out_ref[0, 1:2, :])
+        for lead, (heap, live, mine) in zip(leads, state):
+            row = lane
+            if step_runs is not None:
+                # this lane's row of words in the (outer, lanes, inner)
+                # layout
+                row = ((a * step_runs[0] + lead[0]) * n_lanes + lane) \
+                    * runs[1] + b * step_runs[1] + lead[2]
+            new_heap, new_live = heap, jnp.zeros_like(live)
+            for k in range(k_nodes):
+                word = word_ref[row * (n_groups * k_nodes) + first + k]
+                code_at = codes_i32[pl.ds(word >> 9, 1), :]     # (1, T)
+                here = jnp.logical_and(mine == k, ((word >> 8) & 1) > 0)
+                child = 2 * heap + 1 + (code_at > (word & 0xFF)).astype(
+                    jnp.int32)
+                new_heap = jnp.where(here, child, new_heap)
+                new_live = jnp.where(here, live, new_live)
+            moves = jnp.logical_and(inside, live > 0)
+            heap_at = lead + (slice(0, 1), slice(None))
+            live_at = lead + (slice(1, 2), slice(None))
+            out_ref[heap_at] = jnp.where(moves, new_heap, out_ref[heap_at])
+            out_ref[live_at] = jnp.where(moves, new_live, out_ref[live_at])
 
 
 def _flat(tables):
@@ -306,23 +376,58 @@ def _flat(tables):
     return flat[0], flat[1], flat[2], flat[3]
 
 
+def _lane_axes(n_lanes, runs, step):
+    """A kernel's lane axes over ``n_lanes`` lanes of code tiles, each
+    shared by ``runs = (outer, inner)`` lanes of their own, ``step`` of
+    them a grid step (None: one lane, one tile: no sharing): the grid's
+    leading axes (lanes, then groups of ``step``), the block of a lane's
+    own operand's leading axes ``(outer, L, inner)``, and its block index
+    at (lane, group)."""
+    if step is None:
+        return (n_lanes,), (1,), lambda l, s: (l,)
+    inner = runs[1] // step[1]
+    return ((n_lanes, runs[0] // step[0] * inner), (step[0], 1, step[1]),
+            lambda l, s: (s // inner, l, s % inner))
+
+
+def _spec(step, shape, index):
+    """``BlockSpec(shape)`` whose index is ``index(lane, group, *the
+    kernel's own grid indices and prefetched rows)``: group 0 where no
+    grid axis of groups is there (``step`` None)."""
+    if step is None:
+        return pl.BlockSpec(shape, lambda l, *rest: index(l, 0, *rest))
+    return pl.BlockSpec(shape, index)
+
+
 def _hist_lanes_impl(tables, codes_t, stats_t, state, *pick, level,
-                     n_slots, n_bins, parts, tile, interpret):
+                     n_slots, n_bins, parts, tile, interpret, runs=(1, 1)):
     """``tables (L, 4, items)`` int32, ``codes_t (L, d_pad, n_pad)`` uint8,
     ``stats_t (L, parts * S8, n_pad)`` bfloat16, ``state (L, 8, n_pad)``
     int32 -> ``(L, 2^level, blocks x slots a block, S8, n_bins)`` float32,
     cumulative over the bins.  The slots are the ``n_slots = d`` features,
     or with one more operand, ``pick (L, blocks, 2^level x slots a block,
     d_pad)`` bfloat16 (a node's slot's feature, one-hot), each node's own
-    ``n_slots``."""
+    ``n_slots``.
+
+    With ``runs = (outer, inner)`` (not with ``pick``) every lane's items
+    and code tiles are shared by ``outer x inner`` lanes of statistics and
+    rows, ``stats_t (outer, L, inner, parts * S8, n_pad)`` and ``state
+    (outer, L, inner, 8, n_pad)`` -> ``(outer, L, inner, 2^level, ...)``;
+    a grid step takes as many of them as ``SHARED_VMEM`` holds."""
     (pick,) = pick or (None,)
     n_lanes, _, n_items = tables.shape
-    d_pad, r = codes_t.shape[1], stats_t.shape[1]
+    d_pad, r = codes_t.shape[1], stats_t.shape[-2]
     s8 = r // parts
     k_nodes, n_groups = _group_shape(level)
     block, n_blocks = _slot_blocks(n_slots, pick is not None)
     # a block's own features, or every feature whatever the block
     code_rows = block if pick is None else d_pad
+    step = None
+    if runs != (1, 1):
+        assert pick is None, "a row's slot codes are its own lane's"
+        step = _step_runs(runs, _hist_lane_bytes(k_nodes, block, s8, parts,
+                                                 n_bins, tile))
+    lanes, own, own_at = _lane_axes(n_lanes, runs, step)
 
     def at(lane, i):
         return lane * n_items + i
@@ -331,41 +436,45 @@ def _hist_lanes_impl(tables, codes_t, stats_t, state, *pick, level,
         return jnp.minimum(g[at(lane, i)], n_groups - 1)
 
     in_specs = [
-        pl.BlockSpec((1, code_rows, tile),
-                     lambda l, fb, i, t, g, lo, hi: (
-                         l, fb if pick is None else 0, t[at(l, i)])),
-        pl.BlockSpec((1, r, tile),
-                     lambda l, fb, i, t, g, lo, hi: (l, 0, t[at(l, i)])),
-        pl.BlockSpec((1, 8, tile),
-                     lambda l, fb, i, t, g, lo, hi: (l, 0, t[at(l, i)])),
+        _spec(step, (1, code_rows, tile),
+              lambda l, s, fb, i, t, g, lo, hi: (
+                  l, fb if pick is None else 0, t[at(l, i)])),
+        _spec(step, own + (r, tile),
+              lambda l, s, fb, i, t, g, lo, hi: own_at(l, s) + (
+                  0, t[at(l, i)])),
+        _spec(step, own + (8, tile),
+              lambda l, s, fb, i, t, g, lo, hi: own_at(l, s) + (
+                  0, t[at(l, i)])),
     ]
     operands = [codes_t, stats_t, state]
     if pick is not None:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, k_nodes * block, d_pad),
-            lambda l, fb, i, t, g, lo, hi: (l, fb, group(g, l, i), 0)))
+        in_specs.append(_spec(
+            step, (1, 1, k_nodes * block, d_pad),
+            lambda l, s, fb, i, t, g, lo, hi: (l, fb, group(g, l, i), 0)))
         operands.append(pick)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(n_lanes, n_blocks, n_items),
+        grid=lanes + (n_blocks, n_items),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, k_nodes, block, s8, n_bins),
-            lambda l, fb, i, t, g, lo, hi: (l, group(g, l, i), fb, 0, 0)),
+        out_specs=_spec(
+            step, own + (k_nodes, block, s8, n_bins),
+            lambda l, s, fb, i, t, g, lo, hi: own_at(l, s) + (
+                group(g, l, i), fb, 0, 0)),
         scratch_shapes=[pltpu.VMEM((block, tile), jnp.int32)],
     )
     kernel = functools.partial(
         _hist_kernel, n_items=n_items, n_slots=n_slots, s8=s8, parts=parts,
         n_bins=n_bins, k_nodes=k_nodes, n_groups=n_groups,
-        offset=2 ** level - 1)
+        offset=2 ** level - 1, step_runs=step)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(
-            (n_lanes, 2 ** level, n_blocks * block, s8, n_bins),
+            stats_t.shape[:-2] + (2 ** level, n_blocks * block, s8, n_bins),
             jnp.float32),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel",) * len(lanes)
+            + ("arbitrary", "arbitrary"),
             vmem_limit_bytes=48 * 2 ** 20),
         interpret=interpret,
         name="sst_tree_histogram",
@@ -373,64 +482,127 @@ def _hist_lanes_impl(tables, codes_t, stats_t, state, *pick, level,
 
 
 def _route_lanes_impl(tables, words, codes_t, state, *, level, tile,
-                      interpret):
+                      interpret, runs=(1, 1)):
     """``words (L, 2^level)`` int32 (a node's bin, whether it splits, its
-    feature) -> the rows' new ``state (L, 8, n_pad)``."""
+    feature) -> the rows' new ``state (L, 8, n_pad)``; with ``runs`` as
+    the histograms' (``words (outer, L, inner, 2^level)``)."""
     n_lanes, _, n_items = tables.shape
     d_pad = codes_t.shape[1]
     k_nodes, n_groups = _group_shape(level)
+    # a lane of a shared tile holds its rows' state in and out, each twice
+    step = None if runs == (1, 1) else _step_runs(runs, 2 * 2 * 8 * tile * 4)
+    lanes, own, own_at = _lane_axes(n_lanes, runs, step)
 
     def at(lane, i):
         return lane * n_items + i
 
+    def rows_at(l, s, i, t, g, lo, hi, w):
+        return own_at(l, s) + (0, t[at(l, i)])
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(n_lanes, n_items),
+        grid=lanes + (n_items,),
         in_specs=[
-            pl.BlockSpec((1, d_pad, tile),
-                         lambda l, i, t, g, lo, hi, w: (l, 0, t[at(l, i)])),
-            pl.BlockSpec((1, 8, tile),
-                         lambda l, i, t, g, lo, hi, w: (l, 0, t[at(l, i)])),
+            _spec(step, (1, d_pad, tile),
+                  lambda l, s, i, t, g, lo, hi, w: (l, 0, t[at(l, i)])),
+            _spec(step, own + (8, tile), rows_at),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 8, tile), lambda l, i, t, g, lo, hi, w: (l, 0, t[at(l, i)])),
+        out_specs=_spec(step, own + (8, tile), rows_at),
         scratch_shapes=[pltpu.VMEM((d_pad, tile), jnp.int32)],
     )
     kernel = functools.partial(
         _route_kernel, n_items=n_items, k_nodes=k_nodes, n_groups=n_groups,
-        offset=2 ** level - 1)
+        offset=2 ** level - 1, runs=runs, n_lanes=n_lanes, step_runs=step)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(state.shape, jnp.int32),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel",) * len(lanes) + ("arbitrary",)),
         input_output_aliases={6: 0},    # rows no item names keep theirs
         interpret=interpret,
         name="sst_tree_route",
     )(*_flat(tables), words.reshape(-1), codes_t, state)
 
 
+#: each kernel's operands that are a lane's own where lanes share code
+#: tiles (statistics, words, the rows' state); the others (items, codes)
+#: are the tiles'
+_OWN = {_hist_lanes_impl: (2, 3), _route_lanes_impl: (1, 3)}
+
+
 @functools.lru_cache(maxsize=None)
-def _lanes(impl, **static):
+def _lanes(impl, runs=(1, 1), **static):
     """A kernel call with the lanes in front of every operand, and
     ``jax.vmap`` of it as one call over the merged lanes.  The call is a
     ``jit`` of its own so that its trace is kept by shape: batching a
     ``while_loop``'s body walks it to a fixed point, which traced every
     kernel of every level four times where two will do (the lane-less
-    shapes once, the merged lanes' once)."""
+    shapes once, the merged lanes' once).
+
+    Where the ``vmap`` batches neither the items nor the codes (a
+    booster's runs of one fold; a stage's trees a class), its lanes share
+    those: the axis goes into ``runs`` (the kernels' ``(outer, inner)``
+    axes around the lanes of a lane's own operands, ``_OWN``), not into
+    the lanes, and a grid step reads one code tile for all of them.  It
+    goes outside the lanes where there are lanes, inside where there is
+    one, so that classes, folds and runs nested in that order transpose
+    nothing.  Not where a level's histograms are at each node's own
+    features: a row's slot codes are then its own lane's."""
     call = jax.custom_batching.custom_vmap(
-        jax.jit(functools.partial(impl, **static)))
+        jax.jit(functools.partial(impl, runs=runs, **static)))
+    own = _OWN[impl]
 
     @call.def_vmap
     def _(axis_size, in_batched, *operands):
-        merged = []
-        for x, batched in zip(operands, in_batched):
+        n_lanes = operands[0].shape[-3]         # tables (.., L, 4, items)
+        outer, inner = runs
+
+        def by_run(x, batched):
+            """A lane's own operand as ``(axis, outer, L, inner, ...)``."""
             if not batched:
                 x = jnp.broadcast_to(x, (axis_size,) + x.shape)
-            merged.append(x.reshape((axis_size * x.shape[1],) + x.shape[2:]))
-        out = call(*merged)
-        return out.reshape((axis_size, -1) + out.shape[1:]), True
+            tail = x.shape[2 if runs == (1, 1) else 4:]
+            return x.reshape((axis_size, outer, n_lanes, inner) + tail)
+
+        if len(operands) == 4 and not any(
+                in_batched[j] for j in range(4) if j not in own):
+            new = ((axis_size * outer, inner) if n_lanes > 1
+                   else (1, axis_size * outer * inner))
+            merged = []
+            for j, (x, batched) in enumerate(zip(operands, in_batched)):
+                if j in own:
+                    x = by_run(x, batched)
+                    x = x.reshape((new[0], n_lanes, new[1]) + x.shape[4:])
+                merged.append(x)
+            out = _lanes(impl, runs=new, **static)(*merged)
+            out = out.reshape((axis_size, outer, n_lanes, inner)
+                              + out.shape[3:])
+        else:
+            merged = []
+            for j, (x, batched) in enumerate(zip(operands, in_batched)):
+                if j in own and runs != (1, 1):
+                    x = by_run(x, batched)
+                    if outer > 1:
+                        x = jnp.moveaxis(x, 0, 1)
+                    merged.append(x.reshape(
+                        (outer, axis_size * n_lanes, inner) + x.shape[4:]))
+                    continue
+                if not batched:
+                    x = jnp.broadcast_to(x, (axis_size,) + x.shape)
+                merged.append(
+                    x.reshape((axis_size * x.shape[1],) + x.shape[2:]))
+            out = call(*merged)
+            if runs == (1, 1):
+                return out.reshape((axis_size, -1) + out.shape[1:]), True
+            tail = out.shape[3:]
+            if outer > 1:
+                out = jnp.moveaxis(out.reshape(
+                    (outer, axis_size, n_lanes, inner) + tail), 1, 0)
+            out = out.reshape((axis_size, outer, n_lanes, inner) + tail)
+        if runs == (1, 1):
+            out = out.reshape((axis_size, n_lanes) + out.shape[4:])
+        return out, True
 
     return call
 
@@ -759,6 +931,20 @@ def row_sorts(depth, trees, lanes, ordered):
     return trees * per_tree + (lanes if ordered and kernels else 0)
 
 
+def lanes_per_mask(lanes, trees=1, n_stats=2, parts=3):
+    """Lanes one ``code <= bin`` mask of the histogram kernel serves at a
+    tree's first level, where ``lanes`` lanes of ``trees`` trees each (a
+    fold's runs, a stage's classes) share their code tiles: as many of
+    them as one grid step holds (deeper levels hold fewer); 1 where
+    every lane reads its own tiles; 0 on the plain form, which builds no
+    mask."""
+    if not on_tpu():
+        return 0
+    step = _step_runs((int(lanes), int(trees)), _hist_lane_bytes(
+        1, FEATURE_BLOCK, _round_up(n_stats, 8), parts, 256, ROW_TILE))
+    return step[0] * step[1]
+
+
 def level_histogram_bytes(depth, n_features, n_stats, n_bins=256,
                           slots=None):
     """Bytes of the deepest level's histograms of one lane, every feature
@@ -774,12 +960,14 @@ def level_histogram_bytes(depth, n_features, n_stats, n_bins=256,
             * np.dtype(np.float32).itemsize)
 
 
-def row_bytes(n_features, n_stats, integer_stats):
-    """Bytes of one row as the kernels' sorted copy holds it: its codes,
-    the bfloat16 parts of its statistics, its node and whether it is in a
-    leaf (a tile of 8 words)."""
+def row_bytes(n_features, n_stats, integer_stats, own_codes=True):
+    """Bytes of one row as the kernels' sorted copy holds it: its codes
+    (not where the lane reads a :class:`RowOrder`'s, whose tiles the
+    lanes of its fold share: ``own_codes`` False), the bfloat16 parts of
+    its statistics, its node and whether it is in a leaf (a tile of 8
+    words)."""
     parts = 1 if integer_stats else 3
-    return (_padded_features(n_features)
+    return (_padded_features(n_features) * bool(own_codes)
             + parts * _round_up(n_stats, 8) * 2 + 8 * 4)
 
 

@@ -10,7 +10,9 @@ program is then held to the reference stage for stage: the same (feature,
 bin) at every node, F to float32 rounding, the same split scores.
 """
 
+import contextlib
 import os
+import re
 import sys
 
 import jax
@@ -375,12 +377,14 @@ def test_launch_workspace_prices_a_lane_by_its_rows(family, k, trees):
     row = 5 * tree_hist.row_bytes(54, 2, integer_stats=False) + 16
     assert row == 736
     assert ws["per_candidate_bytes"] == 5 * trees * 145_253 * (row + 24)
-    # the fold's training rows at every stage: one copy of a row a tree,
-    # and a fold's order (its codes twice, its place both ways)
+    # the fold's training rows at every stage: one copy of a row a tree
+    # but its codes, which are its fold's order's, and a fold's order (its
+    # codes twice, its place both ways)
     fixed = family.launch_workspace(145_253, meta, 5, static={})
     assert tree_hist.order_row_bytes(54) == 136
+    assert tree_hist.row_bytes(54, 2, False, own_codes=False) == 80
     assert fixed["fixed_bytes"] == 5 * 145_253 * 136
-    assert fixed["per_candidate_bytes"] == 5 * trees * 145_253 * (160 + 24)
+    assert fixed["per_candidate_bytes"] == 5 * trees * 145_253 * (96 + 24)
     # ... unless the search names subsample
     assert family.launch_workspace(
         145_253, {**meta, "subsample_searched": True}, 5, static={}) == ws
@@ -410,8 +414,10 @@ def test_width_cap_binds_under_a_small_budget():
     assert group["workspace_bytes"] > 0
     assert group["fixed_bytes"] == FOLDS * 600 * tree_hist.order_row_bytes(10)
     lane = group["per_candidate_bytes"]
-    # a tree of it holds a row's kernels' bytes (in the fold's one order)
-    assert lane > FOLDS * 600 * tree_hist.row_bytes(10, 2, False)
+    # a tree of it holds a row's kernels' bytes (in the fold's one order,
+    # whose codes it reads)
+    assert lane > FOLDS * 600 * tree_hist.row_bytes(10, 2, False,
+                                                    own_codes=False)
     budget = int(group["resident_bytes"] + group["fixed_bytes"]
                  + 2.5 * lane)
     capped = search(est, X, y, grid, one_device=True,
@@ -582,11 +588,13 @@ def test_grids_that_are_not_whole_runs_plan_and_score_as_on_the_parent(
     assert np.array_equal(split_scores(ours), split_scores(theirs))
 
 
-def launched(family, X, y, cands, t_max):
+def launched(family, X, y, cands, t_max, shapes=None):
     """`fit_task_batched` over `cands` (dicts of learning_rate,
     n_estimators, subsample) in `launch_layout`'s order with its fact:
     the tasks' models, the candidates' parameters in that order, the run
-    length, and what a candidate's one fit takes (data, meta, masks)."""
+    length, and what a candidate's one fit takes (data, meta, masks).
+    With `shapes`, the output shapes of the launch's histogram kernel
+    calls are appended to it."""
     data, meta = family.prepare_data(X, y)
     meta["max_estimators"] = t_max
     dyn = {k: np.asarray([c[k] for c in cands],
@@ -594,10 +602,18 @@ def launched(family, X, y, cands, t_max):
     order, facts, run = family.launch_layout(dyn, STATIC, meta, FOLDS)
     dyn = {k: v[order] for k, v in dyn.items()}
     masks = np.stack([fold_mask(len(y), seed) for seed in range(FOLDS)])
-    tasks = jax.jit(lambda d, w: family.fit_task_batched(
-        d, {**STATIC, **facts, "__n_folds__": FOLDS}, data, w, meta))(
-        {k: np.repeat(v, FOLDS) for k, v in dyn.items()},
-        np.tile(masks, (len(cands), 1)))
+
+    def launch(d, w):
+        return family.fit_task_batched(
+            d, {**STATIC, **facts, "__n_folds__": FOLDS}, data, w, meta)
+
+    args = ({k: np.repeat(v, FOLDS) for k, v in dyn.items()},
+            np.tile(masks, (len(cands), 1)))
+    if shapes is not None:
+        text = str(jax.make_jaxpr(launch)(*args))
+        shapes += [tuple(int(k) for k in s.split(",")) for s in
+                   re.findall(r":f32\[([\d,]+)\] = pallas_call\[", text)]
+    tasks = jax.jit(launch)(*args)
     return tasks, dyn, run, (data, meta, masks)
 
 
@@ -721,6 +737,80 @@ def test_row_sorts_count_a_lanes_order_once(depth, searched, monkeypatch,
     later = trees if depth > 5 else 0
     assert rep["row_sorts_per_launch"] == [
         trees + later if searched else lanes + later]
+
+
+@contextlib.contextmanager
+def the_parents_kernel_rule():
+    """The kernels' ``vmap`` rule as the commit before the shared masks
+    had it (93609e2): every batched axis merged into the lanes, a fold's
+    codes broadcast to each of its runs (no operand is a lane's own)."""
+    saved = dict(tree_hist._OWN)
+    tree_hist._OWN.update(dict.fromkeys(saved, ()))
+    tree_hist._lanes.cache_clear()
+    try:
+        yield
+    finally:
+        tree_hist._OWN.update(saved)
+        tree_hist._lanes.cache_clear()
+
+
+@pytest.mark.parametrize("k", [2, 3])
+def test_a_folds_runs_share_its_masks_to_the_bit(k, monkeypatch):
+    """2 learning rates x 2 counts x 3 folds on the kernels' form (what a
+    TPU runs), two classes (a tree a stage) and three (a tree a class):
+    the histogram kernel takes a fold's code tiles once for its 2 runs
+    (and 3 classes), ``(runs, folds, classes, ...)``, where the parent's
+    rule broadcast the codes to ``runs x folds`` lanes of their own; every
+    candidate's raw scores are the parent's to the bit."""
+    monkeypatch.setattr(tree_hist, "levels_of", kernels_form)
+    monkeypatch.setattr(tree_hist, "row_order", kernels_order())
+    X, y = make_data(300, 8, k, 23, separation=1.0)
+    cands = [{"learning_rate": lr, "n_estimators": m}
+             for lr in (0.3, 0.1) for m in (3, 2)]
+    classes = 1 if k == 2 else k
+    shapes = []
+    tasks, _, run, _ = launched(GBC, X, y, cands, 3, shapes)
+    assert run == 2
+    assert shapes == [(2, FOLDS, classes, 2 ** level, 32, 8, 256)
+                      for level in range(3)]
+    with the_parents_kernel_rule():
+        parents = []
+        planted, _, _, _ = launched(GBC, X, y, cands, 3, parents)
+    assert parents == [(2 * FOLDS * classes, 2 ** level, 32, 8, 256)
+                       for level in range(3)]
+    assert np.array_equal(np.asarray(tasks["logits"]),
+                          np.asarray(planted["logits"]))
+    assert np.array_equal(np.asarray(tasks["pred"]),
+                          np.asarray(planted["pred"]))
+
+
+@pytest.mark.parametrize("estimator,k,lanes", [
+    ("boost", 2, 2),        # a fold's 2 runs read its order
+    ("boost", 3, 6),        # ... and a stage's 3 trees a class
+    ("subsampled", 2, 1),   # subsample 0.5: every tree its own rows
+    ("forest", 3, 1),       # every tree its own bootstrap rows
+])
+def test_hist_lanes_per_mask_counts_the_lanes_of_a_tile(
+        estimator, k, lanes, monkeypatch, fresh_programs):
+    """On the kernels' form: the lanes one mask serves.  A laid-out grid
+    of 2 learning rates x 2 counts grows 2 runs a fold in its one order;
+    a booster at subsample 0.5 and a forest grow each tree in an order of
+    its own.  Built anew (`fresh_programs`)."""
+    from sklearn.ensemble import RandomForestClassifier
+    monkeypatch.setattr(tree_hist, "on_tpu", lambda: True)
+    monkeypatch.setattr(tree_hist, "levels_of", kernels_form)
+    monkeypatch.setattr(tree_hist, "row_order", kernels_order())
+    X, y = make_data(300, 8, k, 9, separation=1.0)
+    if estimator == "forest":
+        est = RandomForestClassifier(random_state=0, max_depth=3)
+        grid = {"n_estimators": [2, 3]}
+    else:
+        est = GradientBoostingClassifier(
+            random_state=0, subsample=0.5 if estimator == "subsampled"
+            else 1.0)
+        grid = {"learning_rate": [0.1, 0.3], "n_estimators": [2, 3]}
+    gs = search(est, X, y, grid, one_device=True, sort_candidates=False)
+    assert gs.search_report["hist_lanes_per_mask"] == [lanes]
 
 
 @pytest.mark.parametrize("dyn,runs", [
@@ -892,8 +982,9 @@ def test_launch_counters_reach_the_report(kind, trees):
     assert rep["tree_slots_per_launch"] == [3 * lanes * trees]
     assert rep["trees_grown_per_launch"] == rep["tree_slots_per_launch"]
     assert rep["tree_levels_per_launch"] == [3 * lanes * trees * 2]
-    # the plain form sorts no rows
+    # the plain form sorts no rows and builds no mask
     assert rep["row_sorts_per_launch"] == [0]
+    assert rep["hist_lanes_per_mask"] == [0]
     assert rep["trees_per_candidate"] == [2, 3, 2, 3]
     assert rep["hist_features_per_node"] == [8]
     assert rep["hist_bytes_per_lane"] == [2 * 8 * 2 * 256 * 4]

@@ -34,7 +34,8 @@ FOLDS = 3
 CONFIG = {"estimator": {"params": {"random_state": 0}}}
 COUNTERS = ("tree_slots_per_launch", "tree_levels_per_launch",
             "hist_bytes_per_lane", "trees_per_candidate",
-            "trees_grown_per_launch", "hist_features_per_node")
+            "trees_grown_per_launch", "hist_features_per_node",
+            "hist_lanes_per_mask")
 
 
 def make_data(n, d, k, seed):
@@ -150,6 +151,8 @@ def test_counters_of_a_forest_search():
     assert rep["trees_per_candidate"] == [3, 6, 4, 3, 6, 4]
     # a node's histograms hold its own sqrt(20) = 4 features of the 20
     assert rep["hist_features_per_node"] == [4, 4]
+    # the plain form builds no mask (on a TPU a tree's own tiles: 1)
+    assert rep["hist_lanes_per_mask"] == [0, 0]
     assert [(g["hist_features"], g["n_features"])
             for g in rep["per_group"].values()] == [(4, 20)] * 2
     assert rep["hist_bytes_per_lane"] == [

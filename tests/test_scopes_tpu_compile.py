@@ -885,25 +885,29 @@ def test_boost_compiled_op_names_carry_scope(boost_launch, scope):
 def test_boost_launch_is_every_features_histograms_in_three_parts(
         boost_launch):
     """The path no forest takes since PR 38: a slot is a feature (64 with
-    the kernel's padding) at every node of the three levels, 25 lanes a
-    grid axis; a row's statistics are laid out as three bfloat16 parts of
-    8 statistic rows; the rows of a fold are sorted, and their codes' 16
-    words gathered, once a launch (a fold's 5 runs share its order), and
-    no stage sorts or gathers codes or statistics; no level scatters."""
+    the kernel's padding) at every node of the three levels; a row's
+    statistics are laid out as three bfloat16 parts of 8 statistic rows;
+    the rows of a fold are sorted, and their codes' 16 words gathered,
+    once a launch (a fold's 5 runs share its order), and no stage sorts
+    or gathers codes or statistics; no level scatters.  The 25 lanes are
+    5 folds of code tiles a grid axis, each tile shared by its 5 runs:
+    the kernels' outputs are ``(runs, folds, 1, ...)`` and no fold's
+    codes are broadcast to its runs."""
     text = boost_launch[0].as_text()
-    lanes = BOOST_LANES
+    runs = BOOST_CANDIDATES // BOOST_RUN
     calls = re.findall(r'custom_call_target="tpu_custom_call"', text)
     assert len(calls) == 2 * BOOST_DEPTH
     for level in range(BOOST_DEPTH):
-        assert re.search(r'f32\[%d,%d,64,8,256\]\S* custom-call\(' % (
-            lanes, 2 ** level), text)
+        assert re.search(r'f32\[%d,%d,1,%d,64,8,256\]\S* custom-call\(' % (
+            runs, FOLDS, 2 ** level), text)
+    assert not re.search(r'u8\[%d,%d,64,\d+\]' % (runs, FOLDS), text)
     assert " scatter(" not in text
     gathered = [(rows, words) for rows, words in re.findall(
         r'= s32\[(\d+),(\d+)\]\S* gather\(', text) if int(rows) >= TREE_N]
     assert gathered == [(str(FOLDS * TREE_N), "16")]
     sorted_rows = re.findall(r'= \((s32\[[\d,]+\])[^=]*\) sort\(', text)
     assert sorted_rows == ["s32[%d,%d]" % (FOLDS, TREE_N)]
-    assert re.search(r'bf16\[%d,24,\d+\]' % lanes, text)
+    assert re.search(r'bf16\[%d,%d,1,24,\d+\]' % (runs, FOLDS), text)
 
 
 def test_ledger_prices_the_boost_launch(boost_launch):
@@ -930,10 +934,11 @@ def test_ledger_prices_the_boost_launch(boost_launch):
          "n_estimators": np.zeros(BOOST_CANDIDATES, np.int32)},
         BOOST_CANDIDATES, FOLDS, task_batched=True, n_samples=TREE_N,
         workspace=workspace)
-    # a lane is one copy of its rows' kernels' bytes, 26.7 MB, shared by
-    # a run's 3 candidates; a fold's order, 19.8 MB, is the launch's
+    # a lane is one copy of its rows' kernels' bytes but their codes,
+    # 17.4 MB, shared by a run's 3 candidates; a fold's order, 19.8 MB,
+    # and its code tiles are the launch's
     assert modeled["fixed_bytes"] == FOLDS * TREE_N * 136
-    assert modeled["per_candidate_bytes"] > FOLDS * 26e6 / BOOST_RUN
+    assert modeled["per_candidate_bytes"] > FOLDS * 17e6 / BOOST_RUN
     assert abs(modeled["chunk_bytes"] - allotted) < 0.15 * allotted
     # 2.72 GB where every stage sorted and gathered each lane's rows
     # (PERF.md section 4)

@@ -11,6 +11,7 @@ histograms is what the kernel allocates."""
 
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -449,6 +450,162 @@ def test_a_launch_order_grows_the_per_tree_sorts_tree(depth, monkeypatch):
     assert order_sorts == 1 + 2 * deep
 
 
+# --- lanes that share a code tile ---------------------------------------------
+
+def tile_lanes(n, d, n_out, level, n_lanes, integer, seed):
+    """``n_lanes`` lanes of one fold's rows in its launch order: the same
+    items and code tiles, each lane its own statistics and its own rows'
+    nodes of ``level``.  Returns the tiles' ``(tables, codes_t)`` and the
+    lanes' stacked ``(stats_t, state)``."""
+    rng = np.random.default_rng(seed)
+    codes = jnp.asarray(rng.integers(0, N_BINS, (n, d), dtype=np.uint8))
+    w = jnp.asarray((rng.random(n) >= 0.3).astype(np.float32))
+    order = tree_hist.launch_order(codes, w, tile=128)
+    stats_t, state = [], []
+    for j in range(n_lanes):
+        _, stats, local, live = rows(n, d, n_out, 2 ** level, seed + j,
+                                     integer, zero_share=0.0)
+        lane = tree_hist.GroupedLevels(
+            codes, order.rows(jnp.asarray(stats)), N_BINS, integer,
+            tile=128, interpret=True, order=order)
+        lane.heap = (2 ** level - 1) + order.rows(jnp.asarray(local))
+        lane.live = jnp.where(order.rows(jnp.asarray(live)), lane.live, 0)
+        tables = lane._items(level)
+        stats_t.append(lane.stats_t)
+        state.append(lane._state())
+    return tables, order.codes_t, jnp.stack(stats_t), jnp.stack(state)
+
+
+@pytest.mark.parametrize("level,runs,integer,step", [
+    (0, (3, 1), True, (3, 1)),
+    (1, (3, 1), False, (3, 1)),      # a booster's three-part statistics
+    (2, (5, 1), False, (5, 1)),      # the cell's: 5 runs of a fold
+    (2, (2, 3), True, (2, 3)),       # runs around the lanes, classes in
+    (1, (1, 4), False, (1, 4)),
+    # 16 nodes a group: 3 lanes fit a step's VMEM, so 4 are 2 steps of 2
+    (4, (4, 1), False, (2, 1)),
+    (4, (2, 2), True, (1, 2)),
+])
+def test_one_mask_serves_every_lane_of_a_tile(level, runs, integer, step):
+    """The histogram and routing kernels given ``outer x inner`` lanes
+    that share their code tiles against one call a lane, bit for bit:
+    each feature's mask is built once a step for the lanes the step takes
+    (``_step_runs``: as many as ``SHARED_VMEM`` holds), every lane's
+    operand streams through it, and its rows go to their children by its
+    own words."""
+    n, d, n_out = 700, 9, 3 if integer else 1
+    parts = 1 if integer else 3
+    n_lanes = runs[0] * runs[1]
+    tables, codes_t, stats_t, state = tile_lanes(n, d, n_out, level,
+                                                 n_lanes, integer, level)
+    k_nodes = min(2 ** level, tree_hist.GROUP_NODES)
+    assert tree_hist._step_runs(runs, tree_hist._hist_lane_bytes(
+        k_nodes, tree_hist.FEATURE_BLOCK, 8, parts, N_BINS, 128)) == step
+    hist = dict(level=level, n_slots=d, n_bins=N_BINS, parts=parts,
+                tile=128, interpret=True)
+    alone = tree_hist._lanes(tree_hist._hist_lanes_impl, **hist)
+    want = np.stack([np.asarray(alone(tables[None], codes_t[None],
+                                      stats_t[j][None], state[j][None])[0])
+                     for j in range(n_lanes)])
+
+    def by_run(x):
+        return x.reshape((runs[0], 1, runs[1]) + x.shape[1:])
+
+    got = np.asarray(jax.jit(functools.partial(
+        tree_hist._hist_lanes_impl, runs=runs, **hist))(
+        tables[None], codes_t[None], by_run(stats_t), by_run(state)))
+    assert got.shape == (runs[0], 1, runs[1]) + want.shape[1:]
+    got = got.reshape(want.shape)
+    assert np.array_equal(got, want)
+    assert np.abs(want[:, :, :d, :1 + n_out]).sum() > 0
+
+    rng = np.random.default_rng(n_lanes)
+    nodes = 2 ** level
+    words = jnp.asarray(
+        rng.integers(0, N_BINS, (n_lanes, nodes))
+        | (rng.random((n_lanes, nodes)) < 0.7).astype(np.int64) << 8
+        | rng.integers(0, d, (n_lanes, nodes)) << 9, jnp.int32)
+    route = dict(level=level, tile=128, interpret=True)
+    alone = tree_hist._lanes(tree_hist._route_lanes_impl, **route)
+    want = np.stack([np.asarray(alone(tables[None], words[j][None],
+                                      codes_t[None], state[j][None])[0])
+                     for j in range(n_lanes)])
+    got = np.asarray(jax.jit(functools.partial(
+        tree_hist._route_lanes_impl, runs=runs, **route))(
+        tables[None], by_run(words), codes_t[None], by_run(state)))
+    assert np.array_equal(got.reshape(want.shape), want)
+    assert not np.array_equal(want[:, 0], np.asarray(state)[:, 0])
+
+
+def histogram_shapes(fn, *args):
+    """The output shapes of the histogram kernel calls ``fn`` makes (the
+    routing kernel's are int32)."""
+    text = str(jax.make_jaxpr(fn)(*args))
+    return [tuple(int(k) for k in s.split(","))
+            for s in re.findall(r":f32\[([\d,]+)\] = pallas_call\[", text)]
+
+
+@pytest.mark.parametrize("case", ["runs_folds", "codes_batched",
+                                  "runs_folds_classes"])
+def test_vmapped_runs_of_one_order_share_the_masks(case, monkeypatch):
+    """A booster's launch, ``vmap`` over runs of ``vmap`` over folds, each
+    lane a depth-3 tree grown in its fold's launch order.  The codes are
+    the same for every run: the kernels' rule folds the run axis into the
+    lanes that share a fold's tiles (the histograms' calls are ``(runs,
+    folds, 1, ...)``; under a third ``vmap`` over a stage's classes
+    ``(runs, folds, classes, ...)``); handed to each run as its own copy
+    they are batched, and the runs are lanes of their own (``(runs x
+    folds, ...)``).  Every lane's tree is the one it grows alone, bit for
+    bit."""
+    monkeypatch.setattr(tree_hist, "levels_of", functools.partial(
+        tree_hist.GroupedLevels, tile=128, interpret=True))
+    rng = np.random.default_rng(3)
+    n, d, R, F, K, depth = 600, 8, 2, 2, 3, 3
+    classes = case == "runs_folds_classes"
+    codes = jnp.asarray(rng.integers(0, N_BINS, (n, d), dtype=np.uint8))
+    W = jnp.asarray((rng.random((F, n)) >= 0.3).astype(np.float32))
+    p = rng.random((R, F, n, K)).astype(np.float32)
+    G = jnp.asarray(p - (rng.random((R, F, n, K)) < 0.5))
+    H = jnp.asarray(p * (1 - p))
+
+    def tree(codes, g, h, w):
+        order = tree_hist.launch_order(codes, w, tile=128)
+        t = grow_tree(codes, order.rows(g[:, None]), order.rows(h),
+                      order.rows(w), depth, N_BINS, min_child_weight=1.0,
+                      reg_lambda=1e-6, order=order)
+        return t._replace(leaf=order.back(t.leaf))
+
+    def lane(codes, g, h, w):           # g, h: (n, K)
+        if classes:
+            return jax.vmap(lambda g_c, h_c: tree(codes, g_c, h_c, w),
+                            in_axes=(1, 1))(g, h)
+        return tree(codes, g[:, 0], h[:, 0], w)
+
+    def launch(C, G, H):
+        in_codes = 0 if case == "codes_batched" else None
+        return jax.vmap(lambda c, g, h: jax.vmap(
+            lambda g_f, h_f, w_f: lane(c, g_f, h_f, w_f))(g, h, W),
+            in_axes=(in_codes, 0, 0))(C, G, H)
+
+    C = jnp.broadcast_to(codes, (R, n, d)) if case == "codes_batched" \
+        else codes
+    shapes = histogram_shapes(launch, C, G, H)
+    assert len(shapes) == depth
+    lead = {"runs_folds": (R, F, 1), "codes_batched": (R * F,),
+            "runs_folds_classes": (R, F, K)}[case]
+    for level, shape in enumerate(shapes):
+        assert shape == lead + (2 ** level, 32, 8, N_BINS)
+    got = jax.jit(launch)(C, G, H)
+    one = jax.jit(lane)
+    for r in range(R):
+        for f in range(F):
+            want = one(codes, G[r, f], H[r, f], W[f])
+            for name in want._fields:
+                assert np.array_equal(np.asarray(getattr(got, name)[r, f]),
+                                      np.asarray(getattr(want, name))), name
+    assert (np.asarray(got.feat) >= 0).sum() > R * F * depth   # grown
+
+
 @pytest.mark.parametrize("max_features,with_key", [
     (None, True), (12, True), (40, True), (3, False), (None, False)])
 def test_without_a_subset_the_histograms_are_every_features(
@@ -548,8 +705,10 @@ def test_the_ledger_prices_what_the_kernel_allocates(
     assert out.shape == (1, 2 ** level, held, s8, N_BINS)
     lane_bytes = int(np.prod(out.shape)) * out.dtype.itemsize
     facts = family.launch_facts(static, meta, 3, 5)
+    # each tree its own bootstrap rows: a mask serves one lane
     assert facts == {"hist_bytes": lane_bytes,
-                     "hist_features": slots if subset else d}
+                     "hist_features": slots if subset else d,
+                     "mask_lanes": 1}
     # a forest a fold whatever the launch's width; a candidate adds votes
     ws = family.launch_workspace(n, meta, 5, static=static)
     assert ws["fixed_bytes"] >= 5 * 1.39 * lane_bytes
