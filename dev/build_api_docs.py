@@ -30,6 +30,7 @@ MODULES = [
     "spark_sklearn_tpu",
     "spark_sklearn_tpu.search.grid",
     "spark_sklearn_tpu.search.launch",
+    "spark_sklearn_tpu.search.schedule",
     "spark_sklearn_tpu.search.scorers",
     "spark_sklearn_tpu.convert.converter",
     "spark_sklearn_tpu.keyed.keyed",

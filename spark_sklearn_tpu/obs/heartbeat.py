@@ -10,7 +10,7 @@ intra-launch visibility:
 
   - :class:`HeartbeatHub` — a process-global, bounded aggregator of
     *beats*.  The scanned program's step body (``search/grid.py``
-    ``build_scan``) threads a ``jax.debug.callback`` beacon that calls
+    ``_build_scan``) threads a ``jax.debug.callback`` beacon that calls
     :func:`device_beat` with ``(segment token, step index)`` while the
     device is still inside the launch; the per-chunk path emits a
     cheap host-side :func:`note_chunk` at dispatch.  Each beat updates

@@ -399,7 +399,7 @@ SEARCH_REPORT_SCHEMA = (
         "scan loop ran (TpuConfig.chunk_loop='scan' / SST_CHUNK_LOOP), "
         "segments executed and chunks melted into them, launches "
         "saved, fallback reasons, and halving's device-vs-host rung "
-        "elimination counts (search/grid.py scan path)."),
+        "elimination counts (search/schedule.py scan path)."),
     MetricDef(
         "prefix", "struct",
         "The shared-prefix scheduler's per-search view (see the "
@@ -407,8 +407,8 @@ SEARCH_REPORT_SCHEMA = (
         "staged (TpuConfig.prefix_reuse / SST_PREFIX_REUSE), distinct "
         "prefix digests vs candidates, device launches vs plane/"
         "journal re-use, recomputations saved and the recorded "
-        "fallback reasons (search/prefix.py + search/grid.py stage-1 "
-        "scheduler)."),
+        "fallback reasons (search/prefix.py + search/schedule.py "
+        "stage-1 scheduler)."),
     MetricDef(
         "memory", "struct",
         "The device-memory ledger's per-search view (see the "
@@ -1649,7 +1649,7 @@ def schema_markdown() -> str:
     out.append(
         "\nThe device-resident chunk loop's per-search view "
         "(`TpuConfig.chunk_loop=\"scan\"` / `SST_CHUNK_LOOP`; "
-        "`search/grid.py`).  Always present on compiled-tier "
+        "`search/schedule.py`).  Always present on compiled-tier "
         "searches — per-chunk runs report the zeroed "
         "`enabled=False` shape.\n")
     out.append("\n| key | kind | description |\n|---|---|---|\n")
@@ -1659,7 +1659,7 @@ def schema_markdown() -> str:
     out.append(
         "\nThe shared-prefix scheduler's per-search view "
         "(`TpuConfig.prefix_reuse` / `SST_PREFIX_REUSE`, default on; "
-        "`search/prefix.py` + the `search/grid.py` stage-1 "
+        "`search/prefix.py` + the `search/schedule.py` stage-1 "
         "scheduler).  Always present on compiled-tier searches — "
         "atomic runs report the zeroed `enabled=False` shape.\n")
     out.append("\n| key | kind | description |\n|---|---|---|\n")

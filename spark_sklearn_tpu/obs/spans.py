@@ -79,7 +79,7 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
     SpanDef("host.fit_and_score", "span", "search.grid",
             "Host-tier per-candidate sklearn _fit_and_score fan-out.",
             layer="search API", mirror=True),
-    SpanDef("geometry.replan", "span", "search.grid",
+    SpanDef("geometry.replan", "span", "search.schedule",
             "Mid-search geometry re-plan of a halving rung's "
             "surviving candidates (lane reclamation; carries iter and "
             "whether replanning was on).",
@@ -104,9 +104,9 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
             "(build_compile_groups is its child), the mesh and the "
             "memory ledger's baseline in the compiled tier, then "
             "convergence sorting, launch geometry and the ledger's "
-            "width caps and footprints in _run_groups.",
+            "width caps and footprints in search/schedule.py.",
             layer="planning", mirror=True),
-    SpanDef("fit.report", "span", "search.grid",
+    SpanDef("fit.report", "span", "search.schedule",
             "After the last launch: joining the compile thread and "
             "rendering the pipeline, scheduler, chunkloop and prefix "
             "blocks of search_report, feeding the geometry cost "
@@ -139,20 +139,20 @@ SPAN_VOCABULARY: Tuple[SpanDef, ...] = (
             "surviving candidates at this rung's resource (carries "
             "iter, n_candidates, n_resources).",
             layer="search API"),
-    SpanDef("chunkloop.segment", "span", "search.grid",
+    SpanDef("chunkloop.segment", "span", "search.schedule",
             "Host-side staging of one scan segment (chunk_loop="
             "\"scan\"): the member chunks' operands stacked along the "
             "leading step axis and uploaded as one slab (carries "
             "group, n_chunks).",
             layer="search API", mirror=True),
-    SpanDef("chunkloop.scan", "span", "search.grid",
+    SpanDef("chunkloop.scan", "span", "search.schedule",
             "One lax.scan launch executing a whole scan segment — "
             "n_chunks member chunks — as a single device program "
             "(carries group, n_chunks, and topk: the on-device rung "
             "elimination's keep count, 0 when the carry is score-"
             "only).",
             layer="search API"),
-    SpanDef("prefix.stage", "span", "search.grid",
+    SpanDef("prefix.stage", "span", "search.schedule",
             "The shared-prefix stage-1 loop: every DISTINCT Pipeline "
             "prefix digest computed/restored once, vectorized over "
             "folds, before suffix chunks launch (carries "

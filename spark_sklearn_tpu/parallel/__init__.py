@@ -7,4 +7,4 @@ from spark_sklearn_tpu.parallel.taskgrid import (
     build_compile_groups, build_fold_masks, geometry_cost_model,
     plan_geometry)
 from spark_sklearn_tpu.parallel.dataplane import (
-    DataPlane, StagingRing, get_dataplane)
+    DataPlane, get_dataplane)

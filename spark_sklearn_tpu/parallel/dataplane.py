@@ -42,9 +42,8 @@ place after a search produces a new fingerprint (and a fresh upload) on
 the next search — entries are never revalidated on hit.
 
 Plane entries must never be donated to XLA (donation invalidates the
-buffer for every later consumer); the engine only donates per-chunk
-dynamic-parameter staging, which bypasses the cache via
-:func:`upload`.
+buffer for every later consumer); per-chunk dynamic-parameter staging
+bypasses the cache via :func:`upload`.
 """
 
 from __future__ import annotations
@@ -65,7 +64,6 @@ from spark_sklearn_tpu.utils.locks import named_lock, named_rlock
 
 __all__ = [
     "DataPlane",
-    "StagingRing",
     "bytes_uploaded",
     "fingerprint",
     "get_dataplane",
@@ -571,88 +569,3 @@ def report_block(plane: Optional[DataPlane], before: Dict[str, int],
         "budget_bytes": s["budget_bytes"],
         "mask_tiling": mask_tiling,
     }
-
-
-#: does jax.device_put COPY the host buffer (True) or may it alias it
-#: (False)?  On device backends (TPU/GPU — the perf target) host and
-#: device are distinct memory spaces, so the h2d transfer is the last
-#: read of the host buffer and reuse-after-transfer is safe.  XLA:CPU
-#: zero-copies aligned host arrays (observed: mutating the source after
-#: a SHARDED device_put changes the device value), so the pending
-#: launch reads the host memory at execute time — no host-side wait can
-#: bound that, and the ring must not reuse buffers there.
-_DEVICE_PUT_COPIES: Optional[bool] = None
-
-
-def _device_put_copies() -> bool:
-    global _DEVICE_PUT_COPIES
-    if _DEVICE_PUT_COPIES is None:
-        _DEVICE_PUT_COPIES = jax.default_backend() != "cpu"
-    return _DEVICE_PUT_COPIES
-
-
-class StagingRing:
-    """Reusable host buffers for per-chunk dynamic-param staging — the
-    double-buffer behind ``TpuConfig(donate_chunk_buffers=True)``.
-
-    ``pad_chunk`` writes each chunk into a ring slot instead of a fresh
-    allocation, so the stage thread stops allocating at steady state.
-    A slot remembers the device array its last contents fed and blocks
-    on its transfer before handing the buffer out again — sufficient on
-    copying backends (the transfer is the last read of the host
-    buffer), and the block also makes supervisor retries that consume
-    extra slots harmless.  On backends where ``device_put`` may ALIAS
-    host memory (XLA:CPU) the pending launch reads the buffer at
-    execute time, so reuse is never provably safe: the ring detects
-    that once (:func:`_device_put_copies`) and degrades to fresh
-    allocations — identical results, no double-buffer win.
-    """
-
-    class _Slot:
-        __slots__ = ("array", "consumer")
-
-        def __init__(self, array: np.ndarray):
-            self.array = array
-            self.consumer = None
-
-        def commit(self, dev) -> None:
-            """Remember the device array this slot's contents fed."""
-            self.consumer = dev
-
-    def __init__(self, slots: int = 3):
-        self._n = max(2, int(slots))
-        self._lock = named_lock("dataplane.StagingRing._lock")
-        self._rings: Dict[Any, Dict[str, Any]] = {}
-
-    def slot(self, key, shape: Tuple[int, ...], dtype) -> "_Slot":
-        """The next reusable buffer for ``key`` (shape/dtype bound into
-        the ring identity, so an OOM-bisected width gets its own
-        ring)."""
-        if not _device_put_copies():
-            # aliasing backend: a fresh buffer per chunk (see class
-            # docstring) — correctness over the allocation win
-            return StagingRing._Slot(np.empty(shape, dtype))
-        rkey = (key, tuple(shape), str(np.dtype(dtype)))
-        with self._lock:
-            ring = self._rings.get(rkey)
-            if ring is None:
-                ring = {"i": 0, "slots": []}
-                self._rings[rkey] = ring
-            if len(ring["slots"]) < self._n:
-                slot = StagingRing._Slot(np.empty(shape, dtype))
-                ring["slots"].append(slot)
-            else:
-                slot = ring["slots"][ring["i"] % self._n]
-            ring["i"] += 1
-        if slot.consumer is not None:
-            try:
-                jax.block_until_ready(slot.consumer)
-            # a donated-and-deleted consumer raises on the readiness
-            # probe, which PROVES the buffer was consumed — exactly the
-            # condition the wait establishes, so the error is the
-            # success case here, not a hidden failure
-            # sstlint: disable=swallowed-exception
-            except Exception:   # donated-and-deleted: consumed for sure
-                pass
-            slot.consumer = None
-        return slot

@@ -418,11 +418,11 @@ class LaunchSupervisor:
     """Wrap the search's ``LaunchItem`` stream with retry / bisection /
     watchdog / injection semantics.
 
-    Usage (``search/grid.py _run_groups``)::
+    Usage (``search/schedule.py LaunchSchedule.run``)::
 
         sup = LaunchSupervisor(config, faults=metrics.struct("faults"),
                                ckpt=ckpt)
-        pipe.run(sup.wrap(chunk_items()))
+        pipe.run(sup.wrap(schedule.items()))
 
     The fault-free fast path costs one try/except per launch phase; the
     watchdog thread only exists while ``launch_timeout_s`` is set.

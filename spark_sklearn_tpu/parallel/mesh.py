@@ -90,15 +90,6 @@ class TpuConfig:
     # execution order — the debugging/A-B escape hatch); scores are
     # identical at every depth.
     pipeline_depth: int = 2
-    # donate each chunk's per-launch dynamic-parameter buffers to XLA.
-    # Default off, with the measured reason recorded: these programs'
-    # outputs (per-task scores, nc x folds) can never alias the donated
-    # inputs, so XLA reports the donation unusable and ignores it — the
-    # pipeline already caps allocator pressure by dropping each chunk's
-    # staged buffers at dispatch (they free the moment the execution
-    # consumes them).  The knob exists for backends/families where the
-    # aliasing does bind.
-    donate_chunk_buffers: bool = False
     # convergence-sorted chunking: when a family exposes a difficulty
     # proxy (GLM: larger C / smaller alpha converges slower), big compile
     # groups are sorted by it and split into ~8 narrower launches so the
@@ -157,11 +148,6 @@ class TpuConfig:
     # reason in search_report["prefix"].  None defers to
     # SST_PREFIX_REUSE (1/0), then True.
     prefix_reuse: Optional[bool] = None
-    # force the nested per-(candidate, fold) score path even when every
-    # scorer exposes a task-batched core — the A/B control arm
-    # (tools/score_ab.py).  None/False keeps the wide path; the
-    # SST_NESTED_SCORE env var is the process-wide spelling.
-    nested_score: bool = False
     # ---- fault tolerance (parallel/faults.py LaunchSupervisor) ----
     # transient device errors retry with exponential backoff + jitter;
     # budgets are per launch AND per search (a flapping device must not

@@ -121,28 +121,18 @@ def build_compile_groups(
 
 
 def pad_chunk(arr: np.ndarray, lo: int, hi: int, width: int,
-              repeat: int = 1, out: Optional[np.ndarray] = None
-              ) -> np.ndarray:
+              repeat: int = 1) -> np.ndarray:
     """Slice `arr[lo:hi]` and pad it to the launch's uniform `width` by
     repeating the last row, so every chunk of a compile group reuses ONE
     compiled program.  `repeat > 1` additionally repeats each row that
     many times (the task-batched layout's candidate-major fold axis).
     Pure host work: this is the "candidate stacking" phase the pipeline
-    runs on its stage thread.
-
-    Writes into ONE preallocated output buffer (the old concatenate-
-    then-repeat shape allocated twice per chunk); pass `out` — shaped
-    ``(width * repeat,) + arr.shape[1:]`` — to reuse a caller-owned
-    staging buffer (the donate_chunk_buffers double-buffer ring)."""
+    runs on its stage thread.  Writes into ONE preallocated output
+    buffer (the old concatenate-then-repeat shape allocated twice per
+    chunk)."""
     with get_tracer().span("pad_chunk", lo=lo, hi=hi, width=width):
         n = hi - lo
-        shape = (width * repeat,) + arr.shape[1:]
-        if out is None:
-            out = np.empty(shape, arr.dtype)
-        elif out.shape != shape or out.dtype != arr.dtype:
-            raise ValueError(
-                f"pad_chunk out buffer has shape {out.shape}/{out.dtype}, "
-                f"expected {shape}/{arr.dtype}")
+        out = np.empty((width * repeat,) + arr.shape[1:], arr.dtype)
         chunk = arr[lo:hi]
         if repeat == 1:
             out[:n] = chunk

@@ -30,9 +30,7 @@ Execution: two tiers (SURVEY §7.0).
 from __future__ import annotations
 
 import functools
-import math
 import numbers
-import threading
 import time
 import warnings
 from collections import OrderedDict, defaultdict
@@ -72,7 +70,6 @@ from spark_sklearn_tpu.search.scorers import (
 from spark_sklearn_tpu.utils import keycheck as _keycheck
 from spark_sklearn_tpu.utils.locks import named_lock, named_rlock
 from spark_sklearn_tpu.utils.native import fold_masks
-from spark_sklearn_tpu.obs import telemetry as _telemetry
 from spark_sklearn_tpu.obs.log import get_logger
 from spark_sklearn_tpu.obs.metrics import search_registry
 from spark_sklearn_tpu.obs import process as _process
@@ -301,6 +298,350 @@ def chunkloop_block(state, *, mode="per_chunk", enabled=False,
     state["enabled"] = bool(enabled)
     state["score_attribution"] = score_attribution
     return state
+
+
+def _mesh_desc(mesh):
+    """The mesh as a program-store key part: everything in a store key
+    must repr identically across processes, so the mesh becomes its
+    topology and device ids."""
+    return ("mesh", tuple(sorted(dict(mesh.shape).items())),
+            tuple(int(d.id) for d in np.asarray(mesh.devices).flat))
+
+
+def _build_programs(family, meta, config, mesh, scorers, *, static,
+                    nc_batch, n_folds, prefix, all_cores, fused_mode,
+                    sw_blind, return_train, task_shard, store):
+    """A compile group's jitted programs for a launch of `nc_batch`
+    candidates under `static`, through the cross-search program cache:
+    the fit program (models and launch stats), the score program and,
+    with `fused_mode`, the fused fit + health + score program and its
+    raw body, which the scan program steps.  jax.jit is lazy, so a
+    program a search never calls is never traced.  `prefix` (the
+    group's shared-prefix digest, which joins every key) makes them the
+    suffix family's programs over the cached per-fold matrices;
+    `store` is the search's persistent program store."""
+    from spark_sklearn_tpu.search.launch import LaunchResult
+    task_batched = hasattr(family, "fit_task_batched")
+    px = prefix
+    suffix_fam = family.suffix_family() if px else None
+    needed_views = frozenset(
+        v for fn in scorers.values() for v in getattr(fn, "views", ()))
+    # the persistent store's key parts must repr identically across
+    # processes: registry names for the family and scorers, the mesh's
+    # topology
+    score_key = tuple(sorted(scorers.items()))
+    mesh_desc = _mesh_desc(mesh)
+    store_score_names = tuple(sorted(scorers))
+    store_sw_key = tuple(sorted(sw_blind))
+
+    def _fold_data(data_d, Xf):
+        return {**{k: v for k, v in data_d.items()
+                   if k != "X_folds"}, "X": Xf}
+
+    if task_batched:
+        # flatten (candidate x fold) into one leading task axis and
+        # let the family turn it into wide-matmul width (candidate-
+        # major order: task t = (cand t//n_folds, fold t%n_folds))
+
+        def fit_batch_tb(dyn_t, data_d, w_t,
+                         static={**static, "__n_folds__": n_folds,
+                                 "__bf16__": config.bf16_matmul}):
+            with jax.named_scope("sst.fit"):
+                model = family.fit_task_batched(
+                    dyn_t, static, data_d, w_t, meta)
+                return jax.tree_util.tree_map(
+                    lambda l: l.reshape(
+                        (nc_batch, n_folds) + l.shape[1:]), model)
+
+    def fit_batch(dyn_arrs, data_d, train_m, static=static):
+        def one_cand(dyn_scalars):
+            if px:
+                # suffix fit: fold f on its own cached transformed
+                # matrix, bit-exact with the inline transform
+                def one_fold_px(w, Xf):
+                    return suffix_fam.fit(
+                        dyn_scalars, static,
+                        _fold_data(data_d, Xf), w, meta)
+                return jax.vmap(one_fold_px)(
+                    train_m, data_d["X_folds"])
+
+            def one_fold(w):
+                return family.fit(dyn_scalars, static, data_d, w,
+                                  meta)
+            return jax.vmap(one_fold)(train_m)
+        with jax.named_scope("sst.fit"):
+            # the candidate axis has a name, so that a family's
+            # lockstep loop can ask whether ANY candidate still
+            # runs (models/base.py::any_candidate)
+            return jax.vmap(one_cand,
+                            axis_name=CANDIDATE_AXIS)(dyn_arrs)
+
+    def with_stats(fit_fn):
+        # the unfused fit launch's program: the models and, from
+        # the hook the fused body calls, the launch's stats
+        @functools.wraps(fit_fn)    # the program keeps its name
+        def fit_program(*args):
+            models = fit_fn(*args)
+            return models, family.launch_stats(models, static,
+                                               meta)
+        return fit_program
+
+    def score_batch_wide(models, data_d, test_m, train_m, test_u,
+                         train_u, static=static):
+        leaf = jax.tree_util.tree_leaves(models)[0]
+        ncb, nf = leaf.shape[0], leaf.shape[1]
+        n_tasks = ncb * nf
+        flat = jax.tree_util.tree_map(
+            lambda l: l.reshape((n_tasks,) + l.shape[2:]), models)
+        views = {}
+        if px:
+            # suffix views: fold f's models score on fold f's
+            # cached matrix, candidates over folds as the fit
+            # vmaps them.  (Flat over tasks with a gather
+            # X_folds[t % nf] a task, XLA:TPU materialized the
+            # (T, n, d') operand it was meant to fuse away:
+            # 6.6 GB at 60 tasks of 70 000 x 784; compiled for a
+            # v5e the launch's scratch is 7.17 GB that way and
+            # 0.04 GB this way, PERF.md, PR 33.)
+            xf = data_d["X_folds"]
+            for name in needed_views:
+                views[name] = jax.tree_util.tree_map(
+                    lambda v: v.reshape((n_tasks,) + v.shape[2:]),
+                    jax.vmap(lambda m_c, name=name: jax.vmap(
+                        lambda m, Xf: build_view(
+                            name, suffix_fam, m, static,
+                            _fold_data(data_d, Xf), meta))(
+                                m_c, xf))(models))
+        else:
+            wide = getattr(family, "views_task_batched", None)
+            if wide is not None:
+                views = dict(wide(flat, static, data_d, meta,
+                                  needed_views))
+            for name in needed_views:
+                if name not in views:
+                    views[name] = jax.vmap(
+                        lambda m, name=name: build_view(
+                            name, family, m, static, data_d,
+                            meta))(flat)
+
+        y = data_d.get("y")
+        # fold masks are indexed per task (t % n_folds,
+        # candidate-major flattening) instead of tiled to (T, n):
+        # the gather fuses into the reduction cores, where a tile
+        # would materialize ncb copies of every mask buffer
+        fold_idx = jnp.arange(n_tasks, dtype=jnp.int32) % nf
+
+        def one_task(view_t, fi):
+            wte, wtr = test_m[fi], train_m[fi]
+            wteu, wtru = test_u[fi], train_u[fi]
+            te = {s: fn.core(view_t, y,
+                             wteu if s in sw_blind else wte, meta)
+                  for s, fn in scorers.items()}
+            tr = ({s: fn.core(view_t, y,
+                              wtru if s in sw_blind else wtr, meta)
+                   for s, fn in scorers.items()}
+                  if return_train else {})
+            return te, tr
+
+        te, tr = jax.vmap(one_task)(views, fold_idx)
+        return (jax.tree_util.tree_map(
+                    lambda a: a.reshape(ncb, nf), te),
+                jax.tree_util.tree_map(
+                    lambda a: a.reshape(ncb, nf), tr))
+
+    def score_batch_nested(models, data_d, test_m, train_m, test_u,
+                           train_u, static=static):
+        def one_cand(model_c):
+            def one_fold(model, w_test, w_train, w_test_u,
+                         w_train_u):
+                te = {s: fn(family, model, static, data_d, meta,
+                            w_test_u if s in sw_blind else w_test)
+                      for s, fn in scorers.items()}
+                tr = ({s: fn(family, model, static, data_d, meta,
+                             w_train_u if s in sw_blind
+                             else w_train)
+                       for s, fn in scorers.items()}
+                      if return_train else {})
+                return te, tr
+            return jax.vmap(one_fold)(
+                model_c, test_m, train_m, test_u, train_u)
+        return jax.vmap(one_cand)(models)
+
+    score_core = score_batch_wide if all_cores \
+        else score_batch_nested
+
+    @functools.wraps(score_core)    # the program keeps its name
+    def score_batch(*args):
+        with jax.named_scope("sst.score"):
+            return score_core(*args)
+
+    fused_jit = None
+    if fused_mode:
+        fit_core = fit_batch_tb if task_batched else fit_batch
+
+        def fused_batch(dyn_t, data_d, w_fit, test_m, train_m,
+                        test_u, train_u):
+            models = fit_core(dyn_t, data_d, w_fit)
+            with jax.named_scope("sst.score"):
+                bad = _models_health(models)
+                if bad is None:
+                    leaf = jax.tree_util.tree_leaves(models)[0]
+                    bad = jnp.zeros(leaf.shape[:2], bool)
+                # what the family reports of the launch: named
+                # int32 arrays the engine carries without reading
+                stats = family.launch_stats(models, static, meta)
+                te, tr = score_batch_wide(models, data_d, test_m,
+                                          train_m, test_u, train_u)
+            return LaunchResult(te, tr, bad, stats)
+
+        fused_jit = _cached_program(
+            ("fused", family, static, meta, nc_batch, n_folds,
+             bool(config.bf16_matmul), mesh, score_key,
+             return_train, sw_blind, px),
+            lambda: jax.jit(fused_batch),
+            store_parts=(
+                "fused", family.name, static, meta, nc_batch,
+                n_folds, bool(config.bf16_matmul), mesh_desc,
+                store_score_names, store_sw_key, return_train,
+                px),
+            store=store,
+            check_fields={
+                "bf16_matmul": bool(config.bf16_matmul),
+                "mesh": mesh_desc})
+    # separate fit/score programs: every chunk's on the non-fused path,
+    # a group's first live chunk's on the fused path (the calibration)
+    if task_batched:
+        # the mesh joins the in-memory key as mesh_desc joins the store
+        # key: a search on a re-built mesh must not reuse a program
+        # whose store proxy was keyed to the old one
+        fit_jit = _cached_program(
+            ("fit_tb", family, static, meta, nc_batch, n_folds,
+             bool(config.bf16_matmul), mesh),
+            lambda: jax.jit(with_stats(fit_batch_tb)),
+            store_parts=(
+                "fit_tb", family.name, static, meta, nc_batch,
+                n_folds, bool(config.bf16_matmul), mesh_desc),
+            store=store,
+            check_fields={
+                "bf16_matmul": bool(config.bf16_matmul),
+                "mesh": mesh_desc})
+    else:
+        fit_jit = _cached_program(
+            ("fit", family, static, meta, mesh, px),
+            lambda: jax.jit(with_stats(fit_batch),
+                            out_shardings=(task_shard, None)),
+            store_parts=("fit", family.name, static, meta, mesh_desc,
+                         px),
+            store=store,
+            check_fields={"mesh": mesh_desc})
+    score_jit = _cached_program(
+        ("score", family, static, meta, score_key, return_train,
+         sw_blind, bool(all_cores), px, mesh),
+        lambda: jax.jit(score_batch),
+        store_parts=("score", family.name, static, meta,
+                     mesh_desc, store_score_names, store_sw_key,
+                     return_train, bool(all_cores), px),
+        store=store,
+        check_fields={"mesh": mesh_desc})
+    return {"fit": fit_jit, "score": score_jit, "fused": fused_jit,
+            # the raw (un-jitted) fused body: the scan program
+            # wraps it as its lax.scan step function
+            "fused_body": fused_batch if fused_mode else None}
+
+
+def _build_scan(fused_body, family, meta, config, mesh, scorers, *,
+                static, nc_batch, nc, n_folds, n_steps, topk_k, score0,
+                errval, prefix, sw_blind, return_train, hb=False):
+    """ONE jitted program executing `n_steps` chunks of a compile group
+    as a `lax.scan` over the stacked chunk axis.  The step function is
+    the group's fused body, so every lane computes exactly what its
+    solo fused launch would.
+
+    `topk_k > 0` also folds the halving rung's elimination on device: a
+    score carry (a row a candidate position, plus a dump row for padded
+    lanes) accumulates the `score0` test scores, and the program
+    returns the top-k candidate POSITIONS as sklearn's `_top_k` orders
+    them.  `hb=True` threads the heartbeat beacon into the step body:
+    a jax.debug.callback emits (token, step), the token a RUNTIME
+    operand, so ONE compiled program serves every search."""
+    from jax import lax
+    from spark_sklearn_tpu.obs import heartbeat as _heartbeat
+
+    def scan_batch(dyn_st, idx_st, data_d, w_fit, test_m,
+                   train_m, test_u, train_u, hb_tok=None):
+        if topk_k:
+            carry0 = jnp.full((nc + 1, n_folds),
+                              jnp.float32(errval))
+        else:
+            carry0 = jnp.zeros((), jnp.float32)
+
+        def step(carry, xs):
+            if hb:
+                dyn_c, idx_c, step_i = xs
+            else:
+                dyn_c, idx_c = xs
+            res = fused_body(
+                dyn_c, data_d, w_fit, test_m, train_m,
+                test_u, train_u)
+            if hb:
+                # in-flight beat: fires on jax's callback
+                # thread as each scan step executes; unordered
+                # (no token threading cost) — the hub takes
+                # the max step either way
+                jax.debug.callback(
+                    _heartbeat.device_beat, hb_tok, step_i,
+                    ordered=False)
+            if topk_k:
+                # mirror the host-side error_score substitution
+                # BEFORE the mean, so the device ranking sees
+                # the same scores sklearn's _top_k would
+                sc = jnp.where(
+                    res.bad, jnp.float32(errval),
+                    res.test[score0].astype(jnp.float32))
+                carry = carry.at[idx_c].set(sc)
+            return carry, res
+
+        xs = (dyn_st, idx_st)
+        if hb:
+            xs = xs + (jnp.arange(n_steps, dtype=jnp.int32),)
+        carry, ys = lax.scan(step, carry0, xs)
+        if topk_k:
+            mean = carry[:nc].mean(axis=1)
+            order = jnp.roll(jnp.argsort(mean),
+                             jnp.count_nonzero(jnp.isnan(mean)))
+            surv = order[-topk_k:].astype(jnp.int32)
+        else:
+            surv = jnp.zeros((0,), jnp.int32)
+        return ys, surv
+
+    # the nan error_score breaks key equality (nan != nan), so the key
+    # carries its repr; scan programs skip the persistent store.  The
+    # beacon joins the key ONLY when on, so on/off never alias
+    score_key = tuple(sorted(scorers.items()))
+    return _cached_program(
+        ("scan", family, static, meta, nc_batch, n_folds, int(n_steps),
+         bool(config.bf16_matmul), mesh, score_key, return_train,
+         sw_blind, int(topk_k), nc, repr(float(errval)), prefix)
+        + (("hb",) if hb else ()),
+        lambda: jax.jit(scan_batch),
+        store_parts=None,
+        check_fields={
+            "bf16_matmul": bool(config.bf16_matmul),
+            "heartbeat": bool(hb),
+            "mesh": _mesh_desc(mesh)})
+
+
+def _prefix_program(family, static, digest, meta, mesh):
+    """The shared-prefix transform of a compile group's Pipeline, keyed
+    on its DIGEST, not the group statics: two groups differing only in
+    final-step params share one compiled transform."""
+    def build():
+        return jax.jit(
+            lambda data_d, w_f: family.prefix_transform(static, data_d,
+                                                        w_f))
+    return _cached_program(
+        ("prefix", family, digest, meta, mesh), build,
+        check_fields={"prefix_digest": digest})
 
 
 def _looks_like_estimator(obj) -> bool:
@@ -1175,42 +1516,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             scorers, _ = resolve_scoring(self.scoring, family)
             scorer_names = list(scorers)
 
-            # sklearn's log_loss clips probas at THEIR dtype's machine eps
-            # (_classification.py log_loss), and the sklearn twin's proba
-            # dtype is a per-family fact: on this sklearn nearly every
-            # classifier (libsvm, forests, KNN, LogReg, the NB family)
-            # produces f64 probas regardless of X dtype; only MLP and LDA
-            # preserve the user's X dtype (proba_dtype_rule="input") — the
-            # compiled scorer must clip where the oracle clips, not where
-            # the engine's compute dtype lands (see scorers.py
-            # _neg_log_loss)
-            proba_rule = getattr(family, "proba_dtype_rule", "float64")
-            # the dtype that matters is the one sklearn's own validation
-            # would hand the estimator: float32 stays float32, EVERYTHING
-            # else (float64, ints, lists, frames — check_array's numeric
-            # rule) becomes float64.  Resolve it after coercion: sparse
-            # matrices and ndarrays expose .dtype directly; other inputs
-            # (lists, DataFrames) go through np.asarray like sklearn's
-            # check_array would
-            x_dt = getattr(X, "dtype", None)
-            if not isinstance(x_dt, np.dtype):
-                # dtype-less inputs resolve WITHOUT copying the dataset:
-                # DataFrames promote their column dtypes; lists/tuples
-                # resolve from their first row (a float32-ndarray row list
-                # stays float32 under np.asarray, everything else becomes
-                # float64 under check_array's numeric rule)
-                col_dtypes = getattr(X, "dtypes", None)
-                if col_dtypes is not None and len(col_dtypes):
-                    x_dt = np.result_type(*col_dtypes)
-                elif isinstance(X, (list, tuple)) and len(X) \
-                        and isinstance(X[0], np.ndarray):
-                    x_dt = X[0].dtype
-                elif isinstance(X, (list, tuple)):
-                    x_dt = np.dtype(np.float64)
-                else:
-                    x_dt = np.asarray(X).dtype
-            oracle_proba_dt = np.float64 if (
-                proba_rule == "float64" or x_dt != np.float32) else np.float32
+            oracle_proba_dt = self._oracle_proba_dtype(family, X)
             # the pre-densified X (what sklearn estimators would see): the
             # supervisor's per-candidate host fallback fits on THIS, so a
             # bisection that bottoms out reproduces sklearn exactly
@@ -1247,40 +1553,7 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 data, meta = family.prepare_data(X, y, dtype=dtype)
             meta["logloss_clip_eps"] = float(np.finfo(oracle_proba_dt).eps)
             if self.scoring is not None:
-                if "y" not in data:
-                    raise ValueError(
-                        f"scoring={self.scoring!r} needs labels, but none "
-                        f"reached the device ({family.name} is unsupervised: "
-                        "y was absent or not numerically encodable; only its "
-                        "default scorer applies)")
-                from spark_sklearn_tpu.search.scorers import (
-                    compiled_name_for_scorer)
-
-                def _canon(s):
-                    return s if isinstance(s, str) \
-                        else compiled_name_for_scorer(s)
-                if isinstance(self.scoring, str):
-                    wanted = [self.scoring]
-                elif isinstance(self.scoring, dict):
-                    # dict values name the metrics; keys are display labels
-                    wanted = [_canon(s) for s in self.scoring.values()]
-                elif isinstance(self.scoring, (list, tuple, set)):
-                    wanted = [_canon(s) for s in self.scoring]
-                else:
-                    wanted = [_canon(self.scoring)]
-                wanted = [s for s in wanted if s is not None]
-                if any(s in CLASSIFICATION_SCORERS for s in wanted) and \
-                        "n_classes" not in meta:
-                    raise ValueError(
-                        f"scoring={self.scoring!r} requires a classifier "
-                        f"family; {family.name} has no class structure")
-                if any(s in BINARY_ONLY_SCORERS for s in wanted) and \
-                        meta.get("n_classes", 2) > 2:
-                    # sklearn's semantics for these on multiclass (averaging
-                    # options, undefined-metric warnings) live on the host path
-                    raise NotCompiledError(
-                        f"scoring={self.scoring!r} on multiclass targets is "
-                        "not compiled; use backend='host'")
+                self._check_compiled_scoring(family, data, meta)
             n_samples = X.shape[0]
             train_masks, test_masks = fold_masks(
                 splits, n_samples, dtype=dtype)
@@ -1538,64 +1811,13 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
         fit_failed = np.zeros((n_cand, n_folds), bool)
         fit_failed[preval_failed, :] = True
 
-        ckpt = None
-        if config.checkpoint_dir:
-            from spark_sklearn_tpu.utils.checkpoint import (
-                SearchCheckpoint, fingerprint)
-            if sparse_op is not None:
-                # CSR content enters by its canonical components — a
-                # sparse head-slice repr() carries no values, and any
-                # dense staging here would defeat the whole tier
-                _x_head = sparse_op.values[:4096]
-                _x_moments = (
-                    *sparse_op.signature(),
-                    float(np.sum(sparse_op.values, dtype=np.float64)),
-                    float(np.sum(np.square(sparse_op.values,
-                                           dtype=np.float64))),
-                    float(np.sum(sparse_op.indices, dtype=np.float64)))
-            else:
-                _x_head = X[: min(64, n_samples)]
-                # whole-dataset moments so ANY changed X row or label
-                # set breaks the fingerprint (head rows can collide)
-                _x_moments = (
-                    X.shape, float(np.sum(X, dtype=np.float64)),
-                    float(np.sum(np.square(X, dtype=np.float64))))
-            key = fingerprint(
-                type(self.estimator).__name__, base_params, candidates,
-                scorer_names, n_folds, return_train,
-                # result-affecting config: resuming under a different matmul
-                # precision or dtype must not reuse the other run's scores
-                (bool(config.bf16_matmul), str(config.dtype)),
-                _x_head,
-                _x_moments,
-                self._hashable_labels(y),
-                np.asarray(train_masks),
-                # weighted searches must not resume an unweighted run's
-                # chunks (and vice versa); arrays go in as bare top-level
-                # parts so fingerprint() hashes their bytes (tuples would
-                # be repr()'d, which numpy truncates past 1000 elements)
-                "fitw",
-                np.asarray(fit_weight, np.float64)
-                if fit_weight is not None else "none",
-                "scw",
-                np.asarray(score_weight, np.float64)
-                if score_weight is not None else "none",
-                # halving rungs are distinct resumable units: the rung
-                # index (and its resource) joins the fingerprint even
-                # though the candidate set / masks already differ, so
-                # two rungs can never alias one journal file
-                *(("halving", rung.itr, rung.n_resources)
-                  if rung is not None else ()),
-                # a streamed run's journal holds per-shard accumulator
-                # records addressed by the stream geometry — never let a
-                # device-mode resume read (or extend) it
-                *(("stream",) if data_mode == "stream" else ()))
-            _keycheck.note(
-                "checkpoint", key,
-                fields={"bf16_matmul": bool(config.bf16_matmul),
-                        "dtype": str(config.dtype)},
-                detail=type(self.estimator).__name__)
-            ckpt = SearchCheckpoint(config.checkpoint_dir, key)
+        ckpt = self._open_checkpoint(
+            config, base_params=base_params, candidates=candidates,
+            scorer_names=scorer_names, n_folds=n_folds,
+            return_train=return_train, X=X, sparse_op=sparse_op, y=y,
+            train_masks=train_masks, fit_weight=fit_weight,
+            score_weight=score_weight, rung=rung,
+            data_mode=data_mode) if config.checkpoint_dir else None
 
         profiler_cm = None
         if config.profile_dir:
@@ -1604,68 +1826,10 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
             profiler_cm.__enter__()
         debug_ctx = (jax.debug_nans(True) if config.debug_nans
                      else _nullcontext())
-        # search_report = the rendered view of a typed registry whose
-        # schema lives in obs.metrics.SEARCH_REPORT_SCHEMA (keys
-        # materialize here in the legacy order, so the report is
-        # key-for-key identical to the pre-registry dict).  A halving
-        # search's rungs share ONE registry: counters (n_launches,
-        # walls, n_chunks_resumed) accumulate across rungs and the
-        # struct blocks render the whole search's deltas.
-        if rung is not None and rung.registry is not None:
-            metrics = rung.registry
-        else:
-            metrics = search_registry("tpu")
-            if rung is not None:
-                rung.registry = metrics
-        ncg = metrics.gauge("n_compile_groups")
-        if rung is not None:
-            # like the counters: the whole search's group total, not
-            # the last rung's
-            ncg.set(int(ncg.value) + len(groups))
-        else:
-            ncg.set(len(groups))
-        metrics.counter("n_launches")
-        metrics.counter("n_chunks_resumed")
-        metrics.gauge("fit_wall_s")
-        metrics.gauge("score_wall_s")
-        metrics.struct("mesh").update(
-            {"task": n_task_shards, "data": config.n_data_shards})
-        self._search_metrics = metrics
-        self._search_report = metrics.data
+        metrics = self._open_search_registry(
+            rung, len(groups), n_task_shards, config)
 
-        # self-protection context (deadline shed, quarantine, partial-
-        # results degradation — see parallel/faults.py protection_block).
-        # Search-scoped: one ctx spans every halving rung, so the
-        # deadline covers the WHOLE search; the done mask is per-call
-        # (each rung owns fresh result arrays).  protection off -> ctx
-        # is None and every path below is untouched (byte-identical
-        # reports).
-        if _faults.protection_enabled(config):
-            pctx = getattr(self, "_protection_ctx", None)
-            if pctx is None or rung is None or rung.itr == 0:
-                t_dl = None
-                if getattr(config, "search_deadline_s", None):
-                    # the executor stamps the deadline at SUBMIT (queue
-                    # wait spends the budget); a sessionless fit starts
-                    # the clock here
-                    hd = getattr(getattr(_binding, "handle", None),
-                                 "t_deadline", None)
-                    t_dl = hd if hd is not None else (
-                        time.perf_counter()
-                        + float(config.search_deadline_s))
-                pctx = self._protection_ctx = {
-                    "t_start": time.perf_counter(),
-                    "t_deadline": t_dl,
-                    "deadline_hit": False,
-                    "shed": [],
-                    "quarantined": [],
-                }
-            # candidates with written cells: prevalidation failures
-            # already carry error_score, so degradation never
-            # overwrites them
-            pctx["done"] = preval_failed.copy()
-        else:
-            self._protection_ctx = None
+        self._open_protection(config, rung, _binding, preval_failed)
 
         # bound peak HBM: chunk each compile group so one launch holds at
         # most max_tasks_per_batch (candidate x fold) program instances;
@@ -1683,76 +1847,9 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 max(1, max_tasks // max(n_folds, 1)),
                 n_task_shards))
 
-        host_scorer_cache: List[Any] = []
-
-        def host_eval(cand_indices):
-            """Per-candidate host execution for the supervisor's OOM
-            bottom-out: real `clone(est).set_params(**p)` fits via
-            sklearn `_fit_and_score` — exact sklearn error_score
-            semantics — returning (test, train) score dicts shaped
-            (len(cand_indices), n_folds) under the compiled scorer
-            names."""
-            from sklearn.metrics import check_scoring
-            from sklearn.model_selection._validation import (
-                _fit_and_score, _warn_or_raise_about_fit_failures)
-
-            if not host_scorer_cache:
-                if self.scoring is None or isinstance(self.scoring, str) \
-                        or callable(self.scoring):
-                    host_scorer_cache.append(
-                        check_scoring(self.estimator, self.scoring))
-                else:
-                    from sklearn.metrics._scorer import (
-                        _MultimetricScorer, _check_multimetric_scoring)
-                    sc = _check_multimetric_scoring(
-                        self.estimator, self.scoring)
-                    if set(sc) != set(scorer_names):
-                        # compiled names must address the same cells the
-                        # host scorer produces; a mismatch cannot be
-                        # recovered into cv_results_
-                        raise RuntimeError(
-                            "host fallback scorer names "
-                            f"{sorted(sc)} do not match compiled names "
-                            f"{sorted(scorer_names)}")
-                    host_scorer_cache.append(_MultimetricScorer(
-                        scorers=sc,
-                        raise_exc=(self.error_score == "raise")))
-            scorer = host_scorer_cache[0]
-            host_fit_params = ({"sample_weight": fit_weight}
-                               if fit_weight is not None else None)
-            host_score_params = ({"sample_weight": score_weight}
-                                 if score_weight is not None else None)
-            results = []
-            for ci in cand_indices:
-                for tr_idx, te_idx in splits:
-                    results.append(_fit_and_score(
-                        clone(self.estimator), X_host, y, scorer=scorer,
-                        train=tr_idx, test=te_idx, verbose=0,
-                        parameters=candidates[int(ci)],
-                        fit_params=host_fit_params,
-                        score_params=host_score_params,
-                        return_train_score=return_train,
-                        return_times=True,
-                        error_score=self.error_score))
-            _warn_or_raise_about_fit_failures(results, self.error_score)
-            n = len(cand_indices)
-            te = {s: np.empty((n, n_folds)) for s in scorer_names}
-            tr = ({s: np.empty((n, n_folds)) for s in scorer_names}
-                  if return_train else {})
-            for t, res in enumerate(results):
-                i, f = divmod(t, n_folds)
-                ts = res["test_scores"]
-                if not isinstance(ts, dict):
-                    ts = {s: ts for s in scorer_names}
-                for s in scorer_names:
-                    te[s][i, f] = ts.get(s, np.nan)
-                if return_train:
-                    trs = res.get("train_scores", {})
-                    if not isinstance(trs, dict):
-                        trs = {s: trs for s in scorer_names}
-                    for s in scorer_names:
-                        tr[s][i, f] = trs.get(s, np.nan)
-            return te, tr
+        host_eval = functools.partial(
+            self._host_eval, X_host, y, candidates, splits, scorer_names,
+            return_train, fit_weight, score_weight, [])
 
         if ledger is not None:
             # launch-boundary sampling (pipeline._record) is live only
@@ -1813,58 +1910,367 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
         finally:
             if profiler_cm is not None:
                 profiler_cm.__exit__(None, None, None)
-            # let go of the broadcast: _run_groups' closures hold this
-            # dict and one another, so without this a search's X stays on
-            # the device until Python's cycle collector happens to run —
-            # one more copy per search of an X the plane's budget cannot
-            # keep (PERF.md, PR 29: 0.23 GB a search in cell 1)
-            data_dev.clear()
-            # this search's broadcast-cache traffic (hits = arrays
-            # reused with zero transfer; bytes_uploaded = cacheable
-            # bytes actually shipped; bytes_staged = per-chunk dyn
-            # params) — schema in obs.metrics.DATAPLANE_BLOCK_SCHEMA
-            mask_tiling = ("n/a" if not hasattr(family, "fit_task_batched")
-                           else "device" if plane is not None else "host")
-            metrics.put("dataplane", _dataplane.report_block(
-                plane, dp_before, mask_tiling=mask_tiling))
-            # this search's AOT-store traffic (hits = programs served
-            # from serialized artifacts with zero tracing; publishes =
-            # artifacts written for the next cold process) — schema in
-            # obs.metrics.PROGRAMSTORE_BLOCK_SCHEMA
-            metrics.put("programstore", _programstore.report_block(
-                pstore, ps_before))
-            # this search's device-memory view (modeled per-group
-            # footprints, budget/ceiling state, measured watermark) —
-            # schema in obs.metrics.MEMORY_BLOCK_SCHEMA.  Rendered
-            # ONLY when the ledger is on: off, the report shape is
-            # byte-identical to the pre-ledger engine.
-            if ledger is not None:
-                ledger.deactivate()
-                metrics.put("memory", _memledger.report_block(
-                    ledger, mem_before,
-                    getattr(self, "_memory_ctx", {}) or {}))
-            # this search's in-flight heartbeat view (beats/steps,
-            # cadence percentiles, staleness, overhead estimate) —
-            # schema in obs.metrics.HEARTBEAT_BLOCK_SCHEMA.  Rendered
-            # ONLY when heartbeat is on: off, the report shape is
-            # byte-identical to the beacon-less engine.
-            if hb_ctx is not None:
-                metrics.put("heartbeat", _heartbeat.heartbeat_block(
-                    hb_ctx["scope"]))
-            # the search's protection verdict (deadline/shed/quarantine
-            # state) — schema in obs.metrics.PROTECTION_BLOCK_SCHEMA.
-            # Rendered ONLY when protection is on: off, the report is
-            # byte-identical to the unprotected engine.  A halving
-            # search re-puts each rung; the shared ctx accumulates, so
-            # the last put covers the whole search.
-            pctx_fin = getattr(self, "_protection_ctx", None)
-            if pctx_fin is not None:
-                metrics.put("protection", _faults.protection_block(
-                    config, deadline_hit=pctx_fin["deadline_hit"],
-                    shed=pctx_fin["shed"],
-                    quarantined=pctx_fin["quarantined"],
-                    elapsed_s=time.perf_counter()
-                    - pctx_fin["t_start"]))
+            self._put_search_blocks(
+                metrics, config, family, plane=plane, dp_before=dp_before,
+                pstore=pstore, ps_before=ps_before, ledger=ledger,
+                mem_before=mem_before, hb_ctx=hb_ctx)
+        self._account_failed_fits(
+            groups, candidates, preval_failed, fit_failed, scorer_names,
+            test_scores, train_scores, fit_times, score_times, return_train)
+        # scorer_ keeps the sklearn-facing objects so .score() works the
+        # sklearn way even though CV scoring ran compiled
+        if self.scoring is None or isinstance(self.scoring, str):
+            scorer_attr = check_scoring(self.estimator, self.scoring)
+        else:
+            from sklearn.metrics._scorer import _check_multimetric_scoring
+            scorer_attr = _check_multimetric_scoring(
+                self.estimator, self.scoring)
+        return (test_scores, train_scores, fit_times, score_times,
+                scorer_names, scorer_attr)
+
+    @staticmethod
+    def _oracle_proba_dtype(family, X):
+        """The dtype of the probabilities sklearn's twin of `family`
+        produces on `X`, where log_loss clips them."""
+        # sklearn's log_loss clips probas at THEIR dtype's machine eps
+        # (_classification.py log_loss), and the sklearn twin's proba
+        # dtype is a per-family fact: on this sklearn nearly every
+        # classifier (libsvm, forests, KNN, LogReg, the NB family)
+        # produces f64 probas regardless of X dtype; only MLP and LDA
+        # preserve the user's X dtype (proba_dtype_rule="input") — the
+        # compiled scorer must clip where the oracle clips, not where
+        # the engine's compute dtype lands (see scorers.py
+        # _neg_log_loss)
+        proba_rule = getattr(family, "proba_dtype_rule", "float64")
+        # the dtype that matters is the one sklearn's own validation
+        # would hand the estimator: float32 stays float32, EVERYTHING
+        # else (float64, ints, lists, frames — check_array's numeric
+        # rule) becomes float64.  Resolve it after coercion: sparse
+        # matrices and ndarrays expose .dtype directly; other inputs
+        # (lists, DataFrames) go through np.asarray like sklearn's
+        # check_array would
+        x_dt = getattr(X, "dtype", None)
+        if not isinstance(x_dt, np.dtype):
+            # dtype-less inputs resolve WITHOUT copying the dataset:
+            # DataFrames promote their column dtypes; lists/tuples
+            # resolve from their first row (a float32-ndarray row list
+            # stays float32 under np.asarray, everything else becomes
+            # float64 under check_array's numeric rule)
+            col_dtypes = getattr(X, "dtypes", None)
+            if col_dtypes is not None and len(col_dtypes):
+                x_dt = np.result_type(*col_dtypes)
+            elif isinstance(X, (list, tuple)) and len(X) \
+                    and isinstance(X[0], np.ndarray):
+                x_dt = X[0].dtype
+            elif isinstance(X, (list, tuple)):
+                x_dt = np.dtype(np.float64)
+            else:
+                x_dt = np.asarray(X).dtype
+        return np.float64 if (
+            proba_rule == "float64" or x_dt != np.float32) else np.float32
+
+    def _check_compiled_scoring(self, family, data, meta):
+        """Refuse a `scoring` the compiled tier cannot score as sklearn
+        would: labels that never reached the device, a classification
+        metric without classes, a binary-only metric on multiclass."""
+        if "y" not in data:
+            raise ValueError(
+                f"scoring={self.scoring!r} needs labels, but none "
+                f"reached the device ({family.name} is unsupervised: "
+                "y was absent or not numerically encodable; only its "
+                "default scorer applies)")
+        from spark_sklearn_tpu.search.scorers import (
+            compiled_name_for_scorer)
+
+        def _canon(s):
+            return s if isinstance(s, str) \
+                else compiled_name_for_scorer(s)
+        if isinstance(self.scoring, str):
+            wanted = [self.scoring]
+        elif isinstance(self.scoring, dict):
+            # dict values name the metrics; keys are display labels
+            wanted = [_canon(s) for s in self.scoring.values()]
+        elif isinstance(self.scoring, (list, tuple, set)):
+            wanted = [_canon(s) for s in self.scoring]
+        else:
+            wanted = [_canon(self.scoring)]
+        wanted = [s for s in wanted if s is not None]
+        if any(s in CLASSIFICATION_SCORERS for s in wanted) and \
+                "n_classes" not in meta:
+            raise ValueError(
+                f"scoring={self.scoring!r} requires a classifier "
+                f"family; {family.name} has no class structure")
+        if any(s in BINARY_ONLY_SCORERS for s in wanted) and \
+                meta.get("n_classes", 2) > 2:
+            # sklearn's semantics for these on multiclass (averaging
+            # options, undefined-metric warnings) live on the host path
+            raise NotCompiledError(
+                f"scoring={self.scoring!r} on multiclass targets is "
+                "not compiled; use backend='host'")
+
+    def _open_checkpoint(self, config, *, base_params, candidates,
+                         scorer_names, n_folds, return_train, X, sparse_op,
+                         y, train_masks, fit_weight, score_weight, rung,
+                         data_mode):
+        """The search's chunk journal, keyed by everything its cells
+        depend on: a resumed search only reuses chunks computed under a
+        result-identical configuration."""
+        from spark_sklearn_tpu.utils.checkpoint import (
+            SearchCheckpoint, fingerprint)
+        if sparse_op is not None:
+            # CSR content enters by its canonical components — a
+            # sparse head-slice repr() carries no values, and any
+            # dense staging here would defeat the whole tier
+            _x_head = sparse_op.values[:4096]
+            _x_moments = (
+                *sparse_op.signature(),
+                float(np.sum(sparse_op.values, dtype=np.float64)),
+                float(np.sum(np.square(sparse_op.values,
+                                       dtype=np.float64))),
+                float(np.sum(sparse_op.indices, dtype=np.float64)))
+        else:
+            _x_head = X[: min(64, X.shape[0])]
+            # whole-dataset moments so ANY changed X row or label
+            # set breaks the fingerprint (head rows can collide)
+            _x_moments = (
+                X.shape, float(np.sum(X, dtype=np.float64)),
+                float(np.sum(np.square(X, dtype=np.float64))))
+        key = fingerprint(
+            type(self.estimator).__name__, base_params, candidates,
+            scorer_names, n_folds, return_train,
+            # result-affecting config: resuming under a different matmul
+            # precision or dtype must not reuse the other run's scores
+            (bool(config.bf16_matmul), str(config.dtype)),
+            _x_head,
+            _x_moments,
+            self._hashable_labels(y),
+            np.asarray(train_masks),
+            # weighted searches must not resume an unweighted run's
+            # chunks (and vice versa); arrays go in as bare top-level
+            # parts so fingerprint() hashes their bytes (tuples would
+            # be repr()'d, which numpy truncates past 1000 elements)
+            "fitw",
+            np.asarray(fit_weight, np.float64)
+            if fit_weight is not None else "none",
+            "scw",
+            np.asarray(score_weight, np.float64)
+            if score_weight is not None else "none",
+            # halving rungs are distinct resumable units: the rung
+            # index (and its resource) joins the fingerprint even
+            # though the candidate set / masks already differ, so
+            # two rungs can never alias one journal file
+            *(("halving", rung.itr, rung.n_resources)
+              if rung is not None else ()),
+            # a streamed run's journal holds per-shard accumulator
+            # records addressed by the stream geometry — never let a
+            # device-mode resume read (or extend) it
+            *(("stream",) if data_mode == "stream" else ()))
+        _keycheck.note(
+            "checkpoint", key,
+            fields={"bf16_matmul": bool(config.bf16_matmul),
+                    "dtype": str(config.dtype)},
+            detail=type(self.estimator).__name__)
+        return SearchCheckpoint(config.checkpoint_dir, key)
+
+    def _open_search_registry(self, rung, n_groups, n_task_shards, config):
+        """`search_report` as the rendered view of a typed registry whose
+        schema lives in obs.metrics.SEARCH_REPORT_SCHEMA (keys
+        materialize here in the legacy order).  A halving search's rungs
+        share ONE registry: counters (n_launches, walls,
+        n_chunks_resumed) accumulate across rungs and the struct blocks
+        render the whole search's deltas."""
+        if rung is not None and rung.registry is not None:
+            metrics = rung.registry
+        else:
+            metrics = search_registry("tpu")
+            if rung is not None:
+                rung.registry = metrics
+        ncg = metrics.gauge("n_compile_groups")
+        if rung is not None:
+            # like the counters: the whole search's group total, not
+            # the last rung's
+            ncg.set(int(ncg.value) + n_groups)
+        else:
+            ncg.set(n_groups)
+        metrics.counter("n_launches")
+        metrics.counter("n_chunks_resumed")
+        metrics.gauge("fit_wall_s")
+        metrics.gauge("score_wall_s")
+        metrics.struct("mesh").update(
+            {"task": n_task_shards, "data": config.n_data_shards})
+        self._search_metrics = metrics
+        self._search_report = metrics.data
+        return metrics
+
+    def _open_protection(self, config, rung, binding, preval_failed):
+        """The self-protection context (deadline shed, quarantine,
+        partial-results degradation — parallel/faults.py
+        protection_block).  Search-scoped: one ctx spans every halving
+        rung, so the deadline covers the WHOLE search; the done mask is
+        per-call (each rung owns fresh result arrays).  Protection off:
+        None, and every guarded path is untouched."""
+        if _faults.protection_enabled(config):
+            pctx = getattr(self, "_protection_ctx", None)
+            if pctx is None or rung is None or rung.itr == 0:
+                t_dl = None
+                if getattr(config, "search_deadline_s", None):
+                    # the executor stamps the deadline at SUBMIT (queue
+                    # wait spends the budget); a sessionless fit starts
+                    # the clock here
+                    hd = getattr(getattr(binding, "handle", None),
+                                 "t_deadline", None)
+                    t_dl = hd if hd is not None else (
+                        time.perf_counter()
+                        + float(config.search_deadline_s))
+                pctx = self._protection_ctx = {
+                    "t_start": time.perf_counter(),
+                    "t_deadline": t_dl,
+                    "deadline_hit": False,
+                    "shed": [],
+                    "quarantined": [],
+                }
+            # candidates with written cells: prevalidation failures
+            # already carry error_score, so degradation never
+            # overwrites them
+            pctx["done"] = preval_failed.copy()
+        else:
+            self._protection_ctx = None
+
+    def _host_eval(self, X_host, y, candidates, splits, scorer_names,
+                   return_train, fit_weight, score_weight, scorer_cache,
+                   cand_indices):
+        """Per-candidate host execution for the supervisor's OOM
+        bottom-out: real `clone(est).set_params(**p)` fits via
+        sklearn `_fit_and_score` — exact sklearn error_score
+        semantics — returning (test, train) score dicts shaped
+        (len(cand_indices), n_folds) under the compiled scorer
+        names; `scorer_cache` keeps the host scorer once built."""
+        n_folds = len(splits)
+        from sklearn.metrics import check_scoring
+        from sklearn.model_selection._validation import (
+            _fit_and_score, _warn_or_raise_about_fit_failures)
+
+        if not scorer_cache:
+            if self.scoring is None or isinstance(self.scoring, str) \
+                    or callable(self.scoring):
+                scorer_cache.append(
+                    check_scoring(self.estimator, self.scoring))
+            else:
+                from sklearn.metrics._scorer import (
+                    _MultimetricScorer, _check_multimetric_scoring)
+                sc = _check_multimetric_scoring(
+                    self.estimator, self.scoring)
+                if set(sc) != set(scorer_names):
+                    # compiled names must address the same cells the
+                    # host scorer produces; a mismatch cannot be
+                    # recovered into cv_results_
+                    raise RuntimeError(
+                        "host fallback scorer names "
+                        f"{sorted(sc)} do not match compiled names "
+                        f"{sorted(scorer_names)}")
+                scorer_cache.append(_MultimetricScorer(
+                    scorers=sc,
+                    raise_exc=(self.error_score == "raise")))
+        scorer = scorer_cache[0]
+        host_fit_params = ({"sample_weight": fit_weight}
+                           if fit_weight is not None else None)
+        host_score_params = ({"sample_weight": score_weight}
+                             if score_weight is not None else None)
+        results = []
+        for ci in cand_indices:
+            for tr_idx, te_idx in splits:
+                results.append(_fit_and_score(
+                    clone(self.estimator), X_host, y, scorer=scorer,
+                    train=tr_idx, test=te_idx, verbose=0,
+                    parameters=candidates[int(ci)],
+                    fit_params=host_fit_params,
+                    score_params=host_score_params,
+                    return_train_score=return_train,
+                    return_times=True,
+                    error_score=self.error_score))
+        _warn_or_raise_about_fit_failures(results, self.error_score)
+        n = len(cand_indices)
+        te = {s: np.empty((n, n_folds)) for s in scorer_names}
+        tr = ({s: np.empty((n, n_folds)) for s in scorer_names}
+              if return_train else {})
+        for t, res in enumerate(results):
+            i, f = divmod(t, n_folds)
+            ts = res["test_scores"]
+            if not isinstance(ts, dict):
+                ts = {s: ts for s in scorer_names}
+            for s in scorer_names:
+                te[s][i, f] = ts.get(s, np.nan)
+            if return_train:
+                trs = res.get("train_scores", {})
+                if not isinstance(trs, dict):
+                    trs = {s: trs for s in scorer_names}
+                for s in scorer_names:
+                    tr[s][i, f] = trs.get(s, np.nan)
+        return te, tr
+
+    def _put_search_blocks(self, metrics, config, family, *, plane,
+                           dp_before, pstore, ps_before, ledger, mem_before,
+                           hb_ctx):
+        """The search's data-plane, program-store, memory, heartbeat and
+        protection blocks of `search_report`."""
+        from spark_sklearn_tpu.obs import heartbeat as _heartbeat
+        from spark_sklearn_tpu.parallel import dataplane as _dataplane
+        from spark_sklearn_tpu.parallel import memledger as _memledger
+        from spark_sklearn_tpu.parallel import (
+            programstore as _programstore)
+        # this search's broadcast-cache traffic (hits = arrays
+        # reused with zero transfer; bytes_uploaded = cacheable
+        # bytes actually shipped; bytes_staged = per-chunk dyn
+        # params) — schema in obs.metrics.DATAPLANE_BLOCK_SCHEMA
+        mask_tiling = ("n/a" if not hasattr(family, "fit_task_batched")
+                       else "device" if plane is not None else "host")
+        metrics.put("dataplane", _dataplane.report_block(
+            plane, dp_before, mask_tiling=mask_tiling))
+        # this search's AOT-store traffic (hits = programs served
+        # from serialized artifacts with zero tracing; publishes =
+        # artifacts written for the next cold process) — schema in
+        # obs.metrics.PROGRAMSTORE_BLOCK_SCHEMA
+        metrics.put("programstore", _programstore.report_block(
+            pstore, ps_before))
+        # this search's device-memory view (modeled per-group
+        # footprints, budget/ceiling state, measured watermark) —
+        # schema in obs.metrics.MEMORY_BLOCK_SCHEMA.  Rendered
+        # ONLY when the ledger is on: off, the report shape is
+        # byte-identical to the pre-ledger engine.
+        if ledger is not None:
+            ledger.deactivate()
+            metrics.put("memory", _memledger.report_block(
+                ledger, mem_before,
+                getattr(self, "_memory_ctx", {}) or {}))
+        # this search's in-flight heartbeat view (beats/steps,
+        # cadence percentiles, staleness, overhead estimate) —
+        # schema in obs.metrics.HEARTBEAT_BLOCK_SCHEMA.  Rendered
+        # ONLY when heartbeat is on: off, the report shape is
+        # byte-identical to the beacon-less engine.
+        if hb_ctx is not None:
+            metrics.put("heartbeat", _heartbeat.heartbeat_block(
+                hb_ctx["scope"]))
+        # the search's protection verdict (deadline/shed/quarantine
+        # state) — schema in obs.metrics.PROTECTION_BLOCK_SCHEMA.
+        # Rendered ONLY when protection is on: off, the report is
+        # byte-identical to the unprotected engine.  A halving
+        # search re-puts each rung; the shared ctx accumulates, so
+        # the last put covers the whole search.
+        pctx_fin = getattr(self, "_protection_ctx", None)
+        if pctx_fin is not None:
+            metrics.put("protection", _faults.protection_block(
+                config, deadline_hit=pctx_fin["deadline_hit"],
+                shed=pctx_fin["shed"],
+                quarantined=pctx_fin["quarantined"],
+                elapsed_s=time.perf_counter()
+                - pctx_fin["t_start"]))
+
+    def _account_failed_fits(self, groups, candidates, preval_failed,
+                             fit_failed, scorer_names, test_scores,
+                             train_scores, fit_times, score_times,
+                             return_train):
+        """Failed fits' cells, times and warnings, sklearn's way."""
+        n_folds = fit_failed.shape[1]
         if preval_failed.any():
             # failed fits never ran: sklearn records 0.0 for their times
             fit_times[preval_failed, :] = 0.0
@@ -1905,14 +2311,14 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 # EVERY fit failed, even with a numeric error_score (the
                 # host tier inherits this from sklearn directly)
                 raise ValueError(
-                    f"\nAll the {n_cand * n_folds} fits failed.\n"
+                    f"\nAll the {fit_failed.size} fits failed.\n"
                     "It is very likely that your model is misconfigured.\n"
                     "You can try to debug the error by setting "
                     "error_score='raise'.")
             from sklearn.exceptions import FitFailedWarning
             warnings.warn(
                 f"\n{n_bad} fits failed out of a total of "
-                f"{n_cand * n_folds}.\nThe score on these train-test "
+                f"{fit_failed.size}.\nThe score on these train-test "
                 "partitions for these parameters will be set to "
                 f"{self.error_score}. (cause: non-finite model "
                 "parameters or hyperparameters)", FitFailedWarning)
@@ -1920,16 +2326,6 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                 test_scores[s][fit_failed] = self.error_score
                 if return_train:
                     train_scores[s][fit_failed] = self.error_score
-        # scorer_ keeps the sklearn-facing objects so .score() works the
-        # sklearn way even though CV scoring ran compiled
-        if self.scoring is None or isinstance(self.scoring, str):
-            scorer_attr = check_scoring(self.estimator, self.scoring)
-        else:
-            from sklearn.metrics._scorer import _check_multimetric_scoring
-            scorer_attr = _check_multimetric_scoring(
-                self.estimator, self.scoring)
-        return (test_scores, train_scores, fit_times, score_times,
-                scorer_names, scorer_attr)
 
     def _run_groups(self, *, groups, base_params, family, meta, scorers,
                     scorer_names, data_dev, fit_dev, test_dev, train_sc_dev,
@@ -1938,2295 +2334,29 @@ class BaseSearchTPU(CallbackSupportMixin, MetaEstimatorMixin, BaseEstimator):
                     max_cand_per_batch, n_folds, dtype, return_train,
                     test_scores, train_scores, fit_times, score_times, ckpt,
                     fit_failed, candidates, host_eval=None, data_fp=None):
-        """Chunked launch schedule, executed through the pipelined chunk
-        executor (parallel/pipeline.py).
-
-        Every chunk of every compile group becomes one (or, for the
-        calibration chunk, three) `LaunchItem`s: host staging of chunk
-        k+1, the result gather of chunk k-1, and the next compile
-        group's lowering/compile all overlap chunk k's device compute at
-        `config.pipeline_depth >= 1`; depth 0 runs the identical item
-        sequence synchronously (the bit-for-bit escape hatch).  Scores
-        are independent of the depth — only host work is reordered."""
-        from spark_sklearn_tpu.parallel.pipeline import (
-            ChunkPipeline, FuseSpec, LaunchItem, persistent_cache_counts)
-        from spark_sklearn_tpu.parallel.taskgrid import pad_chunk
-        from spark_sklearn_tpu.search.launch import (
-            LaunchResult, record_stats)
-
-        #: successive-halving rung owner (search/halving.py, via the
-        #: launch-ownership protocol): this call is one rung of a
-        #: multi-rung search.  Chunk ids carry the rung namespace,
-        #: geometry re-plans (or pins) the survivors' widths, and the
-        #: pipeline/registry/baselines are shared across rungs.
-        rung = _ownership.current_owner(self, kind="rung")
-        cid_ns = f"{rung.ns}:" if rung is not None else ""
-        # tiled-mask labels share the broadcast masks' rung namespace
-        # (see _fit_compiled_impl): the rung barrier's demote targets
-        # only the previous rung's buffers
-        mask_ns = (f"mask.{rung.ns}." if rung is not None
-                   and rung.resource == "n_samples" else "mask.")
-        tiled_label = mask_ns + "fit.tiled"
-        task_batched = hasattr(family, "fit_task_batched")
-        if config.n_data_shards > 1:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            tb_mask_shard = NamedSharding(
-                mesh, P(mesh_lib.TASK_AXIS, mesh_lib.DATA_AXIS))
-        else:
-            tb_mask_shard = task_shard
-        metrics = self._search_metrics
-        donate = bool(config.donate_chunk_buffers)
-        # the session-scoped device data plane (same instance the
-        # broadcast uploads went through) serves the task-batched mask
-        # tiling on device and the cached all-static pad operand; the
-        # staging ring double-buffers per-chunk dynamic params behind
-        # donate_chunk_buffers (pad_chunk writes into reused host
-        # buffers instead of allocating per chunk)
-        from spark_sklearn_tpu.parallel import dataplane as _dataplane
-        plane = _dataplane.plane_for(config)
-        # the device-memory ledger (parallel/memledger.py): the per-
-        # search accumulator was initialized by _fit_compiled_impl;
-        # this method models the per-group footprints once geometry
-        # resolves, caps planned widths to the HBM budget, and stamps
-        # modeled-vs-budget bytes onto OOM fault events
-        from spark_sklearn_tpu.parallel import memledger as _memledger
-        ledger = _memledger.ledger_for(config)
-        mem_ctx = getattr(self, "_memory_ctx", None) \
-            if ledger is not None else None
-        # the multi-tenant executor binding (serve/executor.py): set
-        # when this search was submitted to a TpuSession's
-        # SearchExecutor — its LaunchItems then route through the
-        # session's shared fair-share dispatch queue, and its plane
-        # uploads are charged to its tenant
-        from spark_sklearn_tpu import serve as _serve
-        binding = _serve.current_binding()
-        sched_tenant = binding.tenant if binding is not None else None
-        # self-protection (deadline shed / quarantine / degradation):
-        # None when protection is off — every guarded path below then
-        # collapses to the unprotected engine
-        pctx = getattr(self, "_protection_ctx", None)
-        # the score a protected search writes for work it never ran:
-        # sklearn's numeric error_score, or NaN under error_score=
-        # 'raise' (shed cells are DECLARED in the protection block,
-        # never routed through fit_failed — a deadline is not a failed
-        # fit, and must not trip the all-fits-failed raise)
-        errval = (np.nan if isinstance(self.error_score, str)
-                  else self.error_score)
-        best_effort = str(getattr(config, "partial_results", "raise")
-                          or "raise") == "best_effort"
-        # multi-controller runs force depth 0 below; resolved here so
-        # the staging ring can size itself to the in-flight window
-        depth = config.pipeline_depth if jax.process_count() == 1 else 0
-        ring = _dataplane.StagingRing(depth + 2) if donate else None
-        #: the fold masks' content digest, hashed once per search (the
-        #: plane's tiled-mask keys need it; fit_masks never mutates)
-        _fm_fp: List[str] = []
-
-        def fit_masks_fp():
-            if not _fm_fp:
-                _fm_fp.append(_dataplane.fingerprint(fit_masks))
-            return _fm_fp[0]
-
-        # score path: every registry scorer decomposes into model views
-        # (pred/decision/proba) + a metric core, so views are computed
-        # ONCE per launch over the flat task axis — for linear families
-        # one wide matmul for ALL (candidate x fold) tasks
-        # (`views_task_batched`) instead of a matvec per task per scorer
-        # — then the cheap reduction cores vmap over tasks.  Custom
-        # scorers without a core (family default_scorer like KMeans
-        # -inertia) keep the nested path.
-        import os as _os
-        # same boolean spelling as the other SST_* switches: "0"/"off"
-        # must NOT force the nested control arm
-        _nested_env = _os.environ.get(
-            "SST_NESTED_SCORE", "").strip().lower() in (
-                "1", "true", "on", "yes")
-        all_cores = all(hasattr(fn, "core") for fn in scorers.values()) \
-            and not getattr(config, "nested_score", False) \
-            and not _nested_env
-        needed_views = frozenset(
-            v for fn in scorers.values()
-            for v in getattr(fn, "views", ()))
-        # fused launch (default): fit + NaN-health + scoring in ONE
-        # compiled program per chunk — the model pytree stays on device.
-        # The FIRST live chunk of each multi-chunk group still runs as
-        # separate fit/score launches plus a warm calibration score
-        # launch that measures the steady-state score cost later fused
-        # chunks attribute out of their single-launch wall.
-        fused_mode = all_cores and config.fuse_fit_score
-        # device-resident chunk loop (chunk_loop="scan"): roll the
-        # compile group's chunk loop INTO the program via lax.scan so a
-        # whole scan segment — ideally the whole group, or a whole
-        # halving rung including its on-device top_k elimination —
-        # executes as ONE launch.  The scan body is the group's fused
-        # program, so scan requires the fused score path: a search that
-        # asks for scan without it (custom scorer on the nested path,
-        # fuse_fit_score=False) runs per-chunk and the chunkloop block
-        # records why.  Per-chunk stays the default and the
-        # resumable/faultable fallback.
-        from spark_sklearn_tpu.parallel.taskgrid import (
-            plan_scan_segments, resolve_chunk_loop)
-        chunk_loop = resolve_chunk_loop(config)
-        scan_mode = (chunk_loop == "scan") and fused_mode
-        cl_state = chunkloop_block(
-            metrics.struct("chunkloop"), mode=chunk_loop,
-            enabled=scan_mode,
-            score_attribution="folded" if scan_mode else "calibrated")
-        if chunk_loop == "scan" and not fused_mode:
-            cl_state["fallbacks"].append("unfused-score-path")
-        # shared-prefix search graphs (search/prefix.py): group the
-        # Pipeline grid's candidates by their transformer-chain digest,
-        # compute each DISTINCT prefix once per fold on device (stage
-        # 1, below, after geometry resolves), and fan the suffix
-        # candidates over the cached matrices through the ordinary
-        # chunk/scan machinery.  Ineligible searches run the atomic
-        # path unchanged and record the reason; prefix_reuse=False is
-        # the byte-identical escape hatch.
-        from spark_sklearn_tpu.search import prefix as _prefix
-        px_on = _prefix.resolve_prefix_reuse(config)
-        px_state = _prefix.prefix_block(
-            metrics.struct("prefix"),
-            mode="shared" if px_on else "atomic", enabled=False)
-        px_reason = None
-        if px_on:
-            px_reason = _prefix.prefix_fallback_reason(
-                family, all_cores=all_cores,
-                n_data_shards=int(config.n_data_shards),
-                x_dev=data_dev.get("X"))
-            if px_reason is None and plane is None:
-                # the derived-buffer cache IS the data plane; without
-                # it there is nowhere resident to fan suffixes over
-                px_reason = "dataplane-disabled"
-            if px_reason is None and data_fp is None:
-                px_reason = "no-x-fingerprint"
-            if px_reason is not None \
-                    and px_reason not in px_state["fallbacks"]:
-                px_state["fallbacks"].append(px_reason)
-        px_stage = px_on and px_reason is None
-        if scan_mode:
-            from jax import lax
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            # stacked per-chunk operands carry a leading scan-step axis;
-            # each step's slice keeps the per-chunk task sharding
-            scan_shard = NamedSharding(
-                mesh, P(None, mesh_lib.TASK_AXIS))
-            repl_shard = mesh_lib.replicated_sharding(mesh)
-        # in-flight heartbeats (obs/heartbeat.py): the scanned step body
-        # beacons (segment token, step index) through jax.debug.callback
-        # while the device is mid-launch, so progress/ETA and the
-        # heartbeat watchdog see liveness per scan step.  The scope was
-        # created by _fit_compiled_impl; hb_on gates EVERY heartbeat
-        # touch below, so off is an exact no-op (no callback traced —
-        # the "hb" cache-key component in build_scan keeps on/off
-        # programs from ever aliasing).
-        from spark_sklearn_tpu.obs import heartbeat as _heartbeat
-        _hb_ctx = getattr(self, "_hb_ctx", None)
-        hb_on = _hb_ctx is not None \
-            and _heartbeat.resolve_heartbeat(config)
-        hb_scope = _hb_ctx["scope"] if hb_on else ""
-        hb_handle = binding.handle.id \
-            if (hb_on and binding is not None) else ""
-        hb_tenant = binding.tenant \
-            if (hb_on and binding is not None) else ""
-        if hb_on:
-            # the geometry cost model's prior prices the ETA blend's
-            # model side: per scan step, one chunk's padded lanes at
-            # lane_cost_s plus the launch overhead amortized over the
-            # segment (config overrides win, like plan_geometry's)
-            from spark_sklearn_tpu.parallel.taskgrid import (
-                geometry_cost_model)
-            _cm_snap = geometry_cost_model().snapshot()
-            hb_overhead_s = getattr(config, "geometry_overhead_s", None)
-            if hb_overhead_s is None:
-                hb_overhead_s = float(
-                    _cm_snap.get("launch_overhead_s", 0.0))
-            hb_lane_cost_s = getattr(config, "geometry_lane_cost_s",
-                                     None)
-            if hb_lane_cost_s is None:
-                hb_lane_cost_s = float(_cm_snap.get("lane_cost_s", 0.0))
-        else:
-            hb_overhead_s = hb_lane_cost_s = 0.0
-        # cross-search launch fusion (serve/executor.py): steady-state
-        # fused chunks of an executor-submitted search offer a FuseSpec
-        # so same-program chunks from OTHER searches coalesce into one
-        # wide launch.  Donated buffers are excluded (a fused re-stage
-        # would read host rows a donated solo launch may have consumed),
-        # first-chunk fit/score/calibration items never fuse (they
-        # share cross-item group state), and scanned segments never
-        # fuse (one segment already serves many chunks; its lanes are
-        # billed to DRR by the member count instead).
-        fusion_on = (fused_mode and binding is not None and not donate
-                     and not scan_mode and _serve.resolve_fusion(config))
-        score_key = tuple(sorted(scorers.items()))
-        # deterministic identity parts for the persistent program store
-        # (parallel/programstore.py): everything in a store key must
-        # repr identically across processes, so the family OBJECT
-        # becomes its registry name, the mesh its topology, and the
-        # scorer closures their registry names (their implementations
-        # are pinned by the package version in the store's environment
-        # fingerprint).  Donated programs skip the store: the exported
-        # wrapper would silently drop the donation.
-        mesh_desc = ("mesh", tuple(sorted(dict(mesh.shape).items())),
-                     tuple(int(d.id)
-                           for d in np.asarray(mesh.devices).flat))
-        store_score_names = tuple(sorted(scorers))
-        store_sw_key = tuple(sorted(sw_blind))
-        # THIS search's store (None when its config doesn't enable one:
-        # a store-less search must never resolve programs through a
-        # store an earlier search in the process activated)
-        from spark_sklearn_tpu.parallel import programstore as _pstore
-        search_store = _pstore.activate_store(config)
-
-        # ------------------------------------------------------------------
-        # group plans: chunk geometry + (lazily built) programs
-        # ------------------------------------------------------------------
-        def reorder(group, order):
-            """The group's candidates in another order (cv_results_ is
-            written through candidate_indices, so its order stays)."""
-            group.candidate_indices = np.asarray(
-                group.candidate_indices)[order]
-            group.dynamic_params = {
-                k: np.asarray(v)[order]
-                for k, v in group.dynamic_params.items()}
-
-        with get_tracer().span("fit.plan", n_groups=len(groups)):
-            plans = []
-            for gi, group in enumerate(groups):
-                static = {**base_params, **group.static_params}
-                nc = group.n_candidates
-
-                # convergence-sorted chunking: a lockstep launch executes the
-                # MAX iteration count over its lanes, so one wide launch pays
-                # the slowest candidate's iterations for every lane.  When
-                # the family knows a difficulty proxy (e.g. GLM: larger C =
-                # weaker regularisation = slower convergence), sort the
-                # group's candidates by it and split into several narrower
-                # launches — all chunks of a group share ONE compiled program
-                # (uniform width), so this costs dispatches, not compiles,
-                # and easy launches early-exit at their own iteration count.
-                # cv_results_ order is unaffected (cells are written through
-                # candidate_indices).
-                sorted_chunks = False
-                proxy_hook = getattr(family, "convergence_proxy", None)
-                if proxy_hook is not None and config.sort_candidates:
-                    proxy = proxy_hook(group.dynamic_params, static)
-                    if proxy is not None:
-                        proxy = np.asarray(proxy)
-                        if len(proxy) >= getattr(
-                                family, "min_sort_candidates", 32) \
-                                and np.unique(proxy).size > 1:
-                            reorder(group,
-                                    np.argsort(proxy, kind="stable"))
-                            sorted_chunks = True
-
-                # a launch layout (Family.launch_layout): an order the
-                # family's task-batched launch can use — SVC's candidates
-                # kernel-major, so that those of one gamma share ONE kernel
-                # matrix — applied as the sort above is, and static facts
-                # that tell the launch so and key its program.  The facts
-                # hold for a launch made of whole runs of `run` candidates
-                # of that order: the group's own chunks (build_programs
-                # gives every other launch the static without them)
-                layout = None if sorted_chunks else family.launch_layout(
-                    group.dynamic_params, static, meta, n_folds)
-                static_plain, run = static, 1
-                if layout is not None:
-                    order, facts, run = layout
-                    reorder(group, order)
-                    static = {**static, **facts}
-
-                sorted_cap = None
-                if sorted_chunks:
-                    # ~8 difficulty-graded launches per group, or one a
-                    # run of equal proxies (bounded below by the
-                    # task-shard multiple so sharding stays uniform)
-                    sorted_cap = min(
-                        mesh_lib.pad_to_multiple(nc, n_task_shards),
-                        max_cand_per_batch,
-                        max(n_task_shards,
-                            mesh_lib.pad_to_multiple(
-                                _sorted_launch_width(
-                                    proxy, -(-nc // _SORTED_LAUNCHES)),
-                                n_task_shards)))
-                plans.append({
-                    "gi": gi, "group": group, "static": static, "nc": nc,
-                    "sorted": sorted_chunks, "sorted_cap": sorted_cap,
-                    "laid_out": layout is not None, "run": int(run),
-                    "static_plain": static_plain})
-
-            # per-group prefix digests (stage-1 grouping): groups map
-            # many-to-one onto digests — groups differing only in
-            # final-step statics share the digest, and therefore the
-            # cached transformed matrix
-            px_digests = [None] * len(plans)
-            if px_stage:
-                px_digests = _prefix.group_prefix_digests(
-                    groups, base_params, family)
-                if all(d is None for d in px_digests):
-                    px_stage = False
-                    px_state["fallbacks"].append("undigestable-prefix")
-            for plan, dg in zip(plans, px_digests):
-                plan["prefix"] = dg if px_stage else None
-
-            # --------------------------------------------------------------
-            # waste-aware launch geometry (parallel/taskgrid.plan_geometry):
-            # per-group chunk widths from power-of-two bucketing over the
-            # measured cost model, minimizing launch overhead + padding
-            # waste.  The chosen plan is pinned into the checkpoint journal
-            # so a resumed search replays the EXACT same chunk ids; a
-            # structurally different journalled geometry is a hard error,
-            # never a silent mix of chunk ids.
-            # --------------------------------------------------------------
-            from spark_sklearn_tpu.parallel.taskgrid import (
-                GeometryMismatchError, GeometryPlan, freeze,
-                geometry_cost_model, plan_geometry)
-            import dataclasses as _dc
-            # ledger-informed width ceiling: resident broadcast bytes (one
-            # count per distinct device buffer) plus each group's modeled
-            # per-candidate slope bound the widest chunk the HBM budget
-            # holds — a chunk the model says cannot fit is never planned,
-            # so OOM bisection becomes the fallback, not the discovery
-            # mechanism.  No budget (CPU default, or hbm_budget_bytes=0)
-            # means no caps: planning is bit-identical to the pre-ledger
-            # engine.
-            mem_caps = None
-            resident_est = 0
-            mem_kw = None
-            budget = 0
-            if ledger is not None:
-                seen_bufs = set()
-                for dev_arr in list(data_dev.values()) + [
-                        fit_dev, test_dev, train_sc_dev, test_unw_dev,
-                        train_unw_dev]:
-                    if id(dev_arr) in seen_bufs:
-                        continue
-                    seen_bufs.add(id(dev_arr))
-                    # leaf-wise so a BCOO data operand prices its
-                    # values+indices components (nnz-proportional; the
-                    # wrapper itself has no nbytes) — dense arrays are
-                    # their own single leaf, so this is the same number
-                    # the old getattr spelling produced
-                    for leaf in jax.tree_util.tree_leaves(dev_arr):
-                        resident_est += int(getattr(leaf, "nbytes", 0))
-                budget = int(mem_ctx.get("budget_bytes", 0)) \
-                    if mem_ctx is not None else 0
-            # what a family's launch works through a tile at a time, sized
-            # to what the budget leaves (Family.launch_tiles): facts of the
-            # group's static, laid out or not, so of every program's key
-            tiles_hook = getattr(family, "launch_tiles", None)
-            for p in plans if tiles_hook is not None else ():
-                tiles = tiles_hook(
-                    p["static"], meta, n_folds, p["nc"],
-                    n_samples=int(fit_masks.shape[1]), budget_bytes=budget,
-                    resident_bytes=resident_est, return_train=return_train)
-                if tiles:
-                    p["static"] = {**p["static"], **tiles}
-                    p["static_plain"] = {**p["static_plain"], **tiles}
-            if ledger is not None:
-                mem_kw = dict(
-                    task_batched=task_batched,
-                    n_samples=int(fit_masks.shape[1]),
-                    mask_itemsize=int(fit_masks.dtype.itemsize),
-                    n_scorers=len(scorers), return_train=return_train,
-                    dtype_itemsize=int(np.dtype(dtype).itemsize))
-
-                def group_mem_kw(plan, width=None):
-                    """`mem_kw` with what the family says a launch of
-                    this compile group holds besides its arguments (the
-                    kernel duals' Gram matrix and decision cache; an MLP
-                    lane's state, which follows the group's hidden
-                    widths) at `width`: a width that is no multiple of
-                    the group's launch layout's run gets the program
-                    without the layout's facts, and their price."""
-                    static = plan["static"]
-                    if width is not None and width % plan["run"]:
-                        static = plan["static_plain"]
-                    ws = family.launch_workspace(
-                        int(fit_masks.shape[1]), meta, n_folds,
-                        int(np.dtype(dtype).itemsize), static=static)
-                    return {**mem_kw, "workspace": ws} if ws else mem_kw
-
-                def group_cap(p, width=None):
-                    fp1 = _memledger.model_group_footprint(
-                        p["group"].dynamic_params, 1, n_folds,
-                        **group_mem_kw(p, width))
-                    return _memledger.width_cap(
-                        budget, resident_est + fp1.get("fixed_bytes", 0),
-                        fp1["per_candidate_bytes"], n_task_shards,
-                        max_cand_per_batch, ledger.safety_margin)
-
-                if budget:
-                    mem_caps = []
-                    for p in plans:
-                        cap = group_cap(p)
-                        if cap is not None and cap % p["run"]:
-                            # the widest whole runs under the cap, else
-                            # the cap of a launch without the layout
-                            step = math.lcm(p["run"], n_task_shards)
-                            cap = cap - cap % step or group_cap(p, cap)
-                        mem_caps.append(cap)
-            geo_kwargs = dict(
-                sizes=[p["nc"] for p in plans],
-                sorted_caps=[p["sorted_cap"] for p in plans],
-                n_folds=n_folds, n_task_shards=n_task_shards,
-                max_width=max_cand_per_batch,
-                mode=getattr(config, "geometry_mode", "auto"),
-                cost_model=geometry_cost_model(),
-                overhead_override=getattr(config, "geometry_overhead_s", None),
-                lane_cost_override=getattr(config, "geometry_lane_cost_s",
-                                           None),
-                width_caps=mem_caps,
-                # fleet-wide padding: under cross-search fusion a padded
-                # lane is fillable by a same-program peer, so it prices at
-                # half the solo waste; 0.0 keeps pre-fusion plans
-                # byte-identical
-                fusion_lane_discount=0.5 if fusion_on else 0.0,
-                # chunk widths are loop-mode-invariant (chunk ids must stay
-                # byte-identical across modes so journals and the per-chunk
-                # OOM fallback interoperate); the key field keeps the two
-                # modes' plans distinct cache residents all the same
-                chunk_loop=chunk_loop,
-                # per-group shared-prefix digests join the PlanKey: a
-                # prefix-staged plan (suffix programs over cached (F, n,
-                # d') matrices) must never alias an atomic plan with the
-                # same sizes in the plan cache or plans.json
-                prefix=[p["prefix"] for p in plans])
-            #: per-group structure identity ACROSS rungs: the static params
-            #: minus the budgeted resource (survivor groups at rung k+1
-            #: carry the same key as the rung-0 group they came from, even
-            #: when the resource itself is static for the family)
-            rung_keys = None
-            if rung is not None:
-                rung_keys = [
-                    freeze({k: v for k, v in p["static"].items()
-                            if k != rung.resource})
-                    for p in plans]
-            if rung is None or rung.itr == 0:
-                # the first rung (and every exhaustive search) prices the
-                # full grid exactly as before, plan-cache included
-                geo = plan_geometry(reuse=True, **geo_kwargs)
-            else:
-                # mid-search re-plan: the survivors' geometry is a
-                # search-local decision fed by the PREVIOUS rungs' measured
-                # timelines (the cost model observed each rung's pipeline
-                # on the way out), so it bypasses the cross-search plan
-                # cache.  With lane reclamation on, widths shrink to the
-                # surviving sizes — width-affine to already-compiled
-                # widths, priced by the model's measured compile wall;
-                # off, survivors stay pinned to rung-0 widths and ride
-                # along as padding (the A/B baseline).  Widths are pure
-                # geometry: cv_results_ is identical either way.
-                with get_tracer().span("geometry.replan", iter=rung.itr,
-                                       replan=bool(rung.replan)):
-                    if rung.replan:
-                        geo = plan_geometry(
-                            reuse=False, min_width=rung.min_rung_width,
-                            preferred=[rung.last_widths.get(k)
-                                       for k in rung_keys],
-                            **geo_kwargs)
-                        geo = _dc.replace(geo, source="halving-replan")
-                    else:
-                        geo = plan_geometry(reuse=False, **geo_kwargs)
-                        pinned = []
-                        for gg, k in zip(geo.groups, rung_keys):
-                            base_w = rung.base_widths.get(k)
-                            if base_w is not None \
-                                    and base_w % n_task_shards == 0 \
-                                    and base_w <= max_cand_per_batch:
-                                gg = _dc.replace(
-                                    gg, width=int(base_w),
-                                    n_chunks=-(-gg.n_candidates
-                                               // int(base_w)))
-                            pinned.append(gg)
-                        geo = _dc.replace(geo, groups=pinned,
-                                          source="halving-pinned")
-            # a laid-out group's chunks are whole runs only where its
-            # width is a multiple of the run: round the width up to
-            # whole runs of shard stripes where the bound and the cap
-            # allow, else down; under one run it stays, and its launch
-            # runs without the layout (`made_of_runs`)
-            whole = list(geo.groups)
-            for i, (p, gg) in enumerate(zip(plans, geo.groups)):
-                if gg.width % p["run"] == 0:
-                    continue
-                step = math.lcm(p["run"], n_task_shards)
-                w = mesh_lib.pad_to_multiple(min(gg.width, p["nc"]), step)
-                if w > min(max_cand_per_batch,
-                           (mem_caps or [None] * len(plans))[i] or w):
-                    w = gg.width - gg.width % step or gg.width
-                whole[i] = _dc.replace(gg, width=int(w),
-                                       n_chunks=-(-gg.n_candidates // w))
-            if whole != list(geo.groups):
-                geo = _dc.replace(geo, groups=whole)
-            if ckpt is not None:
-                journalled = ckpt.get_meta("geometry_plan")
-                if journalled is not None:
-                    jplan = GeometryPlan.from_dict(journalled)
-                    if jplan.signature() != geo.signature():
-                        raise GeometryMismatchError(
-                            "checkpoint was written under a different launch "
-                            "geometry (journalled per-group (n_candidates, "
-                            f"sorted) = {jplan.signature()}, current = "
-                            f"{geo.signature()}); resuming would mix chunk "
-                            "ids across geometries.  Delete "
-                            f"{ckpt.path!r} or restore the original "
-                            "sort_candidates/grid configuration.")
-                    # the journalled widths must still be valid under the
-                    # CURRENT mesh and HBM bound: every other width path
-                    # guarantees shard-multiple widths within
-                    # max_cand_per_batch, and replaying a stale plan would
-                    # silently break that (e.g. resumed on a smaller mesh,
-                    # or after lowering max_tasks_per_batch to dodge an OOM)
-                    bad = [g.width for g in jplan.groups
-                           if g.width % n_task_shards != 0
-                           or g.width > max_cand_per_batch]
-                    if bad:
-                        raise GeometryMismatchError(
-                            f"journalled chunk widths {bad} are invalid "
-                            f"under the current configuration (task shards="
-                            f"{n_task_shards}, max width per launch="
-                            f"{max_cand_per_batch}); the checkpoint was "
-                            "written on a different mesh or "
-                            "max_tasks_per_batch.  Delete "
-                            f"{ckpt.path!r} or restore the original "
-                            "configuration.")
-                    # replay: widths come from the journal, so chunk ids —
-                    # and therefore resume hits — match the original run
-                    # even if the cost model has since drifted
-                    import dataclasses as _dc
-                    geo = _dc.replace(jplan, source="journal")
-                else:
-                    ckpt.put_meta("geometry_plan", geo.to_dict())
-                # the prefix grouping journals beside the geometry: chunk
-                # results written under a prefix-staged run carry suffix
-                # semantics (same numbers, but per-group programs keyed on
-                # the digest), and a resume whose digests drifted — grid
-                # edited, step params changed, prefix_reuse toggled off —
-                # must fail loudly like any other geometry drift, never
-                # mix.  Atomic searches journal NO prefix meta (their
-                # checkpoint artifacts stay byte-compatible with the
-                # pre-prefix format and the prefix_reuse=False escape
-                # hatch), so an atomic checkpoint may resume under shared
-                # staging: the durable chunks are bit-exact either way and
-                # the meta then records the shared grouping going forward
-                px_cur = [p["prefix"] for p in plans]
-                px_journalled = ckpt.get_meta("prefix_plan")
-                if px_journalled is not None:
-                    if list(px_journalled) != list(px_cur):
-                        raise GeometryMismatchError(
-                            "checkpoint was written under a different "
-                            "shared-prefix grouping (journalled per-group "
-                            f"digests = {px_journalled}, current = "
-                            f"{px_cur}); resuming would mix prefix-staged "
-                            "and atomic chunk results.  Delete "
-                            f"{ckpt.path!r} or restore the original grid/"
-                            "prefix_reuse configuration.")
-                elif any(d is not None for d in px_cur):
-                    ckpt.put_meta("prefix_plan", px_cur)
-            metrics.put("geometry", geo.report_block())
-            if rung is not None:
-                # rung bookkeeping: remember rung-0 widths (the pin/affinity
-                # anchors) and account the lanes this rung's re-plan
-                # reclaimed vs. running the SAME survivors at rung-0 widths
-                for gg, k in zip(geo.groups, rung_keys):
-                    rung.base_widths.setdefault(k, int(gg.width))
-                    rung.last_widths[k] = int(gg.width)
-                rung_rec = rung.current
-                if rung_rec is not None:
-                    rung_rec["widths"] = [int(g.width) for g in geo.groups]
-                    rung_rec["n_launches_planned"] = int(
-                        sum(g.n_chunks for g in geo.groups))
-                    rung_rec["cost_observations"] = int(
-                        geo.cost_model.get("n_observations", 0))
-                    if rung.itr > 0:
-                        base_lanes = act_lanes = 0
-                        for gg, k in zip(geo.groups, rung_keys):
-                            bw = rung.base_widths.get(k, gg.width)
-                            base_lanes += (-(-gg.n_candidates // bw)) \
-                                * bw * n_folds
-                            act_lanes += gg.n_chunks * gg.width * n_folds
-                        reclaimed = max(0, base_lanes - act_lanes)
-                        rung_rec["lanes_reclaimed"] = int(reclaimed)
-                        rung_rec["padding_saved_frac"] = round(
-                            reclaimed / base_lanes, 6) if base_lanes else 0.0
-                        rung.lanes_reclaimed_total += int(reclaimed)
-
-            for plan, gg in zip(plans, geo.groups):
-                gi, nc = plan["gi"], plan["nc"]
-                sorted_chunks = plan["sorted"]
-                nc_batch = plan["nc_batch"] = int(gg.width)
-                # chunk resume state resolved up front: the calibration
-                # structure (which chunk calibrates, which chunks fuse) must
-                # be known before dispatch, not discovered mid-pipeline
-                chunks = []
-                for lo in range(0, nc, nc_batch):
-                    hi = min(lo + nc_batch, nc)
-                    # sorted chunks write cells through a PERMUTED index set:
-                    # a checkpoint from an unsorted run must not resume into
-                    # them (and vice versa), so the id carries the mode;
-                    # a family's launch layout permutes them too (":k").
-                    # Halving rungs prefix their namespace ("r2:...") so the
-                    # journal, fault events and trace stay rung-addressable
-                    # and supervisor bisection keys can never collide
-                    # across rungs
-                    chunk_id = cid_ns + f"{gi}:{lo}:{hi}" + \
-                        (":s" if sorted_chunks else
-                         ":k" if plan["laid_out"] else "")
-                    rec = ckpt.get(chunk_id) if ckpt is not None else None
-                    if rec is not None and return_train and \
-                            rec.get("train") is None:
-                        rec = None  # written without train scores: recompute
-                    chunks.append((lo, hi, chunk_id, rec))
-                plan["chunks"] = chunks
-                plan["n_live"] = sum(1 for c in chunks if c[3] is None)
-
-            if ledger is not None and mem_ctx is not None:
-                # register every (group, chosen width) footprint with the
-                # ledger — the per-group records search_report["memory"]
-                # renders, the memory.footprint trace instants
-                # trace_summary digests, and the modeled bytes OOM events
-                # report against the budget
-                mem_ctx["resident_bytes"] = resident_est
-                for plan, gg in zip(plans, geo.groups):
-                    fp = _memledger.model_group_footprint(
-                        plan["group"].dynamic_params, plan["nc_batch"],
-                        n_folds, **group_mem_kw(plan, plan["nc_batch"]))
-                    rec = {"group": cid_ns + str(plan["gi"]),
-                           "width": int(plan["nc_batch"]),
-                           "capped": bool(getattr(gg, "capped", False)),
-                           "resident_bytes": int(resident_est), **fp}
-                    plan["mem_chunk_bytes"] = int(fp["chunk_bytes"])
-                    ledger.note_group(rec)
-                    mem_ctx["groups"].append(rec)
-
-        def plan_data(plan):
-            """The launch data dict: prefix-staged plans swap the raw
-            X for their cached per-fold transformed matrices
-            (``data_d["X_folds"]``, (F, n, d')); atomic plans share
-            the search-wide broadcast dict."""
-            return plan.get("data_dev") or data_dev
-
-        # ------------------------------------------------------------------
-        # stage 1 — shared-prefix compute (search/prefix.py): one
-        # launch per DISTINCT transformer-chain digest, vectorized
-        # over folds, with the stacked (F, n, d') matrix cached in the
-        # DataPlane as a derived buffer (tenant-charged, labelled with
-        # the rung namespace so halving's barrier can demote retired
-        # rungs' matrices).  Completion is journaled with a durable
-        # npz payload, so kill-resume re-UPLOADS a finished prefix
-        # instead of recomputing it.  Digests with no live chunks are
-        # skipped entirely — a fully-journaled rung replays without
-        # touching the device.
-        # ------------------------------------------------------------------
-        px_label = (f"prefix.{rung.ns}." if rung is not None
-                    and rung.resource == "n_samples" else "prefix.")
-        if px_stage:
-            from spark_sklearn_tpu.utils import checkpoint as _ckpt_mod
-            t_px0 = time.perf_counter()
-            distinct = {}
-            for plan in plans:
-                if plan["prefix"] is not None and plan["n_live"] > 0:
-                    distinct.setdefault(plan["prefix"],
-                                        []).append(plan)
-            x_sharding = getattr(data_dev["X"], "sharding", None)
-            base_no_x = {k: v for k, v in data_dev.items()
-                         if k != "X"}
-            n_computed = n_resumed = n_reused = 0
-            px_bytes = 0
-            ck_dir = (_os.path.dirname(ckpt.path)
-                      if ckpt is not None else None)
-            with get_tracer().span("prefix.stage",
-                                   n_distinct=len(distinct)):
-                for dg, dplans in distinct.items():
-                    rep = dplans[0]
-
-                    def _build(_s=rep["static"]):
-                        return jax.jit(
-                            lambda data_d, w_f:
-                            family.prefix_transform(_s, data_d, w_f))
-
-                    # keyed on the DIGEST, not the group statics: two
-                    # groups differing only in final-step params share
-                    # one compiled transform
-                    tf_jit = _cached_program(
-                        ("prefix", family, dg, meta, mesh), _build,
-                        check_fields={"prefix_digest": dg})
-                    aval = jax.eval_shape(tf_jit, data_dev, fit_dev)
-                    nbytes = (int(np.prod(aval.shape))
-                              * np.dtype(aval.dtype).itemsize)
-                    key_parts = (dg, fit_masks_fp(), data_fp,
-                                 _dataplane._sharding_key(x_sharding))
-                    kp_fp = _ckpt_mod.fingerprint(*key_parts)
-                    npz_path = (_os.path.join(ck_dir,
-                                              f"prefix_{kp_fp}")
-                                if ck_dir is not None else None)
-                    ck_meta = (ckpt.get_meta(f"prefix:{kp_fp}")
-                               if ckpt is not None else None)
-                    how = {}
-
-                    def maker(_ckm=ck_meta, _jit=tf_jit,
-                              _path=npz_path, _how=how):
-                        if _ckm is not None and _path is not None:
-                            try:
-                                host = _ckpt_mod.load_pytree(_path)
-                                _how["src"] = "resumed"
-                                return _dataplane.upload(
-                                    np.asarray(host), x_sharding,
-                                    label=px_label + "xt")
-                            # a journal meta whose npz payload is
-                            # missing/torn (killed mid-write) is not
-                            # an error: the recompute below is
-                            # bit-exact with what the payload held
-                            # sstlint: disable=swallowed-exception
-                            except Exception:
-                                _how.pop("src", None)
-                        _how["src"] = "computed"
-                        return _jit(data_dev, fit_dev)
-
-                    xt_dev, cache_hit = plane.derived(
-                        key_parts, maker, nbytes,
-                        label=px_label + "xt", tenant=sched_tenant)
-                    if cache_hit:
-                        n_reused += 1
-                    elif how.get("src") == "resumed":
-                        n_resumed += 1
-                    else:
-                        n_computed += 1
-                        jax.block_until_ready(xt_dev)
-                        if ckpt is not None and npz_path is not None:
-                            _ckpt_mod.save_pytree(
-                                npz_path, np.asarray(xt_dev))
-                            ckpt.put_meta(f"prefix:{kp_fp}",
-                                          {"path": npz_path})
-                    px_bytes += nbytes
-                    for p in dplans:
-                        p["data_dev"] = {**base_no_x,
-                                         "X_folds": xt_dev}
-            px_state["enabled"] = True
-            n_cand_px = sum(p["nc"] for ps in distinct.values()
-                            for p in ps)
-            px_state["n_candidates_total"] += n_cand_px
-            px_state["n_prefixes_distinct"] += len(distinct)
-            px_state["n_prefix_launches"] += n_computed
-            px_state["n_prefix_reused"] += n_reused
-            px_state["n_prefix_resumed"] += n_resumed
-            px_state["recompute_saved"] += max(
-                0, n_cand_px - n_computed)
-            px_state["bytes_cached"] += px_bytes
-            px_state["prefix_wall_s"] += round(
-                time.perf_counter() - t_px0, 6)
-
-        def build_programs(plan, width=None, whole_runs=True):
-            """The group's jitted programs (cross-search cached); built
-            on first need so fully-resumed groups never trace.  `width`
-            overrides the group's uniform chunk width — the supervisor's
-            OOM bisection relaunches at half width, which is a distinct
-            compiled program.  `whole_runs` False: the launch's rows are
-            not whole runs of the group's launch layout (`made_of_runs`
-            below), so its program is built from the static without the
-            layout's facts — the one a group without a layout runs."""
-            nc_batch = width or plan["nc_batch"]
-            plain = not whole_runs and plan["laid_out"]
-            cache = plan.setdefault("progs_by_width", {})
-            progs = cache.get((nc_batch, plain))
-            if progs is not None:
-                return progs
-            static = plan["static_plain"] if plain else plan["static"]
-            donate_kw = {"donate_argnums": (0,)} if donate else {}
-            # prefix-staged plans fit/score the SUFFIX family over the
-            # cached per-fold matrices (data_d["X_folds"][fold]); the
-            # digest joins every cache/store key below so suffix
-            # programs — traced on transformed shapes — never alias
-            # the atomic pipeline's programs
-            px = plan.get("prefix")
-            suffix_fam = family.suffix_family() if px else None
-
-            def _fold_data(data_d, Xf):
-                return {**{k: v for k, v in data_d.items()
-                           if k != "X_folds"}, "X": Xf}
-
-            if task_batched:
-                # flatten (candidate x fold) into one leading task axis and
-                # let the family turn it into wide-matmul width (candidate-
-                # major order: task t = (cand t//n_folds, fold t%n_folds))
-
-                def fit_batch_tb(dyn_t, data_d, w_t,
-                                 static={**static, "__n_folds__": n_folds,
-                                         "__bf16__": config.bf16_matmul}):
-                    with jax.named_scope("sst.fit"):
-                        model = family.fit_task_batched(
-                            dyn_t, static, data_d, w_t, meta)
-                        return jax.tree_util.tree_map(
-                            lambda l: l.reshape(
-                                (nc_batch, n_folds) + l.shape[1:]), model)
-
-            def fit_batch(dyn_arrs, data_d, train_m, static=static):
-                def one_cand(dyn_scalars):
-                    if px:
-                        # suffix fit: fold f consumes its own cached
-                        # transformed matrix — same ops, same order as
-                        # the fused inline transform (bit-exact by
-                        # construction, pinned by test_prefix.py)
-                        def one_fold_px(w, Xf):
-                            return suffix_fam.fit(
-                                dyn_scalars, static,
-                                _fold_data(data_d, Xf), w, meta)
-                        return jax.vmap(one_fold_px)(
-                            train_m, data_d["X_folds"])
-
-                    def one_fold(w):
-                        return family.fit(dyn_scalars, static, data_d, w,
-                                          meta)
-                    return jax.vmap(one_fold)(train_m)
-                with jax.named_scope("sst.fit"):
-                    # the candidate axis has a name, so that a family's
-                    # lockstep loop can ask whether ANY candidate still
-                    # runs (models/base.py::any_candidate)
-                    return jax.vmap(one_cand,
-                                    axis_name=CANDIDATE_AXIS)(dyn_arrs)
-
-            def with_stats(fit_fn):
-                # the unfused fit launch's program: the models and, from
-                # the hook the fused body calls, the launch's stats
-                @functools.wraps(fit_fn)    # the program keeps its name
-                def fit_program(*args):
-                    models = fit_fn(*args)
-                    return models, family.launch_stats(models, static,
-                                                       meta)
-                return fit_program
-
-            def score_batch_wide(models, data_d, test_m, train_m, test_u,
-                                 train_u, static=static):
-                leaf = jax.tree_util.tree_leaves(models)[0]
-                ncb, nf = leaf.shape[0], leaf.shape[1]
-                n_tasks = ncb * nf
-                flat = jax.tree_util.tree_map(
-                    lambda l: l.reshape((n_tasks,) + l.shape[2:]), models)
-                views = {}
-                if px:
-                    # suffix views: fold f's models score on fold f's
-                    # cached matrix, candidates over folds as the fit
-                    # vmaps them.  (Flat over tasks with a gather
-                    # X_folds[t % nf] a task, XLA:TPU materialized the
-                    # (T, n, d') operand it was meant to fuse away:
-                    # 6.6 GB at 60 tasks of 70 000 x 784; compiled for a
-                    # v5e the launch's scratch is 7.17 GB that way and
-                    # 0.04 GB this way, PERF.md, PR 33.)
-                    xf = data_d["X_folds"]
-                    for name in needed_views:
-                        views[name] = jax.tree_util.tree_map(
-                            lambda v: v.reshape((n_tasks,) + v.shape[2:]),
-                            jax.vmap(lambda m_c, name=name: jax.vmap(
-                                lambda m, Xf: build_view(
-                                    name, suffix_fam, m, static,
-                                    _fold_data(data_d, Xf), meta))(
-                                        m_c, xf))(models))
-                else:
-                    wide = getattr(family, "views_task_batched", None)
-                    if wide is not None:
-                        views = dict(wide(flat, static, data_d, meta,
-                                          needed_views))
-                    for name in needed_views:
-                        if name not in views:
-                            views[name] = jax.vmap(
-                                lambda m, name=name: build_view(
-                                    name, family, m, static, data_d,
-                                    meta))(flat)
-
-                y = data_d.get("y")
-                # fold masks are indexed per task (t % n_folds,
-                # candidate-major flattening) instead of tiled to (T, n):
-                # the gather fuses into the reduction cores, where a tile
-                # would materialize ncb copies of every mask buffer
-                fold_idx = jnp.arange(n_tasks, dtype=jnp.int32) % nf
-
-                def one_task(view_t, fi):
-                    wte, wtr = test_m[fi], train_m[fi]
-                    wteu, wtru = test_u[fi], train_u[fi]
-                    te = {s: fn.core(view_t, y,
-                                     wteu if s in sw_blind else wte, meta)
-                          for s, fn in scorers.items()}
-                    tr = ({s: fn.core(view_t, y,
-                                      wtru if s in sw_blind else wtr, meta)
-                           for s, fn in scorers.items()}
-                          if return_train else {})
-                    return te, tr
-
-                te, tr = jax.vmap(one_task)(views, fold_idx)
-                return (jax.tree_util.tree_map(
-                            lambda a: a.reshape(ncb, nf), te),
-                        jax.tree_util.tree_map(
-                            lambda a: a.reshape(ncb, nf), tr))
-
-            def score_batch_nested(models, data_d, test_m, train_m, test_u,
-                                   train_u, static=static):
-                def one_cand(model_c):
-                    def one_fold(model, w_test, w_train, w_test_u,
-                                 w_train_u):
-                        te = {s: fn(family, model, static, data_d, meta,
-                                    w_test_u if s in sw_blind else w_test)
-                              for s, fn in scorers.items()}
-                        tr = ({s: fn(family, model, static, data_d, meta,
-                                     w_train_u if s in sw_blind
-                                     else w_train)
-                               for s, fn in scorers.items()}
-                              if return_train else {})
-                        return te, tr
-                    return jax.vmap(one_fold)(
-                        model_c, test_m, train_m, test_u, train_u)
-                return jax.vmap(one_cand)(models)
-
-            score_core = score_batch_wide if all_cores \
-                else score_batch_nested
-
-            @functools.wraps(score_core)    # the program keeps its name
-            def score_batch(*args):
-                with jax.named_scope("sst.score"):
-                    return score_core(*args)
-
-            fused_jit = None
-            if fused_mode:
-                fit_core = fit_batch_tb if task_batched else fit_batch
-
-                def fused_batch(dyn_t, data_d, w_fit, test_m, train_m,
-                                test_u, train_u):
-                    models = fit_core(dyn_t, data_d, w_fit)
-                    with jax.named_scope("sst.score"):
-                        bad = _models_health(models)
-                        if bad is None:
-                            leaf = jax.tree_util.tree_leaves(models)[0]
-                            bad = jnp.zeros(leaf.shape[:2], bool)
-                        # what the family reports of the launch: named
-                        # int32 arrays the engine carries without reading
-                        stats = family.launch_stats(models, static, meta)
-                        te, tr = score_batch_wide(models, data_d, test_m,
-                                                  train_m, test_u, train_u)
-                    return LaunchResult(te, tr, bad, stats)
-
-                fused_jit = _cached_program(
-                    ("fused", family, static, meta, nc_batch, n_folds,
-                     bool(config.bf16_matmul), mesh, score_key,
-                     return_train, sw_blind, donate, px),
-                    lambda: jax.jit(fused_batch, **donate_kw),
-                    store_parts=None if donate else (
-                        "fused", family.name, static, meta, nc_batch,
-                        n_folds, bool(config.bf16_matmul), mesh_desc,
-                        store_score_names, store_sw_key, return_train,
-                        px),
-                    store=search_store,
-                    check_fields={
-                        "bf16_matmul": bool(config.bf16_matmul),
-                        "donate_chunk_buffers": donate,
-                        "mesh": mesh_desc})
-            # separate fit/score programs: the non-fused path runs them
-            # for every chunk; the fused path runs them for each group's
-            # first live chunk to calibrate the score share that splits
-            # later fused walls (sklearn's fit/score time columns must
-            # never be a silent 0.0 — VERDICT r4 next #4).  jax.jit is
-            # lazy, so a program a search never calls is never traced or
-            # compiled.
-            if task_batched:
-                # the mesh joins the in-memory key exactly as
-                # mesh_desc joins the store key (declared-vs-actual
-                # drift from the pre-store key path: every other
-                # program key already carries it, and a same-shape
-                # search on a re-built mesh must not reuse a program
-                # whose store proxy was keyed to the old one)
-                fit_jit = _cached_program(
-                    ("fit_tb", family, static, meta, nc_batch, n_folds,
-                     bool(config.bf16_matmul), donate, mesh),
-                    lambda: jax.jit(with_stats(fit_batch_tb), **donate_kw),
-                    store_parts=None if donate else (
-                        "fit_tb", family.name, static, meta, nc_batch,
-                        n_folds, bool(config.bf16_matmul), mesh_desc),
-                    store=search_store,
-                    check_fields={
-                        "bf16_matmul": bool(config.bf16_matmul),
-                        "donate_chunk_buffers": donate,
-                        "mesh": mesh_desc})
-            else:
-                fit_jit = _cached_program(
-                    ("fit", family, static, meta, mesh, donate, px),
-                    lambda: jax.jit(with_stats(fit_batch),
-                                    out_shardings=(task_shard, None),
-                                    **donate_kw),
-                    store_parts=None if donate else (
-                        "fit", family.name, static, meta, mesh_desc,
-                        px),
-                    store=search_store,
-                    check_fields={
-                        "donate_chunk_buffers": donate,
-                        "mesh": mesh_desc})
-            # mesh in the in-memory key for the same reason as fit_tb
-            # above: the store key always carried mesh_desc, the
-            # pre-store in-memory key never did
-            score_jit = _cached_program(
-                ("score", family, static, meta, score_key, return_train,
-                 sw_blind, bool(all_cores), px, mesh),
-                lambda: jax.jit(score_batch),
-                store_parts=("score", family.name, static, meta,
-                             mesh_desc, store_score_names, store_sw_key,
-                             return_train, bool(all_cores), px),
-                store=search_store,
-                check_fields={"mesh": mesh_desc})
-            progs = {"fit": fit_jit, "score": score_jit,
-                     "fused": fused_jit,
-                     # the raw (un-jitted) fused body: the scan program
-                     # below wraps it as its lax.scan step function
-                     "fused_body": fused_batch if fused_mode else None}
-            cache[(nc_batch, plain)] = progs
-            return progs
-
-        def made_of_runs(plan, lo, *sizes):
-            """Whether rows cut from the group's candidates at `lo` in
-            consecutive pieces of `sizes` are whole runs of its launch
-            layout (every group's own chunks are, or their width is no
-            multiple of the run and the family's launch sees that; a
-            bisected range and a fuse's seam need not be)."""
-            return all(n % plan["run"] == 0 for n in (lo,) + sizes)
-
-        def build_scan(plan, n_steps, topk_k=0, hb=False):
-            """ONE jitted program executing `n_steps` chunks of the
-            group as a `lax.scan` over the stacked chunk axis — the
-            melted launch boundary.  The step function is the group's
-            fused body, so every lane computes exactly what its solo
-            fused launch would (scan carries no cross-lane state into
-            the step), and XLA's loop buffer aliasing keeps ONE set of
-            model/score working buffers live across steps — the donated
-            carry the per-chunk path only gets via donate_chunk_buffers.
-
-            `topk_k > 0` additionally folds the halving rung's
-            elimination on device: a score carry (one row per group
-            candidate position plus a dump row for padded lanes)
-            accumulates each chunk's first-scorer test scores, and the
-            program returns the top-k candidate POSITIONS mirroring
-            sklearn's `_top_k` (ascending mean with NaNs rolled to the
-            front) — rung N+1's candidate set never round-trips scores
-            to host.
-
-            `hb=True` threads the heartbeat beacon into the step body:
-            the step index rides the scan xs and a jax.debug.callback
-            emits (token, step) to the HeartbeatHub while the device is
-            mid-launch.  The token is a RUNTIME operand (never baked
-            into the trace), so ONE compiled program serves every
-            search's segments; the flag joins the cache key below so
-            on/off programs never alias, and off leaves the key (and
-            the traced program) byte-identical to the beacon-less one.
-            """
-            cache = plan.setdefault("scan_progs", {})
-            ck = (int(n_steps), int(topk_k)) + (("hb",) if hb else ())
-            prog = cache.get(ck)
-            if prog is not None:
-                return prog
-            fused_body = build_programs(plan)["fused_body"]
-            nc = int(plan["nc"])
-            donate_kw = {"donate_argnums": (0,)} if donate else {}
-            score0 = scorer_names[0]
-
-            def scan_batch(dyn_st, idx_st, data_d, w_fit, test_m,
-                           train_m, test_u, train_u, hb_tok=None):
-                if topk_k:
-                    carry0 = jnp.full((nc + 1, n_folds),
-                                      jnp.float32(errval))
-                else:
-                    carry0 = jnp.zeros((), jnp.float32)
-
-                def step(carry, xs):
-                    if hb:
-                        dyn_c, idx_c, step_i = xs
-                    else:
-                        dyn_c, idx_c = xs
-                    res = fused_body(
-                        dyn_c, data_d, w_fit, test_m, train_m,
-                        test_u, train_u)
-                    if hb:
-                        # in-flight beat: fires on jax's callback
-                        # thread as each scan step executes; unordered
-                        # (no token threading cost) — the hub takes
-                        # the max step either way
-                        jax.debug.callback(
-                            _heartbeat.device_beat, hb_tok, step_i,
-                            ordered=False)
-                    if topk_k:
-                        # mirror the host-side error_score substitution
-                        # BEFORE the mean, so the device ranking sees
-                        # the same scores sklearn's _top_k would
-                        sc = jnp.where(
-                            res.bad, jnp.float32(errval),
-                            res.test[score0].astype(jnp.float32))
-                        carry = carry.at[idx_c].set(sc)
-                    return carry, res
-
-                xs = (dyn_st, idx_st)
-                if hb:
-                    xs = xs + (jnp.arange(n_steps, dtype=jnp.int32),)
-                carry, ys = lax.scan(step, carry0, xs)
-                if topk_k:
-                    mean = carry[:nc].mean(axis=1)
-                    order = jnp.roll(jnp.argsort(mean),
-                                     jnp.count_nonzero(jnp.isnan(mean)))
-                    surv = order[-topk_k:].astype(jnp.int32)
-                else:
-                    surv = jnp.zeros((0,), jnp.int32)
-                return ys, surv
-
-            # the nan error_score (the default) breaks dict-key
-            # equality (nan != nan), so the key carries its repr; scan
-            # programs skip the persistent program store — the
-            # exported-wrapper path has no scan coverage yet, and a
-            # store-warm process still skips the python->HLO walk via
-            # this cache
-            # the beacon's presence joins the cache key ONLY when on:
-            # the off-state tuple is byte-identical to the beacon-less
-            # engine's, and on/off programs can never alias (a cached
-            # beacon-less program must not serve a heartbeat fit)
-            scan_jit = _cached_program(
-                ("scan", family, plan["static"], meta, plan["nc_batch"],
-                 n_folds, int(n_steps), bool(config.bf16_matmul), mesh,
-                 score_key, return_train, sw_blind, donate,
-                 int(topk_k), nc, repr(float(errval)),
-                 plan.get("prefix"))
-                + (("hb",) if hb else ()),
-                lambda: jax.jit(scan_batch, **donate_kw),
-                store_parts=None,
-                check_fields={
-                    "bf16_matmul": bool(config.bf16_matmul),
-                    "donate_chunk_buffers": donate,
-                    "heartbeat": bool(hb),
-                    "mesh": mesh_desc})
-            cache[ck] = scan_jit
-            return scan_jit
-
-        def group_masks(plan, width=None):
-            """The fit-mask device buffer of a launch of the group.
-            Task-batched families consume the fold masks tiled to the
-            launch width — under the data plane a cached ON-DEVICE
-            broadcast of the resident base masks (reused across groups
-            sharing a width, OOM relaunches and later searches); the
-            legacy path host-tiles.  At the group's own width (`width`
-            None) the buffer is memoized per plan: re-hashing the mask
-            array every chunk would put serial host work back on the
-            stage thread."""
-            if not task_batched:
-                return fit_dev
-            if width is None and plan.get("w_task_dev") is not None:
-                return plan["w_task_dev"]
-            lanes_w = width or plan["nc_batch"]
-            if plane is not None:
-                w = plane.tiled(
-                    fit_masks, fit_dev, lanes_w, tb_mask_shard,
-                    label=tiled_label, fp=fit_masks_fp(),
-                    tenant=sched_tenant)
-            else:
-                w = _dataplane.upload(
-                    np.tile(fit_masks, (lanes_w, 1)),
-                    tb_mask_shard, label=tiled_label)
-            if width is None:
-                plan["w_task_dev"] = w
-            return w
-
-        def stage_operands(plan, arrays, lo, hi, width, label, ring=None):
-            """What a launch of `width` candidates (None: the group's
-            own width) takes: rows [lo, hi) of every dynamic parameter in
-            `arrays` padded to the width and uploaded under `label`, the
-            `_pad` operand of an all-static group, and the fit masks at
-            that width.  Shared by the per-chunk stage, the bisection's
-            relaunch and the cross-search fused launch."""
-            mask_width, width = width, width or plan["nc_batch"]
-            repeat = n_folds if task_batched else 1
-            dyn = {}
-            for k, arr in arrays.items():
-                # donate mode: pad into a reused host buffer (the slot
-                # blocks on its previous consumer before reuse)
-                slot = None if ring is None else ring.slot(
-                    (plan["gi"], k), (width * repeat,) + arr.shape[1:],
-                    arr.dtype)
-                dyn[k] = _dataplane.upload(
-                    pad_chunk(arr, lo, hi, width, repeat,
-                              out=None if slot is None else slot.array),
-                    task_shard, label=label)
-                if slot is not None:
-                    slot.commit(dyn[k])
-            if not dyn and not task_batched:
-                # all-static group: vmap still needs a batched operand
-                # to define the candidate axis (families ignore unknown
-                # keys).  The plane caches the zeros — except under
-                # donation, where the launch would invalidate them
-                dyn["_pad"] = (
-                    plane.zeros(width, dtype, task_shard,
-                                tenant=sched_tenant)
-                    if plane is not None and not donate else
-                    _dataplane.upload(np.zeros(width, dtype=dtype),
-                                      task_shard, label="dyn.pad"))
-            return dyn, group_masks(plan, mask_width)
-
-        #: the scoring masks every score, fused and scan program takes
-        score_ops = (test_dev, train_sc_dev, test_unw_dev, train_unw_dev)
-
-        #: guards the per-plan staged-chunk bookkeeping: stage normally
-        #: runs on the single stage thread, but supervisor retries
-        #: re-stage on whichever thread is recovering
-        stage_lock = named_lock("grid.stage_lock")
-
-        def note_staged(plan, chunk_ids):
-            """Once the group's last live chunk has staged, drop the
-            plan's tiled-mask reference (each payload keeps its own) so
-            one group's masks never outlive its launches.  A set of ids
-            under a lock: a transient retry re-stages on the recovering
-            thread and must not count twice."""
-            with stage_lock:
-                done = plan.setdefault("staged_ids", set())
-                done.update(chunk_ids)
-                if len(done) >= plan["n_live"]:
-                    plan.pop("w_task_dev", None)
-
-        cache0 = persistent_cache_counts()
-        builds0 = _program_build_count()
-        if rung is not None:
-            # whole-search baselines: the final pipeline block's
-            # n_compiles / persistent-cache deltas cover every rung
-            if rung.cache0 is None:
-                rung.cache0, rung.builds0 = cache0, builds0
-            cache0, builds0 = rung.cache0, rung.builds0
-        # multi-controller runs gather through process_allgather — a
-        # cross-process COLLECTIVE.  Issuing collectives from background
-        # threads would need every process to interleave them in the
-        # same order as its peers; the synchronous schedule guarantees
-        # that, the pipelined one does not — so multihost forces depth 0
-        # (`depth` was resolved with the data-plane setup above)
-        if rung is not None and rung.pipeline is not None:
-            # rung barrier = drain + re-stage: the rungs of one halving
-            # search share ONE pipeline (run() accumulates the timeline
-            # and wall), so its compile thread stays warm and the final
-            # report covers the whole search.  The previous rung's
-            # close was a drain() — no straggler AOT job outlives its
-            # rung's jax config.
-            pipe = rung.pipeline
-        else:
-            pipe = ChunkPipeline(depth, verbose=self.verbose,
-                                 heartbeat=hb_on)
-            if rung is not None:
-                rung.pipeline = pipe
-
-        def submit_precompile(plan):
-            """AOT-lower/compile the group's fused program on the compile
-            thread so the group boundary does not stall the device.  The
-            executable is bit-identical to the jit path (same jaxpr, same
-            compile options); failure here only means the jit path
-            compiles at first dispatch, as it always did."""
-            if plan.get("aot_submitted") or pipe.depth == 0 \
-                    or not fused_mode or scan_mode \
-                    or plan["n_live"] < 2:
-                # scan mode has no per-chunk fused dispatch to warm:
-                # its program compiles once at the segment launch
-                return
-            plan["aot_submitted"] = True
-            try:
-                progs = build_programs(plan)
-                nc_batch = plan["nc_batch"]
-                lanes = nc_batch * n_folds
-                dyn_spec = {}
-                for k, arr in plan["group"].dynamic_params.items():
-                    shape = ((lanes,) if task_batched
-                             else (nc_batch,)) + arr.shape[1:]
-                    dyn_spec[k] = jax.ShapeDtypeStruct(
-                        shape, arr.dtype, sharding=task_shard)
-                if not dyn_spec and not task_batched:
-                    dyn_spec["_pad"] = jax.ShapeDtypeStruct(
-                        (nc_batch,), dtype, sharding=task_shard)
-                if task_batched:
-                    w_spec = jax.ShapeDtypeStruct(
-                        (lanes,) + fit_masks.shape[1:],
-                        fit_masks.dtype, sharding=tb_mask_shard)
-                else:
-                    w_spec = fit_dev
-                plan["aot_future"] = pipe.submit_precompile(
-                    progs["fused"], dyn_spec, plan_data(plan), w_spec,
-                    *score_ops, label=f"fused group {plan['gi']}")
-            # sstlint: disable=launch-except-taxonomy — AOT compile-ahead
-            # is an optimization only: any failure here means the jit
-            # path compiles at first dispatch, exactly as it always did
-            except Exception as exc:   # AOT is an optimization only
-                logger.debug("fused precompile submission failed: %r", exc)
-
-        def resolve_fused(plan):
-            """The callable for this group's fused chunks: the AOT
-            executable when the compile thread produced one, the plain
-            jit program otherwise (identical results either way)."""
-            call = plan.get("fused_call")
-            if call is not None:
-                return call
-            jit_fn = build_programs(plan)["fused"]
-            call = jit_fn
-            fut = plan.pop("aot_future", None)
-            if fut is not None:
-                try:
-                    exe = _process.join_build(
-                        fut, get_tracer(), where="dispatch",
-                        label=f"fused group {plan['gi']}")
-
-                    def call(*args, _exe=exe, _jit=jit_fn, _plan=plan):
-                        try:
-                            return _exe(*args)
-                        except (TypeError, ValueError):
-                            # aval/sharding mismatch only: drop to jit
-                            # forever.  Genuine runtime failures (OOM,
-                            # XlaRuntimeError) must propagate — retrying
-                            # the identical program via jit would only
-                            # recompile and fail again with the original
-                            # context lost
-                            _plan["fused_call"] = _jit
-                            return _jit(*args)
-                # sstlint: disable=launch-except-taxonomy — consuming a
-                # failed AOT future: the plain jit program below is the
-                # sanctioned identical-results fallback
-                except Exception as exc:
-                    logger.debug("fused precompile failed (%r); "
-                                 "falling back to jit", exc)
-            plan["fused_call"] = call
-            return call
-
-        # ------------------------------------------------------------------
-        # OOM recovery: bisected relaunch + per-candidate host bottom-out
-        # (hooks consumed by the launch supervisor, parallel/faults.py)
-        # ------------------------------------------------------------------
-        def host_fused_range(plan, lo, hi, sup, chunk_id):
-            """Candidates [lo, hi) of the plan's group on the host —
-            sklearn `_fit_and_score` per (candidate, fold) with exact
-            error_score semantics — shaped like the fused gather."""
-            idx = plan["group"].candidate_indices[lo:hi]
-            sup.record_host_fallback(f"{chunk_id}[{lo}:{hi}]",
-                                     plan["gi"], len(idx) * n_folds)
-            return LaunchResult.host_fill(*host_eval(idx), hi - lo, n_folds)
-
-        def exec_fused_halves(plan, lo, hi, sup, chunk_id):
-            from spark_sklearn_tpu.parallel.taskgrid import split_range
-            lo_, mid, hi_ = split_range(lo, hi)
-            return LaunchResult.merge(
-                exec_fused_range(plan, lo_, mid, sup, chunk_id),
-                exec_fused_range(plan, mid, hi_, sup, chunk_id))
-
-        def exec_fused_range(plan, lo, hi, sup, chunk_id):
-            """Relaunch candidates [lo, hi) as one fused program at the
-            narrowest padded width (lanes re-padded via
-            taskgrid.pad_chunk), recursing on further OOMs down to
-            single candidates and finally the host path.  Returns a
-            host-side LaunchResult with exactly
-            hi - lo real rows — per-lane results are bit-identical to
-            the full-width launch (vmap lanes are independent), so a
-            successful recovery keeps cv_results_ exact.  Under a launch
-            layout the lanes of a run are NOT independent (they share
-            what the family builds once a run): a range that cuts a run
-            is launched by the program without the layout's facts, and
-            its cells are those of the search without the layout."""
-            group = plan["group"]
-            n = hi - lo
-            width = max(n_task_shards,
-                        mesh_lib.pad_to_multiple(n, n_task_shards))
-            key = f"{chunk_id}[{lo}:{hi}]"
-
-            def attempt():
-                dyn, w = stage_operands(plan, group.dynamic_params, lo, hi,
-                                        width, "dyn.recover")
-                out = build_programs(
-                    plan, width=width,
-                    whole_runs=made_of_runs(plan, lo, n))["fused"](
-                        dyn, plan_data(plan), w, *score_ops)
-                out = sup.wait_ready(out, key=key, group=plan["gi"])
-                return out.to_host(n, n_folds)
-
-            try:
-                return sup.call(attempt, key=key, group=plan["gi"],
-                                n_real=n)
-            except Exception as exc:
-                if _faults.is_oom(exc):
-                    if n <= 1:
-                        return host_fused_range(plan, lo, hi, sup,
-                                                chunk_id)
-                    sup.record_bisection(key, plan["gi"])
-                    return exec_fused_halves(plan, lo, hi, sup, chunk_id)
-                # poison-candidate quarantine (best_effort only — the
-                # supervisor arms quarantine_k solely under
-                # partial_results='best_effort'): FATAL ranges split
-                # like OOM; a single-lane range that still faults K
-                # times is quarantined to error_score instead of
-                # killing the search
-                if not getattr(sup, "quarantine_k", 0) \
-                        or getattr(exc, "_sst_cancelled", False) \
-                        or _faults.classify_error(exc) != _faults.FATAL:
-                    raise
-                if n > 1:
-                    sup.record_bisection(key, plan["gi"],
-                                         fault_class=_faults.FATAL)
-                    return exec_fused_halves(plan, lo, hi, sup, chunk_id)
-                n_faults = sup.note_fatal(key)
-                if n_faults < sup.quarantine_k:
-                    return exec_fused_range(plan, lo, hi, sup, chunk_id)
-                sup.record_quarantine(key, plan["gi"], exc, n_faults)
-                if pctx is not None:
-                    pctx["quarantined"].append({
-                        "key": key,
-                        "group": int(plan["gi"]),
-                        "candidates": [
-                            int(i) for i in
-                            plan["group"].candidate_indices[lo:hi]],
-                        "error": f"{type(exc).__name__}: {exc}"[:300],
-                        "n_faults": int(n_faults)})
-                fill = {s: np.full((n, n_folds), errval)
-                        for s in scorer_names}
-                return LaunchResult.host_fill(
-                    fill, fill if return_train else {}, n, n_folds)
-
-        def make_bisect_fused(plan, lo, hi, chunk_id):
-            def bisect(sup):
-                if hi - lo <= 1:
-                    return host_fused_range(plan, lo, hi, sup, chunk_id)
-                sup.record_bisection(chunk_id, plan["gi"])
-                return exec_fused_halves(plan, lo, hi, sup, chunk_id)
-            return bisect
-
-        # ------------------------------------------------------------------
-        # cross-search launch fusion (the executor's FusedLaunch seam):
-        # a FuseSpec is this chunk's offer to share one wide device
-        # launch with same-program chunks from OTHER searches.  Equal
-        # keys guarantee the members run the SAME compiled fused
-        # program on the SAME resident broadcast buffers (the data
-        # plane dedups identical uploads, so shared X/y means shared
-        # device objects), so concatenating their real rows and
-        # re-padding once is exactly the bisection-recovery relaunch
-        # shape — per-lane results are bit-identical to each member's
-        # solo launch (vmap lanes are independent).
-        # ------------------------------------------------------------------
-        def make_fuse_spec(plan, lo, hi, chunk_id):
-            group = plan["group"]
-            fkey = (
-                "sst-fuse-v1", family.name, freeze(plan["static"]),
-                freeze(meta), int(n_folds),
-                bool(config.bf16_matmul), mesh_desc,
-                store_score_names, store_sw_key, bool(return_train),
-                bool(sw_blind), str(np.dtype(dtype)),
-                int(n_task_shards), bool(task_batched),
-                tuple(sorted(group.dynamic_params)), fit_masks_fp(),
-                plan.get("prefix"),
-                # device-buffer identities: live refs are held by the
-                # member closures, so ids are stable for the launch's
-                # lifetime, and the plane's dedup makes equal content
-                # mean equal objects across searches (the prefix-staged
-                # plans pass their own derived per-fold matrices here)
-                tuple(id(leaf) for leaf in
-                      jax.tree_util.tree_leaves(plan_data(plan))),
-                id(fit_dev), *map(id, score_ops))
-            _keycheck.note(
-                "fuse_spec", fkey,
-                fields={"bf16_matmul": bool(config.bf16_matmul)},
-                detail=family.name)
-
-            def rows(group=group, lo=lo, hi=hi):
-                return {k: np.asarray(arr[lo:hi])
-                        for k, arr in group.dynamic_params.items()}
-
-            def run(specs, plan=plan):
-                total = sum(int(s.n) for s in specs)
-                width = max(n_task_shards,
-                            mesh_lib.pad_to_multiple(total,
-                                                     n_task_shards))
-                member_rows = [s.rows() for s in specs]
-                dyn, w = stage_operands(
-                    plan, {k: np.concatenate(
-                        [np.asarray(r[k]) for r in member_rows])
-                        for k in sorted(member_rows[0])},
-                    0, total, width, "dyn.fuse")
-                # a member is a whole chunk of its group: it starts on a
-                # run of the layout if it is made of whole runs, and
-                # the seams between members then fall between runs
-                return build_programs(
-                    plan, width=width, whole_runs=made_of_runs(
-                        plan, 0, *(int(s.n) for s in specs)))["fused"](
-                            dyn, plan_data(plan), w, *score_ops)
-
-            # the fused width may legitimately exceed one chunk's solo
-            # batch bound (that is the point of fusion); the honest
-            # ceiling is the HBM width cap when the ledger modeled one
-            # (0 = unbounded — an over-wide fused OOM still recovers,
-            # each member bisecting its own range)
-            cap = mem_caps[plan["gi"]] if mem_caps is not None else None
-            return FuseSpec(key=fkey, n=hi - lo,
-                            shard=int(n_task_shards),
-                            max_width=int(cap) if cap else 0,
-                            rows=rows, run=run,
-                            slice_out=lambda out, off, n: out.slice(
-                                off, n, n_folds))
-
-        # quarantine armed: the first-chunk fit/score items also carry
-        # an isolate hook (below), so a poison candidate in ANY chunk
-        # routes through the fused-range recursion instead of the
-        # whole-search degradation path.  Off (the default), those
-        # items keep exactly their pre-protection shape.
-        quarantine_armed = (
-            pctx is not None and best_effort
-            and int(getattr(config, "quarantine_fatal_k", 3) or 0) > 0)
-
-        def make_bisect_fit(plan, lo, hi, chunk_id, cstate):
-            inner = make_bisect_fused(plan, lo, hi, chunk_id)
-
-            def bisect(sup):
-                res = inner(sup)
-                # the score item consumes the recovered cells instead
-                # of launching (same contract as the OOM host fallback)
-                cstate["host"] = (res.test, res.train)
-                return res
-            return bisect
-
-        def make_bisect_score(plan, lo, hi, chunk_id):
-            inner = make_bisect_fused(plan, lo, hi, chunk_id)
-
-            def bisect(sup):
-                res = inner(sup)
-                return res.test, res.train
-            return bisect
-
-        def write_cells(plan, idx, lo, hi, chunk_id, te, tr, t_fit,
-                        t_score, count_launch=True):
-            # charge the launch wall to the REAL candidates in the chunk
-            # (not the padded lane count), so summing ALL per-split
-            # fit-time cells (mean_fit_time x n_splits over candidates)
-            # reconstructs the true device wall; XLA fuses all lanes
-            # into one program, so a finer per-candidate split is not
-            # measurable (ROADMAP)
-            n_real = (hi - lo) * n_folds
-            fit_times[idx, :] = t_fit / n_real
-            score_times[idx, :] = t_score / n_real
-            for s in scorer_names:
-                test_scores[s][idx, :] = np.asarray(te[s])[:hi - lo]
-                if return_train:
-                    train_scores[s][idx, :] = \
-                        np.asarray(tr[s])[:hi - lo]
-            if count_launch:
-                # scan segments call this once per MEMBER chunk (the
-                # per-chunk journal records give segment-granular
-                # resume for free) but count their one real launch in
-                # the segment finalize instead
-                metrics.counter("n_launches").inc()
-            metrics.gauge("fit_wall_s").add(t_fit)
-            metrics.gauge("score_wall_s").add(t_score)
-            lanes_launch = plan["nc_batch"] * n_folds
-            metrics.histogram("padding_waste").observe(
-                (lanes_launch - n_real) / lanes_launch)
-            # per-compile-group walls: candidates in different groups
-            # (or chunks) carry genuinely different launch timings —
-            # only candidates fused into ONE launch share a per-launch
-            # average (XLA executes them as one program, so a finer
-            # split is not measurable; see ROADMAP)
-            rec = per_group_rec(plan)
-            if count_launch:
-                rec["n_launches"] += 1
-            rec["fit_wall_s"] += t_fit
-            rec["score_wall_s"] += t_score
-            if self.verbose > 1:
-                self._print_task_end_lines(
-                    candidates, idx, n_folds, scorer_names,
-                    test_scores, train_scores, return_train,
-                    (t_fit + t_score) / n_real, fit_failed)
-            if ckpt is not None:
-                ckpt.put(chunk_id, {
-                    "test": {s: test_scores[s][idx, :].tolist()
-                             for s in scorer_names},
-                    "train": ({s: train_scores[s][idx, :].tolist()
-                               for s in scorer_names}
-                              if return_train else None),
-                    "fit_t": t_fit / n_real,
-                    "score_t": t_score / n_real,
-                    "failed": fit_failed[idx, :].tolist()})
-            if pctx is not None:
-                # degradation never overwrites a candidate with real
-                # (or host-recovered) cells
-                pctx["done"][idx] = True
-
-        def per_group_rec(plan):
-            pg = metrics.struct("per_group")
-            # rung-namespaced key: a halving search's shared registry
-            # must not merge rung 2's group 0 into rung 0's group 0
-            key = cid_ns + str(plan["gi"]) if rung is not None \
-                else plan["gi"]
-            return pg.setdefault(key, {
-                "static_params": repr(plan["group"].static_params),
-                "n_launches": 0, "fit_wall_s": 0.0, "score_wall_s": 0.0,
-                "score_path": ("scan-fused" if scan_mode else
-                               "wide-fused" if fused_mode else
-                               "wide" if all_cores else "nested"),
-                **({"n_features": int(meta["n_features"])}
-                   if "n_features" in meta else {})})
-
-        def record_launch(plan, res, lanes, idx):
-            """One launch's solver stats into the report: what the
-            family's traced hook reported and what its host hook knows of
-            a launch of this width.  Nothing for a launch without stats
-            (no iterative solver, or every cell from the host)."""
-            if not res.stats:
-                return
-            metrics.series("lanes_per_launch").append(int(lanes))
-            facts = family.launch_facts(plan["static"], meta,
-                                        lanes // n_folds, n_folds)
-            record_stats(metrics, {**facts, **res.stats}, idx,
-                         len(candidates), n_folds, per_group_rec(plan))
-
-        def replay_chunk(idx, rec):
-            """Write a journalled chunk's cells back — shared by the
-            per-chunk and scan dispatch paths, so resume semantics are
-            loop-mode-invariant."""
-            for s_ in scorer_names:
-                test_scores[s_][idx, :] = np.asarray(rec["test"][s_])
-                if return_train:
-                    train_scores[s_][idx, :] = np.asarray(
-                        rec["train"][s_])
-            fit_times[idx, :] = rec["fit_t"]
-            score_times[idx, :] = rec["score_t"]
-            if rec.get("failed") is not None:
-                fit_failed[idx, :] |= np.asarray(rec["failed"], bool)
-            metrics.counter("n_chunks_resumed").inc()
-            if pctx is not None:
-                pctx["done"][idx] = True
-
-        def shed_chunk(idx, chunk_id):
-            """True when the search deadline expired and this chunk was
-            shed to error_score (best_effort); raises under
-            partial_results='raise'.  Shared by both dispatch paths."""
-            if pctx is None or pctx["t_deadline"] is None \
-                    or time.perf_counter() < pctx["t_deadline"]:
-                return False
-            elapsed = time.perf_counter() - pctx["t_start"]
-            if not best_effort:
-                raise _faults.SearchDeadlineError(
-                    float(config.search_deadline_s), elapsed,
-                    n_remaining=int((~pctx["done"]).sum()))
-            if not pctx["deadline_hit"]:
-                pctx["deadline_hit"] = True
-                _telemetry.note_protection("deadline_hit")
-                logger.warning(
-                    "search deadline %.3gs expired after %.3fs: "
-                    "shedding the remaining chunks to error_score "
-                    "(partial_results='best_effort')",
-                    float(config.search_deadline_s), elapsed,
-                    chunk=chunk_id)
-            # un-run candidates carry sklearn's error_score with ZERO
-            # times (like a fit that never ran) — declared in the
-            # protection block, NOT routed through fit_failed
-            for s_ in scorer_names:
-                test_scores[s_][idx, :] = errval
-                if return_train:
-                    train_scores[s_][idx, :] = errval
-            fit_times[idx, :] = 0.0
-            score_times[idx, :] = 0.0
-            pctx["done"][idx] = True
-            pctx["shed"].append({
-                "reason": "deadline", "chunk": chunk_id,
-                "candidates": [int(i) for i in idx]})
-            _telemetry.note_protection("shed", len(idx))
-            return True
-
-        def live_chunks(plan):
-            """The plan's chunks that still have to launch, as (lo, hi,
-            chunk_id); a journalled chunk is replayed and an expired one
-            shed on the way — the same for both dispatch paths."""
-            for lo, hi, chunk_id, rec in plan["chunks"]:
-                idx = plan["group"].candidate_indices[lo:hi]
-                if rec is not None:
-                    replay_chunk(idx, rec)
-                elif not shed_chunk(idx, chunk_id):
-                    yield lo, hi, chunk_id
-
-        def scan_plan_items(plan):
-            """The plan's live chunks as scan-segment LaunchItems: each
-            segment stacks its member chunks' operands along a leading
-            step axis and executes them as ONE `lax.scan` launch
-            (build_scan above).  Segment length is planned against the
-            memory ledger (taskgrid.plan_scan_segments): the stacked
-            operands and the top-k carry are priced BEFORE launch, and
-            an OOM that still slips through falls back to the
-            per-chunk path for that segment only (the bisect hook)."""
-            gi, group = plan["gi"], plan["group"]
-            nc_batch = plan["nc_batch"]
-            lanes = nc_batch * n_folds
-            repeat = n_folds if task_batched else 1
-            live = list(live_chunks(plan))
-            if not live:
-                return
-            # device-resident rung elimination is gated to the shapes
-            # where the carry's candidate-position rows are the whole
-            # rung: one compile group, one scorer, zero resumed/shed
-            # chunks, and (below) a single segment — any partial shape
-            # falls back to sklearn's host _top_k, which reads the
-            # same scores from cv_results_ either way
-            topk_k = 0
-            if rung is not None and len(plans) == 1 \
-                    and len(scorer_names) == 1 \
-                    and len(live) == len(plan["chunks"]):
-                k = int(getattr(rung, "keep_next", 0) or 0)
-                if 0 < k < int(plan["nc"]):
-                    topk_k = k
-            carry_bytes = (int(plan["nc"]) + 1) * n_folds * 4 \
-                if topk_k else 0
-            # per-step stacked bytes: the dynamic operand rows plus the
-            # stacked per-step outputs (scores/bad/iters) — the model
-            # working set itself is step-reused by XLA's loop aliasing
-            # and is priced once via reserved_bytes
-            chunk_dyn_bytes = 0
-            for arr in group.dynamic_params.values():
-                per = 1
-                for d in arr.shape[1:]:
-                    per *= int(d)
-                chunk_dyn_bytes += nc_batch * repeat * per \
-                    * int(arr.dtype.itemsize)
-            out_bytes = nc_batch * n_folds * (
-                len(scorer_names) * (2 if return_train else 1)
-                * int(np.dtype(dtype).itemsize) + 1) + 8
-            budget = int(mem_ctx.get("budget_bytes", 0)) \
-                if mem_ctx is not None else 0
-            seg_plan = plan_scan_segments(
-                len(live), chunk_bytes=chunk_dyn_bytes + out_bytes,
-                carry_bytes=carry_bytes, budget_bytes=budget,
-                reserved_bytes=int(resident_est)
-                + int(plan.get("mem_chunk_bytes", 0)))
-            if seg_plan.capped:
-                topk_k = 0   # the carry cannot cross launches
-                cl_state["fallbacks"].append(
-                    f"segment-capped:{cid_ns}{gi}")
-            cl_state["n_segments"] += seg_plan.n_segments
-
-            for si, (slo, shi) in enumerate(seg_plan.segments()):
-                members = live[slo:shi]
-                n_steps = len(members)
-                seg_key = cid_ns + f"{gi}:scan{si}"
-                seg_tasks = sum((hi - lo) * n_folds
-                                for lo, hi, _ in members)
-                seg_topk = topk_k if n_steps == len(live) else 0
-                # per-step cost estimate seeding the ETA blend: the
-                # geometry model's launch overhead amortizes across the
-                # scanned steps, lane cost scales with the segment's
-                # lane width — observed beat cadence refines this as
-                # beats arrive (heartbeat._Segment.blended_step_s)
-                hb_est = hb_overhead_s / max(1, n_steps) \
-                    + hb_lane_cost_s * lanes
-
-                def stage(members=members, plan=plan, n_steps=n_steps,
-                          seg_key=seg_key, si=si, hb_est=hb_est):
-                    with get_tracer().span(
-                            "chunkloop.segment", group=plan["gi"],
-                            n_chunks=n_steps):
-                        dyn = {}
-                        for k, arr in \
-                                plan["group"].dynamic_params.items():
-                            rows = np.stack([
-                                pad_chunk(arr, lo, hi, nc_batch, repeat)
-                                for lo, hi, _ in members])
-                            dyn[k] = _dataplane.upload(
-                                rows, scan_shard, label="dyn.scan")
-                        if not dyn and not task_batched:
-                            dyn["_pad"] = _dataplane.upload(
-                                np.zeros((n_steps, nc_batch),
-                                         dtype=dtype),
-                                scan_shard, label="dyn.scan.pad")
-                        # per-step candidate POSITIONS for the top-k
-                        # carry scatter (padded lanes hit the dump
-                        # row); always staged — the non-topk program
-                        # ignores it, and the shape keeps one item
-                        # contract for both
-                        idx_rows = np.full((n_steps, nc_batch),
-                                           int(plan["nc"]), np.int32)
-                        for i, (lo, hi, _) in enumerate(members):
-                            idx_rows[i, :hi - lo] = np.arange(
-                                lo, hi, dtype=np.int32)
-                        idx_st = _dataplane.upload(
-                            idx_rows, repl_shard, label="dyn.scan.idx")
-                        w = group_masks(plan)
-                        note_staged(plan, [cid for _, _, cid in members])
-                        # heartbeat segment registration happens at
-                        # stage time (before dispatch) so a launch
-                        # that never produces a beat still shows up
-                        # stale to the watchdog
-                        tok = None
-                        if hb_on:
-                            tok = _heartbeat.get_hub().register_segment(
-                                seg_key, group=plan["gi"], segment=si,
-                                n_steps=n_steps, scope=hb_scope,
-                                handle=hb_handle, tenant=hb_tenant,
-                                est_step_s=hb_est)
-                        return dyn, idx_st, w, tok
-
-                def launch(payload, plan=plan, n_steps=n_steps,
-                           seg_topk=seg_topk):
-                    dyn, idx_st, w, tok = payload
-                    # the trace pin for "no score round-trip": a rung
-                    # scanned with topk > 0 ran its elimination inside
-                    # this one launch
-                    with get_tracer().span(
-                            "chunkloop.scan", group=plan["gi"],
-                            n_chunks=n_steps, topk=seg_topk):
-                        # the heartbeat token is a RUNTIME operand — the
-                        # compiled scan program is shared across searches
-                        hb_ops = () if tok is None \
-                            else (np.asarray(tok, np.int32),)
-                        return build_scan(
-                            plan, n_steps, seg_topk, hb=tok is not None)(
-                            dyn, idx_st, plan_data(plan), w, *score_ops,
-                            *hb_ops)
-
-                def gather(out, members=members, seg_topk=seg_topk):
-                    ys, surv = out
-                    ys_h = ys.to_host()
-                    chunks = [ys_h.step(i).slice(0, hi - lo, n_folds)
-                              for i, (lo, hi, _) in enumerate(members)]
-                    surv_h = (np.asarray(
-                        mesh_lib.device_get_tree(surv))
-                        if seg_topk else None)
-                    return {"chunks": chunks, "survivors": surv_h}
-
-                def bisect(sup, members=members, plan=plan,
-                           seg_key=seg_key):
-                    # OOM on the scanned segment: fall back to the
-                    # per-chunk path for THIS segment only — each
-                    # member relaunches through the existing fused
-                    # bisection recursion (host bottom-out included),
-                    # and the rung's elimination reverts to host
-                    # _top_k (survivors never set)
-                    sup.record_bisection(seg_key, plan["gi"])
-                    cl_state["fallbacks"].append(
-                        f"oom-per-chunk:{cid_ns}{plan['gi']}")
-                    chunks = [exec_fused_range(plan, lo, hi, sup, cid)
-                              for lo, hi, cid in members]
-                    return {"chunks": chunks, "survivors": None}
-
-                def finalize(host, tm, members=members, plan=plan,
-                             seg_topk=seg_topk, lanes=lanes,
-                             seg_key=seg_key):
-                    if hb_on:
-                        # runs after scan success AND after the OOM
-                        # per-chunk fallback (bisect), so progress
-                        # always lands on steps_total for the segment
-                        _heartbeat.get_hub().complete_segment(seg_key)
-                    chunks = host["chunks"]
-                    wall = tm.dispatch_s + tm.compute_s + tm.gather_s
-                    total_real = sum((hi - lo) * n_folds
-                                     for lo, hi, _ in members)
-                    for (lo, hi, chunk_id), res in zip(members, chunks):
-                        idx = plan["group"].candidate_indices[lo:hi]
-                        n_real = (hi - lo) * n_folds
-                        # the melted boundary makes per-chunk walls
-                        # unmeasurable: the segment wall splits by
-                        # real lanes and scoring is folded into fit
-                        # ("folded" attribution in the chunkloop
-                        # block) — time columns are estimates, scores
-                        # are exact
-                        t_fit = wall * n_real / max(1, total_real)
-                        fit_failed[idx, :] |= res.bad
-                        record_launch(plan, res, lanes, idx)
-                        write_cells(plan, idx, lo, hi, chunk_id, res.test,
-                                    res.train, t_fit, 0.0,
-                                    count_launch=False)
-                    metrics.counter("n_launches").inc()
-                    rec = per_group_rec(plan)
-                    rec["n_launches"] += 1
-                    cl_state["n_chunks_scanned"] += len(members)
-                    cl_state["segment_lengths"].append(len(members))
-                    cl_state["n_launches_saved"] += len(members) - 1
-                    surv = host.get("survivors")
-                    if surv is not None and rung is not None:
-                        # device positions -> rung candidate indices,
-                        # in sklearn _top_k order (ascending mean) —
-                        # halving consumes these instead of its host
-                        # elimination
-                        rung.device_survivors = np.asarray(
-                            plan["group"].candidate_indices)[
-                                np.asarray(surv, int)]
-                        cl_state["rung_topk_device"] += 1
-
-                yield LaunchItem(
-                    key=seg_key, kind="scan", group=gi,
-                    n_tasks=seg_tasks, n_chunks=n_steps, stage=stage,
-                    launch=launch, gather=gather, finalize=finalize,
-                    bisect=bisect)
-
-        def chunk_items():
-            """Yield this search's LaunchItems in dispatch order.  Runs
-            on the dispatching thread: the group-level work between
-            yields (program build, AOT future consumption) overlaps the
-            already-dispatched launches' device compute."""
-            for pi, plan in enumerate(plans):
-                if scan_mode:
-                    # device-resident chunk loop: the whole group rolls
-                    # into scan-segment launches (one, memory allowing)
-                    yield from scan_plan_items(plan)
-                    continue
-                gi, group = plan["gi"], plan["group"]
-                nc_batch = plan["nc_batch"]
-                lanes = nc_batch * n_folds
-                # compile-ahead: this group's fused program (overlaps
-                # its own calibration launches) and the next group's
-                # (overlaps this whole group)
-                submit_precompile(plan)
-                if pi + 1 < len(plans):
-                    submit_precompile(plans[pi + 1])
-                #: group-shared state: the calibrated warm score cost
-                #: per task, set by the calibration item's finalize —
-                #: which the (serial, in-order) finalize stream runs
-                #: before any fused chunk of the group finalizes
-                gstate = {"sspt": None}
-                for live_seen, (lo, hi, chunk_id) in enumerate(
-                        live_chunks(plan), 1):
-                    idx = group.candidate_indices[lo:hi]
-                    n_real = (hi - lo) * n_folds
-
-                    def stage(lo=lo, hi=hi, plan=plan, chunk_id=chunk_id):
-                        dyn, w = stage_operands(
-                            plan, plan["group"].dynamic_params, lo, hi,
-                            None, "dyn", ring=ring)
-                        note_staged(plan, [chunk_id])
-                        return dyn, w
-
-                    def gather(out, n=hi - lo):
-                        return out.to_host(n, n_folds)
-
-                    if fused_mode and live_seen > 1:
-                        # steady state: ONE fused launch per chunk
-
-                        def launch(payload, plan=plan):
-                            dyn, w = payload
-                            return resolve_fused(plan)(
-                                dyn, plan_data(plan), w, *score_ops)
-
-                        def finalize(host, tm, plan=plan, idx=idx, lo=lo,
-                                     hi=hi, chunk_id=chunk_id,
-                                     gstate=gstate, lanes=lanes):
-                            wall = tm.dispatch_s + tm.compute_s \
-                                + tm.gather_s
-                            # one launch: attribute the group's measured
-                            # warm score cost — scaled by the PADDED
-                            # lane count, which is what the launch
-                            # actually computes — the rest is fit, so
-                            # the score-time column is an estimate,
-                            # never a silent 0.0 (unless calibration
-                            # itself was lost to OOM recovery: sspt 0.0)
-                            t_score = min((gstate["sspt"] or 0.0) * lanes,
-                                          wall)
-                            t_fit = wall - t_score
-                            fit_failed[idx, :] |= host.bad
-                            record_launch(plan, host, lanes, idx)
-                            write_cells(plan, idx, lo, hi, chunk_id,
-                                        host.test, host.train, t_fit,
-                                        t_score)
-
-                        yield LaunchItem(
-                            key=chunk_id, kind="fused", group=gi,
-                            n_tasks=n_real, stage=stage, launch=launch,
-                            gather=gather, finalize=finalize,
-                            bisect=make_bisect_fused(plan, lo, hi,
-                                                     chunk_id),
-                            fuse=(make_fuse_spec(plan, lo, hi, chunk_id)
-                                  if fusion_on else None))
-                        continue
-
-                    # first live chunk of the group (or the never-fused
-                    # path): separate fit and score launches with exact
-                    # per-phase walls, plus — when later chunks will
-                    # fuse — a warm calibration score launch measuring
-                    # the steady-state score cost
-                    cstate = {}
-                    calibrate = fused_mode and live_seen < plan["n_live"]
-
-                    def launch_fit(payload, plan=plan, cstate=cstate):
-                        dyn, w = payload
-                        models, stats = build_programs(plan)["fit"](
-                            dyn, plan_data(plan), w)
-                        cstate["models"] = models
-                        return LaunchResult({}, {}, _models_health(models),
-                                            stats)
-
-                    def fin_fit(host, tm, plan=plan, idx=idx,
-                                cstate=cstate, lanes=lanes):
-                        if host.bad is not None:
-                            fit_failed[idx, :] |= host.bad
-                        record_launch(plan, host, lanes, idx)
-                        cstate["t_fit"] = tm.dispatch_s + tm.compute_s
-
-                    def host_fb_fit(idx=idx, cstate=cstate):
-                        # the whole chunk (fit AND scores) degrades to
-                        # per-candidate host execution; the score item
-                        # consumes the stashed cells instead of
-                        # launching
-                        cstate["host"] = host_eval(idx)
-                        return LaunchResult.host_fill(
-                            *cstate["host"], len(idx), n_folds)
-
-                    yield LaunchItem(
-                        key=chunk_id + ":fit", kind="fit", group=gi,
-                        n_tasks=n_real, stage=stage, launch=launch_fit,
-                        gather=gather, finalize=fin_fit,
-                        host_fallback=host_fb_fit,
-                        bisect=(make_bisect_fit(plan, lo, hi, chunk_id,
-                                                cstate)
-                                if quarantine_armed else None))
-
-                    def launch_score(payload, plan=plan, cstate=cstate):
-                        if "host" in cstate:
-                            return None   # chunk recovered on the host
-                        return build_programs(plan)["score"](
-                            cstate["models"], plan_data(plan), *score_ops)
-
-                    def gather_score(out, cstate=cstate):
-                        if out is None and "host" in cstate:
-                            return cstate.pop("host")
-                        te, tr = out
-                        return (mesh_lib.device_get_tree(te),
-                                mesh_lib.device_get_tree(tr))
-
-                    def host_fb_score(idx=idx, cstate=cstate):
-                        if "host" in cstate:
-                            return cstate.pop("host")
-                        cstate.pop("models", None)
-                        return host_eval(idx)
-
-                    def fin_score(host, tm, plan=plan, idx=idx, lo=lo,
-                                  hi=hi, chunk_id=chunk_id, cstate=cstate,
-                                  calibrate=calibrate):
-                        te, tr = host
-                        t_score = tm.dispatch_s + tm.compute_s \
-                            + tm.gather_s
-                        if not calibrate:
-                            cstate.pop("models", None)
-                        write_cells(plan, idx, lo, hi, chunk_id, te, tr,
-                                    cstate["t_fit"], t_score)
-
-                    yield LaunchItem(
-                        key=chunk_id + ":score", kind="score", group=gi,
-                        n_tasks=n_real, launch=launch_score,
-                        gather=gather_score, finalize=fin_score,
-                        host_fallback=host_fb_score,
-                        bisect=(make_bisect_score(plan, lo, hi,
-                                                  chunk_id)
-                                if quarantine_armed else None))
-
-                    if calibrate:
-                        # calibration: a SECOND, warm score launch (the
-                        # first's wall includes trace+compile) measures
-                        # the steady-state score cost later fused chunks
-                        # attribute out of their single-launch wall.
-                        # It is real device work: counted in n_launches
-                        # and score_wall_s (not in any candidate's
-                        # cells — sklearn never ran it)
-
-                        def launch_cal(payload, plan=plan,
-                                       cstate=cstate, gstate=gstate):
-                            models = cstate.pop("models", None)
-                            if models is None:
-                                # the chunk recovered on the host: no
-                                # device models to calibrate with
-                                gstate["cal_skip"] = True
-                                return None
-                            return build_programs(plan)["score"](
-                                models, plan_data(plan), *score_ops)
-
-                        def host_fb_cal(cstate=cstate, gstate=gstate):
-                            cstate.pop("models", None)
-                            gstate["cal_skip"] = True
-                            return None
-
-                        def fin_cal(host, tm, plan=plan, gstate=gstate,
-                                    lanes=lanes):
-                            if gstate.pop("cal_skip", False):
-                                # calibration lost to recovery: later
-                                # fused chunks attribute a zero score
-                                # share (documented estimate, not a
-                                # silent wrong one)
-                                gstate["sspt"] = 0.0
-                                return
-                            wall = tm.dispatch_s + tm.compute_s
-                            # per PADDED lane: the launch computes
-                            # nc_batch lanes regardless of how many are
-                            # real, and fused chunks scale back up by
-                            # the same padded count
-                            gstate["sspt"] = wall / lanes
-                            metrics.counter("n_launches").inc()
-                            metrics.gauge("score_wall_s").add(wall)
-                            rec = per_group_rec(plan)
-                            rec["n_launches"] += 1
-                            rec["score_wall_s"] += wall
-                            rec["score_s_per_task_calibrated"] = round(
-                                gstate["sspt"], 7)
-
-                        yield LaunchItem(
-                            key=chunk_id + ":calibrate", kind="calibrate",
-                            group=gi, n_tasks=n_real, launch=launch_cal,
-                            finalize=fin_cal, host_fallback=host_fb_cal)
-
-        # every LaunchItem runs under the fault supervisor: transient
-        # retry with backoff, OOM bisection through the hooks above, a
-        # watchdog on the blocking wait, and deterministic injection for
-        # tests — identical at every pipeline depth (same item order)
-        from spark_sklearn_tpu.parallel.faults import LaunchSupervisor
-        memory_info = None
-        if ledger is not None:
-            # OOM forensics: every OOM fault event carries the failing
-            # chunk's modeled bytes next to the budget, and the FIRST
-            # OOM per chunk trains the ledger's safety margin — so
-            # bisection outcomes tighten the width ceiling instead of
-            # repeating.  Bisected sub-ranges ("id[lo:hi]") share
-            # their parent chunk's model.
-            mem_oom_lock = named_lock("grid.mem_oom_lock")
-            oom_trained: set = set()
-
-            def memory_info(key, group):
-                plan = plans[group] if 0 <= group < len(plans) else None
-                modeled = int(resident_est) + (
-                    int(plan.get("mem_chunk_bytes", 0))
-                    if plan is not None else 0)
-                budget = int(mem_ctx.get("budget_bytes", 0)) \
-                    if mem_ctx is not None else 0
-                base_key = key.split("[", 1)[0]
-                with mem_oom_lock:
-                    fresh = base_key not in oom_trained
-                    if fresh:
-                        oom_trained.add(base_key)
-                if fresh:
-                    ledger.observe_oom(modeled, budget)
-                return {"modeled_bytes": modeled,
-                        "budget_bytes": budget}
-
-        supervisor = LaunchSupervisor(
-            config, faults=metrics.struct("faults"), ckpt=ckpt,
-            verbose=self.verbose, memory_info=memory_info,
-            # later rungs accumulate into the shared faults struct
-            # instead of zeroing the earlier rungs' recovery record
-            reset_faults=(rung is None or rung.itr == 0))
-        items = chunk_items()
-        if binding is not None:
-            # executor wrapping sits UNDER the supervisor: a routed
-            # launch that fails re-enters the supervisor on THIS
-            # search's threads (retries re-queue fairly; one tenant's
-            # OOM bisection never blocks the shared dispatch loop)
-            n_live_total = sum(p["n_live"] for p in plans)
-            if rung is not None:
-                # progress() spans the whole halving search: planned
-                # chunks accumulate rung by rung as geometry resolves
-                rung.planned_total += n_live_total
-                n_live_total = rung.planned_total
-            binding.executor.note_planned(binding.handle, n_live_total)
-            items = binding.executor.wrap_items(binding.handle, items)
-        resumed0 = int(metrics.data.get("n_chunks_resumed", 0))
+        """Run the search's compile groups as one launch schedule
+        (search/schedule.py): plan them, run every live chunk's launches
+        through the pipelined chunk executor, and let go of the
+        schedule's device buffers however the run ends."""
+        from spark_sklearn_tpu.search.schedule import LaunchSchedule
+        schedule = LaunchSchedule(
+            self, groups=groups, base_params=base_params, family=family,
+            meta=meta, scorers=scorers, scorer_names=scorer_names,
+            data_dev=data_dev, fit_dev=fit_dev, test_dev=test_dev,
+            train_sc_dev=train_sc_dev, test_unw_dev=test_unw_dev,
+            train_unw_dev=train_unw_dev, sw_blind=sw_blind,
+            fit_masks=fit_masks, mesh=mesh, config=config,
+            n_task_shards=n_task_shards, task_shard=task_shard,
+            max_cand_per_batch=max_cand_per_batch, n_folds=n_folds,
+            dtype=dtype, return_train=return_train,
+            test_scores=test_scores, train_scores=train_scores,
+            fit_times=fit_times, score_times=score_times, ckpt=ckpt,
+            fit_failed=fit_failed, candidates=candidates,
+            host_eval=host_eval, data_fp=data_fp)
         try:
-            pipe.run(supervisor.wrap(items))
-        except Exception as exc:
-            # graceful degradation: under partial_results='best_effort'
-            # a persistent non-memory fault (retries exhausted, a
-            # watchdog timeout) stops the search WITHOUT killing it —
-            # every candidate still missing cells is declared shed and
-            # written to error_score.  Cancellation, OOM (the bisection
-            # hooks own it) and raise-mode searches propagate
-            # unchanged.
-            degradable = (
-                pctx is not None and best_effort
-                and not getattr(exc, "_sst_cancelled", False)
-                and not _faults.is_oom(exc))
-            if not degradable:
-                raise
-            left = np.flatnonzero(~pctx["done"])
-            for s_ in scorer_names:
-                test_scores[s_][left, :] = errval
-                if return_train:
-                    train_scores[s_][left, :] = errval
-            fit_times[left, :] = 0.0
-            score_times[left, :] = 0.0
-            pctx["done"][left] = True
-            pctx["shed"].append({
-                "reason": "fault",
-                "chunk": None,
-                "candidates": [int(i) for i in left],
-                "error": f"{type(exc).__name__}: {exc}"[:300]})
-            _telemetry.note_protection("shed", len(left))
-            logger.warning(
-                "persistent fault under partial_results='best_effort' "
-                "(%r): %d candidate(s) shed to error_score, the search "
-                "returns declared-partial results", exc, len(left))
+            schedule.run()
         finally:
-            with get_tracer().span("fit.report"):
-                # the scheduler's per-search view (queue waits, interleave,
-                # measured tenant shares) — zeroed enabled=False shape for
-                # a standalone fit, so the report schema never changes
-                metrics.put("scheduler", _serve.report_block(binding))
-                # the compile thread traces under this search's jax config
-                # (e.g. temporarily-enabled x64): join it before returning.
-                # A halving rung only DRAINS it — no queued AOT job crosses
-                # the rung boundary's config restore, but the thread stays
-                # warm for the next rung (halving closes the shared
-                # pipeline when the last rung ends).
-                if rung is None:
-                    pipe.close()
-                else:
-                    pipe.drain()
-                pr = pipe.report()
-                cache1 = persistent_cache_counts()
-                pr["persistent_cache_hits"] = cache1["hits"] - cache0["hits"]
-                pr["persistent_cache_misses"] = \
-                    cache1["misses"] - cache0["misses"]
-                # distinct traced-program constructions this search (program-
-                # cache misses; each is one python->jaxpr->HLO walk whether
-                # the compile then ran on the AOT thread or at jit dispatch)
-                total_builds = _program_build_count() - builds0
-                pr["n_compiles"] = total_builds
-                metrics.put("pipeline", pr)
-                metrics.put("chunkloop", chunkloop_block(
-                    metrics.struct("chunkloop"), mode=chunk_loop,
-                    enabled=scan_mode,
-                    score_attribution="folded" if scan_mode
-                    else "calibrated"))
-                metrics.put("prefix", _prefix.prefix_block(
-                    metrics.struct("prefix"),
-                    mode="shared" if px_on else "atomic",
-                    enabled=bool(px_state.get("enabled"))))
-                # feed the measured per-launch overhead / per-lane cost back
-                # into the geometry planner's cost model: the NEXT search
-                # over a new structure prices its widths from real walls
-                # (plans already computed this process keep their widths via
-                # the plan cache, so drift never forces recompiles).  For a
-                # halving search this runs at EVERY rung boundary over that
-                # rung's timeline slice — rung k+1's re-plan prices its
-                # widths from rung k's measured overhead and lane cost, not
-                # from cross-search priors.
-                # n_builds normalizes the compile lane PER PROGRAM: a
-                # scanned group compiles once however many chunks it
-                # serves, and the old per-timeline-median heuristic would
-                # double-count that one compile into every launch's excess
-                launches = pr.get("launches") or []
-                if rung is not None:
-                    new_launches = launches[rung.launches_seen:]
-                    rung.launches_seen = len(launches)
-                    nb = total_builds - int(
-                        getattr(rung, "builds_observed", 0))
-                    rung.builds_observed = total_builds
-                    geometry_cost_model().observe(new_launches, n_builds=nb)
-                    rung_rec = rung.current
-                    if rung_rec is not None:
-                        rung_rec["n_chunks_resumed"] = int(
-                            metrics.data.get("n_chunks_resumed", 0)) \
-                            - resumed0
-                        wall = float(pr.get("wall_s", 0.0))
-                        rung_rec["pipe_wall_s"] = round(
-                            max(0.0, wall - rung.prev_pipe_wall), 4)
-                        rung.prev_pipe_wall = wall
-                        # the rung's end boundary in the shared pipeline's
-                        # cumulative launch timeline — what the attribution
-                        # analyzer slices per-rung lanes from
-                        rung_rec["launches_end"] = len(launches)
-                else:
-                    geometry_cost_model().observe(launches,
-                                                  n_builds=total_builds)
-                # persist the plan cache + cost-model state next to the AOT
-                # artifacts: a fresh process then plans the SAME chunk
-                # widths — and resolves the same stored programs — without
-                # re-measuring (parallel/programstore.py plans.json)
-                if search_store is not None:
-                    from spark_sklearn_tpu.parallel.taskgrid import (
-                        export_plan_state)
-                    search_store.save_plan_state(export_plan_state())
+            schedule.close()
 
     def _print_task_end_lines(self, candidates, idx, n_folds, scorer_names,
                               test_scores, train_scores, return_train,

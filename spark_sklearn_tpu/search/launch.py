@@ -1,10 +1,10 @@
-"""What one launch hands back: the seam between ``search/grid.py``'s launch
-paths and the families.
+"""What one launch hands back: the seam between the launch paths of
+``search/schedule.py`` and the families.
 
-Every path of ``_run_groups`` (the fused per-chunk launch, the unfused fit
-launch of a group's first chunk, the scanned segment, the bisection
-recursion, the cross-search ``FuseSpec``) returns, gathers, merges, slices
-and finalizes the same :class:`LaunchResult`.  Its ``stats`` are what the
+Every launch path of the ``LaunchSchedule`` (the fused per-chunk launch,
+the unfused fit launch of a group's first chunk, the scanned segment, the
+bisection recursion, the cross-search ``FuseSpec``) returns, gathers,
+merges, slices and finalizes the same :class:`LaunchResult`.  Its ``stats`` are what the
 family's ``launch_stats`` hook reported (``models/base.py``); how one
 combines and which ``search_report`` series it feeds is declared once, in
 ``obs.metrics.LAUNCH_STATS``, and read only here.

@@ -57,7 +57,7 @@ __all__ = [
 #:     ``key-part-dead``;
 #:   - ``key_tokens``: per declared field, the LOCAL NAME that carries
 #:     its value into key expressions when the raw ``config.<field>``
-#:     attribute does not appear there (``donate``/``hb`` in grid);
+#:     attribute does not appear there (``hb`` in grid);
 #:   - ``aliases``: store-key identifier -> the in-memory-key
 #:     identifier carrying the same information (``mesh_desc`` ->
 #:     ``mesh``), for the store-parts-vs-key consistency check;
@@ -72,10 +72,8 @@ KEY_SURFACES: Dict[str, Dict[str, Any]] = {
             "the cross-search in-memory cache of jitted programs "
             "(fit/score/fused/scan/prefix), keyed by everything the "
             "per-search closures capture"),
-        "config_fields": ("bf16_matmul", "donate_chunk_buffers",
-                          "heartbeat"),
-        "key_tokens": {"donate_chunk_buffers": "donate",
-                       "heartbeat": "hb"},
+        "config_fields": ("bf16_matmul", "heartbeat"),
+        "key_tokens": {"heartbeat": "hb"},
         "aliases": {"mesh_desc": "mesh",
                     "store_score_names": "score_key",
                     "store_sw_key": "sw_blind"},
@@ -94,7 +92,7 @@ KEY_SURFACES: Dict[str, Dict[str, Any]] = {
         "dataflow": False,
     },
     "fuse_spec": {
-        "relpath": "search/grid.py",
+        "relpath": "search/schedule.py",
         "anchor": "make_fuse_spec",
         "description": (
             "cross-search launch fusion: equal keys guarantee members "

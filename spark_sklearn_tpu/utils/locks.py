@@ -2,7 +2,7 @@
 
 Every lock the engine's threads contend on is created through
 :func:`named_lock` / :func:`named_rlock` with a stable dotted name
-(``"dataplane._TOTALS_LOCK"``, ``"grid.stage_lock"``, ...).  Two
+(``"dataplane._TOTALS_LOCK"``, ``"schedule.GroupPlan._lock"``, ...).  Two
 consumers build on the names:
 
   - ``tools/sstlint`` finds the lock registry STATICALLY (the factory

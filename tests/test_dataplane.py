@@ -10,7 +10,6 @@ Contracts under test:
     broadcast, never a per-group host np.tile + upload;
   - `pad_chunk` writes into one preallocated buffer, bit-identical to
     the old concatenate-then-repeat implementation (satellite pin);
-  - the staging ring (donate_chunk_buffers) keeps scores exact;
   - `search_report["dataplane"]` renders the pinned schema block.
 """
 
@@ -19,7 +18,6 @@ import warnings
 import numpy as np
 import pytest
 
-import jax
 
 import spark_sklearn_tpu as sst
 from spark_sklearn_tpu.parallel import dataplane as dp
@@ -155,44 +153,6 @@ class TestPadChunkPinned:
         expected = self._reference(arr, lo, hi, width, repeat)
         np.testing.assert_array_equal(
             pad_chunk(arr, lo, hi, width, repeat), expected)
-        # and through a caller-owned preallocated buffer
-        out = np.empty((width * repeat,) + arr.shape[1:], arr.dtype)
-        got = pad_chunk(arr, lo, hi, width, repeat, out=out)
-        assert got is out
-        np.testing.assert_array_equal(got, expected)
-
-    def test_out_shape_mismatch_raises(self):
-        arr = np.zeros(8, np.float32)
-        with pytest.raises(ValueError, match="out buffer"):
-            pad_chunk(arr, 0, 4, 8, out=np.empty(7, np.float32))
-
-
-class TestStagingRing:
-    def test_slots_cycle_and_reuse_on_copying_backend(self, monkeypatch):
-        # force the copying-backend path (TPU/GPU semantics): slots
-        # cycle and are reused after their consumer's transfer
-        monkeypatch.setattr(dp, "_DEVICE_PUT_COPIES", True)
-        ring = dp.StagingRing(slots=2)
-        s1 = ring.slot("k", (4,), np.float32)
-        s2 = ring.slot("k", (4,), np.float32)
-        assert s1 is not s2
-        s1.array[:] = 1.0
-        s1.commit(jax.device_put(s1.array))
-        s3 = ring.slot("k", (4,), np.float32)   # wraps to slot 1
-        assert s3 is s1 and s3.consumer is None
-        # a different shape gets its own ring
-        s4 = ring.slot("k", (8,), np.float32)
-        assert s4 is not s1 and s4.array.shape == (8,)
-
-    def test_aliasing_backend_never_reuses(self, monkeypatch):
-        # XLA:CPU may alias host memory into device arrays: a pending
-        # launch reads the buffer at execute time, so the ring must
-        # hand out FRESH buffers there (correctness over reuse)
-        monkeypatch.setattr(dp, "_DEVICE_PUT_COPIES", False)
-        ring = dp.StagingRing(slots=2)
-        slots = [ring.slot("k", (4,), np.float32) for _ in range(4)]
-        assert len({id(s) for s in slots}) == 4
-        assert len({id(s.array) for s in slots}) == 4
 
 
 class TestDataPlaneSearchIntegration:
@@ -220,16 +180,6 @@ class TestDataPlaneSearchIntegration:
         assert d_off["mask_tiling"] in ("host", "n/a")
         _assert_exact_equal(_non_time_results(on),
                             _non_time_results(off))
-
-    def test_donate_staging_ring_parity(self, digits):
-        X, y = digits
-        Xs, ys = X[:240], y[:240]
-        grid = {"C": np.logspace(-2, 1, 40).tolist()}
-        base = _fit(Xs, ys, grid)
-        ringed = _fit(Xs, ys, grid, donate_chunk_buffers=True,
-                      pipeline_depth=2)
-        _assert_exact_equal(_non_time_results(base),
-                            _non_time_results(ringed))
 
     def test_report_block_schema_keys(self):
         from spark_sklearn_tpu.obs.metrics import DATAPLANE_BLOCK_SCHEMA

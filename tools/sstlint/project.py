@@ -229,9 +229,6 @@ class Project:
                                    "hits", "misses", "evictions",
                                    "bytes_uploaded", "bytes_tiled",
                                    "bytes_derived", "byte_budget")),
-                SharedState("parallel/dataplane.py",
-                            "dataplane.StagingRing._lock",
-                            cls="StagingRing", attrs=("_rings",)),
                 # the process ledger: totals, records and the
                 # persistent-cache event counters, fed by jax's
                 # monitoring callbacks on whichever thread builds
@@ -258,9 +255,11 @@ class Project:
                             cls="GeometryCostModel",
                             attrs=("launch_overhead_s", "lane_cost_s",
                                    "compile_wall_s", "n_observations")),
-                # grid: per-plan staged-chunk id sets
-                SharedState("search/grid.py", "grid.stage_lock",
-                            taint_key="staged_ids"),
+                # schedule: a group plan's staged-chunk id set, noted by
+                # the stage thread and by a retry's recovering thread
+                SharedState("search/schedule.py",
+                            "schedule.GroupPlan._lock", cls="GroupPlan",
+                            attrs=("staged_ids",)),
                 # grid: the cross-search program cache, hit by every
                 # concurrent search's worker + compile threads
                 SharedState("search/grid.py", "grid._PROGRAM_CACHE_LOCK",
@@ -360,7 +359,8 @@ class Project:
                 BlockSpec("pipeline", "PIPELINE_BLOCK_SCHEMA", (
                     Producer("dict-keys", "parallel/pipeline.py",
                              "ChunkPipeline.report"),
-                    Producer("subscript-var", "search/grid.py", "pr"),
+                    Producer("subscript-var", "search/schedule.py",
+                             "pr"),
                 )),
                 BlockSpec("dataplane", "DATAPLANE_BLOCK_SCHEMA", (
                     Producer("dict-keys", "parallel/dataplane.py",
@@ -434,15 +434,19 @@ class Project:
                 "parallel/pipeline.py",
                 "serve/executor.py",
                 "search/grid.py::_dispatch",
-                "search/grid.py::submit_precompile",
-                "search/grid.py::resolve_fused",
-                "search/grid.py::exec_fused_range",
-                "search/grid.py::attempt",
                 "search/grid.py::guarded_launch",
                 "search/grid.py::guarded_wait",
-                "search/grid.py::host_eval",
+                "search/grid.py::_host_eval",
+                "search/schedule.py::submit_precompile",
+                "search/schedule.py::resolve_fused",
+                "search/schedule.py::exec_fused_range",
+                "search/schedule.py::_relaunch",
             ),
             env_field_exceptions={
+                "SST_NESTED_SCORE": (
+                    "the nested score path's A/B control arm "
+                    "(tests/test_score_parity.py, tools/score_ab.py); "
+                    "one spelling, read once a search"),
                 "SST_LOCKCHECK": (
                     "process-wide test-harness toggle; the lock shim "
                     "must exist before any TpuConfig is constructed"),
